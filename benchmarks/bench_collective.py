@@ -22,10 +22,10 @@ def main():
 
     from dmlc_core_tpu.collective.mesh_collectives import (
         allreduce_bandwidth_gbps)
+    from dmlc_core_tpu.device import init_device
     from dmlc_core_tpu.parallel.mesh import make_mesh
-    from dmlc_core_tpu.utils.platform import sync_platform_from_env
 
-    sync_platform_from_env()
+    init_device()
     args = sys.argv[1:]
     ndev = len(jax.devices())
     axis = int(args[0]) if args else ndev

@@ -802,12 +802,12 @@ def bench_infeed(uri, record_bytes=600, batch=256):
     import jax
     import numpy as np
 
+    from dmlc_core_tpu.device import init_device
     from dmlc_core_tpu.io.input_split import create_input_split
     from dmlc_core_tpu.io.recordio import RecordIOChunkReader
-    from dmlc_core_tpu.utils.platform import sync_platform_from_env
     from dmlc_core_tpu.utils.profiler import ThroughputMeter
 
-    sync_platform_from_env()
+    init_device()
     record_bytes, batch = int(record_bytes), int(batch)
     device = jax.devices()[0]
     split = create_input_split(uri, 0, 1, type="recordio")

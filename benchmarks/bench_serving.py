@@ -112,7 +112,8 @@ NUM_FEATURE = 16
 
 def _host_info():
     return {"cores": os.cpu_count(), "python": platform.python_version(),
-            "platform": platform.platform()}
+            "platform": platform.platform(),
+            "jax_platforms": os.environ["JAX_PLATFORMS"]}
 
 
 def _start_server(max_batch, max_delay_ms, max_queue_bytes=None):
@@ -238,7 +239,6 @@ def _launch_server_subprocess(extra_args=(), extra_env=None):
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env.update(extra_env or {})
     cmd = [sys.executable, "-m", "dmlc_core_tpu.serve", "--model",
            "linear", "--num-feature", str(NUM_FEATURE), "--port", "0",
@@ -655,7 +655,6 @@ def run_continuous(args) -> int:
         env = dict(os.environ)
         env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH",
                                                              "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
         if plan_active:
             env["DMLC_FAULT_PLAN"] = "@" + os.path.abspath(plan_path)
         state_path = os.path.join(ckpt, f"state-{inc}.json")
@@ -1256,6 +1255,13 @@ def main(argv=None) -> int:
     ab.add_argument("--duration", type=float, default=6.0)
     ab.add_argument("--rows", type=int, default=2)
     args = p.parse_args(argv)
+    # every drill here is a CPU *correctness* drill: a parent plus server /
+    # replica / trainer children that would each need a device of their
+    # own.  Pinned for this process before it touches jax, inherited by
+    # every child, and stated in each report's host block.
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    print("bench_serving: CPU correctness drill — platform: cpu "
+          "(JAX_PLATFORMS=cpu pinned for this process and its children)")
     if args.cmd == "c10k":
         return run_c10k(args)
     if args.cmd == "evloop-ab":

@@ -49,10 +49,7 @@ class MeshCollective:
     def _shard_map(self, fn, in_spec, out_spec):
         import jax
 
-        from dmlc_core_tpu.parallel.compat import get_shard_map
-
-        shard_map = get_shard_map()
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             fn, mesh=self.mesh, in_specs=in_spec, out_specs=out_spec))
 
     def _allreduce_fn(self, op: str):
@@ -194,9 +191,6 @@ def _build_ring_allreduce(mesh, axis: str):
     import jax.numpy as jnp  # noqa: F401
     from jax.sharding import PartitionSpec as P
 
-    from dmlc_core_tpu.parallel.compat import get_shard_map
-
-    shard_map = get_shard_map()
     n = mesh.shape[axis]
     perm_fwd = [(i, (i + 1) % n) for i in range(n)]
 
@@ -226,8 +220,8 @@ def _build_ring_allreduce(mesh, axis: str):
         segs = lax.fori_loop(0, n - 1, ag_step, segs)
         return segs.reshape((-1,) + x.shape[1:])
 
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=P(axis),
-                             out_specs=P(axis)))
+    return jax.jit(jax.shard_map(kernel, mesh=mesh, in_specs=P(axis),
+                                 out_specs=P(axis)))
 
 
 def allreduce_bandwidth_gbps(mesh, axis: str, nbytes: int = 64 << 20,

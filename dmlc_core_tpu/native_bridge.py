@@ -2,9 +2,12 @@
 
 The reference's hot byte path is C++ (src/data/); here the same role is played
 by ``libdmlc_tpu_native.so``: multi-threaded chunk parsers returning numpy
-arrays.  The library is built from ``native/`` with ``make`` on first use
-(g++ is in the image); every caller falls back to the numpy path when the
-library is unavailable, so the pure-Python package remains fully functional.
+arrays.  ``make -C native`` runs on first load (a no-op when the library is
+newer than its sources), so what gets loaded always comes from the
+committed ``native/*.cc`` — never a stale git-ignored ``.so`` that was
+copied along with the tree.  Every caller falls back to the numpy path
+when the library cannot be built (no compiler on the host), so the
+pure-Python package remains fully functional.
 """
 
 from __future__ import annotations
@@ -16,6 +19,8 @@ import threading
 from typing import Optional, Tuple
 
 import numpy as np
+
+from dmlc_core_tpu.utils.logging import log_warning
 
 __all__ = ["available", "parse_libsvm", "parse_libfm", "parse_csv",
            "find_magic_positions"]
@@ -30,11 +35,16 @@ _SO_PATH = os.path.join(_NATIVE_DIR, "libdmlc_tpu_native.so")
 
 
 def _build() -> bool:
+    """Bring the library up to date with ``native/*.cc`` (make's own
+    timestamps make this a no-op when it already is)."""
     try:
         subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
                        capture_output=True, timeout=300)
         return os.path.exists(_SO_PATH)
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as exc:
+        detail = getattr(exc, "stderr", b"") or b""
+        log_warning(f"native core not built ({exc!r}); numpy parsers in "
+                    f"use. {detail.decode(errors='replace')[-500:]}")
         return False
 
 
@@ -46,7 +56,7 @@ def _load() -> Optional[ctypes.CDLL]:
         _tried = True
         if os.environ.get("DMLC_TPU_DISABLE_NATIVE"):
             return None
-        if not os.path.exists(_SO_PATH) and not _build():
+        if not _build():
             return None
         try:
             lib = ctypes.CDLL(_SO_PATH)

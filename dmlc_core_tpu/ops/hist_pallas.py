@@ -23,6 +23,8 @@ exactly (same bf16 one-hot / bf16 W / f32 accumulate).
 
 Used automatically on TPU via ``resolve_hist_method("auto")`` when the
 histogram block fits VMEM; falls back to the plain one-hot matmul otherwise.
+On a TPU backend a kernel Mosaic rejects raises with the compiler's message
+— nothing here probes-and-swallows on behalf of ``auto``.
 """
 
 from __future__ import annotations
@@ -31,18 +33,41 @@ import functools
 
 import numpy as np
 
+from dmlc_core_tpu.utils.logging import log_warning
+
 __all__ = ["hist_matmul_pallas", "grad_hist_pallas",
            "grad_hist_pallas_fused", "grad_hist_pallas_sharded",
-           "ambient_mesh", "sharded_hist_plan", "pallas_supported",
+           "ambient_mesh", "hist_kernel_plan", "fit_row_multiple",
+           "interpret_mode",
            "pallas_fused_supported", "pallas_i8_supported", "hist_fits_vmem",
            "hist_node_block", "BLOCK_ROWS", "DATA_AXIS"]
 
 # interpreter mode: runs the kernels on CPU for tests/debugging (flipped by
-# tests, or set DMLC_TPU_PALLAS_INTERPRET=1 to debug without a chip)
+# tests, or set DMLC_TPU_PALLAS_INTERPRET=1 to debug without a chip).
+# Read through interpret_mode(), which refuses it on a TPU backend.
 import os as _os
 
 _INTERPRET = _os.environ.get("DMLC_TPU_PALLAS_INTERPRET",
                              "").strip().lower() in ("1", "true", "yes")
+
+
+def interpret_mode() -> bool:
+    """Whether kernels run in the Pallas interpreter (tests, CPU debugging).
+
+    On a TPU backend the answer is never yes: an interpreted kernel there
+    would pass every check while the Mosaic kernel — the thing the chip is
+    for — never compiled, so the request is refused instead of honoured.
+    """
+    if not _INTERPRET:
+        return False
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "Pallas interpret mode (DMLC_TPU_PALLAS_INTERPRET / "
+            "hist_pallas._INTERPRET) is refused on a TPU backend: the "
+            "kernels must compile through Mosaic there")
+    return True
 
 # row-tile size: callers that want the wrapper's internal padding to no-op
 # (e.g. GBDT's fit-level padding) must pad to a multiple of this.
@@ -183,7 +208,7 @@ def hist_matmul_pallas(w, bins, num_bins: int, block_rows: int = BLOCK_ROWS):
         out_specs=pl.BlockSpec((m, bf * num_bins), lambda i: (0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, bf * num_bins), jnp.float32),
-        interpret=_INTERPRET,
+        interpret=interpret_mode(),
     )(w, bins)
 
 
@@ -286,7 +311,7 @@ def grad_hist_pallas_fused(bins, node_ids, grad, hess, num_nodes: int,
         out_specs=pl.BlockSpec((m, bf * num_bins), lambda i: (0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, bf * num_bins), jnp.float32),
-        interpret=_INTERPRET,
+        interpret=interpret_mode(),
     )(node, g, h, bins)
     return _split_gh(out, n_pad, num_nodes, bf, num_bins)
 
@@ -297,122 +322,131 @@ DATA_AXIS = "data"
 
 
 def ambient_mesh():
-    """The Mesh of an enclosing ``with mesh:`` block, or None.
+    """The Mesh of an enclosing ``with mesh:`` block, or None outside one.
 
     grad_histogram reads this at trace time to shard_map the kernel for
-    model-parallel runs; callers opt in simply by tracing under their mesh
-    (the convention every sharded path in this package already follows).
-    Guarded: if a jax upgrade moves the thread-resources accessor, model-
-    sharded callers degrade to the onehot fallback instead of crashing.
+    sharded runs; callers opt in simply by tracing under their mesh (the
+    convention every sharded path in this package already follows).  One
+    accessor, the one the installed jax keeps the ``with mesh:`` stack in;
+    if an upgrade moves it this raises (and tests/test_hist_pallas.py pins
+    it) rather than quietly un-sharding the kernel.
     """
-    try:
-        from jax._src import mesh as mesh_lib
+    from jax._src import mesh as mesh_lib
 
-        m = mesh_lib.thread_resources.env.physical_mesh
-    except Exception:
-        try:
-            from jax.interpreters import pxla
-
-            m = pxla.thread_resources.env.physical_mesh
-        except Exception:
-            return None
+    m = mesh_lib.thread_resources.env.physical_mesh
     return None if m.empty else m
 
 
-def sharded_hist_plan(model_axis, num_feature: int, num_nodes: int,
-                      num_bins: int, batch=None, mesh=None):
-    """The mesh to shard_map the hist kernel over, or None to fall back.
+def _data_parallelism(mesh) -> int:
+    return 1 if mesh is None else mesh.shape.get(DATA_AXIS, 1)
 
-    Single source of truth for the model-sharded-pallas gate (used by both
-    ``grad_histogram`` and ``GBDT._method`` so the two can't drift): requires
-    an ambient (or given) mesh carrying ``model_axis``, features dividing
-    evenly across it, rows dividing across the data axis (``batch=None``
-    skips that check for callers that pad rows later), and the per-shard
-    ``F/mp`` slice supporting at least a node-blocked accumulator (deep
-    levels sweep node blocks inside each shard, same as unsharded).
+
+def fit_row_multiple() -> int:
+    """Row count a fit pads to ONCE so no kernel call pads again: the tile
+    size times the ambient data axis (each data shard must itself be a
+    whole number of tiles once the kernel runs under shard_map)."""
+    return BLOCK_ROWS * _data_parallelism(ambient_mesh())
+
+
+def hist_kernel_plan(method: str, model_axis, num_feature: int,
+                     num_nodes: int, num_bins: int, batch=None):
+    """Settle a ``pallas``/``pallas_fused`` request against the shapes and
+    the ambient mesh.  Returns ``(method, mesh)``: the method that will
+    actually run and the mesh to shard_map the kernel over (None = one
+    plain kernel call).
+
+    Single source of truth for ``GBDT._method`` (decides once per fit, for
+    the deepest level, so an ``onehot`` outcome still amortises its matmul
+    RHS across rounds) and ``grad_histogram`` (per level) — the two cannot
+    drift.
+
+    - A Mosaic kernel has no GSPMD partitioning rule; on a TPU, jit refuses
+      one over sharded operands ("Mosaic kernels cannot be automatically
+      partitioned").  So under a mesh that actually shards — a
+      ``model_axis``, or a data axis wider than one device — the kernel
+      runs inside shard_map: rows over data, features over model.
+    - ``onehot`` (a GSPMD-partitionable matmul) takes over only where the
+      shapes forbid the kernel: even an 8-node block of the per-shard
+      ``F/mp`` slice overflows VMEM, features do not divide the model
+      axis, rows do not divide the data axis (``batch=None`` skips that
+      check for callers that pad rows later), or a ``model_axis`` is named
+      with no mesh to find it in.
+    - Node-blocked sweeps have no fused variant.
     """
-    if model_axis is None:
-        return None
-    if mesh is None:
-        mesh = ambient_mesh()
-    if mesh is None:
-        return None
-    mp = mesh.shape.get(model_axis)
-    dp = mesh.shape.get(DATA_AXIS, 1)
-    if (mp is None or num_feature % mp != 0
-            or (batch is not None and batch % dp != 0)
-            or hist_node_block(num_nodes, num_feature // mp, num_bins)
-            is None):
-        return None
-    return mesh
+    mesh = ambient_mesh()
+    dp = _data_parallelism(mesh)
+    mp = 1
+    if model_axis is not None:
+        mp = None if mesh is None else mesh.shape.get(model_axis)
+        if mp is None:
+            return "onehot", None
+    sharded = model_axis is not None or dp > 1
+    if sharded and (num_feature % mp != 0
+                    or (batch is not None and batch % dp != 0)):
+        return "onehot", None
+    block = hist_node_block(num_nodes, num_feature // mp, num_bins)
+    if block is None:
+        return "onehot", None
+    if block < num_nodes and method == "pallas_fused":
+        method = "pallas"
+    return method, (mesh if sharded else None)
 
 
 def grad_hist_pallas_sharded(bins, node_ids, grad, hess, num_nodes: int,
-                             num_bins: int, mesh, model_axis: str,
+                             num_bins: int, mesh, model_axis=None,
                              data_axis: str = DATA_AXIS,
                              fused: bool = False):
     """shard_map-wrapped VMEM hist: rows dp-sharded, features model-sharded.
 
-    Keeps the Pallas kernel under tensor parallelism (SURVEY §2.9) instead of
-    falling back to the HBM-tiled one-hot matmul: each model shard slices its
-    own ``F/mp`` feature columns (bins arrive feature-replicated), runs the
-    VMEM kernel on its local row shard, and psums partial histograms over the
-    data axis.  Output is ``P(None, model_axis, None)`` — exactly the
-    constraint the GSPMD path advertises, so split-finding code downstream is
-    unchanged.
+    The only way the Pallas kernel runs on more than one device: each shard
+    runs the VMEM kernel on its local rows and partial histograms are
+    psummed over the data axis — the distributed-hist aggregation XGBoost
+    does over Rabit.  With a ``model_axis`` each model shard also slices its
+    own ``F/mp`` feature columns (bins arrive feature-replicated) and the
+    output is ``P(None, model_axis, None)`` — exactly the constraint the
+    GSPMD path advertises, so split-finding code downstream is unchanged;
+    without one the output is replicated.
 
     Requires ``F % mesh.shape[model_axis] == 0``; callers check this (and the
-    per-shard VMEM fit) before dispatching here.
+    per-shard VMEM fit) through :func:`hist_kernel_plan` first.
     """
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from dmlc_core_tpu.parallel.compat import shard_map_unchecked
-
-    F = bins.shape[1]
-    mp = mesh.shape[model_axis]
-    f_local = F // mp
     row_axis = data_axis if data_axis in mesh.shape else None
     inner = grad_hist_pallas_fused if fused else grad_hist_pallas
+    f_local = (bins.shape[1] if model_axis is None
+               else bins.shape[1] // mesh.shape[model_axis])
 
     def local_hist(b, n, g, h):
-        idx = jax.lax.axis_index(model_axis)
-        b_local = jax.lax.dynamic_slice_in_dim(b, idx * f_local, f_local,
-                                               axis=1)
-        G, H = inner(b_local, n.astype(jnp.int32), g, h, num_nodes, num_bins)
+        if model_axis is not None:
+            idx = jax.lax.axis_index(model_axis)
+            b = jax.lax.dynamic_slice_in_dim(b, idx * f_local, f_local,
+                                             axis=1)
+        G, H = inner(b, n.astype(jnp.int32), g, h, num_nodes, num_bins)
         if row_axis is not None:
             G = jax.lax.psum(G, row_axis)
             H = jax.lax.psum(H, row_axis)
         return G, H
 
     out_spec = P(None, model_axis, None)
-    # unchecked variant: pallas_call's out_shape carries no vma annotation;
-    # the psum above already makes the outputs data-axis-invariant
-    return shard_map_unchecked(
-        local_hist, mesh,
+    # check_vma off: pallas_call's out_shape carries no vma annotation; the
+    # psum above already makes the outputs data-axis-invariant
+    return jax.shard_map(
+        local_hist, mesh=mesh,
         in_specs=(P(row_axis, None), P(row_axis), P(row_axis), P(row_axis)),
-        out_specs=(out_spec, out_spec),
+        out_specs=(out_spec, out_spec), check_vma=False,
     )(bins, node_ids, grad, hess)
 
 
-@functools.lru_cache(maxsize=None)
-def pallas_supported() -> bool:
-    """Probe once whether the Pallas TPU path compiles+runs on this backend."""
-    import jax
-
-    if jax.default_backend() == "cpu" and not _INTERPRET:
-        return False
-    try:
-        import jax.numpy as jnp
-
-        w = jnp.zeros((16, 128), jnp.bfloat16).at[0, 0].set(1.0)
-        bins = jnp.zeros((128, 2), jnp.int32)
-        out = jax.jit(lambda w, b: hist_matmul_pallas(w, b, 8,
-                                                      block_rows=128))(w, bins)
-        return bool(np.asarray(out)[0, 0] == 1.0)
-    except Exception:
-        return False
+def _probe_failed(what: str, exc: Exception) -> bool:
+    """A variant probe is allowed to say no — never silently: the variant
+    changes which kernel ``auto`` runs, so the compiler's reason is logged."""
+    reason = (str(exc).strip().splitlines() or [""])[0]
+    log_warning(f"hist_pallas: {what} rejected by the compiler, variant "
+                f"off ({type(exc).__name__}: {reason})")
+    return False
 
 
 @functools.lru_cache(maxsize=None)
@@ -421,38 +455,44 @@ def pallas_i8_supported() -> bool:
 
     Probed with a direct pallas_call (not through the wrappers, which would
     recurse into this gate): an int8 bins tile against the shared tile body.
-    Falls back to int32 bins when Mosaic rejects the int8 vector ops, and is
-    disabled outright by DMLC_TPU_HIST_I8=0 for A/B benchmarking.
+    Falls back to int32 bins — with a logged reason — when Mosaic rejects
+    the int8 vector ops (the v5e does: "Target does not support this
+    comparison"), and is disabled outright by DMLC_TPU_HIST_I8=0.
     """
     if _os.environ.get("DMLC_TPU_HIST_I8", "").strip() == "0":
         return False
     import jax
 
-    if jax.default_backend() == "cpu" and not _INTERPRET:
+    interpret = interpret_mode()
+    if jax.default_backend() == "cpu" and not interpret:
         return False
-    try:
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-        kernel = functools.partial(_kernel, num_feature=2, num_bins=8)
+    kernel = functools.partial(_kernel, num_feature=2, num_bins=8)
+    probe = jax.jit(lambda w, b: pl.pallas_call(
+        kernel,
+        grid=(1,),
+        in_specs=[pl.BlockSpec((16, 128), lambda i: (0, i),
+                               memory_space=pltpu.VMEM),
+                  pl.BlockSpec((128, 2), lambda i: (i, 0),
+                               memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((16, 16), lambda i: (0, 0),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((16, 16), jnp.float32),
+        interpret=interpret,
+    )(w, b))
+    # the gate is first consulted while a kernel wrapper is being traced:
+    # run the probe eagerly there, not as part of the caller's program
+    with jax.core.eval_context():
         w = jnp.zeros((16, 128), jnp.bfloat16).at[0, 0].set(1.0)
         bins = jnp.zeros((128, 2), jnp.int8)
-        out = jax.jit(lambda w, b: pl.pallas_call(
-            kernel,
-            grid=(1,),
-            in_specs=[pl.BlockSpec((16, 128), lambda i: (0, i),
-                                   memory_space=pltpu.VMEM),
-                      pl.BlockSpec((128, 2), lambda i: (i, 0),
-                                   memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec((16, 16), lambda i: (0, 0),
-                                   memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((16, 16), jnp.float32),
-            interpret=_INTERPRET,
-        )(w, b))(w, bins)
-        return bool(np.asarray(out)[0, 0] == 1.0)
-    except Exception:
-        return False
+        try:
+            out = np.asarray(probe(w, bins))
+        except Exception as exc:  # noqa: BLE001 — logged, never silent
+            return _probe_failed("int8 bin compares", exc)
+    return bool(out[0, 0] == 1.0)
 
 
 @functools.lru_cache(maxsize=None)
@@ -461,21 +501,24 @@ def pallas_fused_supported() -> bool:
 
     The fused kernel's in-VMEM bf16 concat at the n_pad=8 boundary (below the
     16-sublane tile) can fail to lower on real Mosaic even when
-    :func:`hist_matmul_pallas` compiles — probing only the plain kernel would
-    let a user-selected ``pallas_fused`` crash at first use.
+    :func:`hist_matmul_pallas` compiles.  ``auto`` never selects the fused
+    kernel; a user-selected ``pallas_fused`` that does not lower falls back
+    to ``pallas`` with the compiler's reason logged.
     """
-    if not pallas_supported():
-        return False
-    try:
-        import jax
-        import jax.numpy as jnp
+    import jax
 
+    if jax.default_backend() == "cpu" and not interpret_mode():
+        return False
+    import jax.numpy as jnp
+
+    probe = jax.jit(lambda b, n, g, h: grad_hist_pallas_fused(
+        b, n, g, h, num_nodes=4, num_bins=8, block_rows=128))
+    with jax.core.eval_context():
         bins = jnp.zeros((128, 2), jnp.int32)
         node = jnp.zeros((128,), jnp.int32)
         one = jnp.ones((128,), jnp.float32)
-        G, _ = jax.jit(lambda b, n, g, h: grad_hist_pallas_fused(
-            b, n, g, h, num_nodes=4, num_bins=8, block_rows=128))(
-                bins, node, one, one)
-        return bool(np.asarray(G)[0, 0, 0] == 128.0)
-    except Exception:
-        return False
+        try:
+            G = np.asarray(probe(bins, node, one, one)[0])
+        except Exception as exc:  # noqa: BLE001 — logged, never silent
+            return _probe_failed("the fused-W kernel", exc)
+    return bool(G[0, 0, 0] == 128.0)

@@ -45,9 +45,11 @@ def resolve_hist_method(method: str, *arrays) -> str:
     """Resolve ``"auto"`` to a concrete histogram algorithm.
 
     Prefers the committed platform of any input jax.Array, falling back to
-    ``jax.default_backend()``: on TPU/GPU the VMEM-resident Pallas kernel
-    when available (else the plain one-hot MXU matmul), scatter segment-sums
-    on CPU.
+    ``jax.default_backend()``: the VMEM-resident Pallas kernel on TPU,
+    scatter segment-sums on CPU, the plain one-hot matmul anywhere else.
+    Nothing is probed: on a TPU ``auto`` *means* ``pallas``, and a kernel
+    Mosaic rejects raises with the compiler's message instead of quietly
+    training through the HBM-bound ``onehot`` path.
     """
     if method != "auto":
         return method
@@ -64,11 +66,7 @@ def resolve_hist_method(method: str, *arrays) -> str:
                 continue
     if platform is None:
         platform = jax.default_backend()
-    if platform == "cpu":
-        return "scatter"
-    from dmlc_core_tpu.ops.hist_pallas import pallas_supported
-
-    return "pallas" if pallas_supported() else "onehot"
+    return {"cpu": "scatter", "tpu": "pallas"}.get(platform, "onehot")
 
 
 def bin_onehot(bins, num_bins: int, dtype=None):
@@ -303,43 +301,21 @@ def grad_histogram(bins, node_ids, grad, hess, num_nodes: int, num_bins: int,
     B, F = bins.shape
     method = resolve_hist_method(method, bins, grad)
     if method == "pallas_fused":
-        from dmlc_core_tpu.ops.hist_pallas import (pallas_fused_supported,
-                                                   pallas_supported)
+        from dmlc_core_tpu.ops.hist_pallas import pallas_fused_supported
 
         if not pallas_fused_supported():
             # the fused kernel can fail to lower on real Mosaic where the
-            # plain kernel still compiles (sub-16-sublane concat)
-            method = "pallas" if pallas_supported() else "onehot"
+            # plain kernel still compiles (sub-16-sublane concat); the
+            # probe logs the compiler's reason
+            method = "pallas"
     sharded_mesh = None
     if method in ("pallas", "pallas_fused"):
-        from dmlc_core_tpu.ops.hist_pallas import (hist_node_block,
-                                                   sharded_hist_plan)
+        from dmlc_core_tpu.ops.hist_pallas import hist_kernel_plan
 
-        if model_axis is None:
-            # the kernel keeps a [2n, F*nbins] accumulator resident in
-            # VMEM; deeper levels run in node blocks (plain kernel only —
-            # the blocked sweep has no fused variant), and only when even
-            # an 8-node block overflows does the matmul take over
-            block = hist_node_block(num_nodes, F, num_bins)
-            if block is None:
-                method = "onehot"
-            elif block < num_nodes and method == "pallas_fused":
-                method = "pallas"
-        else:
-            # model-sharded: pallas_call is not GSPMD-partitionable, but the
-            # kernel stays on via shard_map — each model shard runs it (node-
-            # blocked when deep) on its own F/mp feature slice
-            sharded_mesh = sharded_hist_plan(model_axis, F, num_nodes,
-                                             num_bins, batch=B)
-            if sharded_mesh is None:
-                method = "onehot"
-            elif method == "pallas_fused":
-                mp = sharded_mesh.shape[model_axis]
-                if hist_node_block(num_nodes, F // mp,
-                                   num_bins) < num_nodes:
-                    method = "pallas"   # blocked sweeps have no fused variant
+        method, sharded_mesh = hist_kernel_plan(method, model_axis, F,
+                                                num_nodes, num_bins, batch=B)
 
-    if method in ("pallas", "pallas_fused") and sharded_mesh is not None:
+    if sharded_mesh is not None:
         from dmlc_core_tpu.ops.hist_pallas import grad_hist_pallas_sharded
 
         G, H = grad_hist_pallas_sharded(

@@ -29,8 +29,11 @@ def make_mesh(axes: Optional[Dict[str, int]] = None, devices=None):
     """Build a Mesh from named axis sizes, e.g. ``{"data": 4, "model": 2}``.
 
     One axis may be -1 (inferred).  Default: 1-D ``data`` mesh over all
-    devices.  Uses ``mesh_utils.create_device_mesh`` so the assignment follows
-    the physical ICI topology when running on TPU.
+    devices.  On hardware ``mesh_utils.create_device_mesh`` assigns devices
+    along the physical ICI topology, and a failure there is a real error (a
+    silent reshape would lay named axes across the wrong links) and
+    propagates.  CPU devices have no topology: they are reshaped in id
+    order, which keeps axis semantics testable on a virtual mesh.
     """
     import jax
     from jax.experimental import mesh_utils
@@ -51,10 +54,10 @@ def make_mesh(axes: Optional[Dict[str, int]] = None, devices=None):
         sizes = [ndev // known if s == -1 else s for s in sizes]
     CHECK(int(np.prod(sizes)) == ndev,
           f"mesh axes {dict(zip(names, sizes))} do not cover {ndev} devices")
-    try:
-        dev_array = mesh_utils.create_device_mesh(sizes, devices=devices)
-    except Exception:
+    if all(d.platform == "cpu" for d in devices):
         dev_array = np.asarray(devices).reshape(sizes)
+    else:
+        dev_array = mesh_utils.create_device_mesh(sizes, devices=devices)
     return Mesh(dev_array, names)
 
 

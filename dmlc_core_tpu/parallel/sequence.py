@@ -109,17 +109,15 @@ def _ring_fn(mesh, axis: str, causal: bool, sm_scale):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from dmlc_core_tpu.parallel.compat import get_shard_map
-
     n = mesh.shape[axis]
-    shard_map = get_shard_map()
     spec = P(None, axis, None, None)
 
     def kernel(q, k, v):
         return _ring_attention_local(q, k, v, axis, n, causal, sm_scale)
 
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
-                             out_specs=spec))
+    return jax.jit(jax.shard_map(kernel, mesh=mesh,
+                                 in_specs=(spec, spec, spec),
+                                 out_specs=spec))
 
 
 def ring_attention(q, k, v, mesh, axis: str = "data", causal: bool = False,
@@ -140,10 +138,7 @@ def _ulysses_fn(mesh, axis: str, causal: bool, sm_scale):
     import jax.lax as lax
     from jax.sharding import PartitionSpec as P
 
-    from dmlc_core_tpu.parallel.compat import get_shard_map
-
     n = mesh.shape[axis]
-    shard_map = get_shard_map()
     spec = P(None, axis, None, None)
 
     def kernel(q, k, v):
@@ -160,8 +155,9 @@ def _ulysses_fn(mesh, axis: str, causal: bool, sm_scale):
         oh = reference_attention(qh, kh, vh, causal=causal, sm_scale=sm_scale)
         return to_seq(oh)
 
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
-                             out_specs=spec))
+    return jax.jit(jax.shard_map(kernel, mesh=mesh,
+                                 in_specs=(spec, spec, spec),
+                                 out_specs=spec))
 
 
 def ulysses_attention(q, k, v, mesh, axis: str = "data", causal: bool = False,

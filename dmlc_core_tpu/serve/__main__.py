@@ -161,16 +161,16 @@ def _raise_nofile_limit() -> None:
 
 def main(argv: Optional[List[str]] = None) -> int:
     args = make_parser().parse_args(argv)
-    # honor an explicit JAX_PLATFORMS request even under plugin-pinning
-    # images (the same discipline the examples follow)
-    from dmlc_core_tpu.utils.platform import sync_platform_from_env
-
-    sync_platform_from_env()
     _raise_nofile_limit()
     if args.replicas < 1:
         raise SystemExit(f"--replicas must be >= 1, got {args.replicas}")
     if args.replicas > 1:
+        # the router parent stays off JAX: a process that has touched the
+        # backend holds the chip, and its replicas could not
         return _run_replicated(args)
+    from dmlc_core_tpu.device import init_device
+
+    init_device()
     # a server without metrics cannot state its SLOs: collection on
     # unconditionally (flushing still needs DMLC_TELEMETRY_DIR)
     telemetry.enable()

@@ -15,10 +15,14 @@ move on.  Under an open-loop load storm this must record **zero**
 ``crashed`` client samples — the chaos gate ``bench_serving.py router``
 enforces.
 
-The fleet inherits the parent environment (so ``DMLC_TELEMETRY_DIR`` and
-``DMLC_FAULT_PLAN`` flow through to replicas), prepends the repo root to
-``PYTHONPATH``, and pins ``JAX_PLATFORMS`` to the parent's choice (cpu
-default) — the same launch discipline the continuous-training ring uses.
+The fleet inherits the parent environment unchanged (so
+``DMLC_TELEMETRY_DIR``, ``DMLC_FAULT_PLAN`` and ``JAX_PLATFORMS`` flow
+through to replicas — a replica runs on whatever the parent's environment
+names, never on a quietly defaulted CPU) and prepends the repo root to
+``PYTHONPATH``.  Every replica needs a device of its own: N > 1 replicas
+on a one-chip host cannot all start, and :meth:`ReplicaFleet.start` says
+so with the failed replica's own error instead of waiting out the
+readiness deadline.
 """
 
 from __future__ import annotations
@@ -79,8 +83,9 @@ class ReplicaFleet:
     ``per_replica_env``/``per_replica_args`` key on the replica index —
     how the chaos drill makes exactly one replica a straggler (its own
     ``DMLC_FAULT_PLAN``) without touching the others.  ``log_dir=None``
-    sends replica output to the void; the drills always pass a directory
-    so a failed gate has logs to read.
+    drops replica stdout and leaves stderr on the parent's (a replica
+    that cannot start must be able to say why); the drills always pass a
+    directory so a failed gate has logs to read.
     """
 
     def __init__(self, count: int, *, model: str = "linear",
@@ -175,7 +180,6 @@ class ReplicaFleet:
         env = dict(os.environ)
         env["PYTHONPATH"] = _REPO_ROOT + os.pathsep \
             + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env.update(self.extra_env)
         env.update(self.per_replica_env.get(i, {}))
         if self.log_dir:
@@ -187,8 +191,7 @@ class ReplicaFleet:
                     stdout=log_fh, stderr=subprocess.STDOUT)
         else:
             proc = subprocess.Popen(
-                self._argv(i), env=env,
-                stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+                self._argv(i), env=env, stdout=subprocess.DEVNULL)
         with self._lock:
             self._procs[i] = proc
             self._launches[i] += 1
@@ -201,7 +204,11 @@ class ReplicaFleet:
         for i in range(self.count):
             self._launch(i)
         if wait_healthy:
-            self.wait_healthy(timeout_s=timeout_s)
+            try:
+                self.wait_healthy(timeout_s=timeout_s)
+            except BaseException:
+                self.close()   # no orphans behind a failed start
+                raise
         if self.auto_restart:
             self._monitor = threading.Thread(
                 target=self._monitor_loop, name="fleet-monitor",
@@ -220,6 +227,8 @@ class ReplicaFleet:
                 payload = _probe_healthz(self.host, self.ports[i])
                 if payload is not None and payload.get("status") == "ok":
                     pending.discard(i)
+                else:
+                    self._raise_if_gone(i)
             if not pending:
                 return
             if clock.monotonic() >= deadline:
@@ -228,6 +237,26 @@ class ReplicaFleet:
                     f"{timeout_s:g}s (ports "
                     f"{[self.ports[i] for i in sorted(pending)]})")
             time.sleep(0.1)
+
+    def _raise_if_gone(self, i: int) -> None:
+        """A replica that has exited and that nobody will relaunch (fleet
+        start-up, or a supervised restart holding the slot) cannot become
+        healthy: fail now, with its own words, not at the deadline."""
+        with self._lock:
+            proc = self._procs[i]
+            supervised = self._monitor is not None and not self._paused[i]
+        code = None if proc is None else proc.poll()
+        if supervised or code is None:
+            return
+        said = "its stderr is above"
+        if self.log_dir:
+            with open(os.path.join(self.log_dir, f"replica-{i}.log"),
+                      "rb") as log_fh:
+                log_fh.seek(max(0, os.fstat(log_fh.fileno()).st_size - 2000))
+                said = "log tail:\n" + log_fh.read().decode(errors="replace")
+        raise RuntimeError(
+            f"replica {i} (pid {proc.pid}) exited rc={code} before "
+            f"becoming healthy on {self.url(i)}; {said}")
 
     def _monitor_loop(self) -> None:
         """Relaunch any replica whose process exits (unless its slot is

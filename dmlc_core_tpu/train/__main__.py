@@ -77,6 +77,9 @@ def main(argv=None) -> int:
                     choices=["logistic", "squared", "softmax"])
     args = ap.parse_args(argv)
 
+    from dmlc_core_tpu.device import init_device
+
+    init_device()
     param = GBDTParam()
     param.update({"learning_rate": args.learning_rate,
                   "max_depth": args.max_depth,

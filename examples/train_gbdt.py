@@ -73,10 +73,11 @@ def _fit_resumable(model, param, bins, y, args):
 
 
 def _fit_distributed(model, bins, y, collective):
-    """One GLOBAL data-parallel fit across the worker world (the
-    tests/test_distributed_gbdt.py path as a user-facing CLI): rows are
-    sharded across processes on a global mesh, histogram aggregation
-    compiles to collectives, and every rank holds the SAME ensemble.
+    """One GLOBAL data-parallel fit over every device of the worker world
+    (the tests/test_distributed_gbdt.py path as a user-facing CLI; one
+    process driving a multi-chip host takes it too): rows are sharded
+    over a global mesh, histogram aggregation compiles to collectives,
+    and every rank holds the SAME ensemble.
 
     Ranks' shard sizes differ by up to a row after InputSplit partitioning,
     so every rank pads to the max local count with weight-0 rows — inert in
@@ -189,10 +190,6 @@ def main():
 
     import jax
 
-    from dmlc_core_tpu.utils.platform import sync_platform_from_env
-
-    sync_platform_from_env()
-
     from dmlc_core_tpu.bridge.batching import dense_batches
     from dmlc_core_tpu.bridge.checkpoint import save_checkpoint
     from dmlc_core_tpu.data.factory import create_parser
@@ -204,8 +201,10 @@ def main():
     # jax.process_count() reflects the worker world only after
     # collective.init() has initialized jax.distributed
     from dmlc_core_tpu import collective
+    from dmlc_core_tpu.device import init_device
 
     collective.init()
+    init_device()   # after init(): jax.distributed must precede the backend
     part, nparts = local_shard_info()
     parser = create_parser(args.data, part, nparts, type="auto")
 
@@ -251,20 +250,27 @@ def main():
     bins = np.asarray(model.bin_features(x)).astype(np.int32)
 
     rounds_run = args.rounds
-    if nparts > 1:
-        # one GLOBAL model across the worker world; eval/resume flows are
-        # single-host features for now — error, never silently train
-        # per-shard models
-        if args.eval_data or args.checkpoint_dir:
-            ap.error("--eval-data/--checkpoint-dir are single-host flows; "
-                     "under a multi-worker launch the fit is one global "
-                     "data-parallel program")
+    ndev = jax.device_count()
+    single_device_flow = bool(args.eval_data or args.checkpoint_dir)
+    # eval/resume flows are single-device features for now.  Across workers
+    # that is an error — never silently train per-shard models; on one
+    # multi-chip host they run, and say what they left idle
+    if nparts > 1 and single_device_flow:
+        ap.error("--eval-data/--checkpoint-dir are single-host flows; "
+                 "under a multi-worker launch the fit is one global "
+                 "data-parallel program")
+    if ndev > 1 and single_device_flow:
+        print(f"note: --eval-data/--checkpoint-dir train on one device; "
+              f"{ndev - 1} of {ndev} devices stay idle")
+    elif ndev > 1:
+        # one GLOBAL model over every device: the worker world's, or a
+        # single process's own chips
         ensemble, acc, secs, global_rows = _fit_distributed(
             model, bins, y, collective)
         rows_per_sec = global_rows * rounds_run / secs
         print(f"trained {rounds_run} rounds on {global_rows} rows over "
-              f"{nparts} workers in {secs:.2f}s ({rows_per_sec:,.0f} "
-              f"rows/sec), train acc {acc:.4f}")
+              f"{nparts} workers ({ndev} devices) in {secs:.2f}s "
+              f"({rows_per_sec:,.0f} rows/sec), train acc {acc:.4f}")
         if args.checkpoint and part == 0:
             save_checkpoint(args.checkpoint, ensemble._asdict())
             print(f"checkpoint written to {args.checkpoint}")

@@ -35,13 +35,13 @@ def main():
     from dmlc_core_tpu import collective
     from dmlc_core_tpu.bridge.loader import MeshBatchLoader
     from dmlc_core_tpu.data.factory import create_parser
+    from dmlc_core_tpu.device import init_device
     from dmlc_core_tpu.models.linear import LinearModel, LinearParam
     from dmlc_core_tpu.parallel.mesh import local_shard_info, make_mesh
-    from dmlc_core_tpu.utils.platform import sync_platform_from_env
     from dmlc_core_tpu.utils.profiler import ThroughputMeter
 
-    sync_platform_from_env()
     collective.init()
+    init_device()   # after init(): jax.distributed must precede the backend
     part, nparts = local_shard_info()
     collective.tracker_print(f"starting logreg: {nparts} process(es)")
 

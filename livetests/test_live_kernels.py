@@ -14,18 +14,6 @@ import numpy as np
 import pytest
 
 
-@pytest.fixture(scope="module")
-def jx():
-    import jax
-
-    # skip (not error) when another conftest pinned the process to CPU —
-    # e.g. `pytest livetests/ tests/` collects this lane first but
-    # tests/conftest.py still forces the CPU platform process-wide
-    if jax.devices()[0].platform == "cpu":
-        pytest.skip("process is pinned to the CPU platform")
-    return jax
-
-
 def _scatter_ref(jx, bins, node_ids, grad, hess, num_nodes, num_bins):
     from dmlc_core_tpu.ops.histogram import grad_histogram
 
@@ -42,11 +30,17 @@ def _rand_problem(rows=4096, F=4, NB=32, num_nodes=4, seed=0):
     return bins, node_ids, grad, hess
 
 
-def test_probe_reports_supported(jx):
+def test_auto_means_pallas_and_interpret_is_refused(jx, monkeypatch):
+    """On the chip ``auto`` is the Mosaic kernel, unprobed, and the
+    interpreter cannot be switched on behind it."""
     from dmlc_core_tpu.ops import hist_pallas
+    from dmlc_core_tpu.ops.histogram import resolve_hist_method
 
-    assert hist_pallas.pallas_supported(), \
-        "pallas kernel must lower on a real chip"
+    assert resolve_hist_method("auto") == "pallas"
+    assert hist_pallas.interpret_mode() is False
+    monkeypatch.setattr(hist_pallas, "_INTERPRET", True)
+    with pytest.raises(RuntimeError, match="refused on a TPU backend"):
+        hist_pallas.interpret_mode()
 
 
 def test_grad_hist_matches_scatter_on_chip(jx):
@@ -130,7 +124,7 @@ def test_tiny_gbdt_fit_on_chip(jx):
     from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
     from dmlc_core_tpu.ops.histogram import apply_bins, resolve_hist_method
 
-    assert resolve_hist_method("auto") in ("pallas", "onehot")
+    assert resolve_hist_method("auto") == "pallas"
     rng = np.random.RandomState(0)
     rows, F = 8192, 8
     x = rng.randn(rows, F).astype(np.float32)

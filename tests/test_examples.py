@@ -16,11 +16,7 @@ def run_example(script, args, env_extra=None):
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
     env.update(env_extra or {})
-    # examples force cpu themselves only via env; patch through jax config
-    code = (f"import jax; jax.config.update('jax_platforms','cpu'); "
-            f"import runpy, sys; sys.argv = {[script] + args!r}; "
-            f"runpy.run_path({script!r}, run_name='__main__')")
-    return subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
+    return subprocess.run([sys.executable, script] + args, env=env, cwd=REPO,
                           capture_output=True, text=True, timeout=300)
 
 

@@ -15,12 +15,10 @@ from dmlc_core_tpu.ops.histogram import grad_histogram
 @pytest.fixture(autouse=True)
 def interpret_mode():
     hist_pallas._INTERPRET = True
-    hist_pallas.pallas_supported.cache_clear()
     hist_pallas.pallas_fused_supported.cache_clear()
     hist_pallas.pallas_i8_supported.cache_clear()
     yield
     hist_pallas._INTERPRET = False
-    hist_pallas.pallas_supported.cache_clear()
     hist_pallas.pallas_fused_supported.cache_clear()
     hist_pallas.pallas_i8_supported.cache_clear()
 
@@ -329,8 +327,8 @@ def test_ambient_mesh_probe_on_current_jax():
             + __import__("jax").__version__)
         assert m.shape["model"] == 2
         # and the single-source-of-truth gate selects the kernel with it
-        assert hist_pallas.sharded_hist_plan("model", 8, 4, 16,
-                                             batch=256) is m
+        assert hist_pallas.hist_kernel_plan("pallas", "model", 8, 4, 16,
+                                            batch=256) == ("pallas", m)
     assert hist_pallas.ambient_mesh() is None
 
 
@@ -402,3 +400,79 @@ def test_subsample_draw_independent_of_row_padding(interpret_mode):
         sfs.append(np.asarray(tree[0]))
     np.testing.assert_array_equal(np.stack(sfs),
                                   np.asarray(ens_fit.split_feat))
+
+
+def test_dp_only_mesh_runs_the_kernel_under_shard_map():
+    """A Mosaic kernel has no GSPMD partitioning rule — on a TPU, jit
+    refuses one over sharded operands — so rows sharded over a data axis
+    (no model axis) route through grad_hist_pallas_sharded: per-shard
+    kernel + psum over data.  Interpret mode lowers to ordinary ops and
+    could not show the refusal; the routing is what is pinned here."""
+    import jax
+    from dmlc_core_tpu.parallel.mesh import data_sharding, make_mesh
+
+    mesh = make_mesh({"data": 8})
+    bins, node, g, h = _rand_case(512, 8, 16, 4, seed=31)
+    # no mesh: one plain kernel call
+    assert hist_pallas.hist_kernel_plan("pallas", None, 8, 4, 16,
+                                        batch=512) == ("pallas", None)
+    assert hist_pallas.fit_row_multiple() == hist_pallas.BLOCK_ROWS
+    calls = []
+    orig = hist_pallas.grad_hist_pallas_sharded
+
+    def spy(*args, **kwargs):
+        calls.append(args[7])          # model_axis
+        return orig(*args, **kwargs)
+
+    hist_pallas.grad_hist_pallas_sharded = spy
+    try:
+        with mesh:
+            assert hist_pallas.hist_kernel_plan(
+                "pallas", None, 8, 4, 16, batch=512) == ("pallas", mesh)
+            # rows that do not divide the data axis cannot be shard_mapped
+            assert hist_pallas.hist_kernel_plan(
+                "pallas", None, 8, 4, 16, batch=510) == ("onehot", None)
+            assert hist_pallas.fit_row_multiple() \
+                == 8 * hist_pallas.BLOCK_ROWS
+            placed = [jax.device_put(a, data_sharding(mesh, ndim=a.ndim))
+                      for a in (bins, node, g, h)]
+            G, H = jax.jit(lambda *a: grad_histogram(
+                *a, 4, 16, method="pallas"))(*placed)
+            G, H = np.asarray(G), np.asarray(H)
+    finally:
+        hist_pallas.grad_hist_pallas_sharded = orig
+    assert calls == [None], "dp-only sharded kernel path was not taken"
+    Gr, Hr = grad_histogram(bins, node, g, h, 4, 16, method="scatter")
+    np.testing.assert_allclose(G, np.asarray(Gr), rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(H, np.asarray(Hr), rtol=2e-2, atol=2e-2)
+
+
+def test_gbdt_fit_on_a_dp_mesh_matches_the_one_device_kernel_fit():
+    """The whole compiled fit, rows sharded over 8 devices with the kernel
+    under shard_map, grows the trees the one-device kernel fit grows."""
+    import jax
+    from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
+    from dmlc_core_tpu.parallel.mesh import data_sharding, make_mesh
+
+    rng = np.random.RandomState(33)
+    n, F = 2000, 6                      # 2000 % (8 * 1024) != 0 -> fit pads
+    x = rng.randn(n, F).astype(np.float32)
+    y = (x[:, 0] * x[:, 1] > 0).astype(np.float32)
+    model = GBDT(GBDTParam(num_boost_round=3, max_depth=3, num_bins=16,
+                           hist_method="pallas"), num_feature=F)
+    model.make_bins(x)
+    bins = np.asarray(model.bin_features(x), np.uint8)
+    ens_one, margin_one = model.fit_binned(bins, y)
+    mesh = make_mesh({"data": 8})
+    with mesh:
+        assert model._fit_method(bins) == "pallas"
+        ens_dp, margin_dp = model.fit_binned(
+            jax.device_put(bins, data_sharding(mesh, ndim=2)),
+            jax.device_put(y, data_sharding(mesh)))
+        margin_dp = np.asarray(margin_dp)
+    np.testing.assert_array_equal(np.asarray(ens_dp.split_feat),
+                                  np.asarray(ens_one.split_feat))
+    np.testing.assert_array_equal(np.asarray(ens_dp.split_bin),
+                                  np.asarray(ens_one.split_bin))
+    np.testing.assert_allclose(margin_dp, np.asarray(margin_one),
+                               rtol=1e-4, atol=1e-4)

@@ -685,3 +685,26 @@ def test_local_submit_cleans_job_dir_when_staging_fails(tmp_path,
     with pytest.raises(RuntimeError, match="injected staging failure"):
         local_mod.submit(opts)
     assert made and not os.path.exists(made[0])
+
+
+def test_local_job_stops_at_once_when_a_task_fails_for_good(tmp_path):
+    """``--cluster local`` gives every task this host's devices; on a
+    one-chip host the second worker dies at backend init.  Its peers must
+    not wait out a rendezvous timeout for it: the job fails promptly and
+    names the task that failed first."""
+    import time
+
+    from tests.conftest import run_tracker_workers
+
+    script = (
+        "import os, sys, time\n"
+        "if os.environ['DMLC_TASK_ID'] == '1':\n"
+        "    sys.stderr.write('Unable to initialize backend: chip held\\n')\n"
+        "    sys.exit(3)\n"
+        "time.sleep(300)\n")
+    start = time.monotonic()
+    proc = run_tracker_workers(tmp_path, script, 2, timeout=120)
+    assert time.monotonic() - start < 60
+    assert proc.returncode != 0
+    assert "Unable to initialize backend: chip held" in proc.stderr
+    assert "task worker:1 failed with exit 3" in proc.stderr
