@@ -1,0 +1,106 @@
+"""XGBoost ``hist`` boosting in plain numpy float32: the fit cells' reference.
+
+An exact ``bincount`` gradient histogram and greedy level-wise split
+finding with the same parameters the program is given (max_depth, num_bins,
+eta, lambda, min_child_weight, objective).  No kernels, no bf16, no
+batching; it imports nothing from ``dmlc_core_tpu``.
+
+Split rule (XGBoost ``hist``, as the program documents it): at each level
+every node scores every (feature, threshold) by
+``GL^2/(HL+lam) + GR^2/(HR+lam) - GT^2/(HT+lam)``, both children need
+``H >= min_child_weight``, the last bin is never a threshold, the first
+maximum wins, and a node splits only if its best gain is > 0.  Rows go
+right when ``bin > threshold``.  Leaves take ``-G/(H+lam) * eta``.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+
+def histogram(bins, node, g, h, num_nodes, num_bins):
+    """Exact per-(node, feature, bin) sums of ``g`` and ``h``:
+    two ``[num_nodes, F, num_bins]`` float32 arrays.  Rows whose node id is
+    outside ``[0, num_nodes)`` count nowhere."""
+    n, f = bins.shape
+    live = (node >= 0) & (node < num_nodes)
+    flat = ((node[live, None].astype(np.int64) * f + np.arange(f)) * num_bins
+            + bins[live].astype(np.int64)).ravel()
+    size = num_nodes * f * num_bins
+    out = []
+    for v in (g, h):
+        w = np.repeat(v[live].astype(np.float64), f)
+        out.append(np.bincount(flat, weights=w, minlength=size)
+                   .reshape(num_nodes, f, num_bins).astype(np.float32))
+    return out
+
+
+def grad_hess(margin, label, objective):
+    if objective == "logistic":
+        p = 1.0 / (1.0 + np.exp(-margin))
+        return (p - label).astype(np.float32), (p * (1 - p)).astype(
+            np.float32)
+    return (margin - label).astype(np.float32), np.ones_like(margin)
+
+
+def logloss(margin, label):
+    """Mean binary cross-entropy of logistic margins, float64."""
+    m = margin.astype(np.float64)
+    return float(np.mean(np.logaddexp(0.0, m) - label * m))
+
+
+def build_tree(bins, g, h, max_depth, num_bins, reg_lambda,
+               min_child_weight, learning_rate):
+    """Grow one tree; returns ``(split_feat, split_bin, leaf_value,
+    margin_delta)`` in the level-order layout (``-1`` = no split)."""
+    n, f = bins.shape
+    n_internal = 2 ** max_depth - 1
+    split_feat = np.full(n_internal, -1, np.int32)
+    split_bin = np.zeros(n_internal, np.int32)
+    node = np.zeros(n, np.int64)
+    lam = np.float32(reg_lambda)
+    for depth in range(max_depth):
+        n_nodes = 2 ** depth
+        G, H = histogram(bins, node, g, h, n_nodes, num_bins)
+        GL, HL = np.cumsum(G, -1), np.cumsum(H, -1)
+        GT, HT = GL[..., -1:], HL[..., -1:]
+        GR, HR = GT - GL, HT - HL
+        gain = (GL * GL / (HL + lam) + GR * GR / (HR + lam)
+                - GT * GT / (HT + lam))
+        valid = (HL >= min_child_weight) & (HR >= min_child_weight)
+        valid[..., num_bins - 1] = False
+        gain = np.where(valid, gain, -np.inf).reshape(n_nodes, -1)
+        best = np.argmax(gain, axis=1)
+        best_gain = gain[np.arange(n_nodes), best]
+        do_split = best_gain > 0
+        sf = np.where(do_split, best // num_bins, -1).astype(np.int32)
+        sb = (best % num_bins).astype(np.int32)
+        lvl = n_nodes - 1 + np.arange(n_nodes)
+        split_feat[lvl], split_bin[lvl] = sf, sb
+        nf = sf[node]
+        row_bin = bins[np.arange(n), np.maximum(nf, 0)].astype(np.int64)
+        go_right = (row_bin > sb[node]) & (nf >= 0)
+        node = node * 2 + go_right
+    n_leaf = 2 ** max_depth
+    Gl = np.bincount(node, weights=g.astype(np.float64), minlength=n_leaf)
+    Hl = np.bincount(node, weights=h.astype(np.float64), minlength=n_leaf)
+    leaf = (-Gl / (Hl + reg_lambda) * learning_rate).astype(np.float32)
+    return split_feat, split_bin, leaf, leaf[node]
+
+
+def boost(bins, label, rounds, *, max_depth, num_bins, learning_rate,
+          reg_lambda, min_child_weight, objective="logistic",
+          base_score=0.0):
+    """``rounds`` boosting rounds; returns ``(trees, margin)`` where trees
+    is a list of ``(split_feat, split_bin, leaf_value)``."""
+    margin = np.full(bins.shape[0], base_score, np.float32)
+    label = label.astype(np.float32)
+    trees = []
+    for _ in range(rounds):
+        g, h = grad_hess(margin, label, objective)
+        sf, sb, leaf, delta = build_tree(bins, g, h, max_depth, num_bins,
+                                         reg_lambda, min_child_weight,
+                                         learning_rate)
+        trees.append((sf, sb, leaf))
+        margin = margin + delta
+    return trees, margin

@@ -1,0 +1,43 @@
+"""Score rows by walking published trees in plain numpy: the score cell's
+reference.  Takes the arrays as they were published (level-order
+``split_feat`` / ``split_bin`` / ``leaf_value``, quantile ``boundaries``)
+and imports nothing from the code under test."""
+
+from __future__ import annotations
+
+import numpy as np
+
+
+def bin_rows(x, boundaries):
+    """``searchsorted(boundaries[f], x[:, f], side="right")`` per feature:
+    the binning contract both the trainer and the server state."""
+    x = np.asarray(x, np.float32)
+    out = np.empty(x.shape, np.int64)
+    for f in range(x.shape[1]):
+        out[:, f] = np.searchsorted(boundaries[f], x[:, f], side="right")
+    return out
+
+
+def margins(bins, split_feat, split_bin, leaf_value, base_score=0.0):
+    """Sum over trees of the leaf each row reaches.  ``split_feat[t, i] ==
+    -1`` means node ``i`` does not split: the row falls to child ``2i``."""
+    n = bins.shape[0]
+    depth = int(np.log2(leaf_value.shape[1]))
+    rows = np.arange(n)
+    out = np.full(n, base_score, np.float64)
+    for sf, sb, leaf in zip(split_feat, split_bin, leaf_value):
+        node = np.zeros(n, np.int64)
+        for d in range(depth):
+            at = 2 ** d - 1 + node
+            f = sf[at]
+            go_right = (bins[rows, np.maximum(f, 0)] > sb[at]) & (f >= 0)
+            node = node * 2 + go_right
+        out += leaf[node].astype(np.float64)
+    return out
+
+
+def predict_logistic(x, boundaries, split_feat, split_bin, leaf_value,
+                     base_score=0.0):
+    m = margins(bin_rows(x, boundaries), split_feat, split_bin, leaf_value,
+                base_score)
+    return 1.0 / (1.0 + np.exp(-m))

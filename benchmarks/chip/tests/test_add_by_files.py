@@ -1,0 +1,93 @@
+"""A later PR adds a configuration, a cell, a traffic kind and a per-layer
+metric as NEW FILES plus manifest entries; nothing that exists is edited.
+Shown here by building such an addition in a scratch tree and running it
+through the unchanged harness."""
+
+import json
+import shutil
+import textwrap
+
+import jax
+
+from benchmarks.chip import harness, layer_metrics, tracereduce, traffic
+from benchmarks.chip.tests import rehearsal
+
+KIND = '''
+def setup(ctx):
+    return {"ticks": ctx.config["ticks"]}
+
+def window(ctx, state, t_start):
+    return {"setup_s": 0.25, "attempted": state["ticks"], "failed": 0}
+
+def check(ctx, state, window):
+    yield True, "a dummy is always right"
+
+def end_to_end(ctx, state, window):
+    return {"setup_s": window["setup_s"], "ticks_per_s": 4.0}
+'''
+
+METRIC = '''
+NAME, UNIT, LAYER, MOVES = "dummy_ticks", "ticks", "dummy layer", "ticks_per_s"
+KINDS = ("dummy",)
+
+def reduce(evidence):
+    return float(evidence["window"]["attempted"])
+'''
+
+
+def test_a_cell_a_config_a_kind_and_a_metric_arrive_as_files(
+        tmp_path, monkeypatch):
+    root = tmp_path / "checkout"
+    here = root / "benchmarks" / "chip"
+    shutil.copytree(harness.HERE, here,
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    before = {p.relative_to(here): p.read_bytes()
+              for p in here.rglob("*") if p.is_file()}
+    # the addition: four new files ...
+    (here / "configs" / "dummy.json").write_text(json.dumps(
+        {"name": "dummy", "ticks": 3}))
+    (here / "workloads" / "dummy.tick.json").write_text(json.dumps(
+        {"kind": "dummy", "config": "dummy", "chips": 1}))
+    (here / "traffic" / "dummy.py").write_text(textwrap.dedent(KIND))
+    (here / "layer_metrics" / "dummy_ticks.py").write_text(
+        textwrap.dedent(METRIC))
+    # ... and entries appended to the manifest
+    manifest = harness.load_manifest()
+    manifest["configs"].append({"name": "dummy", "source": "none",
+                                "file": "benchmarks/chip/configs/dummy.json",
+                                "reduced": [], "why": "a test"})
+    manifest["workloads"].append({"name": "dummy.tick", "config": "dummy",
+                                  "traffic": "tick", "chips": 1,
+                                  "why": "a test"})
+    manifest["end_to_end"].append({"name": "ticks_per_s", "unit": "ticks/s",
+                                   "workloads": ["dummy.tick"]})
+    manifest["per_layer"].append({"name": "dummy_ticks", "unit": "ticks",
+                                  "workloads": ["dummy.tick"]})
+    assert all(p.read_bytes() == old for p, old in
+               ((here / rel, old) for rel, old in before.items()))
+
+    # the unchanged harness finds all four by name
+    monkeypatch.setattr(traffic, "__path__",
+                        traffic.__path__ + [str(here / "traffic")])
+    monkeypatch.setattr(layer_metrics, "__path__",
+                        layer_metrics.__path__
+                        + [str(here / "layer_metrics")])
+
+    class NoDeviceTrace:
+        busy_s, window_s, chips = 0.5, 1.0, []
+
+        def breakdown(self):
+            return {"device_ops": [], "idle_gaps": []}
+
+    monkeypatch.setattr(tracereduce, "load", lambda *a: NoDeviceTrace())
+    cell, config = harness.load_cell(manifest, "dummy.tick", str(root))
+    assert (cell["kind"], config["ticks"]) == ("dummy", 3)
+    for trace, want in ((False, {"ticks_per_s", "setup_s"}),
+                        (True, {"dummy_ticks"})):
+        ctx, _ = rehearsal.context(
+            dict(cell, trace_after_s=0, trace_seconds=0), config, tmp_path,
+            jax.devices()[:1], trace=trace)
+        result = harness.run_cell(ctx, manifest, 0.0)
+        assert result["correct"] and set(result["metrics"]) == want
+    assert result["metrics"]["dummy_ticks"] == {"value": 3.0,
+                                                "unit": "ticks"}

@@ -1,0 +1,203 @@
+"""Traffic kind ``fit``: whole ``GBDT.fit_binned`` calls, back to back.
+
+Set-up makes the configuration's rows on the device from the seed, bins
+them with the model's own boundaries into the uint8 wire form (sharded
+over the mesh's ``data`` axis when the cell takes more than one chip) and
+runs one warm fit.  The window calls ``fit_binned`` until the time is up;
+the fit in flight when the clock runs out is finished and counted.  Every
+fit ends in ``block_until_ready``.
+
+``check`` (outside the window) holds the program to the plain numpy
+reference in ``reference/gbdt_hist.py``; see each check's message.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import time
+
+import numpy as np
+
+from benchmarks.chip import datagen, stats
+from benchmarks.chip.reference import gbdt_hist, tree_walk
+
+
+def make_model(config, rounds):
+    from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
+
+    return GBDT(GBDTParam(
+        num_boost_round=rounds, max_depth=config["max_depth"],
+        num_bins=config["num_bins"], learning_rate=config["learning_rate"],
+        reg_lambda=config["reg_lambda"],
+        min_child_weight=config["min_child_weight"],
+        objective=config["objective"], hist_method=config["hist_method"]),
+        num_feature=config["num_feature"])
+
+
+def fit_bins(config, seed, model):
+    """Quantile boundaries from a seeded sample, as a user's job fits them
+    (``GBDT.make_bins``)."""
+    model.make_bins(datagen.device_sample(config, seed,
+                                          config["bin_sample_rows"]))
+
+
+def _logloss(margin, label):
+    import jax.numpy as jnp
+
+    return float(jnp.mean(jnp.logaddexp(0.0, margin) - label * margin))
+
+
+def setup(ctx):
+    import jax
+
+    from dmlc_core_tpu.bridge.binning import wire_dtype
+    from dmlc_core_tpu.parallel.mesh import data_sharding, make_mesh
+
+    config, cell = ctx.config, ctx.cell
+    rows = int(cell.get("rows", config["rows"]))
+    model = make_model(config, int(cell["rounds_per_fit"]))
+    fit_bins(config, ctx.seed, model)
+    ctx.say(f"{config['num_bins']} quantile bins from a "
+            f"{config['bin_sample_rows']}-row sample")
+    mesh = sharding = None
+    if len(ctx.devices) > 1:
+        mesh = make_mesh(dict(config["mesh"]), devices=ctx.devices)
+        sharding = data_sharding(mesh)
+    data = datagen.device_binned(config, ctx.seed, rows, model.boundaries,
+                                 wire_dtype(config["num_bins"]), sharding)
+    jax.block_until_ready(data)
+    ctx.say(f"{rows} x {config['num_feature']} rows made and binned on the "
+            f"device ({data[0].dtype}, {len(ctx.devices)} chip(s))")
+    state = {"model": model, "data": data, "mesh": mesh, "rows": rows,
+             "rounds": int(cell["rounds_per_fit"])}
+    with _under(mesh):
+        state["method"] = model._fit_method(data[0])
+        start = time.perf_counter()
+        state["warm"] = _fit(state)
+    ctx.say(f"warm fit ({state['method']}): "
+            f"{time.perf_counter() - start:.3f} s")
+    return state
+
+
+def _under(mesh):
+    return mesh if mesh is not None else contextlib.nullcontext()
+
+
+def _fit(state):
+    import jax
+
+    ensemble, margin = state["model"].fit_binned(*state["data"])
+    jax.block_until_ready(margin)
+    return ensemble, margin
+
+
+def window(ctx, state, t_start):
+    seconds = []
+    with _under(state["mesh"]):
+        t0 = time.perf_counter()
+        while True:
+            start = time.perf_counter()
+            last = _fit(state)
+            end = time.perf_counter()
+            seconds.append(end - start)
+            if end - t0 >= ctx.seconds:
+                break
+    ctx.say(f"{len(seconds)} fits of {state['rounds']} rounds in "
+            f"{end - t0:.3f} s; the first: "
+            f"{[round(s, 4) for s in seconds[:12]]}")
+    return {"setup_s": t0 - t_start, "fit_seconds": seconds, "last": last,
+            "attempted": len(seconds), "failed": 0}
+
+
+def end_to_end(ctx, state, window):
+    per_fit = state["rows"] * state["rounds"]
+    return {"setup_s": window["setup_s"],
+            "train_rows_per_s":
+                per_fit / stats.median(window["fit_seconds"])}
+
+
+def check(ctx, state, window):
+    import jax
+
+    from dmlc_core_tpu.ops.histogram import grad_histogram
+
+    config, model = ctx.config, state["model"]
+    spec = config["check"]
+    bins, label, weight = state["data"]
+    ensemble, margin = window["last"]
+    want = config["expect_hist_method"]
+    yield (state["method"] == want,
+           f"hist_method={config['hist_method']!r} resolved to "
+           f"{state['method']!r} (want {want!r})")
+
+    # a fit is deterministic in the seed: the warm fit and the window's
+    # last fit chose the same splits
+    same = all(np.array_equal(np.asarray(a), np.asarray(b))
+               for a, b in zip(state["warm"][0][:2], ensemble[:2]))
+    yield same, "two fits of the same rows chose identical splits"
+
+    # the kernel against the exact bincount histogram, deepest level's nodes
+    n = min(int(spec["hist_rows"]), state["rows"])
+    nodes = 2 ** (config["max_depth"] - 1)
+    rng = np.random.default_rng([ctx.seed, 0xC4EC])
+    hb = np.asarray(bins[:n])
+    node = rng.integers(0, nodes, n).astype(np.int32)
+    g = rng.standard_normal(n).astype(np.float32)
+    h = np.abs(rng.standard_normal(n)).astype(np.float32)
+    got = grad_histogram(hb, node, g, h, num_nodes=nodes,
+                         num_bins=config["num_bins"],
+                         method=state["method"])
+    ref = gbdt_hist.histogram(hb, node, g, h, nodes, config["num_bins"])
+    close = all(np.allclose(np.asarray(a), b, rtol=spec["hist_rtol"],
+                            atol=spec["hist_atol"])
+                for a, b in zip(got, ref))
+    yield close, (f"{state['method']} histogram == bincount histogram at "
+                  f"{n} rows x {nodes} nodes (rtol {spec['hist_rtol']}, "
+                  f"atol {spec['hist_atol']})")
+
+    # the program's fit of a seeded subsample against the plain reference's
+    # fit of the same bins: same parameters, same rounds
+    m = min(int(spec["sample_rows"]), state["rows"])
+    sb, sl = np.asarray(bins[:m]), np.asarray(label[:m])
+    _, ref_margin = gbdt_hist.boost(
+        sb, sl, state["rounds"], max_depth=config["max_depth"],
+        num_bins=config["num_bins"], learning_rate=config["learning_rate"],
+        reg_lambda=config["reg_lambda"],
+        min_child_weight=config["min_child_weight"],
+        objective=config["objective"])
+    ref_loss = gbdt_hist.logloss(ref_margin, sl)
+    _, sub_margin = model.fit_binned(sb, sl)
+    sub_loss = _logloss(sub_margin, jax.numpy.asarray(sl))
+    tol = spec["logloss_tolerance"]
+    yield (abs(sub_loss - ref_loss) <= tol,
+           f"train logloss after {state['rounds']} rounds on {m} sampled "
+           f"rows: program {sub_loss:.5f} vs reference {ref_loss:.5f} "
+           f"(tolerance {tol})")
+    # the whole fit: its returned margins are what a plain walk of its own
+    # trees gives on the sampled rows, and its loss is the sample's but for
+    # the sample's overfit
+    walked = tree_walk.margins(sb.astype(np.int64),
+                               *(np.asarray(a) for a in ensemble[:3]))
+    worst = float(np.abs(walked - np.asarray(margin[:m])).max())
+    yield (worst <= spec["margin_atol"],
+           f"the {state['rows']}-row fit's margins equal a numpy walk of "
+           f"its own trees on {m} rows: worst difference {worst:.2e} "
+           f"(atol {spec['margin_atol']})")
+    full_loss = _logloss(margin, label)
+    band = spec["full_vs_sample_band"]
+    yield (abs(full_loss - sub_loss) <= band and np.isfinite(full_loss),
+           f"train logloss of the whole {state['rows']}-row fit "
+           f"{full_loss:.5f} within {band} of the sample's")
+
+    if state["mesh"] is not None:
+        with state["mesh"]:
+            hlo = model._fit_fn(state["rounds"], state["method"]).lower(
+                bins, label, weight).compile().as_text()
+        full = f"[{bins.shape[0]},{config['num_feature']}]"
+        shard_rows = {s.data.shape[0] for s in bins.addressable_shards}
+        yield (all(mark in hlo for mark in spec["hlo_has"])
+               and full not in hlo
+               and shard_rows == {state["rows"] // len(ctx.devices)},
+               f"optimized HLO has {spec['hlo_has']} and no full {full} "
+               f"operand; every chip holds "
+               f"{state['rows'] // len(ctx.devices)} rows")
