@@ -1,0 +1,15 @@
+"""Device time of one level's ``grad_histogram`` outside the Mosaic kernel
+(ops scoped ``gbdt.hist`` that are not the custom call): the W build, the
+pad, and on four chips the all-reduce's slivers on the ``XLA Ops`` line."""
+
+from benchmarks.chip import scopes
+
+NAME = "hist_prep_ms_per_level"
+UNIT = "ms"
+LAYER = "ops: grad_histogram outside the kernel"
+MOVES = "train_rows_per_s"
+KINDS = ("fit",)
+
+
+def reduce(evidence):
+    return scopes.phase_ms(evidence, ("gbdt.hist",), "level")

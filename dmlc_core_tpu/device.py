@@ -3,7 +3,7 @@
 Every program that touches JAX (``examples/*``, ``python -m
 dmlc_core_tpu.serve``, ``python -m dmlc_core_tpu.train``, ``bench.py``,
 ``chip_smoke.py``, ``__graft_entry__``) calls :func:`init_device` first.
-It does two things and nothing else:
+It does three things and nothing else:
 
 - **compile cache**: when ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
   itself and no code sets another directory; when it is not, the cache
@@ -16,6 +16,17 @@ It does two things and nothing else:
   code for the CPU that compiled it (the loader warns of SIGILL on any
   feature mismatch, and does so even on the compiling machine), a
   checkout travels between machines, and CPU compiles are cheap.
+- **names are part of what is cached**: this system reads its own
+  profiles (the ``gbdt.*`` scopes of ``models/gbdt.py``, found again in a
+  ``jax.profiler`` trace's ``tf_op``), and JAX's cache key leaves the name
+  stack out by default — a change that only adds or renames a
+  ``jax.named_scope`` then loads its parent's executable and its trace
+  shows the parent's names: a wrong measurement with nothing wrong in the
+  code.  So ``jax_compilation_cache_include_metadata_in_key`` is set.  With
+  it the key also holds source file names, and a checkout is not always
+  unpacked at one path, so ``jax_hlo_source_file_canonicalization_regex``
+  strips the checkout root (from the package location, like the default
+  cache directory): two copies of one tree share their cache entries.
 - **device statement**: returns and logs what JAX found.  It never changes
   the platform and never falls back: the CPU is used when, and only when,
   the caller's environment says ``JAX_PLATFORMS=cpu``.  A run that asked
@@ -26,6 +37,7 @@ It does two things and nothing else:
 from __future__ import annotations
 
 import os
+import re
 from typing import NamedTuple
 
 from dmlc_core_tpu.utils.logging import log_info
@@ -33,8 +45,8 @@ from dmlc_core_tpu.utils.logging import log_info
 __all__ = ["DeviceInfo", "init_device", "CACHE_ENV", "DEFAULT_CACHE_DIR"]
 
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
-DEFAULT_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+_CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DEFAULT_CACHE_DIR = os.path.join(_CHECKOUT_ROOT, ".jax_cache")
 
 
 class DeviceInfo(NamedTuple):
@@ -60,6 +72,9 @@ def init_device() -> DeviceInfo:
     import jax
 
     devices = jax.devices()
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      re.escape(_CHECKOUT_ROOT + os.sep))
     if not os.environ.get(CACHE_ENV):
         jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
         if devices[0].platform == "cpu":

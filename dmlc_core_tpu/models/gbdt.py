@@ -30,6 +30,7 @@ from typing import Any, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from dmlc_core_tpu import telemetry
 from dmlc_core_tpu.ops.histogram import (apply_bins, bin_onehot,
                                          distributed_quantile_boundaries,
                                          grad_histogram, resolve_hist_method)
@@ -262,7 +263,13 @@ def _build_tree(bins, g, h, max_depth: int, num_bins: int, reg_lambda: float,
     XGBoost's interval-aware scoring that affects split choice, never the
     monotonicity guarantee.  The ``max_delta_step`` clamp, by contrast,
     DOES enter gain scoring, via ``_score``.)
+
+    Each phase runs under a ``jax.named_scope`` (``gbdt.hist`` /
+    ``gbdt.split`` / ``gbdt.route`` / ``gbdt.leaf``; the callers add
+    ``gbdt.grad_hess``): metadata only, read back per phase from a
+    ``jax.profiler`` trace's ``tf_op`` (docs/observability.md).
     """
+    import jax
     import jax.numpy as jnp
 
     B, F = bins.shape
@@ -285,170 +292,176 @@ def _build_tree(bins, g, h, max_depth: int, num_bins: int, reg_lambda: float,
     for depth in range(max_depth):
         n_nodes = 2 ** depth
         level_off = n_nodes - 1
-        G, H = grad_histogram(bins, node, g, h, n_nodes, num_bins,
-                              model_axis=model_axis, method=method,
-                              onehot=onehot)             # [n, F, nbins]
-        GL = jnp.cumsum(G, axis=-1)
-        HL = jnp.cumsum(H, axis=-1)
-        GT = GL[..., -1:]
-        HT = HL[..., -1:]
-        lam = reg_lambda
+        with jax.named_scope("gbdt.hist"):
+            G, H = grad_histogram(bins, node, g, h, n_nodes, num_bins,
+                                  model_axis=model_axis, method=method,
+                                  onehot=onehot)             # [n, F, nbins]
+        with jax.named_scope("gbdt.split"):
+            GL = jnp.cumsum(G, axis=-1)
+            HL = jnp.cumsum(H, axis=-1)
+            GT = GL[..., -1:]
+            HT = HL[..., -1:]
+            lam = reg_lambda
 
-        mds = max_delta_step
+            mds = max_delta_step
 
-        def _clamp_w(w):
-            return jnp.clip(w, -mds, mds) if mds > 0.0 else w
+            def _clamp_w(w):
+                return jnp.clip(w, -mds, mds) if mds > 0.0 else w
 
-        def _opt_w(Gv, Hv):
-            # the (possibly mds-clamped) optimum leaf weight — the ONE
-            # definition shared by gain scoring, monotone masking, and the
-            # monotone interval midpoints, so they can never desynchronize
-            return _clamp_w(-_l1_threshold(Gv, reg_alpha) / (Hv + lam))
+            def _opt_w(Gv, Hv):
+                # the (possibly mds-clamped) optimum leaf weight — the ONE
+                # definition shared by gain scoring, monotone masking, and the
+                # monotone interval midpoints, so they can never desynchronize
+                return _clamp_w(-_l1_threshold(Gv, reg_alpha) / (Hv + lam))
 
-        def _weights(GLv, HLv):
-            return _opt_w(GLv, HLv), _opt_w(GT - GLv, HT - HLv)
+            def _weights(GLv, HLv):
+                return _opt_w(GLv, HLv), _opt_w(GT - GLv, HT - HLv)
 
-        def _score(Gv, Hv):
-            # -2x the leaf objective at the (possibly clamped) optimum
-            # weight; algebraically equal to ThresholdL1(G)^2/(H+lam)
-            # when max_delta_step leaves the weight unclamped, so split
-            # choices under the cap match XGBoost's CalcWeight-clamped
-            # CalcGain rather than ignoring the cap.  Known deviation:
-            # with reg_alpha>0 AND a binding cap, the alpha term here is
-            # -2a|w| (the self-consistent -2x objective) where XGBoost's
-            # CalcGain adds +a|w| — gains, and possibly argmax splits,
-            # differ from XGBoost in that corner
-            if mds == 0.0:
-                return _l1_threshold(Gv, reg_alpha) ** 2 / (Hv + lam)
-            w = _opt_w(Gv, Hv)
-            return (-(2.0 * Gv * w + (Hv + lam) * w * w)
-                    - 2.0 * reg_alpha * jnp.abs(w))
+            def _score(Gv, Hv):
+                # -2x the leaf objective at the (possibly clamped) optimum
+                # weight; algebraically equal to ThresholdL1(G)^2/(H+lam)
+                # when max_delta_step leaves the weight unclamped, so split
+                # choices under the cap match XGBoost's CalcWeight-clamped
+                # CalcGain rather than ignoring the cap.  Known deviation:
+                # with reg_alpha>0 AND a binding cap, the alpha term here is
+                # -2a|w| (the self-consistent -2x objective) where XGBoost's
+                # CalcGain adds +a|w| — gains, and possibly argmax splits,
+                # differ from XGBoost in that corner
+                if mds == 0.0:
+                    return _l1_threshold(Gv, reg_alpha) ** 2 / (Hv + lam)
+                w = _opt_w(Gv, Hv)
+                return (-(2.0 * Gv * w + (Hv + lam) * w * w)
+                        - 2.0 * reg_alpha * jnp.abs(w))
 
-        def _gain(GLv, HLv):
-            GRv = GT - GLv
-            HRv = HT - HLv
-            gn = (_score(GLv, HLv) + _score(GRv, HRv)
-                  - _score(GT, HT))                      # [n, F, nbins]
-            ok = (HLv >= min_child_weight) & (HRv >= min_child_weight)
-            if monotone is not None:
-                wl, wr = _weights(GLv, HLv)
-                c = mono[None, :, None]
-                ok = ok & ~(c * (wl - wr) > 0)           # violating splits
-            return gn, ok
+            def _gain(GLv, HLv):
+                GRv = GT - GLv
+                HRv = HT - HLv
+                gn = (_score(GLv, HLv) + _score(GRv, HRv)
+                      - _score(GT, HT))                      # [n, F, nbins]
+                ok = (HLv >= min_child_weight) & (HRv >= min_child_weight)
+                if monotone is not None:
+                    wl, wr = _weights(GLv, HLv)
+                    c = mono[None, :, None]
+                    ok = ok & ~(c * (wl - wr) > 0)           # violating splits
+                return gn, ok
 
-        gain, valid = _gain(GL, HL)
-        if missing:
-            # default-right scored above (thresholds below the missing bin
-            # exclude its mass from GL, so it lands right for free); score
-            # default-left by shifting the missing mass into the left sums
-            gain_l, valid_l = _gain(GL + G[..., miss_id:miss_id + 1],
-                                    HL + H[..., miss_id:miss_id + 1])
-            gain = jnp.where(valid, gain, -jnp.inf)
-            gain_l = jnp.where(valid_l, gain_l, -jnp.inf)
-            go_left_default = gain_l > gain
-            gain = jnp.maximum(gain, gain_l)
-            valid = valid | valid_l
-        # splitting on the last bin sends everything left: never valid
-        # (with missing handling the last REAL threshold is num_bins - 2,
-        # which separates non-missing from missing — allowed)
-        valid = valid & (jnp.arange(num_bins) < num_bins - 1)[None, None, :]
-        if level_mask_fn is not None:
-            # the level/node draw consumes the tree mask (nested sampling)
-            valid = valid & level_mask_fn(depth, n_nodes,
-                                          feat_mask)[:, :, None]
-        elif feat_mask is not None:
-            valid = valid & feat_mask[None, :, None]
-        gain = jnp.where(valid, gain, -jnp.inf)
-        flat = gain.reshape(n_nodes, F * num_bins)
-        best = jnp.argmax(flat, axis=-1)                 # [n]
-        best_gain = jnp.take_along_axis(flat, best[:, None], axis=-1)[:, 0]
-        bf = (best // num_bins).astype(jnp.int32)
-        bb = (best % num_bins).astype(jnp.int32)
-        do_split = best_gain > min_split_loss
-        sf = jnp.where(do_split, bf, -1)
-        if missing:
-            dl = jnp.take_along_axis(
-                go_left_default.reshape(n_nodes, F * num_bins),
-                best[:, None], axis=-1)[:, 0] & do_split
-        else:
-            dl = jnp.zeros((n_nodes,), jnp.bool_)
-        lvl = level_off + jnp.arange(n_nodes)
-        split_feat = split_feat.at[lvl].set(sf)
-        split_bin = split_bin.at[lvl].set(bb)
-        default_left = default_left.at[lvl].set(dl)
-        split_gain = split_gain.at[lvl].set(
-            jnp.where(do_split, best_gain, 0.0))
-        split_cover = split_cover.at[lvl].set(
-            jnp.where(do_split, HT[:, 0, 0], 0.0))
-        if monotone is not None:
-            # child intervals: the chosen split's child weights set the
-            # midpoint; constrained features split the node interval there
-            def _at_best(a):
-                return jnp.take_along_axis(
-                    a.reshape(n_nodes, F * num_bins), best[:, None],
-                    axis=-1)[:, 0]
-
-            # gather the chosen split's sums first: wl/wr become
-            # [n]-sized math instead of full [n, F, nbins] passes
-            GLb, HLb = _at_best(GL), _at_best(HL)
+            gain, valid = _gain(GL, HL)
             if missing:
-                GLb = jnp.where(dl, _at_best(GL + G[..., miss_id:miss_id + 1]),
-                                GLb)
-                HLb = jnp.where(dl, _at_best(HL + H[..., miss_id:miss_id + 1]),
-                                HLb)
-            GTn, HTn = GT[:, 0, 0], HT[:, 0, 0]
-            wl = _opt_w(GLb, HLb)
-            wr = _opt_w(GTn - GLb, HTn - HLb)
-            wl = jnp.clip(wl, node_lo, node_hi)
-            wr = jnp.clip(wr, node_lo, node_hi)
-            mid = 0.5 * (wl + wr)
-            c_node = jnp.where(do_split, mono[bf], 0)    # [n]
-            # c=+1: left subtree weights <= mid <= right subtree weights
-            lo_l = node_lo
-            hi_l = jnp.where(c_node > 0, jnp.minimum(node_hi, mid), node_hi)
-            lo_r = jnp.where(c_node > 0, jnp.maximum(node_lo, mid), node_lo)
-            hi_r = node_hi
-            lo_l = jnp.where(c_node < 0, jnp.maximum(node_lo, mid), lo_l)
-            hi_r = jnp.where(c_node < 0, jnp.minimum(node_hi, mid), hi_r)
-            node_lo = jnp.stack([lo_l, lo_r], axis=1).reshape(-1)
-            node_hi = jnp.stack([hi_l, hi_r], axis=1).reshape(-1)
-        # advance every row one level.  The per-row feature pick is a
-        # compare-select-reduce over the (28-lane) feature axis, NOT a
-        # take_along_axis gather: profiled on v5e the gather lowering costs
-        # ~1.7 ms/level (52% of the whole round) while this select-sum is
-        # ~0.1 ms — rows' split features come from a tiny per-node table, so
-        # the one-hot select is the TPU-shaped formulation.
-        nf = sf[node]                                    # [B]
-        row_bin = jnp.sum(jnp.where(nf[:, None] == fiota[None, :], bins, 0),
-                          axis=1)
-        go_right = (row_bin > bb[node]) & (nf >= 0)
-        if missing:
-            # missing rows sit at bin num_bins-1 > any threshold, so they
-            # already go right; default-left overrides that
-            go_right = go_right & ~((row_bin == miss_id) & dl[node])
-        node = node * 2 + go_right.astype(jnp.int32)
+                # default-right scored above (thresholds below the missing bin
+                # exclude its mass from GL, so it lands right for free); score
+                # default-left by shifting the missing mass into the left sums
+                gain_l, valid_l = _gain(GL + G[..., miss_id:miss_id + 1],
+                                        HL + H[..., miss_id:miss_id + 1])
+                gain = jnp.where(valid, gain, -jnp.inf)
+                gain_l = jnp.where(valid_l, gain_l, -jnp.inf)
+                go_left_default = gain_l > gain
+                gain = jnp.maximum(gain, gain_l)
+                valid = valid | valid_l
+            # splitting on the last bin sends everything left: never valid
+            # (with missing handling the last REAL threshold is num_bins - 2,
+            # which separates non-missing from missing — allowed)
+            valid = valid & (jnp.arange(num_bins)
+                             < num_bins - 1)[None, None, :]
+            if level_mask_fn is not None:
+                # the level/node draw consumes the tree mask (nested sampling)
+                valid = valid & level_mask_fn(depth, n_nodes,
+                                              feat_mask)[:, :, None]
+            elif feat_mask is not None:
+                valid = valid & feat_mask[None, :, None]
+            gain = jnp.where(valid, gain, -jnp.inf)
+            flat = gain.reshape(n_nodes, F * num_bins)
+            best = jnp.argmax(flat, axis=-1)                 # [n]
+            best_gain = jnp.take_along_axis(flat, best[:, None], axis=-1)[:, 0]
+            bf = (best // num_bins).astype(jnp.int32)
+            bb = (best % num_bins).astype(jnp.int32)
+            do_split = best_gain > min_split_loss
+            sf = jnp.where(do_split, bf, -1)
+            if missing:
+                dl = jnp.take_along_axis(
+                    go_left_default.reshape(n_nodes, F * num_bins),
+                    best[:, None], axis=-1)[:, 0] & do_split
+            else:
+                dl = jnp.zeros((n_nodes,), jnp.bool_)
+            lvl = level_off + jnp.arange(n_nodes)
+            split_feat = split_feat.at[lvl].set(sf)
+            split_bin = split_bin.at[lvl].set(bb)
+            default_left = default_left.at[lvl].set(dl)
+            split_gain = split_gain.at[lvl].set(
+                jnp.where(do_split, best_gain, 0.0))
+            split_cover = split_cover.at[lvl].set(
+                jnp.where(do_split, HT[:, 0, 0], 0.0))
+            if monotone is not None:
+                # child intervals: the chosen split's child weights set the
+                # midpoint; constrained features split the node interval there
+                def _at_best(a):
+                    return jnp.take_along_axis(
+                        a.reshape(n_nodes, F * num_bins), best[:, None],
+                        axis=-1)[:, 0]
 
-    import jax
+                # gather the chosen split's sums first: wl/wr become
+                # [n]-sized math instead of full [n, F, nbins] passes
+                GLb, HLb = _at_best(GL), _at_best(HL)
+                if missing:
+                    GLb = jnp.where(
+                        dl, _at_best(GL + G[..., miss_id:miss_id + 1]), GLb)
+                    HLb = jnp.where(
+                        dl, _at_best(HL + H[..., miss_id:miss_id + 1]), HLb)
+                GTn, HTn = GT[:, 0, 0], HT[:, 0, 0]
+                wl = _opt_w(GLb, HLb)
+                wr = _opt_w(GTn - GLb, HTn - HLb)
+                wl = jnp.clip(wl, node_lo, node_hi)
+                wr = jnp.clip(wr, node_lo, node_hi)
+                mid = 0.5 * (wl + wr)
+                c_node = jnp.where(do_split, mono[bf], 0)    # [n]
+                # c=+1: left subtree weights <= mid <= right subtree weights
+                lo_l = node_lo
+                hi_l = jnp.where(c_node > 0, jnp.minimum(node_hi, mid),
+                                 node_hi)
+                lo_r = jnp.where(c_node > 0, jnp.maximum(node_lo, mid),
+                                 node_lo)
+                hi_r = node_hi
+                lo_l = jnp.where(c_node < 0, jnp.maximum(node_lo, mid), lo_l)
+                hi_r = jnp.where(c_node < 0, jnp.minimum(node_hi, mid), hi_r)
+                node_lo = jnp.stack([lo_l, lo_r], axis=1).reshape(-1)
+                node_hi = jnp.stack([hi_l, hi_r], axis=1).reshape(-1)
+        with jax.named_scope("gbdt.route"):
+            # advance every row one level.  The per-row feature pick is a
+            # compare-select-reduce over the (28-lane) feature axis, NOT a
+            # take_along_axis gather: profiled on v5e the gather lowering
+            # costs ~1.7 ms/level (52% of the whole round) while this
+            # select-sum is ~0.1 ms — rows' split features come from a tiny
+            # per-node table, so the one-hot select is the TPU-shaped
+            # formulation.
+            nf = sf[node]                                    # [B]
+            row_bin = jnp.sum(
+                jnp.where(nf[:, None] == fiota[None, :], bins, 0), axis=1)
+            go_right = (row_bin > bb[node]) & (nf >= 0)
+            if missing:
+                # missing rows sit at bin num_bins-1 > any threshold, so they
+                # already go right; default-left overrides that
+                go_right = go_right & ~((row_bin == miss_id) & dl[node])
+            node = node * 2 + go_right.astype(jnp.int32)
 
-    n_leaf = 2 ** max_depth
-    if method in ("onehot", "pallas", "pallas_fused"):
-        # leaf sums as a (tiny) f32 matmul — TPU scatter-adds serialise
-        leafhot = (node[:, None] == jnp.arange(n_leaf, dtype=node.dtype)
-                   ).astype(jnp.float32)                 # [B, n_leaf]
-        gh = jnp.stack([g, h], axis=1)                   # [B, 2]
-        sums = jax.lax.dot_general(leafhot, gh, (((0,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        Gl, Hl = sums[:, 0], sums[:, 1]
-    else:
-        Gl = jax.ops.segment_sum(g, node, num_segments=n_leaf)
-        Hl = jax.ops.segment_sum(h, node, num_segments=n_leaf)
-    leaf_w = -_l1_threshold(Gl, reg_alpha) / (Hl + reg_lambda)
-    if max_delta_step > 0.0:
-        leaf_w = jnp.clip(leaf_w, -max_delta_step, max_delta_step)
-    if monotone is not None:
-        leaf_w = jnp.clip(leaf_w, node_lo, node_hi)
-    leaf_value = leaf_w * learning_rate
-    margin_delta = leaf_value[node]
+    with jax.named_scope("gbdt.leaf"):
+        n_leaf = 2 ** max_depth
+        if method in ("onehot", "pallas", "pallas_fused"):
+            # leaf sums as a (tiny) f32 matmul — TPU scatter-adds serialise
+            leafhot = (node[:, None] == jnp.arange(n_leaf, dtype=node.dtype)
+                       ).astype(jnp.float32)                 # [B, n_leaf]
+            gh = jnp.stack([g, h], axis=1)                   # [B, 2]
+            sums = jax.lax.dot_general(leafhot, gh, (((0,), (0,)), ((), ())),
+                                       preferred_element_type=jnp.float32)
+            Gl, Hl = sums[:, 0], sums[:, 1]
+        else:
+            Gl = jax.ops.segment_sum(g, node, num_segments=n_leaf)
+            Hl = jax.ops.segment_sum(h, node, num_segments=n_leaf)
+        leaf_w = -_l1_threshold(Gl, reg_alpha) / (Hl + reg_lambda)
+        if max_delta_step > 0.0:
+            leaf_w = jnp.clip(leaf_w, -max_delta_step, max_delta_step)
+        if monotone is not None:
+            leaf_w = jnp.clip(leaf_w, node_lo, node_hi)
+        leaf_value = leaf_w * learning_rate
+        margin_delta = leaf_value[node]
     return (split_feat, split_bin, leaf_value, default_left, split_gain,
             split_cover, margin_delta)
 
@@ -542,21 +555,26 @@ def _softmax_round(p, bins, margin, label, weight, rnd, grow,
     (XGBoost multi:softmax — gradients evaluated before any of the round's
     K updates land), each tree drawing its own row/feature subset.
     ``grow`` is the caller's _build_tree closure."""
+    import jax
     import jax.numpy as jnp
 
     K = p.num_class
     B = bins.shape[0]
     n_rows = B if n_rows is None else n_rows
-    g_all, h_all = _softmax_grad_hess(margin, label, K)
+    with jax.named_scope("gbdt.grad_hess"):
+        g_all, h_all = _softmax_grad_hess(margin, label, K)
     trees = []
     for k in range(K):
-        row_w, fmask = _row_sampling(p, rnd, n_rows, B, bins.shape[1],
-                                     class_index=k)
-        w = weight if row_w is None else weight * row_w
-        trees.append(grow(bins, g_all[:, k] * w, h_all[:, k] * w, rnd,
-                          fmask))
-    delta = jnp.stack([t[6] for t in trees], axis=1)     # [B, K]
-    return margin + delta, tuple(
+        with jax.named_scope("gbdt.grad_hess"):
+            row_w, fmask = _row_sampling(p, rnd, n_rows, B, bins.shape[1],
+                                         class_index=k)
+            w = weight if row_w is None else weight * row_w
+            gk, hk = g_all[:, k] * w, h_all[:, k] * w
+        trees.append(grow(bins, gk, hk, rnd, fmask))
+    with jax.named_scope("gbdt.grad_hess"):
+        delta = jnp.stack([t[6] for t in trees], axis=1)     # [B, K]
+        margin = margin + delta
+    return margin, tuple(
         jnp.stack([t[i] for t in trees]) for i in range(6))
 
 
@@ -727,14 +745,17 @@ class GBDT:
             if p.objective == "softmax":
                 return _softmax_round(p, bins, margin, label, weight, rnd,
                                       grow)
-            g, h = _grad_hess(margin, label, p.objective)
-            row_w, fmask = _tree_sampling(p, rnd, bins.shape[0],
-                                          bins.shape[1])
-            if row_w is not None:
-                weight = weight * row_w
-            sf, sb, lv, dl, sg, sc, delta = grow(bins, g * weight,
-                                                 h * weight, rnd, fmask)
-            return margin + delta, (sf, sb, lv, dl, sg, sc)
+            with jax.named_scope("gbdt.grad_hess"):
+                g, h = _grad_hess(margin, label, p.objective)
+                row_w, fmask = _tree_sampling(p, rnd, bins.shape[0],
+                                              bins.shape[1])
+                if row_w is not None:
+                    weight = weight * row_w
+                g, h = g * weight, h * weight
+            sf, sb, lv, dl, sg, sc, delta = grow(bins, g, h, rnd, fmask)
+            with jax.named_scope("gbdt.grad_hess"):
+                margin = margin + delta
+            return margin, (sf, sb, lv, dl, sg, sc)
 
         return jax.jit(one_round)
 
@@ -803,13 +824,17 @@ class GBDT:
 
             def round_step(margin, rnd):
                 if K == 1:
-                    row_w, fmask = _row_sampling(p, rnd, n_rows, B,
-                                                 bins.shape[1])
-                    w = weight if row_w is None else weight * row_w
-                    g, h = _grad_hess(margin, label, p.objective)
-                    sf, sb, lv, dl, sg, sc, delta = grow(bins, g * w,
-                                                         h * w, rnd, fmask)
-                    return margin + delta, (sf, sb, lv, dl, sg, sc)
+                    with jax.named_scope("gbdt.grad_hess"):
+                        row_w, fmask = _row_sampling(p, rnd, n_rows, B,
+                                                     bins.shape[1])
+                        w = weight if row_w is None else weight * row_w
+                        g, h = _grad_hess(margin, label, p.objective)
+                        g, h = g * w, h * w
+                    sf, sb, lv, dl, sg, sc, delta = grow(bins, g, h, rnd,
+                                                         fmask)
+                    with jax.named_scope("gbdt.grad_hess"):
+                        margin = margin + delta
+                    return margin, (sf, sb, lv, dl, sg, sc)
                 return _softmax_round(p, bins, margin, label, weight, rnd,
                                       grow, n_rows=n_rows)
 
@@ -889,14 +914,19 @@ class GBDT:
         """Train on pre-binned features; returns (ensemble, final margin)."""
         import jax.numpy as jnp
 
-        if self.param.objective == "softmax":
-            _check_softmax_labels(label, self.param.num_class)
-        weight = (jnp.ones(bins.shape[0], jnp.float32)
-                  if weight is None else jnp.asarray(weight))
-        bins = jnp.asarray(bins)
-        return self._fit_fn(self.param.num_boost_round,
-                            self._fit_method(bins))(
-            bins, jnp.asarray(label, jnp.float32), weight)
+        # the host's part of a fit: argument staging and the asynchronous
+        # dispatch of the compiled program (it does not wait for the device)
+        with telemetry.span("gbdt.fit.dispatch",
+                            rounds=self.param.num_boost_round) as sp:
+            if self.param.objective == "softmax":
+                _check_softmax_labels(label, self.param.num_class)
+            weight = (jnp.ones(bins.shape[0], jnp.float32)
+                      if weight is None else jnp.asarray(weight))
+            bins = jnp.asarray(bins)
+            method = self._fit_method(bins)
+            sp.set(method=method)
+            return self._fit_fn(self.param.num_boost_round, method)(
+                bins, jnp.asarray(label, jnp.float32), weight)
 
     def boost_round(self, margin, bins, label, weight,
                     round_index: Optional[int] = None):

@@ -209,6 +209,7 @@ def hist_matmul_pallas(w, bins, num_bins: int, block_rows: int = BLOCK_ROWS):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, bf * num_bins), jnp.float32),
         interpret=interpret_mode(),
+        name="hist_level",
     )(w, bins)
 
 
@@ -312,6 +313,7 @@ def grad_hist_pallas_fused(bins, node_ids, grad, hess, num_nodes: int,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, bf * num_bins), jnp.float32),
         interpret=interpret_mode(),
+        name="hist_level_fused",
     )(node, g, h, bins)
     return _split_gh(out, n_pad, num_nodes, bf, num_bins)
 

@@ -18,6 +18,18 @@ for its dynamic extent, so nested spans parent automatically.  With no
 active context, events record exactly as before: untraced, never dropped
 for it.
 
+**The profiler's clock**: in a process that has imported ``jax``, a
+context-managed :class:`Span` also enters a
+``jax.profiler.TraceAnnotation`` of its name for its extent, so every
+``with telemetry.span(...)`` lands on the ``/host:CPU`` plane of any
+``jax.profiler`` trace, beside the device planes — idle device time can
+then be laid against what the host was doing (on the v5e the two planes'
+clocks differ by about 2 ms: ``benchmarks/chip/scopes.py`` bounds the
+lead before it overlaps anything).  ``jax`` is never
+imported from here (the tracker and the load generator run without it),
+and spans recorded from explicit readings (``record_complete``) are not
+bridged.
+
 Every recorded event is also fed to the flight recorder's bounded ring
 (:mod:`.flight`) — including events the main buffer drops — so a crashed
 or SIGTERMed process still leaves its last N spans behind.
@@ -36,6 +48,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
@@ -50,7 +63,8 @@ TraceIds = Tuple[str, str, Optional[str]]
 class Span:
     """Context manager recording one complete event on exit."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_start", "_trace", "_token")
+    __slots__ = ("_tracer", "_name", "_attrs", "_start", "_trace", "_token",
+                 "_annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str,
                  attrs: Optional[Dict[str, Any]]):
@@ -60,9 +74,16 @@ class Span:
         self._start = 0.0
         self._trace: Optional[TraceIds] = None
         self._token: Optional[tracecontext.TraceContext] = None
+        self._annotation: Any = None
 
     def __enter__(self) -> "Span":
         self._start = clock.trace_time_us()
+        # a half-imported jax (another thread is inside ``import jax``)
+        # has no ``profiler`` yet: no annotation then, never an error
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            self._annotation = profiler.TraceAnnotation(self._name)
+            self._annotation.__enter__()
         ctx = tracecontext.current()
         if ctx is not None:
             span_id = tracecontext.new_span_id()
@@ -87,6 +108,8 @@ class Span:
         self._attrs.update(attrs)
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         end = clock.trace_time_us()
         if self._trace is not None:
             tracecontext._pop(self._token)

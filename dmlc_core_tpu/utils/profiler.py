@@ -7,7 +7,8 @@ equivalents here:
   (reference src/data/basic_row_iter.h:70-75), reusable by any byte stage;
 - :func:`trace` — context manager around ``jax.profiler`` producing a
   TensorBoard-loadable trace directory (device timelines, XLA ops);
-- :func:`annotate` — named TraceAnnotation spans visible in those traces;
+  (``with telemetry.span(...)`` is the one way to put a named host span
+  into such a trace: :mod:`dmlc_core_tpu.telemetry.spans`);
 - :func:`device_timer` — ``block_until_ready``-bracketed wall timing for
   honest device measurements (async dispatch otherwise lies).
 """
@@ -21,7 +22,7 @@ from dmlc_core_tpu import telemetry
 from dmlc_core_tpu.telemetry import clock
 from dmlc_core_tpu.utils.logging import log_info
 
-__all__ = ["ThroughputMeter", "trace", "annotate", "device_timer"]
+__all__ = ["ThroughputMeter", "trace", "device_timer"]
 
 
 class ThroughputMeter:
@@ -92,15 +93,6 @@ def trace(log_dir: str) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named span inside a profiler trace."""
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield
 
 
 def device_timer(fn: Callable, *args: Any, iters: int = 1,

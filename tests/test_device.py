@@ -71,6 +71,118 @@ def test_cache_dir_env_set_means_code_sets_nothing(tmp_path):
     assert got["used"] is True               # ...and nothing else was touched
 
 
+# -- names are part of what is cached -----------------------------------------
+#
+# A program run as ``python <checkout>/prog.py <plain|scoped> [options]``:
+# it calls init_device(), compiles one function — with a
+# ``jax.named_scope("gbdt.route")`` inside it or without — and reports
+# whether the compiled text names the scope and what the cache holds.
+_CACHE_PROG = """
+import json, os, sys
+import jax, jax.numpy as jnp
+from dmlc_core_tpu.device import init_device
+
+info = init_device()
+for option in sys.argv[2:]:
+    name, value = option.split("=")
+    jax.config.update(name, {"False": False, "None": None}[value])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
+def f(x):
+    if sys.argv[1] == "scoped":
+        with jax.named_scope("gbdt.route"):
+            return jnp.sum(x * 2.0)
+    return jnp.sum(x * 2.0)
+
+text = jax.jit(f).lower(jnp.ones((8, 128))).compile().as_text()
+print(json.dumps({
+    "scope": "gbdt.route" in text,
+    "entries": sorted(os.listdir(info.cache_dir)),
+    "metadata_in_key":
+        jax.config.jax_compilation_cache_include_metadata_in_key,
+    "regex": jax.config.jax_hlo_source_file_canonicalization_regex}))
+"""
+
+
+def _checkout_copy(where):
+    """A stand-in for a second copy of the tree at another path: the
+    package is linked, not copied, and ``init_device`` derives the checkout
+    root from where the package was imported from."""
+    where.mkdir()
+    os.symlink(os.path.join(REPO, "dmlc_core_tpu"),
+               where / "dmlc_core_tpu", target_is_directory=True)
+    (where / "prog.py").write_text(_CACHE_PROG)
+    return where
+
+
+def _run_cache_prog(checkout, cache, *argv):
+    import json
+
+    env = os.environ.copy()
+    env.pop("XLA_FLAGS", None)
+    env["PYTHONPATH"] = str(checkout)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache)
+    proc = subprocess.run([sys.executable, str(checkout / "prog.py"), *argv],
+                          cwd=str(checkout), env=env, capture_output=True,
+                          text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_init_device_puts_names_into_the_cache_key(tmp_path):
+    import re
+
+    checkout = _checkout_copy(tmp_path / "tree")
+    got = _run_cache_prog(checkout, tmp_path / "cache", "plain")
+    assert got["metadata_in_key"] is True
+    # ...and takes the checkout's own path out of it again
+    assert re.sub(got["regex"], "", str(checkout / "prog.py")) == "prog.py"
+
+
+@pytest.mark.parametrize("metadata_in_key", [True, False])
+def test_a_scope_added_since_the_cached_compile_is_not_lost(
+        tmp_path, metadata_in_key):
+    """One cache, the same function compiled plain and then with a
+    ``named_scope`` added.  With JAX's default key (names left out) the
+    second compile loads the first one's executable and the scope is gone
+    from its text and from any profile; ``init_device`` keys on the names,
+    so the scoped program is compiled and cached beside the plain one."""
+    checkout = _checkout_copy(tmp_path / "tree")
+    cache = tmp_path / "cache"
+    options = [] if metadata_in_key else [
+        "jax_compilation_cache_include_metadata_in_key=False"]
+    plain = _run_cache_prog(checkout, cache, "plain", *options)
+    assert plain["scope"] is False and plain["entries"]
+    scoped = _run_cache_prog(checkout, cache, "scoped", *options)
+    if metadata_in_key:
+        assert scoped["scope"] is True
+        assert len(scoped["entries"]) > len(plain["entries"])
+    else:       # the staleness init_device exists to prevent
+        assert scoped["scope"] is False
+        assert scoped["entries"] == plain["entries"]
+
+
+@pytest.mark.parametrize("canonical", [True, False])
+def test_a_second_copy_of_the_tree_hits_the_first_copys_cache(
+        tmp_path, canonical):
+    """With names in the key the key also holds source file names; the
+    checkout root is stripped from them, so a copy of the tree at another
+    path compiles nothing anew (without the regex it would)."""
+    cache = tmp_path / "cache"
+    options = [] if canonical else [
+        "jax_hlo_source_file_canonicalization_regex=None"]
+    first = _run_cache_prog(_checkout_copy(tmp_path / "one"), cache,
+                            "scoped", *options)
+    second = _run_cache_prog(_checkout_copy(tmp_path / "two"), cache,
+                             "scoped", *options)
+    assert first["entries"] and first["scope"] and second["scope"]
+    if canonical:
+        assert second["entries"] == first["entries"]
+    else:
+        assert len(second["entries"]) > len(first["entries"])
+
+
 def test_cpu_without_an_explicit_request_is_an_error():
     """JAX's silent no-accelerator fallback is refused: the CPU is used
     when, and only when, the environment says JAX_PLATFORMS=cpu."""

@@ -1,0 +1,120 @@
+"""The names the compiled fit carries for its own profiles: the ``gbdt.*``
+``jax.named_scope``s of a boosting round, the kernels' ``name=`` and the
+``gbdt.fit.dispatch`` host span (docs/observability.md, "Device scopes").
+
+A scope is metadata: it reaches the compiled program as a component of an
+instruction's ``op_name`` and a ``jax.profiler`` trace as the stat
+``tf_op``; ``benchmarks/chip/scopes.py`` reads it back per phase.
+"""
+
+import functools
+import re
+
+import numpy as np
+import pytest
+
+from dmlc_core_tpu import telemetry
+from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
+
+SCOPES = ("gbdt.hist", "gbdt.split", "gbdt.route", "gbdt.leaf",
+          "gbdt.grad_hess")
+OBJECTIVES = {"logistic": {}, "softmax": {"num_class": 3}}
+ROWS, FEATURES, ROUNDS = 64, 3, 2
+
+
+def _model(objective):
+    return GBDT(GBDTParam(num_boost_round=ROUNDS, max_depth=2, num_bins=8,
+                          objective=objective, hist_method="scatter",
+                          **OBJECTIVES[objective]), num_feature=FEATURES)
+
+
+def _data(objective, seed=0):
+    rng = np.random.default_rng(seed)
+    classes = OBJECTIVES[objective].get("num_class", 2)
+    return (rng.integers(0, 8, (ROWS, FEATURES)).astype(np.uint8),
+            rng.integers(0, classes, ROWS).astype(np.float32),
+            np.ones(ROWS, np.float32))
+
+
+@functools.lru_cache(maxsize=None)
+def _op_name_components(objective):
+    """Every path component of every ``op_name`` of the compiled fit."""
+    compiled = _model(objective)._fit_fn(ROUNDS, "scatter").lower(
+        *_data(objective)).compile()
+    names = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    assert names, "the compiled text carries no op_name metadata"
+    return {part for name in names for part in name.split("/")}
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+@pytest.mark.parametrize("objective", sorted(OBJECTIVES))
+def test_compiled_fit_names_every_phase(objective, scope):
+    assert scope in _op_name_components(objective)
+
+
+def test_streaming_round_carries_the_same_scopes():
+    model = _model("logistic")
+    bins, label, weight = _data("logistic")
+    compiled = model._round_fn("scatter").lower(
+        np.zeros(ROWS, np.float32), bins, label, weight,
+        np.uint32(0)).compile()
+    text = compiled.as_text()
+    for scope in SCOPES:
+        assert f"/{scope}/" in text, scope
+
+
+@pytest.mark.parametrize("wrapper, name", [
+    ("hist_matmul_pallas", "hist_level"),
+    ("grad_hist_pallas_fused", "hist_level_fused"),
+])
+def test_hist_kernels_are_named(monkeypatch, wrapper, name):
+    import jax
+    import jax.numpy as jnp
+
+    from dmlc_core_tpu.ops import hist_pallas
+
+    monkeypatch.setattr(hist_pallas, "_INTERPRET", True)
+    bins = jnp.zeros((hist_pallas.BLOCK_ROWS, FEATURES), jnp.int32)
+    if wrapper == "hist_matmul_pallas":
+        w = jnp.zeros((16, hist_pallas.BLOCK_ROWS), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(
+            lambda w, b: hist_pallas.hist_matmul_pallas(w, b, 8))(w, bins)
+    else:
+        row = jnp.zeros((hist_pallas.BLOCK_ROWS,), jnp.float32)
+        jaxpr = jax.make_jaxpr(
+            lambda b, n, g, h: hist_pallas.grad_hist_pallas_fused(
+                b, n, g, h, 2, 8))(bins, row.astype(jnp.int32), row, row)
+    calls = [e for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert [c.params["name"] for c in calls] == [name]
+
+
+@pytest.fixture
+def spans():
+    was_enabled = telemetry.enabled()
+    telemetry.reset()
+    telemetry.enable()
+    yield lambda: [e for e in telemetry.get_tracer().events()
+                   if e["name"] == "gbdt.fit.dispatch"]
+    telemetry.disable()
+    telemetry.reset()
+    if was_enabled:
+        telemetry.enable()
+
+
+def test_fit_binned_records_one_dispatch_span_per_call(spans):
+    model = _model("logistic")
+    data = _data("logistic")
+    for calls in (1, 2):
+        model.fit_binned(*data)
+        found = spans()
+        assert len(found) == calls
+        assert found[-1]["args"] == {"rounds": ROUNDS, "method": "scatter"}
+        assert found[-1]["ph"] == "X" and found[-1]["dur"] > 0
+
+
+def test_fit_binned_records_nothing_when_telemetry_is_off():
+    assert not telemetry.enabled()
+    before = len(telemetry.get_tracer().events())
+    _model("logistic").fit_binned(*_data("logistic"))
+    assert len(telemetry.get_tracer().events()) == before

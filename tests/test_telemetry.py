@@ -168,6 +168,81 @@ def test_span_records_exception_and_propagates():
     assert event["args"]["error"] == "KeyError"
 
 
+# -- the profiler's clock: spans as jax.profiler.TraceAnnotations --------------
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """Every ``jax.profiler.TraceAnnotation`` entered and exited, in order,
+    as ``("enter" | "exit", name)``."""
+    import jax
+
+    seen = []
+
+    class Recorder:
+        def __init__(self, name, **_):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    return seen
+
+
+def test_enabled_span_enters_and_exits_an_annotation_of_its_name(annotations):
+    telemetry.enable()
+    with telemetry.span("outer.stage", rows=3):
+        with telemetry.span("inner.stage"):
+            pass
+    assert annotations == [("enter", "outer.stage"), ("enter", "inner.stage"),
+                           ("exit", "inner.stage"), ("exit", "outer.stage")]
+    # the span itself records as before
+    assert [e["name"] for e in telemetry.get_tracer().events()] \
+        == ["inner.stage", "outer.stage"]
+
+
+def test_disabled_span_enters_no_annotation(annotations):
+    with telemetry.span("outer.stage"):
+        pass
+    assert annotations == []
+
+
+def test_span_closes_its_annotation_when_the_body_raises(annotations):
+    telemetry.enable()
+    with pytest.raises(KeyError):
+        with telemetry.span("doomed"):
+            raise KeyError("boom")
+    assert annotations == [("enter", "doomed"), ("exit", "doomed")]
+    (ev,) = telemetry.get_tracer().events()
+    assert ev["args"]["error"] == "KeyError"
+
+
+def test_span_without_jax_in_the_process_enters_no_annotation(
+        annotations, monkeypatch):
+    """The tracker and the load generator never import jax, and telemetry
+    never imports it for them."""
+    monkeypatch.delitem(sys.modules, "jax")
+    telemetry.enable()
+    with telemetry.span("tracker.start"):
+        pass
+    assert annotations == []
+    assert "jax" not in sys.modules
+    assert [e["name"] for e in telemetry.get_tracer().events()] \
+        == ["tracker.start"]
+
+
+def test_record_span_is_not_bridged_to_the_profiler(annotations):
+    telemetry.enable()
+    start = clock.monotonic()
+    telemetry.record_span("serve.queue.wait", start, start + 0.001)
+    assert annotations == []
+    assert len(telemetry.get_tracer().events()) == 1
+
+
 def test_record_span_uses_monotonic_domain():
     telemetry.enable()
     start = clock.monotonic()
