@@ -150,6 +150,51 @@ def _widen_bins(bins):
     return bins if bins.dtype == jnp.int32 else bins.astype(jnp.int32)
 
 
+def _bin_layouts(bins, pad: int = 0):
+    """The two device layouts a fit keeps of one ``[rows, F]`` binned batch,
+    rows padded by ``pad``: the widened int32 ``[rows, F]`` the histogram
+    kernels read, and ``[F, rows]`` in the wire dtype for ``_feature_pick``
+    — rows on the minor (lane) axis, so a pass over it streams rows x F
+    narrow bytes instead of a row-major array whose F lanes pad to 128.
+    Made once per fit (once per streamed round) under ``gbdt.layout``."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("gbdt.layout"):
+        bins = jnp.asarray(bins)
+        if pad:
+            bins = jnp.pad(bins, ((0, pad), (0, 0)))
+        return _widen_bins(bins), bins.T
+
+
+def _feature_pick(bins_fm, feat):
+    """``bins_fm[feat[r], r]`` for every row r of feature-major bins
+    ``[F, rows]``, as a compare-select-sum over the leading axis (the
+    widening fuses into the select).  ``feat == -1`` picks nothing: 0."""
+    import jax.numpy as jnp
+
+    fiota = jnp.arange(bins_fm.shape[0], dtype=jnp.int32)
+    return jnp.sum(jnp.where(feat[None, :] == fiota[:, None],
+                             bins_fm.astype(jnp.int32), 0), axis=0)
+
+
+def _table_pick(table, node):
+    """``table[node]`` for a per-node table of n entries and row ids
+    ``node`` in ``[0, n)``, as n compare-selects on dense ``[rows]``
+    vectors reduced over the leading axis — not the gather, which XLA
+    lowers on the TPU to one-hot reductions over lanes.  Exact: one term
+    of the sum is non-zero.  Ids outside ``[0, n)`` would read 0 / False
+    where a gather clamps; ``_build_tree`` never makes one (padded rows
+    route like any other)."""
+    import jax.numpy as jnp
+
+    hit = node[None, :] == jnp.arange(table.shape[0],
+                                      dtype=node.dtype)[:, None]
+    if table.dtype == jnp.bool_:
+        return jnp.any(hit & table[:, None], axis=0)
+    return jnp.sum(jnp.where(hit, table[:, None], 0), axis=0)
+
+
 def _grad_hess(margin, label, objective: str):
     import jax.numpy as jnp
 
@@ -232,8 +277,9 @@ def _parse_monotone(spec: str, num_feature: int):
     return None if not arr.any() else arr
 
 
-def _build_tree(bins, g, h, max_depth: int, num_bins: int, reg_lambda: float,
-                min_child_weight: float, learning_rate: float,
+def _build_tree(bins, bins_fm, g, h, max_depth: int, num_bins: int,
+                reg_lambda: float, min_child_weight: float,
+                learning_rate: float,
                 model_axis: Optional[str] = None, method: str = "scatter",
                 onehot=None, min_split_loss: float = 0.0, feat_mask=None,
                 missing: bool = False, reg_alpha: float = 0.0,
@@ -242,6 +288,10 @@ def _build_tree(bins, g, h, max_depth: int, num_bins: int, reg_lambda: float,
     """Grow one tree level-by-level; returns (split_feat, split_bin,
     leaf_value, default_left, split_gain, split_cover, margin_delta).
     Pure jax, shapes static in (max_depth, num_bins, F).
+
+    ``bins`` is the widened ``[rows, F]`` the histogram reads, ``bins_fm``
+    the same bins as ``[F, rows]`` in their wire dtype (``_bin_layouts``):
+    every per-row pick reduces over a leading axis with rows on the lanes.
 
     ``feat_mask`` ([F] bool, optional) disables features for this tree
     (colsample); ``min_split_loss`` is the XGBoost gamma pruning threshold.
@@ -266,8 +316,8 @@ def _build_tree(bins, g, h, max_depth: int, num_bins: int, reg_lambda: float,
 
     Each phase runs under a ``jax.named_scope`` (``gbdt.hist`` /
     ``gbdt.split`` / ``gbdt.route`` / ``gbdt.leaf``; the callers add
-    ``gbdt.grad_hess``): metadata only, read back per phase from a
-    ``jax.profiler`` trace's ``tf_op`` (docs/observability.md).
+    ``gbdt.layout`` and ``gbdt.grad_hess``): metadata only, read back per
+    phase from a ``jax.profiler`` trace's ``tf_op`` (docs/observability.md).
     """
     import jax
     import jax.numpy as jnp
@@ -280,7 +330,6 @@ def _build_tree(bins, g, h, max_depth: int, num_bins: int, reg_lambda: float,
     split_gain = jnp.zeros((n_internal,), dtype=jnp.float32)
     split_cover = jnp.zeros((n_internal,), dtype=jnp.float32)
     node = jnp.zeros((B,), dtype=jnp.int32)  # node id within the level
-    fiota = jnp.arange(F, dtype=jnp.int32)
     miss_id = num_bins - 1
     if monotone is not None:
         mono = jnp.asarray(monotone, jnp.int32)          # [F]
@@ -425,21 +474,22 @@ def _build_tree(bins, g, h, max_depth: int, num_bins: int, reg_lambda: float,
                 node_lo = jnp.stack([lo_l, lo_r], axis=1).reshape(-1)
                 node_hi = jnp.stack([hi_l, hi_r], axis=1).reshape(-1)
         with jax.named_scope("gbdt.route"):
-            # advance every row one level.  The per-row feature pick is a
-            # compare-select-reduce over the (28-lane) feature axis, NOT a
-            # take_along_axis gather: profiled on v5e the gather lowering
-            # costs ~1.7 ms/level (52% of the whole round) while this
-            # select-sum is ~0.1 ms — rows' split features come from a tiny
-            # per-node table, so the one-hot select is the TPU-shaped
-            # formulation.
-            nf = sf[node]                                    # [B]
-            row_bin = jnp.sum(
-                jnp.where(nf[:, None] == fiota[None, :], bins, 0), axis=1)
-            go_right = (row_bin > bb[node]) & (nf >= 0)
+            # advance every row one level.  Rows' split features come from
+            # a per-node table of 1..2**(d-1) entries and their bin from one
+            # of F columns: both are compare-select-sums over a LEADING
+            # axis, rows on the lanes.  At 11M x 28 on a v5e the row-major
+            # select-sum streamed the lane-padded int32 [rows, F] (512 B a
+            # row) at 81% of the HBM peak, 8.5 ms a level, and the table
+            # gathers took 8.5 + 11 ms a round as one-hot reductions over
+            # lanes; take_along_axis was slower still (PERF.md, PR 25).
+            nf = _table_pick(sf, node)                       # [B]
+            row_bin = _feature_pick(bins_fm, nf)
+            go_right = (row_bin > _table_pick(bb, node)) & (nf >= 0)
             if missing:
                 # missing rows sit at bin num_bins-1 > any threshold, so they
                 # already go right; default-left overrides that
-                go_right = go_right & ~((row_bin == miss_id) & dl[node])
+                go_right = go_right & ~((row_bin == miss_id)
+                                        & _table_pick(dl, node))
             node = node * 2 + go_right.astype(jnp.int32)
 
     with jax.named_scope("gbdt.leaf"):
@@ -461,7 +511,7 @@ def _build_tree(bins, g, h, max_depth: int, num_bins: int, reg_lambda: float,
         if monotone is not None:
             leaf_w = jnp.clip(leaf_w, node_lo, node_hi)
         leaf_value = leaf_w * learning_rate
-        margin_delta = leaf_value[node]
+        margin_delta = _table_pick(leaf_value, node)
     return (split_feat, split_bin, leaf_value, default_left, split_gain,
             split_cover, margin_delta)
 
@@ -595,7 +645,9 @@ def _route_tree(split_feat, split_bin, default_left, bins,
         level_off = 2 ** depth - 1
         sf = split_feat[level_off + node]
         sb = split_bin[level_off + node]
-        # select-sum instead of take_along_axis: see _build_tree routing note
+        # select-sum, not take_along_axis (the gather lowering is slower on
+        # the TPU); still row-major, unlike _build_tree's picks: no measured
+        # path runs this at more than a serving batch (PERF.md section 7)
         row_bin = jnp.sum(jnp.where(sf[:, None] == fiota[None, :], bins, 0),
                           axis=1)
         go_right = (row_bin > sb) & (sf >= 0)
@@ -727,14 +779,15 @@ class GBDT:
         p = self.param
 
         def one_round(margin, bins, label, weight, rnd):
-            bins = _widen_bins(bins)
+            bins, bins_fm = _bin_layouts(bins)
             onehot = (bin_onehot(bins, p.num_bins)
                       if method == "onehot" else None)
 
             def grow(bins_, g, h, rnd_, fmask):
                 return _build_tree(
-                    bins_, g, h, p.max_depth, p.num_bins, p.reg_lambda,
-                    p.min_child_weight, p.learning_rate, self.model_axis,
+                    bins_, bins_fm, g, h, p.max_depth, p.num_bins,
+                    p.reg_lambda, p.min_child_weight, p.learning_rate,
+                    self.model_axis,
                     method=method, onehot=onehot,
                     min_split_loss=p.min_split_loss, feat_mask=fmask,
                     missing=p.handle_missing, reg_alpha=p.reg_alpha,
@@ -788,10 +841,8 @@ class GBDT:
         def fit(bins, label, weight, ev_bins=None, ev_label=None):
             import jax.numpy as jnp
 
-            bins = _widen_bins(bins)
-            if ev_bins is not None:
-                ev_bins = _widen_bins(ev_bins)
             n_rows = bins.shape[0]
+            pad = 0
             if method in ("pallas", "pallas_fused"):
                 from dmlc_core_tpu.ops.hist_pallas import fit_row_multiple
 
@@ -799,10 +850,13 @@ class GBDT:
                 # rows carry weight 0, so they vanish from every histogram);
                 # per-call padding inside the kernel wrapper then no-ops
                 pad = -n_rows % fit_row_multiple()
+            with jax.named_scope("gbdt.layout"):
+                if ev_bins is not None:
+                    ev_bins = _widen_bins(ev_bins)
                 if pad:
-                    bins = jnp.pad(bins, ((0, pad), (0, 0)))
                     label = jnp.pad(label, (0, pad))
                     weight = jnp.pad(weight, (0, pad))
+            bins, bins_fm = _bin_layouts(bins, pad)
             B = bins.shape[0]
             weight = _apply_pos_weight(weight, label, p)
             # the bin one-hot (the matmul RHS) is invariant across rounds and
@@ -813,8 +867,9 @@ class GBDT:
 
             def grow(bins_, g, h, rnd, fmask):
                 return _build_tree(
-                    bins_, g, h, p.max_depth, p.num_bins, p.reg_lambda,
-                    p.min_child_weight, p.learning_rate, self.model_axis,
+                    bins_, bins_fm, g, h, p.max_depth, p.num_bins,
+                    p.reg_lambda, p.min_child_weight, p.learning_rate,
+                    self.model_axis,
                     method=method, onehot=onehot,
                     min_split_loss=p.min_split_loss, feat_mask=fmask,
                     missing=p.handle_missing, reg_alpha=p.reg_alpha,

@@ -16,8 +16,8 @@ import pytest
 from dmlc_core_tpu import telemetry
 from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
 
-SCOPES = ("gbdt.hist", "gbdt.split", "gbdt.route", "gbdt.leaf",
-          "gbdt.grad_hess")
+SCOPES = ("gbdt.layout", "gbdt.hist", "gbdt.split", "gbdt.route",
+          "gbdt.leaf", "gbdt.grad_hess")
 OBJECTIVES = {"logistic": {}, "softmax": {"num_class": 3}}
 ROWS, FEATURES, ROUNDS = 64, 3, 2
 
