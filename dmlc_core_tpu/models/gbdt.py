@@ -754,7 +754,8 @@ class GBDT:
             from dmlc_core_tpu.ops.hist_pallas import hist_kernel_plan
 
             # settled once per fit for the deepest level, so an onehot
-            # outcome still amortises its matmul RHS across rounds.
+            # outcome (a mesh the kernel cannot be shard_mapped over; never
+            # width or depth) still amortises its matmul RHS across rounds.
             # ``batch`` is the row count grad_histogram will actually see
             # (padded for fit, raw for boost_round) so this and the
             # per-level call inside grad_histogram cannot disagree.
@@ -771,6 +772,20 @@ class GBDT:
 
         mult = fit_row_multiple()
         return self._method(bins, batch=-(-bins.shape[0] // mult) * mult)
+
+    def _hist_blocks(self, method: str) -> dict:
+        """The kernel shape a fit's deepest level runs, as the
+        ``gbdt.fit.dispatch`` span records it: calls a level
+        (``node_blocks``) and grid steps over features inside each
+        (``feature_blocks``); 0 and 0 for a method that is no kernel."""
+        counts = (0, 0)
+        if method in ("pallas", "pallas_fused"):
+            from dmlc_core_tpu.ops.hist_pallas import hist_block_counts
+
+            counts = hist_block_counts(
+                self.model_axis, self.num_feature,
+                2 ** (self.param.max_depth - 1), self.param.num_bins)
+        return dict(zip(("node_blocks", "feature_blocks"), counts))
 
     @functools.lru_cache(maxsize=None)
     def _round_fn(self, method: str = "scatter"):
@@ -979,7 +994,7 @@ class GBDT:
                       if weight is None else jnp.asarray(weight))
             bins = jnp.asarray(bins)
             method = self._fit_method(bins)
-            sp.set(method=method)
+            sp.set(method=method, **self._hist_blocks(method))
             return self._fit_fn(self.param.num_boost_round, method)(
                 bins, jnp.asarray(label, jnp.float32), weight)
 

@@ -9,20 +9,28 @@ int32 bins), and every tree level of every boosting round re-reads all of it.
 
 This kernel keeps the matmul but builds the one-hot **tile-by-tile in VMEM**:
 
-- grid = row tiles (1-D, sequential on TPU);
-- the ``[M, F*nbins]`` f32 accumulator lives in one VMEM output block whose
-  index map is constant, so it persists across grid steps (zeroed at step 0);
-- per step: DMA ``W`` tile ``[M, TB]`` (bf16) + bins tile ``[TB, F]``
+- grid = (feature blocks, row tiles), both sequential on TPU, rows inner;
+- an ``[M, F_blk*nbins]`` f32 accumulator lives in one VMEM output block
+  indexed by the feature block alone, so it persists across a block's row
+  tiles (zeroed at the first);
+- per step: DMA ``W`` tile ``[M, TB]`` (bf16) + bins tile ``[TB, F_blk]``
   (int32), then for each feature compare-to-iota -> ``[TB, nbins]`` one-hot
-  in VMEM and issue one MXU dot, accumulating in f32.
+  in VMEM and issue one MXU dot, accumulating in f32;
+- a table whose ``[M, F*nbins]`` accumulator fits the VMEM budget is one
+  feature block; a wider one is cut by :func:`hist_block_plan` into blocks
+  of 128 features, ``W`` re-read once per block.  Every feature's one-hot
+  is still built once, so a level stays ONE ``hist_level`` call whose cost
+  follows rows x features.
 
 HBM traffic per level falls from ``B*F*nbins*2`` bytes to
 ``B*(4F + 2M + 12)`` — ~100x for the flagship shapes — turning the histogram
 from bandwidth- to compute-bound.  Numerics match the ``"onehot"`` method
 exactly (same bf16 one-hot / bf16 W / f32 accumulate).
 
-Used automatically on TPU via ``resolve_hist_method("auto")`` when the
-histogram block fits VMEM; falls back to the plain one-hot matmul otherwise.
+Used automatically on TPU via ``resolve_hist_method("auto")``: the plan
+blocks features, then nodes, until an accumulator block fits VMEM, so width
+and depth never send a fit to the plain one-hot matmul (only a mesh the
+kernel cannot be shard_mapped over does — :func:`hist_kernel_plan`).
 On a TPU backend a kernel Mosaic rejects raises with the compiler's message
 — nothing here probes-and-swallows on behalf of ``auto``.
 """
@@ -40,7 +48,8 @@ __all__ = ["hist_matmul_pallas", "grad_hist_pallas",
            "ambient_mesh", "hist_kernel_plan", "fit_row_multiple",
            "interpret_mode",
            "pallas_fused_supported", "pallas_i8_supported", "hist_fits_vmem",
-           "hist_node_block", "BLOCK_ROWS", "DATA_AXIS"]
+           "hist_block_plan", "hist_block_counts",
+           "BLOCK_ROWS", "DATA_AXIS"]
 
 # interpreter mode: runs the kernels on CPU for tests/debugging (flipped by
 # tests, or set DMLC_TPU_PALLAS_INTERPRET=1 to debug without a chip).
@@ -95,9 +104,12 @@ def _bins_compare_dtype(num_bins: int):
         return jnp.int8
     return jnp.int32
 
-# VMEM budget for the resident accumulator block (bytes); above this
-# callers fall back to the plain one-hot matmul.
+# VMEM budget for the resident accumulator block (bytes): what
+# hist_block_plan cuts a level's [2n, F*nbins] histogram down to.
 _ACC_BYTES_LIMIT = 8 * 1024 * 1024
+
+# a bins tile's minor (feature) extent is whole or a multiple of the lanes
+_LANES = 128
 
 
 def _pad_nodes(num_nodes: int) -> int:
@@ -111,30 +123,54 @@ def hist_fits_vmem(num_nodes: int, num_feature: int, num_bins: int) -> bool:
         <= _ACC_BYTES_LIMIT
 
 
-def hist_node_block(num_nodes: int, num_feature: int, num_bins: int):
-    """Nodes per kernel sweep, or None when even 8 node slots overflow VMEM.
+def hist_block_plan(num_nodes: int, num_feature: int, num_bins: int):
+    """``(nodes per kernel call, features per accumulator block)`` of one
+    level, or None when even 8 node slots of the narrowest feature block
+    overflow VMEM.  The one place the budget is applied.
 
-    Deep tree levels whose full [2n, F*nbins] accumulator exceeds VMEM run
-    the kernel in node blocks: each sweep re-reads the bins tile and
-    re-builds the one-hot, but kernel cost is VPU-bound and m-independent
-    (measured — BASELINE.md r3 profile), so #sweeps scales the cost, while
-    the one-hot-matmul fallback's MXU work scales with the FULL node count
-    AND re-reads the 2n x B x F*nbins problem from HBM.  Blocking keeps the
-    kernel the fastest choice for every depth the GBDT allows.
+    Features are blocked first: a feature block is a grid step of the SAME
+    kernel call (every feature's one-hot is still built once; only ``W``
+    is re-read), while a node block is another call that re-reads the bins
+    and re-builds every one-hot — kernel cost is VPU-bound and
+    m-independent (measured — BASELINE.md r3 profile), so #node sweeps
+    scales the cost.  So: the most nodes for which the narrowest legal
+    feature block (128 bins columns, or all F of a narrower table) fits,
+    and beside them all F features in one block where those fit,
+    else blocks of 128.  Not wider where the budget would allow it: the
+    tile body is unrolled over the block's features, and at 512 of them
+    Mosaic's register allocator spilled 173 MB for a v5e (PR 27), while
+    ``W``'s re-reads are small beside the bins at any node count.
     """
-    if hist_fits_vmem(num_nodes, num_feature, num_bins):
-        return num_nodes
-    block = 1 << (num_nodes - 1).bit_length()
-    while block >= 8:
-        if hist_fits_vmem(block, num_feature, num_bins):
-            return block
-        block //= 2
-    return None
+    narrow = min(num_feature, _LANES)
+    nodes = num_nodes
+    if not hist_fits_vmem(nodes, narrow, num_bins):
+        nodes = 1 << (num_nodes - 1).bit_length()
+        while nodes >= 8 and not hist_fits_vmem(nodes, narrow, num_bins):
+            nodes //= 2
+        if nodes < 8:
+            return None
+    if hist_fits_vmem(nodes, num_feature, num_bins):
+        return nodes, num_feature
+    return nodes, _LANES
 
 
-def _accumulate_tile(w, bins_ref, out_ref, num_feature: int, num_bins: int):
-    """Shared tile body: zero-init at step 0, then per-feature one-hot dots
-    of ``w`` [M, TB] accumulated into the resident ``out_ref``.
+def hist_block_counts(model_axis, num_feature: int, num_nodes: int,
+                      num_bins: int):
+    """``(node blocks, feature blocks)`` one chip's kernel runs a level of
+    ``num_nodes`` nodes in: kernel calls, and grid steps over features
+    inside each.  For a fit :func:`hist_kernel_plan` settled on the kernel;
+    what ``gbdt.fit.dispatch`` records beside the method."""
+    if model_axis is not None:
+        num_feature //= ambient_mesh().shape[model_axis]
+    nodes, feats = hist_block_plan(num_nodes, num_feature, num_bins)
+    return -(-num_nodes // nodes), -(-num_feature // feats)
+
+
+def _accumulate_tile(w, bins_ref, out_ref, num_feature: int, num_bins: int,
+                     row_axis: int = 0):
+    """Shared tile body: zero-init at the first step of the grid's row axis,
+    then per-feature one-hot dots of ``w`` [M, TB] accumulated into the
+    resident ``out_ref``.
 
     The iota matches the bins dtype: callers may pass bins as int8 (the
     profiled v5e bottleneck is this in-VMEM one-hot build, not the MXU dots
@@ -146,7 +182,7 @@ def _accumulate_tile(w, bins_ref, out_ref, num_feature: int, num_bins: int):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    @pl.when(pl.program_id(0) == 0)
+    @pl.when(pl.program_id(row_axis) == 0)
     def _zero():
         out_ref[:] = jnp.zeros_like(out_ref)
 
@@ -166,11 +202,14 @@ def _split_gh(out, n_pad: int, num_nodes: int, num_feature: int,
     return out[0, :num_nodes], out[1, :num_nodes]
 
 
-def _kernel(w_ref, bins_ref, out_ref, *, num_feature: int, num_bins: int):
-    _accumulate_tile(w_ref[:], bins_ref, out_ref, num_feature, num_bins)
+def _kernel(w_ref, bins_ref, out_ref, *, num_feature: int, num_bins: int,
+            row_axis: int = 0):
+    _accumulate_tile(w_ref[:], bins_ref, out_ref, num_feature, num_bins,
+                     row_axis)
 
 
-def hist_matmul_pallas(w, bins, num_bins: int, block_rows: int = BLOCK_ROWS):
+def hist_matmul_pallas(w, bins, num_bins: int, block_rows: int = BLOCK_ROWS,
+                       block_features=None):
     """``out[m, f*nbins + b] = sum_i w[m, i] * (bins[i, f] == b)``.
 
     Args:
@@ -179,6 +218,8 @@ def hist_matmul_pallas(w, bins, num_bins: int, block_rows: int = BLOCK_ROWS):
       bins: [B, F] int32 binned features in [0, num_bins).
       num_bins: static bin count.
       block_rows: row-tile size (B is padded up to a multiple internally).
+      block_features: features per accumulator block (128, or a multiple);
+        None or >= F keeps all F in one block.
 
     Returns [M, F*num_bins] float32.
     """
@@ -195,18 +236,32 @@ def hist_matmul_pallas(w, bins, num_bins: int, block_rows: int = BLOCK_ROWS):
         w = jnp.pad(w, ((0, 0), (0, pad)))         # zero W => zero contribution
         bins = jnp.pad(bins, ((0, pad), (0, 0)))
         b += pad
-    kernel = functools.partial(_kernel, num_feature=bf, num_bins=num_bins)
+    tiles = b // block_rows
+    if block_features is None or block_features >= bf:
+        block_features = bf
+    # feature blocks on the OUTER axis, row tiles inside: the accumulator
+    # block moves only when a feature block's rows are all in, and W's tile
+    # is the same for every block.  F need not divide: the last block's
+    # columns beyond F read unspecified bins whose histogram columns lie
+    # beyond the output and are never written back.  One buffer for an
+    # output block whose index moves — Pallas would keep two, and the
+    # budget is for one.
+    blocks = pl.cdiv(bf, block_features)
+    out_buffering = {"pipeline_mode": pl.Buffered(1)} if blocks > 1 else {}
+    kernel = functools.partial(_kernel, num_feature=block_features,
+                               num_bins=num_bins, row_axis=1)
     return pl.pallas_call(
         kernel,
-        grid=(b // block_rows,),
+        grid=(blocks, tiles),
         in_specs=[
-            pl.BlockSpec((m, block_rows), lambda i: (0, i),
+            pl.BlockSpec((m, block_rows), lambda j, i: (0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((block_rows, bf), lambda i: (i, 0),
+            pl.BlockSpec((block_rows, block_features), lambda j, i: (i, j),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((m, bf * num_bins), lambda i: (0, 0),
-                               memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec((m, block_features * num_bins),
+                               lambda j, i: (0, j),
+                               memory_space=pltpu.VMEM, **out_buffering),
         out_shape=jax.ShapeDtypeStruct((m, bf * num_bins), jnp.float32),
         interpret=interpret_mode(),
         name="hist_level",
@@ -221,29 +276,32 @@ def grad_hist_pallas(bins, node_ids, grad, hess, num_nodes: int,
     [num_nodes, F, num_bins] float32.  Rows with out-of-range (e.g. negative)
     node ids contribute nothing.
 
-    Levels too deep for one resident accumulator run in node blocks (see
-    :func:`hist_node_block`): shifting node ids by the block base makes the
-    kernel's own out-of-range drop do the partitioning.
+    Levels too wide or deep for one resident accumulator run blocked (see
+    :func:`hist_block_plan`): feature blocks are grid steps of one kernel
+    call; node blocks are calls of their own, and shifting node ids by the
+    block base makes the kernel's own out-of-range drop do the partitioning.
     """
     import jax.numpy as jnp
 
-    block = hist_node_block(num_nodes, bins.shape[1], num_bins)
-    assert block is not None, "caller must gate on hist_node_block"
+    plan = hist_block_plan(num_nodes, bins.shape[1], num_bins)
+    assert plan is not None, "caller must gate on hist_block_plan"
+    block, block_features = plan
     if block < num_nodes:
         node_ids = node_ids.astype(jnp.int32)
         parts = [
             _grad_hist_pallas_block(bins, node_ids - b0, grad, hess,
-                                    min(block, num_nodes - b0), num_bins)
+                                    min(block, num_nodes - b0), num_bins,
+                                    block_features)
             for b0 in range(0, num_nodes, block)
         ]
         return (jnp.concatenate([p[0] for p in parts]),
                 jnp.concatenate([p[1] for p in parts]))
     return _grad_hist_pallas_block(bins, node_ids, grad, hess, num_nodes,
-                                   num_bins)
+                                   num_bins, block_features)
 
 
 def _grad_hist_pallas_block(bins, node_ids, grad, hess, num_nodes: int,
-                            num_bins: int):
+                            num_bins: int, block_features=None):
     import jax.numpy as jnp
 
     bins = jnp.asarray(bins).astype(jnp.int32)
@@ -255,7 +313,8 @@ def _grad_hist_pallas_block(bins, node_ids, grad, hess, num_nodes: int,
         jnp.where(nodehot, grad[None, :], 0.0),
         jnp.where(nodehot, hess[None, :], 0.0),
     ], axis=0).astype(jnp.bfloat16)                # [2*n_pad, B]
-    out = hist_matmul_pallas(w, bins, num_bins)
+    out = hist_matmul_pallas(w, bins, num_bins,
+                             block_features=block_features)
     return _split_gh(out, n_pad, num_nodes, bf, num_bins)
 
 
@@ -368,12 +427,14 @@ def hist_kernel_plan(method: str, model_axis, num_feature: int,
       ``model_axis``, or a data axis wider than one device — the kernel
       runs inside shard_map: rows over data, features over model.
     - ``onehot`` (a GSPMD-partitionable matmul) takes over only where the
-      shapes forbid the kernel: even an 8-node block of the per-shard
-      ``F/mp`` slice overflows VMEM, features do not divide the model
-      axis, rows do not divide the data axis (``batch=None`` skips that
-      check for callers that pad rows later), or a ``model_axis`` is named
-      with no mesh to find it in.
-    - Node-blocked sweeps have no fused variant.
+      mesh forbids the kernel: features do not divide the model axis, rows
+      do not divide the data axis (``batch=None`` skips that check for
+      callers that pad rows later), or a ``model_axis`` is named with no
+      mesh to find it in.  Width and depth never do: the per-shard ``F/mp``
+      slice is blocked by :func:`hist_block_plan` (whose None — 8 node
+      slots of 128 features over the budget, bins in the tens of
+      thousands — is the one shape left to ``onehot``).
+    - Blocked levels (nodes or features) have no fused variant.
     """
     mesh = ambient_mesh()
     dp = _data_parallelism(mesh)
@@ -386,10 +447,10 @@ def hist_kernel_plan(method: str, model_axis, num_feature: int,
     if sharded and (num_feature % mp != 0
                     or (batch is not None and batch % dp != 0)):
         return "onehot", None
-    block = hist_node_block(num_nodes, num_feature // mp, num_bins)
-    if block is None:
+    plan = hist_block_plan(num_nodes, num_feature // mp, num_bins)
+    if plan is None:
         return "onehot", None
-    if block < num_nodes and method == "pallas_fused":
+    if plan != (num_nodes, num_feature // mp) and method == "pallas_fused":
         method = "pallas"
     return method, (mesh if sharded else None)
 

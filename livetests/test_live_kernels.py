@@ -65,8 +65,8 @@ def test_node_blocked_deep_level_on_chip(jx):
     from dmlc_core_tpu.ops import hist_pallas
 
     NB, F, NN = 256, 28, 512   # 512 nodes x 28 feat x 256 bins > VMEM budget
-    block = hist_pallas.hist_node_block(NN, F, NB)
-    assert block is not None and block < NN
+    block, features = hist_pallas.hist_block_plan(NN, F, NB)
+    assert block < NN and features == F
     bins, node_ids, grad, hess = _rand_problem(rows=2048, F=F, NB=NB,
                                                num_nodes=NN, seed=1)
     g, h = hist_pallas.grad_hist_pallas(bins, node_ids, grad, hess,

@@ -109,7 +109,9 @@ def test_fit_binned_records_one_dispatch_span_per_call(spans):
         model.fit_binned(*data)
         found = spans()
         assert len(found) == calls
-        assert found[-1]["args"] == {"rounds": ROUNDS, "method": "scatter"}
+        # scatter is no kernel: no blocks of one
+        assert found[-1]["args"] == {"rounds": ROUNDS, "method": "scatter",
+                                     "node_blocks": 0, "feature_blocks": 0}
         assert found[-1]["ph"] == "X" and found[-1]["dur"] > 0
 
 

@@ -73,19 +73,19 @@ def test_vmem_overflow_blocks_or_falls_back():
     """Deep trees keep the kernel via node-blocked sweeps; onehot only when
     even an 8-node block overflows VMEM."""
     from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
-    from dmlc_core_tpu.ops.hist_pallas import hist_fits_vmem, hist_node_block
+    from dmlc_core_tpu.ops.hist_pallas import hist_block_plan, hist_fits_vmem
 
     assert hist_fits_vmem(32, 28, 256)
     assert not hist_fits_vmem(512, 28, 256)       # depth-10 deepest level
-    assert hist_node_block(512, 28, 256) == 128   # ... -> 4 blocked sweeps
-    assert hist_node_block(32, 28, 256) == 32     # fits: single sweep
-    assert hist_node_block(512, 512, 1024) is None  # 8-node block > VMEM
+    assert hist_block_plan(512, 28, 256) == (128, 28)   # 4 blocked sweeps
+    assert hist_block_plan(32, 28, 256) == (32, 28)     # fits: single sweep
+    assert hist_block_plan(512, 512, 1024) == (8, 128)  # both axes blocked
     deep = GBDT(GBDTParam(max_depth=10, num_bins=256, hist_method="pallas"),
                 num_feature=28)
     assert deep._method() == "pallas"             # blocked, not onehot
     wide = GBDT(GBDTParam(max_depth=10, num_bins=1024,
                           hist_method="pallas"), num_feature=512)
-    assert wide._method() == "onehot"
+    assert wide._method() == "pallas"             # 8 nodes x 128 features
     # a user-selected fused method degrades to the (blockable) plain kernel
     deep_fused = GBDT(GBDTParam(max_depth=10, num_bins=256,
                                 hist_method="pallas_fused"), num_feature=28)
@@ -107,7 +107,7 @@ def test_blocked_hist_matches_scatter():
     orig = hist_pallas._ACC_BYTES_LIMIT
     hist_pallas._ACC_BYTES_LIMIT = 2 * 8 * 3 * 16 * 4   # 8-node blocks
     try:
-        assert hist_pallas.hist_node_block(32, 3, 16) == 8
+        assert hist_pallas.hist_block_plan(32, 3, 16) == (8, 3)
         bins, node, g, h = _rand_case(700, 3, 16, 32, seed=31)
         G, H = hist_pallas.grad_hist_pallas(bins, node, g, h, 32, 16)
         Gr, Hr = grad_histogram(bins, node, g, h, 32, 16, method="scatter")
@@ -475,4 +475,203 @@ def test_gbdt_fit_on_a_dp_mesh_matches_the_one_device_kernel_fit():
     np.testing.assert_array_equal(np.asarray(ens_dp.split_bin),
                                   np.asarray(ens_one.split_bin))
     np.testing.assert_allclose(margin_dp, np.asarray(margin_one),
+                               rtol=1e-4, atol=1e-4)
+
+
+# -- feature blocks: a second, outer grid axis of the same kernel call -------
+
+@pytest.fixture()
+def feature_block_budget():
+    """Shrink the VMEM budget to 8 node slots x 128 features x ``nbins``
+    bins, so test-size tables are blocked (module attribute, NOT a
+    from-import: the mutation must hit the live gate)."""
+    orig = hist_pallas._ACC_BYTES_LIMIT
+
+    def shrink(nbins):
+        hist_pallas._ACC_BYTES_LIMIT = 2 * 8 * 128 * nbins * 4
+
+    yield shrink
+    hist_pallas._ACC_BYTES_LIMIT = orig
+
+
+@pytest.mark.parametrize("b,f,nbins,nnodes,plan", [
+    (700, 384, 4, 4, (4, 128)),     # three whole feature blocks, two tiles
+    (300, 300, 4, 8, (8, 128)),     # F no multiple of the block; row padding
+    (300, 130, 8, 3, (3, 128)),     # a last block of two features
+    (500, 260, 4, 20, (8, 128)),    # node blocks x feature blocks, short last
+    (256, 100, 4, 32, (8, 100)),    # under 128 features: node blocks alone
+])
+def test_feature_blocked_hist_matches_scatter(feature_block_budget, b, f,
+                                              nbins, nnodes, plan):
+    feature_block_budget(nbins)
+    assert hist_pallas.hist_block_plan(nnodes, f, nbins) == plan
+    bins, node, g, h = _rand_case(b, f, nbins, nnodes, seed=41)
+    G, H = hist_pallas.grad_hist_pallas(bins, node, g, h, nnodes, nbins)
+    Gr, Hr = grad_histogram(bins, node, g, h, nnodes, nbins,
+                            method="scatter")
+    assert G.shape == (nnodes, f, nbins)
+    # bf16 rounding of g and h is a random walk over a bucket's rows: over
+    # thousands of buckets its tail reaches a few 1e-2 (as livetests/ hold)
+    np.testing.assert_allclose(np.asarray(G), np.asarray(Gr),
+                               rtol=2e-2, atol=6e-2)
+    np.testing.assert_allclose(np.asarray(H), np.asarray(Hr),
+                               rtol=2e-2, atol=6e-2)
+
+
+@pytest.mark.parametrize("f", [256, 300])
+def test_feature_blocks_side_by_side_are_the_unblocked_result(
+        feature_block_budget, f):
+    """Blocking changes where a feature's partial sums live, not one bit of
+    them: same tiles, same dots, same order over the rows."""
+    bins, node, g, h = _rand_case(2100, f, 8, 6, seed=42)
+    whole = hist_pallas.grad_hist_pallas(bins, node, g, h, 6, 8)
+    feature_block_budget(8)
+    assert hist_pallas.hist_block_plan(6, f, 8) == (6, 128)
+    blocked = hist_pallas.grad_hist_pallas(bins, node, g, h, 6, 8)
+    for a, b in zip(whole, blocked):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_one_grid_for_every_width():
+    """One kernel program for every table: a grid of (feature blocks, row
+    tiles) in one call; a table whose features fit one block is the case
+    of one block, whose output block never moves and keeps the default
+    buffering."""
+    import jax
+    import jax.numpy as jnp
+
+    w = jnp.zeros((16, 2 * hist_pallas.BLOCK_ROWS), jnp.bfloat16)
+    bins = jnp.zeros((2 * hist_pallas.BLOCK_ROWS, 300), jnp.int32)
+
+    def calls(block_features):
+        jaxpr = jax.make_jaxpr(lambda w, b: hist_pallas.hist_matmul_pallas(
+            w, b, 8, block_features=block_features))(w, bins)
+        return [(m.grid, m.block_mappings[-1].pipeline_mode is not None)
+                for m in (e.params["grid_mapping"] for e in jaxpr.jaxpr.eqns
+                          if e.primitive.name == "pallas_call")]
+
+    assert calls(None) == calls(300) == calls(512) == [((1, 2), False)]
+    assert calls(128) == [((3, 2), True)]
+
+
+def test_wide_tables_plan_the_kernel_not_onehot():
+    """2,000 features x 256 bins (epsilon): 16 blocks of 128 features under
+    all 32 nodes of the deepest level, one call a level."""
+    from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
+
+    assert hist_pallas.hist_block_plan(32, 2000, 256) == (32, 128)
+    assert hist_pallas.hist_block_plan(1, 2000, 256) == (1, 128)
+    assert hist_pallas.hist_block_plan(32, 28, 256) == (32, 28)
+    assert hist_pallas.hist_block_plan(512, 2000, 256) == (32, 128)
+    assert hist_pallas.hist_block_counts(None, 2000, 32, 256) == (1, 16)
+    assert hist_pallas.hist_block_counts(None, 2000, 512, 256) == (16, 16)
+    assert hist_pallas.hist_block_counts(None, 28, 32, 256) == (1, 1)
+    assert hist_pallas.hist_kernel_plan("pallas", None, 2000, 32,
+                                        256) == ("pallas", None)
+    # a blocked level has no fused variant
+    assert hist_pallas.hist_kernel_plan("pallas_fused", None, 2000, 32,
+                                        256) == ("pallas", None)
+    # bins in the tens of thousands: 8 node slots x 128 features overflow
+    assert hist_pallas.hist_block_plan(8, 2000, 2 ** 15) is None
+    assert hist_pallas.hist_kernel_plan("pallas", None, 2000, 8,
+                                        2 ** 15) == ("onehot", None)
+    wide = GBDT(GBDTParam(max_depth=6, num_bins=256, hist_method="pallas"),
+                num_feature=2000)
+    assert wide._method() == "pallas"
+    assert wide._hist_blocks("pallas") == {"node_blocks": 1,
+                                           "feature_blocks": 16}
+    assert wide._hist_blocks("scatter") == {"node_blocks": 0,
+                                            "feature_blocks": 0}
+    with _mesh_2d():
+        sharded = GBDT(GBDTParam(max_depth=6, num_bins=256,
+                                 hist_method="pallas"), num_feature=2000,
+                       model_axis="model")
+        assert sharded._method() == "pallas"
+        # each model shard blocks its own 1,000 features
+        assert sharded._hist_blocks("pallas") == {"node_blocks": 1,
+                                                  "feature_blocks": 8}
+
+
+def _wide_rehearsal(n=900, f=260, seed=51):
+    """Rows whose label hangs on one feature of each 128-feature block."""
+    from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
+
+    rng = np.random.RandomState(seed)
+    x = rng.randn(n, f).astype(np.float32)
+    y = (x[:, 5] + x[:, 140] * x[:, 259] + 0.1 * rng.randn(n) > 0
+         ).astype(np.float32)
+
+    def model(method, **kw):
+        m = GBDT(GBDTParam(num_boost_round=2, max_depth=3, num_bins=8,
+                           hist_method=method), num_feature=f, **kw)
+        m.make_bins(x)
+        return m
+
+    bins = np.asarray(model("scatter").bin_features(x), np.uint8)
+    return model, bins, y
+
+
+def test_gbdt_wide_fit_matches_scatter_and_the_plain_reference(
+        feature_block_budget):
+    """A fit whose every level runs three feature blocks grows the trees of
+    the exact scatter fit, and those are the plain numpy reference's."""
+    from benchmarks.chip.reference import gbdt_hist
+
+    feature_block_budget(8)
+    model, bins, y = _wide_rehearsal()
+    kernel = model("pallas")
+    assert kernel._fit_method(bins) == "pallas"
+    assert kernel._hist_blocks("pallas") == {"node_blocks": 1,
+                                             "feature_blocks": 3}
+    ens_p, margin_p = kernel.fit_binned(bins, y)
+    ens_s, margin_s = model("scatter").fit_binned(bins, y)
+    np.testing.assert_array_equal(np.asarray(ens_p.split_feat),
+                                  np.asarray(ens_s.split_feat))
+    np.testing.assert_array_equal(np.asarray(ens_p.split_bin),
+                                  np.asarray(ens_s.split_bin))
+    trees, margin_r = gbdt_hist.boost(
+        bins, y, 2, max_depth=3, num_bins=8, learning_rate=0.3,
+        reg_lambda=1.0, min_child_weight=1.0)
+    np.testing.assert_array_equal(np.asarray(ens_p.split_feat),
+                                  np.stack([t[0] for t in trees]))
+    np.testing.assert_array_equal(np.asarray(ens_p.split_bin),
+                                  np.stack([t[1] for t in trees]))
+    # splits in all three feature blocks
+    used = set(np.asarray(ens_p.split_feat).ravel()) - {-1}
+    assert {f // 128 for f in used} == {0, 1, 2}
+    np.testing.assert_allclose(np.asarray(margin_p), margin_r,
+                               rtol=5e-2, atol=5e-2)
+
+
+@pytest.mark.parametrize("axes,model_axis", [
+    ({"data": 8}, None),
+    ({"data": 2, "model": 2}, "model"),
+])
+def test_sharded_fits_with_feature_blocks_match_the_one_device_fit(
+        feature_block_budget, axes, model_axis):
+    """dp: every chip's kernel blocks all F features; model axis: every
+    model shard blocks its own F/mp.  Either grows the one-device trees."""
+    import jax
+    from dmlc_core_tpu.parallel.mesh import data_sharding, make_mesh
+
+    feature_block_budget(8)
+    f = 260 if model_axis is None else 520
+    model, bins, y = _wide_rehearsal(n=1000, f=f, seed=52)
+    ens_one, margin_one = model("pallas").fit_binned(bins, y)
+    count = int(np.prod(list(axes.values())))
+    mesh = make_mesh(axes, devices=jax.devices()[:count])
+    sharded = model("pallas", model_axis=model_axis)
+    with mesh:
+        assert sharded._fit_method(bins) == "pallas"
+        assert sharded._hist_blocks("pallas") == {"node_blocks": 1,
+                                                  "feature_blocks": 3}
+        ens_sh, margin_sh = sharded.fit_binned(
+            jax.device_put(bins, data_sharding(mesh, ndim=2)),
+            jax.device_put(y, data_sharding(mesh)))
+        margin_sh = np.asarray(margin_sh)
+    np.testing.assert_array_equal(np.asarray(ens_sh.split_feat),
+                                  np.asarray(ens_one.split_feat))
+    np.testing.assert_array_equal(np.asarray(ens_sh.split_bin),
+                                  np.asarray(ens_one.split_bin))
+    np.testing.assert_allclose(margin_sh, np.asarray(margin_one),
                                rtol=1e-4, atol=1e-4)
