@@ -131,7 +131,6 @@ def run_bench(info):
     from dmlc_core_tpu import telemetry
     from dmlc_core_tpu.bridge.binning import HostBinner
     from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
-    from dmlc_core_tpu.ops.hist_pallas import pallas_i8_supported
     from dmlc_core_tpu.ops.histogram import resolve_hist_method
 
     # per-stage attribution: collect the whole run (parser/threadediter/
@@ -176,7 +175,6 @@ def run_bench(info):
             "device_count": info.count,
             "compile_cache_dir": info.cache_dir,
             "hist_method": method,
-            "hist_i8_compares": pallas_i8_supported(),
             "rounds": ROUNDS,
             "seconds": round(seconds, 3),
             "train_acc": round(acc, 4),

@@ -383,9 +383,8 @@ def main():
             libsvm_path = data_phase(cfg, workdir)
         trained = train_phase(cfg, libsvm_path, times)
         check_train(cfg, trained, expect_method="pallas")
-        say(f"hist variants (neither is what 'auto' runs): int8 compares "
-            f"{hist_pallas.pallas_i8_supported()}, fused-W kernel "
-            f"{hist_pallas.pallas_fused_supported()}")
+        say("hist_level bin splits (HxL, root first): "
+            + trained["model"]._hist_blocks("pallas")["bin_split"])
         serve_phase(cfg, trained, workdir, times)
         if info.count > 1:
             mesh_phase(cfg, libsvm_path, trained, info, times)

@@ -109,9 +109,9 @@ class GBDTParam(Parameter):
     hist_method = field(str, default="auto",
                         enum=["auto", "pallas", "pallas_fused", "onehot", "scatter"],
                         help="histogram algorithm: VMEM-resident pallas "
-                             "kernel (TPU; 'pallas_fused' also builds the "
-                             "node-weight matrix in-kernel), one-hot MXU "
-                             "matmul, or segment-sum scatter (CPU)")
+                             "kernel (TPU; 'pallas_fused' is an older name "
+                             "for it), one-hot MXU matmul, or segment-sum "
+                             "scatter (CPU)")
 
 
 class TreeEnsemble(NamedTuple):
@@ -150,12 +150,16 @@ def _widen_bins(bins):
     return bins if bins.dtype == jnp.int32 else bins.astype(jnp.int32)
 
 
-def _bin_layouts(bins, pad: int = 0):
+def _bin_layouts(bins, pad: int = 0, kernel: bool = False):
     """The two device layouts a fit keeps of one ``[rows, F]`` binned batch,
-    rows padded by ``pad``: the widened int32 ``[rows, F]`` the histogram
-    kernels read, and ``[F, rows]`` in the wire dtype for ``_feature_pick``
-    — rows on the minor (lane) axis, so a pass over it streams rows x F
-    narrow bytes instead of a row-major array whose F lanes pad to 128.
+    rows padded by ``pad``: the widened int32 the histogram reads, and
+    ``[F, rows]`` in the wire dtype for ``_feature_pick`` — rows on the
+    minor (lane) axis, so a pass over it streams rows x F narrow bytes
+    instead of a row-major array whose F lanes pad to 128.  The histogram's
+    copy is ``[rows, F]`` for ``scatter`` / ``onehot`` and, with ``kernel``,
+    the same ``[F, rows]`` widened: what the ``pallas`` kernel reads (v5e
+    Mosaic lowers no sub-32-bit compare), and a quarter or less of the
+    lane-padded row-major one.
     Made once per fit (once per streamed round) under ``gbdt.layout``."""
     import jax
     import jax.numpy as jnp
@@ -164,7 +168,7 @@ def _bin_layouts(bins, pad: int = 0):
         bins = jnp.asarray(bins)
         if pad:
             bins = jnp.pad(bins, ((0, pad), (0, 0)))
-        return _widen_bins(bins), bins.T
+        return _widen_bins(bins.T if kernel else bins), bins.T
 
 
 def _feature_pick(bins_fm, feat):
@@ -289,9 +293,10 @@ def _build_tree(bins, bins_fm, g, h, max_depth: int, num_bins: int,
     leaf_value, default_left, split_gain, split_cover, margin_delta).
     Pure jax, shapes static in (max_depth, num_bins, F).
 
-    ``bins`` is the widened ``[rows, F]`` the histogram reads, ``bins_fm``
-    the same bins as ``[F, rows]`` in their wire dtype (``_bin_layouts``):
-    every per-row pick reduces over a leading axis with rows on the lanes.
+    ``bins`` is the widened copy the histogram reads (``[rows, F]``, or
+    ``[F, rows]`` for the ``pallas`` kernel), ``bins_fm`` the same bins as
+    ``[F, rows]`` in their wire dtype (``_bin_layouts``): every per-row
+    pick reduces over a leading axis with rows on the lanes.
 
     ``feat_mask`` ([F] bool, optional) disables features for this tree
     (colsample); ``min_split_loss`` is the XGBoost gamma pruning threshold.
@@ -322,7 +327,7 @@ def _build_tree(bins, bins_fm, g, h, max_depth: int, num_bins: int,
     import jax
     import jax.numpy as jnp
 
-    B, F = bins.shape
+    F, B = bins_fm.shape
     n_internal = 2 ** max_depth - 1
     split_feat = jnp.full((n_internal,), -1, dtype=jnp.int32)
     split_bin = jnp.zeros((n_internal,), dtype=jnp.int32)
@@ -342,9 +347,11 @@ def _build_tree(bins, bins_fm, g, h, max_depth: int, num_bins: int,
         n_nodes = 2 ** depth
         level_off = n_nodes - 1
         with jax.named_scope("gbdt.hist"):
+            # G, H: [n, F, nbins]
             G, H = grad_histogram(bins, node, g, h, n_nodes, num_bins,
                                   model_axis=model_axis, method=method,
-                                  onehot=onehot)             # [n, F, nbins]
+                                  onehot=onehot,
+                                  feature_major=method == "pallas")
         with jax.named_scope("gbdt.split"):
             GL = jnp.cumsum(G, axis=-1)
             HL = jnp.cumsum(H, axis=-1)
@@ -494,7 +501,7 @@ def _build_tree(bins, bins_fm, g, h, max_depth: int, num_bins: int,
 
     with jax.named_scope("gbdt.leaf"):
         n_leaf = 2 ** max_depth
-        if method in ("onehot", "pallas", "pallas_fused"):
+        if method in ("onehot", "pallas"):
             # leaf sums as a (tiny) f32 matmul — TPU scatter-adds serialise
             leafhot = (node[:, None] == jnp.arange(n_leaf, dtype=node.dtype)
                        ).astype(jnp.float32)                 # [B, n_leaf]
@@ -600,23 +607,23 @@ def _row_sampling(p, rnd, n_rows: int, B: int, F: int, class_index=0):
 
 
 def _softmax_round(p, bins, margin, label, weight, rnd, grow,
-                   n_rows=None):
+                   num_feature: int, n_rows=None):
     """One multiclass boosting round: K trees from one margin snapshot
     (XGBoost multi:softmax — gradients evaluated before any of the round's
     K updates land), each tree drawing its own row/feature subset.
-    ``grow`` is the caller's _build_tree closure."""
+    ``grow`` is the caller's _build_tree closure, ``bins`` what it reads."""
     import jax
     import jax.numpy as jnp
 
     K = p.num_class
-    B = bins.shape[0]
+    B = margin.shape[0]
     n_rows = B if n_rows is None else n_rows
     with jax.named_scope("gbdt.grad_hess"):
         g_all, h_all = _softmax_grad_hess(margin, label, K)
     trees = []
     for k in range(K):
         with jax.named_scope("gbdt.grad_hess"):
-            row_w, fmask = _row_sampling(p, rnd, n_rows, B, bins.shape[1],
+            row_w, fmask = _row_sampling(p, rnd, n_rows, B, num_feature,
                                          class_index=k)
             w = weight if row_w is None else weight * row_w
             gk, hk = g_all[:, k] * w, h_all[:, k] * w
@@ -750,7 +757,7 @@ class GBDT:
     # -- compiled round/predict ----------------------------------------------
     def _method(self, *arrays, batch: Optional[int] = None) -> str:
         method = resolve_hist_method(self.param.hist_method, *arrays)
-        if method in ("pallas", "pallas_fused"):
+        if method == "pallas":
             from dmlc_core_tpu.ops.hist_pallas import hist_kernel_plan
 
             # settled once per fit for the deepest level, so an onehot
@@ -774,18 +781,24 @@ class GBDT:
         return self._method(bins, batch=-(-bins.shape[0] // mult) * mult)
 
     def _hist_blocks(self, method: str) -> dict:
-        """The kernel shape a fit's deepest level runs, as the
-        ``gbdt.fit.dispatch`` span records it: calls a level
-        (``node_blocks``) and grid steps over features inside each
-        (``feature_blocks``); 0 and 0 for a method that is no kernel."""
-        counts = (0, 0)
-        if method in ("pallas", "pallas_fused"):
-            from dmlc_core_tpu.ops.hist_pallas import hist_block_counts
+        """The kernel shape a fit runs, as the ``gbdt.fit.dispatch`` span
+        records it: calls at the deepest level (``node_blocks``), grid steps
+        over features inside each (``feature_blocks``), and the split of the
+        bin index each level's call runs, ``HxL`` from the root down
+        (``bin_split``, :func:`~dmlc_core_tpu.ops.hist_pallas.
+        hist_split_plan`); 0, 0 and "" for a method that is no kernel."""
+        counts, split = (0, 0), ""
+        if method == "pallas":
+            from dmlc_core_tpu.ops.hist_pallas import (hist_block_counts,
+                                                       hist_level_splits)
 
-            counts = hist_block_counts(
-                self.model_axis, self.num_feature,
-                2 ** (self.param.max_depth - 1), self.param.num_bins)
-        return dict(zip(("node_blocks", "feature_blocks"), counts))
+            p = self.param
+            counts = hist_block_counts(self.model_axis, self.num_feature,
+                                       2 ** (p.max_depth - 1), p.num_bins)
+            split = ",".join(f"{hi}x{lo}" for hi, lo in hist_level_splits(
+                self.model_axis, self.num_feature, p.max_depth, p.num_bins))
+        return {"node_blocks": counts[0], "feature_blocks": counts[1],
+                "bin_split": split}
 
     @functools.lru_cache(maxsize=None)
     def _round_fn(self, method: str = "scatter"):
@@ -794,7 +807,8 @@ class GBDT:
         p = self.param
 
         def one_round(margin, bins, label, weight, rnd):
-            bins, bins_fm = _bin_layouts(bins)
+            B, F = bins.shape
+            bins, bins_fm = _bin_layouts(bins, kernel=method == "pallas")
             onehot = (bin_onehot(bins, p.num_bins)
                       if method == "onehot" else None)
 
@@ -807,16 +821,15 @@ class GBDT:
                     min_split_loss=p.min_split_loss, feat_mask=fmask,
                     missing=p.handle_missing, reg_alpha=p.reg_alpha,
                     monotone=self._monotone,
-                    level_mask_fn=_level_mask_fn(p, rnd_, bins_.shape[1]),
+                    level_mask_fn=_level_mask_fn(p, rnd_, F),
                     max_delta_step=p.max_delta_step)
 
             if p.objective == "softmax":
                 return _softmax_round(p, bins, margin, label, weight, rnd,
-                                      grow)
+                                      grow, F)
             with jax.named_scope("gbdt.grad_hess"):
                 g, h = _grad_hess(margin, label, p.objective)
-                row_w, fmask = _tree_sampling(p, rnd, bins.shape[0],
-                                              bins.shape[1])
+                row_w, fmask = _tree_sampling(p, rnd, B, F)
                 if row_w is not None:
                     weight = weight * row_w
                 g, h = g * weight, h * weight
@@ -856,9 +869,9 @@ class GBDT:
         def fit(bins, label, weight, ev_bins=None, ev_label=None):
             import jax.numpy as jnp
 
-            n_rows = bins.shape[0]
+            n_rows, F = bins.shape
             pad = 0
-            if method in ("pallas", "pallas_fused"):
+            if method == "pallas":
                 from dmlc_core_tpu.ops.hist_pallas import fit_row_multiple
 
                 # pad rows to the kernel's tile multiple ONCE per fit (padded
@@ -871,8 +884,8 @@ class GBDT:
                 if pad:
                     label = jnp.pad(label, (0, pad))
                     weight = jnp.pad(weight, (0, pad))
-            bins, bins_fm = _bin_layouts(bins, pad)
-            B = bins.shape[0]
+            bins, bins_fm = _bin_layouts(bins, pad, kernel=method == "pallas")
+            B = n_rows + pad
             weight = _apply_pos_weight(weight, label, p)
             # the bin one-hot (the matmul RHS) is invariant across rounds and
             # levels: materialise once, outside the scan
@@ -889,14 +902,13 @@ class GBDT:
                     min_split_loss=p.min_split_loss, feat_mask=fmask,
                     missing=p.handle_missing, reg_alpha=p.reg_alpha,
                     monotone=self._monotone,
-                    level_mask_fn=_level_mask_fn(p, rnd, bins_.shape[1]),
+                    level_mask_fn=_level_mask_fn(p, rnd, F),
                     max_delta_step=p.max_delta_step)
 
             def round_step(margin, rnd):
                 if K == 1:
                     with jax.named_scope("gbdt.grad_hess"):
-                        row_w, fmask = _row_sampling(p, rnd, n_rows, B,
-                                                     bins.shape[1])
+                        row_w, fmask = _row_sampling(p, rnd, n_rows, B, F)
                         w = weight if row_w is None else weight * row_w
                         g, h = _grad_hess(margin, label, p.objective)
                         g, h = g * w, h * w
@@ -906,7 +918,7 @@ class GBDT:
                         margin = margin + delta
                     return margin, (sf, sb, lv, dl, sg, sc)
                 return _softmax_round(p, bins, margin, label, weight, rnd,
-                                      grow, n_rows=n_rows)
+                                      grow, F, n_rows=n_rows)
 
             margin0 = jnp.full((B,) if K == 1 else (B, K), p.base_score,
                                jnp.float32)

@@ -7,25 +7,44 @@ HBM-bound: the materialised one-hot is ``F*nbins/8`` times larger than the
 binned features (28 feat x 256 bins -> 14 KB/row in bf16 vs 112 B/row of
 int32 bins), and every tree level of every boosting round re-reads all of it.
 
-This kernel keeps the matmul but builds the one-hot **tile-by-tile in VMEM**:
+This kernel keeps the matmul but builds both of its operands **tile-by-tile
+in VMEM**, rows on the lanes, and splits the bin index between them
+(``bin = hi * L + lo``, :func:`hist_split_plan`)::
+
+    key_i            = node_i * H + hi_i      (node outside [0, n): no key)
+    A [(n, hi, c), i] = (key_i == n * H + hi) ? (g_i if c == 0 else h_i) : 0
+    LO[lo, i]         = (lo_i == lo)
+    acc_f[lo, (n, hi, c)] += LO . A^T         (contract the tile's rows; f32)
+
+A level of n nodes needs only ``2n * num_bins`` buckets a feature, so ``H``
+and ``L`` are chosen per level to give the product ``2nH * L`` just that
+many, shared out so that neither side of the MXU waits for the other:
+(16, 16) at the root of a 256-bin fit, (2, 128) at 32 nodes, where the dot
+is a full 128 x 128 tile a feature and 128 rows and runs at the MXU's peak;
+the shallower levels cost a quarter to a half of that, not all of it.
+From 128 nodes ``H = 1``: the plain one-hot matmul, transposed.
 
 - grid = (feature blocks, row tiles), both sequential on TPU, rows inner;
-- an ``[M, F_blk*nbins]`` f32 accumulator lives in one VMEM output block
+- the ``[F_blk, L, 2nH]`` f32 accumulator lives in one VMEM output block
   indexed by the feature block alone, so it persists across a block's row
   tiles (zeroed at the first);
-- per step: DMA ``W`` tile ``[M, TB]`` (bf16) + bins tile ``[TB, F_blk]``
-  (int32), then for each feature compare-to-iota -> ``[TB, nbins]`` one-hot
-  in VMEM and issue one MXU dot, accumulating in f32;
-- a table whose ``[M, F*nbins]`` accumulator fits the VMEM budget is one
-  feature block; a wider one is cut by :func:`hist_block_plan` into blocks
-  of 128 features, ``W`` re-read once per block.  Every feature's one-hot
-  is still built once, so a level stays ONE ``hist_level`` call whose cost
-  follows rows x features.
+- per step: DMA the node / g / h row tiles ``[1, TB]`` and the bins tile
+  ``[F_blk, TB]`` (feature-major int32: v5e Mosaic lowers no sub-32-bit
+  compare), then for each feature two compare-selects against a sublane
+  iota and one MXU dot.  Both operands are built as int32 words that hold
+  TWO bf16 rows each (g and h of one key; ones of two neighbouring ``lo``)
+  and are bitcast to bf16: half the compares, and no convert;
+- a table whose accumulator fits the VMEM budget is one feature block; a
+  wider one is cut by :func:`hist_block_plan` into blocks of 128 features.
+  Every feature's one-hots are still built once, so a level stays ONE
+  ``hist_level`` call whose cost follows rows x features;
+- the result leaves the kernel as ``[F, L, 2nH]``; one XLA transpose a level
+  puts it back to ``(G, H)[n, F, num_bins]``.
 
-HBM traffic per level falls from ``B*F*nbins*2`` bytes to
-``B*(4F + 2M + 12)`` — ~100x for the flagship shapes — turning the histogram
-from bandwidth- to compute-bound.  Numerics match the ``"onehot"`` method
-exactly (same bf16 one-hot / bf16 W / f32 accumulate).
+HBM traffic per level is ``B*(4F + 12)`` bytes — no weight matrix is ever
+written.  Numerics match the ``"onehot"`` method term by term (a bf16-rounded
+g or h, or 0; a bf16 0/1; f32 accumulate); only the order of the f32
+additions may differ.
 
 Used automatically on TPU via ``resolve_hist_method("auto")``: the plan
 blocks features, then nodes, until an accumulator block fits VMEM, so width
@@ -38,17 +57,14 @@ On a TPU backend a kernel Mosaic rejects raises with the compiler's message
 from __future__ import annotations
 
 import functools
-
-import numpy as np
-
-from dmlc_core_tpu.utils.logging import log_warning
+import math
 
 __all__ = ["hist_matmul_pallas", "grad_hist_pallas",
-           "grad_hist_pallas_fused", "grad_hist_pallas_sharded",
+           "grad_hist_pallas_sharded",
            "ambient_mesh", "hist_kernel_plan", "fit_row_multiple",
-           "interpret_mode",
-           "pallas_fused_supported", "pallas_i8_supported", "hist_fits_vmem",
-           "hist_block_plan", "hist_block_counts",
+           "interpret_mode", "hist_fits_vmem",
+           "hist_block_plan", "hist_block_counts", "hist_split_plan",
+           "hist_level_splits",
            "BLOCK_ROWS", "DATA_AXIS"]
 
 # interpreter mode: runs the kernels on CPU for tests/debugging (flipped by
@@ -80,10 +96,12 @@ def interpret_mode() -> bool:
 
 # row-tile size: callers that want the wrapper's internal padding to no-op
 # (e.g. GBDT's fit-level padding) must pad to a multiple of this.
-# DMLC_TPU_HIST_BLOCK_ROWS overrides for on-chip tuning sweeps; 1024 is the
-# measured-best default on v5e (see BASELINE.md round-3 block_rows sweep).
+# DMLC_TPU_HIST_BLOCK_ROWS overrides for on-chip tuning sweeps.  2048 from
+# this body's sweep on a v5e (a HIGGS fit: 1024 +3.2%, 4096 -1.6%; PERF.md,
+# PR 28): a grid step costs about a third of a microsecond whatever it
+# holds, and at 4096 the widest blocked level leaves Mosaic's default VMEM.
 try:
-    BLOCK_ROWS = int(_os.environ.get("DMLC_TPU_HIST_BLOCK_ROWS", "") or 1024)
+    BLOCK_ROWS = int(_os.environ.get("DMLC_TPU_HIST_BLOCK_ROWS", "") or 2048)
 except ValueError:
     raise ValueError(
         "DMLC_TPU_HIST_BLOCK_ROWS must be an integer multiple of the 128 "
@@ -95,30 +113,27 @@ if BLOCK_ROWS < 128 or BLOCK_ROWS % 128:
         f"lane width, got {BLOCK_ROWS}")
 
 
-def _bins_compare_dtype(num_bins: int):
-    """dtype bins are compared in inside the kernel: int8 when the bin ids
-    fit (<=256 with wraparound) AND the backend lowers it, else int32."""
-    import jax.numpy as jnp
-
-    if num_bins <= 256 and pallas_i8_supported():
-        return jnp.int8
-    return jnp.int32
-
 # VMEM budget for the resident accumulator block (bytes): what
 # hist_block_plan cuts a level's [2n, F*nbins] histogram down to.
 _ACC_BYTES_LIMIT = 8 * 1024 * 1024
 
-# a bins tile's minor (feature) extent is whole or a multiple of the lanes
+# features of a blocked accumulator's block
 _LANES = 128
+
+# VMEM a call may count on unasked: Mosaic's default on a v5e is 16 MiB (of
+# 128), less a quarter for what the compiler keeps there itself
+_VMEM_UNASKED = 12 * 1024 * 1024
 
 
 def _pad_nodes(num_nodes: int) -> int:
-    """Node-slot padding so M = 2*n_pad is a multiple of the bf16 tile (16)."""
+    """Node slots the byte rule counts: a multiple of 8, and 8 at least."""
     return -(-max(8, num_nodes) // 8) * 8
 
 
 def hist_fits_vmem(num_nodes: int, num_feature: int, num_bins: int) -> bool:
-    """Whether the resident [2*n_pad, F*nbins] f32 accumulator fits VMEM."""
+    """Whether a level's resident f32 accumulator fits the VMEM budget,
+    counted as ``[2*n_pad, F*nbins]``: an upper bound of the kernel's
+    ``[F, L, 2nH]`` block wherever ``H * L`` is ``num_bins``."""
     return 2 * _pad_nodes(num_nodes) * num_feature * num_bins * 4 \
         <= _ACC_BYTES_LIMIT
 
@@ -129,17 +144,14 @@ def hist_block_plan(num_nodes: int, num_feature: int, num_bins: int):
     overflow VMEM.  The one place the budget is applied.
 
     Features are blocked first: a feature block is a grid step of the SAME
-    kernel call (every feature's one-hot is still built once; only ``W``
-    is re-read), while a node block is another call that re-reads the bins
-    and re-builds every one-hot — kernel cost is VPU-bound and
-    m-independent (measured — BASELINE.md r3 profile), so #node sweeps
-    scales the cost.  So: the most nodes for which the narrowest legal
-    feature block (128 bins columns, or all F of a narrower table) fits,
-    and beside them all F features in one block where those fit,
-    else blocks of 128.  Not wider where the budget would allow it: the
-    tile body is unrolled over the block's features, and at 512 of them
-    Mosaic's register allocator spilled 173 MB for a v5e (PR 27), while
-    ``W``'s re-reads are small beside the bins at any node count.
+    kernel call (every feature's one-hots are still built once; only the
+    12 B a row of node, g and h are re-read), while a node block is another
+    call that re-reads the bins and re-builds every one-hot.  So: the most
+    nodes for which the narrowest legal feature block (128 features, or all
+    F of a narrower table) fits, and beside them all F features in one
+    block where those fit, else blocks of 128.  Not wider where the budget
+    would allow it: at 512 features a block Mosaic spilled 173 MB for a
+    v5e (PR 27, the body then unrolled over the block's features).
     """
     narrow = min(num_feature, _LANES)
     nodes = num_nodes
@@ -154,127 +166,227 @@ def hist_block_plan(num_nodes: int, num_feature: int, num_bins: int):
     return nodes, _LANES
 
 
+def _shard_features(model_axis, num_feature: int) -> int:
+    """Features one chip's kernel sees: ``F/mp`` under a ``model_axis``."""
+    if model_axis is None:
+        return num_feature
+    return num_feature // ambient_mesh().shape[model_axis]
+
+
 def hist_block_counts(model_axis, num_feature: int, num_nodes: int,
                       num_bins: int):
     """``(node blocks, feature blocks)`` one chip's kernel runs a level of
     ``num_nodes`` nodes in: kernel calls, and grid steps over features
     inside each.  For a fit :func:`hist_kernel_plan` settled on the kernel;
     what ``gbdt.fit.dispatch`` records beside the method."""
-    if model_axis is not None:
-        num_feature //= ambient_mesh().shape[model_axis]
+    num_feature = _shard_features(model_axis, num_feature)
     nodes, feats = hist_block_plan(num_nodes, num_feature, num_bins)
     return -(-num_nodes // nodes), -(-num_feature // feats)
 
 
-def _accumulate_tile(w, bins_ref, out_ref, num_feature: int, num_bins: int,
-                     row_axis: int = 0):
-    """Shared tile body: zero-init at the first step of the grid's row axis,
-    then per-feature one-hot dots of ``w`` [M, TB] accumulated into the
-    resident ``out_ref``.
+def hist_level_splits(model_axis, num_feature: int, max_depth: int,
+                      num_bins: int):
+    """:func:`hist_split_plan` of every level of a ``max_depth`` fit, root
+    first, for the nodes one kernel call of that level holds (a node block
+    where the level is cut into several).  For a fit
+    :func:`hist_kernel_plan` settled on the kernel."""
+    num_feature = _shard_features(model_axis, num_feature)
+    return [hist_split_plan(min(2 ** depth, hist_block_plan(
+        2 ** depth, num_feature, num_bins)[0]), num_bins)
+        for depth in range(max_depth)]
 
-    The iota matches the bins dtype: callers may pass bins as int8 (the
-    profiled v5e bottleneck is this in-VMEM one-hot build, not the MXU dots
-    — kernel time is m-independent — and int8 compares run 4 lanes/cycle
-    wider on the VPU).  num_bins=256 still fits: both sides wrap through
-    int8 identically, so equality is preserved.
+
+def hist_split_plan(num_nodes: int, num_bins: int):
+    """``(H, L)``: how one kernel call of ``num_nodes`` nodes splits the bin
+    index, ``bin = hi * L + lo`` with ``hi`` in ``[0, H)`` on the node's side
+    of the product and ``lo`` in ``[0, L)`` on the other.  A pure function
+    of the two static shapes; what ``gbdt.fit.dispatch`` records per level.
+
+    ``L`` is the power of two at or above ``sqrt(num_nodes * num_bins)``,
+    which makes the node side's ``2nH`` rows about twice the ``L`` of the
+    other: on a v5e the dot costs ``max(2nH, 2L)`` cycles a 1,024-row tile
+    and feature (per 128 of ``2nH``; PERF.md, PR 28), and among the splits
+    that tie on it this one builds the fewest one-hot elements.  At 256
+    bins: (16, 16) at the root, then (8, 32), (8, 32), (4, 64), (4, 64),
+    (2, 128) at 32 nodes, (1, 256) from 128.  Never under 16 (the bf16
+    tile's sublanes) nor past the bins: a table of 16 bins or fewer is not
+    split.  ``H * L >= num_bins``; pairs past ``num_bins`` (255, 257 bins)
+    are columns no row matches.
     """
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    @pl.when(pl.program_id(row_axis) == 0)
-    def _zero():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, num_bins), 1)
-    iota = iota.astype(bins_ref.dtype)
-    for f in range(num_feature):
-        onehot = (bins_ref[:, f:f + 1] == iota).astype(w.dtype)  # [TB, nbins]
-        out_ref[:, f * num_bins:(f + 1) * num_bins] += jax.lax.dot_general(
-            w, onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    most = 1 << max(4, (num_bins - 1).bit_length())
+    want = math.isqrt(num_nodes * num_bins - 1) + 1
+    lo = min(most, max(16, 1 << (want - 1).bit_length()))
+    return -(-num_bins // lo), lo
 
 
-def _split_gh(out, n_pad: int, num_nodes: int, num_feature: int,
-              num_bins: int):
-    """Shared epilogue: [2*n_pad, F*nbins] -> (G, H) trimmed to num_nodes."""
-    out = out.reshape(2, n_pad, num_feature, num_bins)
-    return out[0, :num_nodes], out[1, :num_nodes]
+def _key_rows(num_nodes: int, hi: int) -> int:
+    """int32 sublanes of the node-side operand: one per (node, hi) key,
+    padded to the int32 tile (8) with keys no row carries."""
+    return -(-num_nodes * hi // 8) * 8
 
 
-def _kernel(w_ref, bins_ref, out_ref, *, num_feature: int, num_bins: int,
-            row_axis: int = 0):
-    _accumulate_tile(w_ref[:], bins_ref, out_ref, num_feature, num_bins,
-                     row_axis)
+# bf16 1.0 in the low / high half of an int32 word
+_ONE_LOW, _ONE_HIGH = 0x3F80, 0x3F800000
+
+# features of a block the tile body is unrolled over, a whole blocked block:
+# straight-line code is what lets the scheduler build one feature's operands
+# under the previous feature's dot (a loop of single features took 1.75x as
+# long, groups of 16 6-25% longer; PERF.md, PR 28).  A wider block (a table
+# of few bins that fits unblocked) loops over groups of these.
+_UNROLL = _LANES
 
 
-def hist_matmul_pallas(w, bins, num_bins: int, block_rows: int = BLOCK_ROWS,
-                       block_features=None):
-    """``out[m, f*nbins + b] = sum_i w[m, i] * (bins[i, f] == b)``.
+def _kernel(node_ref, g_ref, h_ref, bins_ref, out_ref, *, num_nodes: int,
+            hi: int, lo: int, num_feature: int):
+    """One (feature block, row tile) step: zero the resident accumulator at
+    the block's first tile, then per feature build ``A`` and ``LO`` from the
+    tile's row vectors and accumulate ``LO . A^T``.
 
-    Args:
-      w: [M, B] bf16 per-row weights (M multiple of 16; rows beyond the live
-        node count must be zero).
-      bins: [B, F] int32 binned features in [0, num_bins).
-      num_bins: static bin count.
-      block_rows: row-tile size (B is padded up to a multiple internally).
-      block_features: features per accumulator block (128, or a multiple);
-        None or >= F keeps all F in one block.
-
-    Returns [M, F*num_bins] float32.
+    Both operands are built two bf16 rows to an int32 word, the pair that
+    ``pltpu.bitcast`` unfolds along the sublanes (low half first): a key's
+    g and h, and the ones of ``lo`` = 2k and 2k + 1.  So ``A`` costs one
+    compare and one select per KEY and tile lane, ``LO`` one per two bins.
     """
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    m, b = w.shape
-    bf = bins.shape[1]
-    bins = bins.astype(_bins_compare_dtype(num_bins))
+    @pl.when(pl.program_id(1) == 0)
+    def _zero():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    shift = lo.bit_length() - 1
+    node = node_ref[:]                                       # [1, TB]
+    # a row whose node is outside [0, n) gets a key no iota value equals
+    base = jnp.where((node >= 0) & (node < num_nodes), node * hi, -(1 << 30))
+
+    def bf16_bits(x):
+        return pltpu.bitcast(x.astype(jnp.bfloat16).astype(jnp.float32),
+                             jnp.int32)
+
+    gh = jax.lax.shift_right_logical(bf16_bits(g_ref[:]), 16) \
+        | bf16_bits(h_ref[:])                                # g low, h high
+    key_iota = jax.lax.broadcasted_iota(
+        jnp.int32, (_key_rows(num_nodes, hi), 1), 0)
+    lo_iota = jax.lax.broadcasted_iota(jnp.int32, (lo // 2, 1), 0)
+
+    def one_feature(f):
+        b = bins_ref[pl.ds(f, 1), :]                         # [1, TB]
+        a = jnp.where(key_iota == base + (b >> shift), gh, 0)
+        low = b & (lo - 1)
+        one = jnp.where((low & 1) == 1, _ONE_HIGH, _ONE_LOW)
+        lo_hot = jnp.where(lo_iota == (low >> 1), one, 0)
+        out_ref[f] += jax.lax.dot_general(
+            pltpu.bitcast(lo_hot, jnp.bfloat16),             # [L, TB]
+            pltpu.bitcast(a, jnp.bfloat16),                  # [2 keys, TB]
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+    def group(k, carry):
+        for u in range(_UNROLL):
+            one_feature(k * _UNROLL + u)
+        return carry
+
+    # a block of at most _UNROLL features is straight-line code throughout
+    groups = num_feature // _UNROLL if num_feature > _UNROLL else 0
+    if groups:
+        jax.lax.fori_loop(0, groups, group, 0)
+    for f in range(groups * _UNROLL, num_feature):
+        one_feature(f)
+
+
+def hist_matmul_pallas(rows, bins, num_bins: int, *, num_nodes: int,
+                       block_rows: int = BLOCK_ROWS, block_features=None):
+    """``out[c*n + k, f*nbins + b] = sum_i [node_i == k] * (g_i, h_i)[c] *
+    (bins[f, i] == b)``: the kernel's entry, one ``hist_level`` call.
+
+    Args:
+      rows: the per-row ``(node, g, h)``, each ``[B]``: int32 node ids (a row
+        whose id is outside ``[0, num_nodes)`` adds nothing) and f32 g, h.
+      bins: [F, B] int32 binned features in [0, num_bins), feature-major.
+      num_bins, num_nodes: static.
+      block_rows: row-tile size (B is padded up to a multiple internally).
+      block_features: features per accumulator block (a multiple of 8);
+        None or >= F keeps all F in one block.
+
+    Returns [2*num_nodes, F*num_bins] float32, the kernel's ``[F, L, 2nH]``
+    transposed back.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    node, g, h = (jnp.asarray(r)[None, :] for r in rows)
+    bf, b = bins.shape
     if b % block_rows:
-        pad = block_rows - b % block_rows
-        w = jnp.pad(w, ((0, 0), (0, pad)))         # zero W => zero contribution
-        bins = jnp.pad(bins, ((0, pad), (0, 0)))
-        b += pad
-    tiles = b // block_rows
+        pad = ((0, 0), (0, block_rows - b % block_rows))
+        node = jnp.pad(node, pad, constant_values=-1)        # no key
+        g, h, bins = jnp.pad(g, pad), jnp.pad(h, pad), jnp.pad(bins, pad)
+        b += pad[1][1]
     if block_features is None or block_features >= bf:
         block_features = bf
+    hi, lo = hist_split_plan(num_nodes, num_bins)
+    cols = 2 * _key_rows(num_nodes, hi)
     # feature blocks on the OUTER axis, row tiles inside: the accumulator
-    # block moves only when a feature block's rows are all in, and W's tile
-    # is the same for every block.  F need not divide: the last block's
-    # columns beyond F read unspecified bins whose histogram columns lie
-    # beyond the output and are never written back.  One buffer for an
+    # block moves only when a feature block's rows are all in, and the row
+    # vectors' tiles are the same for every block.  F need not divide: the
+    # last block's features beyond F read unspecified bins whose histograms
+    # lie beyond the output and are never written back.  One buffer for an
     # output block whose index moves — Pallas would keep two, and the
     # budget is for one.
     blocks = pl.cdiv(bf, block_features)
     out_buffering = {"pipeline_mode": pl.Buffered(1)} if blocks > 1 else {}
-    kernel = functools.partial(_kernel, num_feature=block_features,
-                               num_bins=num_bins, row_axis=1)
-    return pl.pallas_call(
+    # VMEM the call holds, as Mosaic tiles it: the accumulator (its minor
+    # extent padded to the lanes: few bins or few nodes leave a tile mostly
+    # empty, which the byte rule of hist_block_plan does not count), the
+    # bins tile twice, the two operands.  Within Mosaic's default for every
+    # blocked shape; an unblocked table of many narrow features asks for
+    # what it needs.
+    vmem = ((1 if blocks > 1 else 2) * block_features * lo
+            * -(-cols // _LANES) * _LANES * 4
+            + 2 * block_features * block_rows * 4
+            + (cols + lo) * block_rows * 2)
+    params = {}
+    if vmem > _VMEM_UNASKED:
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=vmem + _VMEM_UNASKED // 3)
+    row_spec = pl.BlockSpec((1, block_rows), lambda j, i: (0, i),
+                            memory_space=pltpu.VMEM)
+    kernel = functools.partial(_kernel, num_nodes=num_nodes, hi=hi, lo=lo,
+                               num_feature=block_features)
+    out = pl.pallas_call(
         kernel,
-        grid=(blocks, tiles),
-        in_specs=[
-            pl.BlockSpec((m, block_rows), lambda j, i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((block_rows, block_features), lambda j, i: (i, j),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((m, block_features * num_bins),
-                               lambda j, i: (0, j),
+        grid=(blocks, b // block_rows),
+        in_specs=[row_spec, row_spec, row_spec,
+                  pl.BlockSpec((block_features, block_rows),
+                               lambda j, i: (j, i),
+                               memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((block_features, lo, cols),
+                               lambda j, i: (j, 0, 0),
                                memory_space=pltpu.VMEM, **out_buffering),
-        out_shape=jax.ShapeDtypeStruct((m, bf * num_bins), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((bf, lo, cols), jnp.float32),
         interpret=interpret_mode(),
         name="hist_level",
-    )(w, bins)
+        **params,
+    )(node.astype(jnp.int32), g.astype(jnp.float32), h.astype(jnp.float32),
+      bins)
+    # [F, lo, (node, hi, c)] -> [(c, node), (F, hi, lo)], bins past num_bins
+    # (no row has them) dropped
+    out = out[:, :, :2 * num_nodes * hi].reshape(bf, lo, num_nodes, hi, 2)
+    out = out.transpose(4, 2, 0, 3, 1).reshape(2 * num_nodes, bf, hi * lo)
+    return out[:, :, :num_bins].reshape(2 * num_nodes, bf * num_bins)
 
 
 def grad_hist_pallas(bins, node_ids, grad, hess, num_nodes: int,
                      num_bins: int):
     """Per-(node, feature, bin) gradient/hessian sums via the VMEM kernel.
 
-    Same contract as :func:`.histogram.grad_histogram`; returns (G, H) each
-    [num_nodes, F, num_bins] float32.  Rows with out-of-range (e.g. negative)
-    node ids contribute nothing.
+    ``bins`` is FEATURE-MAJOR, ``[F, B]`` int32 — the layout the kernel
+    reads; otherwise the contract of :func:`.histogram.grad_histogram`
+    (which hands row-major bins over transposed): returns (G, H) each
+    [num_nodes, F, num_bins] float32.  Rows with out-of-range (e.g.
+    negative) node ids contribute nothing.
 
     Levels too wide or deep for one resident accumulator run blocked (see
     :func:`hist_block_plan`): feature blocks are grid steps of one kernel
@@ -283,98 +395,20 @@ def grad_hist_pallas(bins, node_ids, grad, hess, num_nodes: int,
     """
     import jax.numpy as jnp
 
-    plan = hist_block_plan(num_nodes, bins.shape[1], num_bins)
+    bf = bins.shape[0]
+    plan = hist_block_plan(num_nodes, bf, num_bins)
     assert plan is not None, "caller must gate on hist_block_plan"
     block, block_features = plan
-    if block < num_nodes:
-        node_ids = node_ids.astype(jnp.int32)
-        parts = [
-            _grad_hist_pallas_block(bins, node_ids - b0, grad, hess,
-                                    min(block, num_nodes - b0), num_bins,
-                                    block_features)
-            for b0 in range(0, num_nodes, block)
-        ]
-        return (jnp.concatenate([p[0] for p in parts]),
-                jnp.concatenate([p[1] for p in parts]))
-    return _grad_hist_pallas_block(bins, node_ids, grad, hess, num_nodes,
-                                   num_bins, block_features)
-
-
-def _grad_hist_pallas_block(bins, node_ids, grad, hess, num_nodes: int,
-                            num_bins: int, block_features=None):
-    import jax.numpy as jnp
-
-    bins = jnp.asarray(bins).astype(jnp.int32)
-    bf = bins.shape[1]
-    n_pad = _pad_nodes(num_nodes)
-    iota_n = jnp.arange(n_pad, dtype=jnp.int32)
-    nodehot = node_ids.astype(jnp.int32)[None, :] == iota_n[:, None]  # [n, B]
-    w = jnp.concatenate([
-        jnp.where(nodehot, grad[None, :], 0.0),
-        jnp.where(nodehot, hess[None, :], 0.0),
-    ], axis=0).astype(jnp.bfloat16)                # [2*n_pad, B]
-    out = hist_matmul_pallas(w, bins, num_bins,
-                             block_features=block_features)
-    return _split_gh(out, n_pad, num_nodes, bf, num_bins)
-
-
-def _fused_kernel(node_ref, g_ref, h_ref, bins_ref, out_ref, *,
-                  n_pad: int, num_feature: int, num_bins: int):
-    import jax
-    import jax.numpy as jnp
-
-    # W tile [2*n_pad, TB] built in VMEM from node/g/h (12 B/row of HBM
-    # traffic instead of 4*n_pad B/row for a materialised W)
-    iota_n = jax.lax.broadcasted_iota(jnp.int32, (n_pad, 1), 0)
-    nodehot = (iota_n == node_ref[:]).astype(jnp.bfloat16)   # [n_pad, TB]
-    w = jnp.concatenate([nodehot * g_ref[:].astype(jnp.bfloat16),
-                         nodehot * h_ref[:].astype(jnp.bfloat16)], axis=0)
-    _accumulate_tile(w, bins_ref, out_ref, num_feature, num_bins)
-
-
-def grad_hist_pallas_fused(bins, node_ids, grad, hess, num_nodes: int,
-                           num_bins: int, block_rows: int = BLOCK_ROWS):
-    """Like :func:`grad_hist_pallas`, with the weight matrix built in-kernel.
-
-    Skips the XLA-side [2n, B] W materialisation entirely: the kernel reads
-    node/g/h row tiles and bins, and builds both one-hots in VMEM.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    bins = jnp.asarray(bins).astype(_bins_compare_dtype(num_bins))
-    b, bf = bins.shape
-    n_pad = _pad_nodes(num_nodes)
-    node = node_ids.astype(jnp.int32).reshape(1, b)
-    g = grad.astype(jnp.float32).reshape(1, b)
-    h = hess.astype(jnp.float32).reshape(1, b)
-    if b % block_rows:
-        pad = block_rows - b % block_rows
-        bins = jnp.pad(bins, ((0, pad), (0, 0)))
-        node = jnp.pad(node, ((0, 0), (0, pad)), constant_values=-1)
-        g = jnp.pad(g, ((0, 0), (0, pad)))
-        h = jnp.pad(h, ((0, 0), (0, pad)))
-        b += pad
-    m = 2 * n_pad
-    kernel = functools.partial(_fused_kernel, n_pad=n_pad, num_feature=bf,
-                               num_bins=num_bins)
-    row_spec = pl.BlockSpec((1, block_rows), lambda i: (0, i),
-                            memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
-        kernel,
-        grid=(b // block_rows,),
-        in_specs=[row_spec, row_spec, row_spec,
-                  pl.BlockSpec((block_rows, bf), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((m, bf * num_bins), lambda i: (0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((m, bf * num_bins), jnp.float32),
-        interpret=interpret_mode(),
-        name="hist_level_fused",
-    )(node, g, h, bins)
-    return _split_gh(out, n_pad, num_nodes, bf, num_bins)
+    node_ids = node_ids.astype(jnp.int32)
+    parts = [
+        hist_matmul_pallas((node_ids - b0, grad, hess), bins, num_bins,
+                           num_nodes=min(block, num_nodes - b0),
+                           block_features=block_features
+                           ).reshape(2, -1, bf, num_bins)
+        for b0 in range(0, num_nodes, block)
+    ]
+    out = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+    return out[0], out[1]
 
 
 # mesh axis name the whole package shards batch rows over (parallel/mesh.py
@@ -411,8 +445,7 @@ def fit_row_multiple() -> int:
 
 def hist_kernel_plan(method: str, model_axis, num_feature: int,
                      num_nodes: int, num_bins: int, batch=None):
-    """Settle a ``pallas``/``pallas_fused`` request against the shapes and
-    the ambient mesh.  Returns ``(method, mesh)``: the method that will
+    """Settle a ``pallas`` request against the shapes and the ambient mesh.  Returns ``(method, mesh)``: the method that will
     actually run and the mesh to shard_map the kernel over (None = one
     plain kernel call).
 
@@ -434,7 +467,6 @@ def hist_kernel_plan(method: str, model_axis, num_feature: int,
       slice is blocked by :func:`hist_block_plan` (whose None — 8 node
       slots of 128 features over the budget, bins in the tens of
       thousands — is the one shape left to ``onehot``).
-    - Blocked levels (nodes or features) have no fused variant.
     """
     mesh = ambient_mesh()
     dp = _data_parallelism(mesh)
@@ -447,25 +479,22 @@ def hist_kernel_plan(method: str, model_axis, num_feature: int,
     if sharded and (num_feature % mp != 0
                     or (batch is not None and batch % dp != 0)):
         return "onehot", None
-    plan = hist_block_plan(num_nodes, num_feature // mp, num_bins)
-    if plan is None:
+    if hist_block_plan(num_nodes, num_feature // mp, num_bins) is None:
         return "onehot", None
-    if plan != (num_nodes, num_feature // mp) and method == "pallas_fused":
-        method = "pallas"
     return method, (mesh if sharded else None)
 
 
 def grad_hist_pallas_sharded(bins, node_ids, grad, hess, num_nodes: int,
                              num_bins: int, mesh, model_axis=None,
-                             data_axis: str = DATA_AXIS,
-                             fused: bool = False):
+                             data_axis: str = DATA_AXIS):
     """shard_map-wrapped VMEM hist: rows dp-sharded, features model-sharded.
 
     The only way the Pallas kernel runs on more than one device: each shard
     runs the VMEM kernel on its local rows and partial histograms are
     psummed over the data axis — the distributed-hist aggregation XGBoost
-    does over Rabit.  With a ``model_axis`` each model shard also slices its
-    own ``F/mp`` feature columns (bins arrive feature-replicated) and the
+    does over Rabit.  ``bins`` is feature-major ``[F, B]`` as the kernel
+    reads it.  With a ``model_axis`` each model shard also slices its
+    own ``F/mp`` feature rows (bins arrive feature-replicated) and the
     output is ``P(None, model_axis, None)`` — exactly the constraint the
     GSPMD path advertises, so split-finding code downstream is unchanged;
     without one the output is replicated.
@@ -478,16 +507,16 @@ def grad_hist_pallas_sharded(bins, node_ids, grad, hess, num_nodes: int,
     from jax.sharding import PartitionSpec as P
 
     row_axis = data_axis if data_axis in mesh.shape else None
-    inner = grad_hist_pallas_fused if fused else grad_hist_pallas
-    f_local = (bins.shape[1] if model_axis is None
-               else bins.shape[1] // mesh.shape[model_axis])
+    f_local = (bins.shape[0] if model_axis is None
+               else bins.shape[0] // mesh.shape[model_axis])
 
     def local_hist(b, n, g, h):
         if model_axis is not None:
             idx = jax.lax.axis_index(model_axis)
             b = jax.lax.dynamic_slice_in_dim(b, idx * f_local, f_local,
-                                             axis=1)
-        G, H = inner(b, n.astype(jnp.int32), g, h, num_nodes, num_bins)
+                                             axis=0)
+        G, H = grad_hist_pallas(b, n.astype(jnp.int32), g, h, num_nodes,
+                                num_bins)
         if row_axis is not None:
             G = jax.lax.psum(G, row_axis)
             H = jax.lax.psum(H, row_axis)
@@ -498,90 +527,6 @@ def grad_hist_pallas_sharded(bins, node_ids, grad, hess, num_nodes: int,
     # psum above already makes the outputs data-axis-invariant
     return jax.shard_map(
         local_hist, mesh=mesh,
-        in_specs=(P(row_axis, None), P(row_axis), P(row_axis), P(row_axis)),
+        in_specs=(P(None, row_axis), P(row_axis), P(row_axis), P(row_axis)),
         out_specs=(out_spec, out_spec), check_vma=False,
     )(bins, node_ids, grad, hess)
-
-
-def _probe_failed(what: str, exc: Exception) -> bool:
-    """A variant probe is allowed to say no — never silently: the variant
-    changes which kernel ``auto`` runs, so the compiler's reason is logged."""
-    reason = (str(exc).strip().splitlines() or [""])[0]
-    log_warning(f"hist_pallas: {what} rejected by the compiler, variant "
-                f"off ({type(exc).__name__}: {reason})")
-    return False
-
-
-@functools.lru_cache(maxsize=None)
-def pallas_i8_supported() -> bool:
-    """Probe whether int8 bins compare+select lowers in the kernel.
-
-    Probed with a direct pallas_call (not through the wrappers, which would
-    recurse into this gate): an int8 bins tile against the shared tile body.
-    Falls back to int32 bins — with a logged reason — when Mosaic rejects
-    the int8 vector ops (the v5e does: "Target does not support this
-    comparison"), and is disabled outright by DMLC_TPU_HIST_I8=0.
-    """
-    if _os.environ.get("DMLC_TPU_HIST_I8", "").strip() == "0":
-        return False
-    import jax
-
-    interpret = interpret_mode()
-    if jax.default_backend() == "cpu" and not interpret:
-        return False
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    kernel = functools.partial(_kernel, num_feature=2, num_bins=8)
-    probe = jax.jit(lambda w, b: pl.pallas_call(
-        kernel,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((16, 128), lambda i: (0, i),
-                               memory_space=pltpu.VMEM),
-                  pl.BlockSpec((128, 2), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((16, 16), lambda i: (0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((16, 16), jnp.float32),
-        interpret=interpret,
-    )(w, b))
-    # the gate is first consulted while a kernel wrapper is being traced:
-    # run the probe eagerly there, not as part of the caller's program
-    with jax.core.eval_context():
-        w = jnp.zeros((16, 128), jnp.bfloat16).at[0, 0].set(1.0)
-        bins = jnp.zeros((128, 2), jnp.int8)
-        try:
-            out = np.asarray(probe(w, bins))
-        except Exception as exc:  # noqa: BLE001 — logged, never silent
-            return _probe_failed("int8 bin compares", exc)
-    return bool(out[0, 0] == 1.0)
-
-
-@functools.lru_cache(maxsize=None)
-def pallas_fused_supported() -> bool:
-    """Probe the fused-W kernel separately from the plain one.
-
-    The fused kernel's in-VMEM bf16 concat at the n_pad=8 boundary (below the
-    16-sublane tile) can fail to lower on real Mosaic even when
-    :func:`hist_matmul_pallas` compiles.  ``auto`` never selects the fused
-    kernel; a user-selected ``pallas_fused`` that does not lower falls back
-    to ``pallas`` with the compiler's reason logged.
-    """
-    import jax
-
-    if jax.default_backend() == "cpu" and not interpret_mode():
-        return False
-    import jax.numpy as jnp
-
-    probe = jax.jit(lambda b, n, g, h: grad_hist_pallas_fused(
-        b, n, g, h, num_nodes=4, num_bins=8, block_rows=128))
-    with jax.core.eval_context():
-        bins = jnp.zeros((128, 2), jnp.int32)
-        node = jnp.zeros((128,), jnp.int32)
-        one = jnp.ones((128,), jnp.float32)
-        try:
-            G = np.asarray(probe(bins, node, one, one)[0])
-        except Exception as exc:  # noqa: BLE001 — logged, never silent
-            return _probe_failed("the fused-W kernel", exc)
-    return bool(G[0, 0, 0] == 128.0)

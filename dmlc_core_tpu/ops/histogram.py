@@ -51,6 +51,10 @@ def resolve_hist_method(method: str, *arrays) -> str:
     Mosaic rejects raises with the compiler's message instead of quietly
     training through the HBM-bound ``onehot`` path.
     """
+    if method == "pallas_fused":
+        # the name of a retired variant (W built in the kernel, which the one
+        # kernel now does at every level): still accepted, runs ``pallas``
+        return "pallas"
     if method != "auto":
         return method
     import jax
@@ -273,7 +277,7 @@ def apply_bins(x, boundaries, missing_bin: Optional[int] = None):
 
 def grad_histogram(bins, node_ids, grad, hess, num_nodes: int, num_bins: int,
                    model_axis: Optional[str] = None, method: str = "scatter",
-                   onehot=None):
+                   onehot=None, feature_major: bool = False):
     """Per-(node, feature, bin) gradient/hessian sums.
 
     Args:
@@ -291,6 +295,9 @@ def grad_histogram(bins, node_ids, grad, hess, num_nodes: int, num_bins: int,
         the default so existing callers keep f32 semantics.
       onehot: optional precomputed :func:`bin_onehot` (amortised across
         levels/rounds by callers; only used by the onehot method).
+      feature_major: ``bins`` is handed over as ``[F, B]`` int32, the layout
+        the ``pallas`` kernel reads (a fit keeps it once, ``gbdt.layout``);
+        row-major bins are transposed and widened here for the kernel.
 
     Returns (G, H): each [num_nodes, F, num_bins] float32.
     """
@@ -298,39 +305,30 @@ def grad_histogram(bins, node_ids, grad, hess, num_nodes: int, num_bins: int,
     import jax.numpy as jnp
 
     bins = jnp.asarray(bins)
-    B, F = bins.shape
+    F, B = bins.shape if feature_major else bins.shape[::-1]
     method = resolve_hist_method(method, bins, grad)
-    if method == "pallas_fused":
-        from dmlc_core_tpu.ops.hist_pallas import pallas_fused_supported
-
-        if not pallas_fused_supported():
-            # the fused kernel can fail to lower on real Mosaic where the
-            # plain kernel still compiles (sub-16-sublane concat); the
-            # probe logs the compiler's reason
-            method = "pallas"
     sharded_mesh = None
-    if method in ("pallas", "pallas_fused"):
+    if method == "pallas":
         from dmlc_core_tpu.ops.hist_pallas import hist_kernel_plan
 
         method, sharded_mesh = hist_kernel_plan(method, model_axis, F,
                                                 num_nodes, num_bins, batch=B)
+    if feature_major != (method == "pallas"):
+        bins = bins.T
+    if method == "pallas":
+        bins = bins.astype(jnp.int32)
 
     if sharded_mesh is not None:
         from dmlc_core_tpu.ops.hist_pallas import grad_hist_pallas_sharded
 
         G, H = grad_hist_pallas_sharded(
             bins, node_ids, grad, hess, num_nodes, num_bins, sharded_mesh,
-            model_axis, fused=(method == "pallas_fused"))
+            model_axis)
     elif method == "pallas":
         from dmlc_core_tpu.ops.hist_pallas import grad_hist_pallas
 
         G, H = grad_hist_pallas(bins, node_ids, grad, hess, num_nodes,
                                 num_bins)
-    elif method == "pallas_fused":
-        from dmlc_core_tpu.ops.hist_pallas import grad_hist_pallas_fused
-
-        G, H = grad_hist_pallas_fused(bins, node_ids, grad, hess, num_nodes,
-                                      num_bins)
     elif method == "onehot":
         if onehot is None:
             onehot = bin_onehot(bins, num_bins)

@@ -150,7 +150,8 @@ def main():
                     choices=["auto", "pallas", "pallas_fused", "onehot",
                              "scatter"],
                     help="histogram algorithm (auto: pallas VMEM kernel on "
-                         "TPU, scatter on CPU)")
+                         "TPU, scatter on CPU; pallas_fused: an older name "
+                         "for pallas)")
     ap.add_argument("--objective", default="logistic",
                     choices=["logistic", "squared", "softmax"])
     ap.add_argument("--num-class", type=int, default=1,

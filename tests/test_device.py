@@ -231,31 +231,27 @@ def test_auto_on_tpu_means_pallas_with_nothing_probed(monkeypatch):
     assert resolve_hist_method("scatter") == "scatter"   # explicit wins
 
 
-@pytest.mark.parametrize("probe,what", [
-    ("pallas_i8_supported", "int8 bin compares"),
-    ("pallas_fused_supported", "the fused-W kernel"),
-])
-def test_a_variant_probe_that_says_no_says_why(monkeypatch, capsys, probe,
-                                               what):
-    """Neither probe can change which kernel runs without a log line."""
+@pytest.mark.parametrize("name", ["pallas", "pallas_fused"])
+def test_a_kernel_the_compiler_rejects_raises_its_message(monkeypatch, name):
+    """No probe stands between a request for the kernel and the compiler:
+    what Mosaic says about the one kernel body reaches the caller, under
+    either of the kernel's names."""
+    import numpy as np
+
     from dmlc_core_tpu.ops import hist_pallas
+    from dmlc_core_tpu.ops.histogram import grad_histogram
 
     monkeypatch.setattr(hist_pallas, "_INTERPRET", True)
-    monkeypatch.delenv("DMLC_TPU_HIST_I8", raising=False)
 
     def rejected(*args, **kwargs):
         raise RuntimeError("Mosaic failed to compile TPU kernel: nope\nmore")
 
-    # both probes bottom out in the shared tile body
-    monkeypatch.setattr(hist_pallas, "_accumulate_tile", rejected)
-    fn = getattr(hist_pallas, probe)
-    fn.cache_clear()
-    try:
-        assert fn() is False
-    finally:
-        fn.cache_clear()
-    err = capsys.readouterr().err
-    assert what in err and "Mosaic failed to compile TPU kernel: nope" in err
+    monkeypatch.setattr(hist_pallas, "_kernel", rejected)
+    rows = np.zeros(128, np.float32)
+    with pytest.raises(RuntimeError, match="Mosaic failed to compile TPU "
+                                           "kernel: nope"):
+        grad_histogram(np.zeros((128, 2), np.int32), rows.astype(np.int32),
+                       rows, rows, 4, 8, method=name)
 
 
 def _fake_devices(platform, n=4):

@@ -63,30 +63,24 @@ def test_streaming_round_carries_the_same_scopes():
         assert f"/{scope}/" in text, scope
 
 
-@pytest.mark.parametrize("wrapper, name", [
-    ("hist_matmul_pallas", "hist_level"),
-    ("grad_hist_pallas_fused", "hist_level_fused"),
-])
-def test_hist_kernels_are_named(monkeypatch, wrapper, name):
+@pytest.mark.parametrize("nodes", [1, 32])
+def test_the_hist_kernel_is_named(monkeypatch, nodes):
+    """One Mosaic call, ``hist_level``, whatever split of the bin index the
+    level runs: the benchmark counts rounds and levels on that name."""
     import jax
     import jax.numpy as jnp
 
     from dmlc_core_tpu.ops import hist_pallas
 
     monkeypatch.setattr(hist_pallas, "_INTERPRET", True)
-    bins = jnp.zeros((hist_pallas.BLOCK_ROWS, FEATURES), jnp.int32)
-    if wrapper == "hist_matmul_pallas":
-        w = jnp.zeros((16, hist_pallas.BLOCK_ROWS), jnp.bfloat16)
-        jaxpr = jax.make_jaxpr(
-            lambda w, b: hist_pallas.hist_matmul_pallas(w, b, 8))(w, bins)
-    else:
-        row = jnp.zeros((hist_pallas.BLOCK_ROWS,), jnp.float32)
-        jaxpr = jax.make_jaxpr(
-            lambda b, n, g, h: hist_pallas.grad_hist_pallas_fused(
-                b, n, g, h, 2, 8))(bins, row.astype(jnp.int32), row, row)
+    bins = jnp.zeros((FEATURES, hist_pallas.BLOCK_ROWS), jnp.int32)
+    row = jnp.zeros((hist_pallas.BLOCK_ROWS,), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda b, n, g, h: hist_pallas.grad_hist_pallas(
+            b, n, g, h, nodes, 256))(bins, row.astype(jnp.int32), row, row)
     calls = [e for e in jaxpr.jaxpr.eqns
              if e.primitive.name == "pallas_call"]
-    assert [c.params["name"] for c in calls] == [name]
+    assert [c.params["name"] for c in calls] == ["hist_level"]
 
 
 @pytest.fixture
@@ -111,7 +105,8 @@ def test_fit_binned_records_one_dispatch_span_per_call(spans):
         assert len(found) == calls
         # scatter is no kernel: no blocks of one
         assert found[-1]["args"] == {"rounds": ROUNDS, "method": "scatter",
-                                     "node_blocks": 0, "feature_blocks": 0}
+                                     "node_blocks": 0, "feature_blocks": 0,
+                                     "bin_split": ""}
         assert found[-1]["ph"] == "X" and found[-1]["dur"] > 0
 
 

@@ -1,8 +1,8 @@
 """Pallas histogram kernel vs the exact scatter formulation (interpret mode).
 
-The kernel's numerics are bf16-one-hot x bf16-W with f32 accumulation — the
-same contract as the plain one-hot matmul — so tolerances below reflect bf16
-rounding of g/h, not algorithmic drift.
+The kernel's numerics are bf16 one-hot x bf16-rounded g/h with f32
+accumulation — the same contract as the plain one-hot matmul — so tolerances
+below reflect bf16 rounding of g/h, not algorithmic drift.
 """
 
 import numpy as np
@@ -15,12 +15,8 @@ from dmlc_core_tpu.ops.histogram import grad_histogram
 @pytest.fixture(autouse=True)
 def interpret_mode():
     hist_pallas._INTERPRET = True
-    hist_pallas.pallas_fused_supported.cache_clear()
-    hist_pallas.pallas_i8_supported.cache_clear()
     yield
     hist_pallas._INTERPRET = False
-    hist_pallas.pallas_fused_supported.cache_clear()
-    hist_pallas.pallas_i8_supported.cache_clear()
 
 
 def _rand_case(b, f, nbins, nnodes, seed=0):
@@ -32,26 +28,147 @@ def _rand_case(b, f, nbins, nnodes, seed=0):
     return bins, node, g, h
 
 
+def _kernel_hist(bins, node, g, h, nnodes, nbins):
+    """The kernel's wrapper on row-major test bins: it reads ``[F, B]``."""
+    return hist_pallas.grad_hist_pallas(np.ascontiguousarray(bins.T), node,
+                                        g, h, nnodes, nbins)
+
+
+def _bf16(a):
+    import jax.numpy as jnp
+
+    return np.asarray(jnp.asarray(a).astype(jnp.bfloat16).astype(jnp.float32))
+
+
+def _assert_hist_of_rounded(got, bins, node, g, h, nnodes, nbins):
+    """The kernel's contract to the last bit but the f32 order of addition:
+    the exact histogram of bf16-rounded g and h."""
+    Gr, Hr = grad_histogram(bins, node, _bf16(g), _bf16(h), nnodes, nbins,
+                            method="scatter")
+    assert got[0].shape == (nnodes, bins.shape[1], nbins)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(Gr),
+                               rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(got[1]), np.asarray(Hr),
+                               rtol=1e-5, atol=1e-4)
+
+
+# -- the split of the bin index: a pure function of (nodes, bins) ------------
+
+@pytest.mark.parametrize("nbins", [8, 16, 255, 256, 257, 1024])
+@pytest.mark.parametrize("nnodes", [1, 2, 3, 4, 8, 12, 16, 32, 64, 128])
+def test_split_plan_covers_the_bins_on_whole_tiles(nnodes, nbins):
+    hi, lo = hist_pallas.hist_split_plan(nnodes, nbins)
+    assert hi * lo >= nbins and (hi - 1) * lo < nbins
+    assert lo >= 16 and lo & (lo - 1) == 0      # a power of two of bf16 tiles
+    # the node side's bf16 rows: whole 16-sublane tiles, every key inside
+    rows = 2 * hist_pallas._key_rows(nnodes, hi)
+    assert rows % 16 == 0 and rows >= 2 * nnodes * hi
+    if nbins <= 16:
+        assert (hi, lo) == (1, 16)               # a small table: no split
+
+
+def test_split_plan_at_256_bins():
+    plan = {n: hist_pallas.hist_split_plan(n, 256) for n in PLAN_256}
+    assert plan == PLAN_256
+    for n, (hi, lo) in plan.items():
+        # the two sides of the dot balanced: 2nH within a factor two of 2L
+        assert n >= 128 or lo <= 2 * n * hi <= 4 * lo
+    # one kernel call's nodes at every level of a fit, root first: a level
+    # cut into node blocks runs a block's plan
+    assert hist_pallas.hist_level_splits(None, 28, 6, 256) \
+        == [PLAN_256[2 ** d] for d in range(6)]
+    assert hist_pallas.hist_block_plan(512, 28, 256) == (128, 28)
+    assert hist_pallas.hist_level_splits(None, 28, 10, 256)[-2:] \
+        == [PLAN_256[128]] * 2
+
+
+PLAN_256 = {1: (16, 16), 2: (8, 32), 4: (8, 32), 8: (4, 64), 16: (4, 64),
+            32: (2, 128), 64: (2, 128), 128: (1, 256), 512: (1, 256)}
+
+
+# -- the body against scatter -------------------------------------------------
+
+@pytest.mark.parametrize("nbins", [8, 16, 255, 256, 257])
+@pytest.mark.parametrize("nnodes", [1, 2, 4, 8, 16, 32, 64])
+def test_every_level_shape_matches_scatter(nnodes, nbins):
+    """Every (H, L) a fit can run, rows no multiple of the tile, node ids
+    below 0 and at or past ``nnodes`` (rows of other node blocks)."""
+    b, f = 1300, 3
+    bins, node, g, h = _rand_case(b, f, nbins, nnodes, seed=nnodes + nbins)
+    bins[:8, 0] = nbins - 1                      # the last (hi, lo) pair
+    node[::7] = -1
+    node[3::11] = nnodes + 5
+    got = _kernel_hist(bins, node, g, h, nnodes, nbins)
+    keep = (node >= 0) & (node < nnodes)
+    _assert_hist_of_rounded(got, bins[keep], node[keep], g[keep], h[keep],
+                            nnodes, nbins)
+
+
+@pytest.mark.parametrize("f", [1, 13, 28])
+def test_feature_counts_of_the_cells_match_scatter(f):
+    bins, node, g, h = _rand_case(2100, f, 256, 8, seed=f)
+    got = _kernel_hist(bins, node, g, h, 8, 256)
+    _assert_hist_of_rounded(got, bins, node, g, h, 8, 256)
+
+
+def test_grad_histogram_takes_either_layout():
+    """Row-major bins (the contract; the benchmark's check calls it so on
+    numpy bins) and the same call with the kernel's layout handed over."""
+    import jax.numpy as jnp
+
+    bins, node, g, h = _rand_case(1500, 5, 256, 4, seed=61)
+    narrow = bins.astype(np.uint8)               # 255 must not wrap
+    row_major = grad_histogram(narrow, node, g, h, num_nodes=4,
+                               num_bins=256, method="pallas")
+    handed = grad_histogram(jnp.asarray(bins.T), node, g, h, num_nodes=4,
+                            num_bins=256, method="pallas",
+                            feature_major=True)
+    for a, b in zip(row_major, handed):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _assert_hist_of_rounded(handed, bins, node, g, h, 4, 256)
+    # the layout is the caller's to state for the other methods too
+    exact = grad_histogram(bins.T, node, g, h, 4, 256, method="scatter",
+                           feature_major=True)
+    want = grad_histogram(bins, node, g, h, 4, 256, method="scatter")
+    np.testing.assert_array_equal(np.asarray(exact[0]), np.asarray(want[0]))
+
+
+@pytest.mark.parametrize("name", ["pallas", "pallas_fused"])
+def test_pallas_fused_is_a_name_of_the_one_kernel(name):
+    """``pallas_fused`` built W in the kernel; the one kernel does now, so
+    the name stays accepted and runs it."""
+    from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
+    from dmlc_core_tpu.ops.histogram import resolve_hist_method
+
+    assert resolve_hist_method(name) == "pallas"
+    model = GBDT(GBDTParam(max_depth=3, num_bins=16, hist_method=name),
+                 num_feature=4)
+    assert model._method() == "pallas"
+    bins, node, g, h = _rand_case(256, 3, 8, 4, seed=9)
+    G, H = grad_histogram(bins, node, g, h, 4, 8, method=name)
+    _assert_hist_of_rounded((G, H), bins, node, g, h, 4, 8)
+
+
 @pytest.mark.parametrize("b,f,nbins,nnodes", [
-    (256, 3, 8, 4),      # one tile exactly (block_rows padding no-op path)
+    (2048, 3, 8, 4),     # one tile exactly (block_rows padding no-op path)
     (300, 5, 16, 2),     # row padding inside the wrapper
-    (700, 2, 4, 8),      # multi-tile accumulation across grid steps
+    (4500, 2, 4, 8),     # accumulation across three grid steps, the last short
 ])
 def test_matches_scatter(b, f, nbins, nnodes):
     bins, node, g, h = _rand_case(b, f, nbins, nnodes)
-    G, H = hist_pallas.grad_hist_pallas(bins, node, g, h, nnodes, nbins)
-    Gr, Hr = grad_histogram(bins, node, g, h, nnodes, nbins, method="scatter")
-    assert G.shape == (nnodes, f, nbins)
-    np.testing.assert_allclose(np.asarray(G), np.asarray(Gr),
-                               rtol=2e-2, atol=2e-2)
-    np.testing.assert_allclose(np.asarray(H), np.asarray(Hr),
-                               rtol=2e-2, atol=2e-2)
+    got = _kernel_hist(bins, node, g, h, nnodes, nbins)
+    _assert_hist_of_rounded(got, bins, node, g, h, nnodes, nbins)
+    Gr, _ = grad_histogram(bins, node, g, h, nnodes, nbins, method="scatter")
+    # against the exact f32 histogram: bf16 rounding of g, a random walk
+    # over a bucket's rows
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(Gr),
+                               rtol=2e-2, atol=6e-2)
 
 
 def test_negative_node_ids_drop_out():
     bins, node, g, h = _rand_case(128, 2, 4, 2, seed=1)
     node[:50] = -1
-    G, H = hist_pallas.grad_hist_pallas(bins, node, g, h, 2, 4)
+    G, H = _kernel_hist(bins, node, g, h, 2, 4)
     mask = node >= 0
     Gr, Hr = grad_histogram(bins[mask], node[mask], g[mask], h[mask], 2, 4,
                             method="scatter")
@@ -86,10 +203,6 @@ def test_vmem_overflow_blocks_or_falls_back():
     wide = GBDT(GBDTParam(max_depth=10, num_bins=1024,
                           hist_method="pallas"), num_feature=512)
     assert wide._method() == "pallas"             # 8 nodes x 128 features
-    # a user-selected fused method degrades to the (blockable) plain kernel
-    deep_fused = GBDT(GBDTParam(max_depth=10, num_bins=256,
-                                hist_method="pallas_fused"), num_feature=28)
-    assert deep_fused._method() == "pallas"
     shallow = GBDT(GBDTParam(max_depth=6, num_bins=256,
                              hist_method="pallas"), num_feature=28)
     assert shallow._method() == "pallas"
@@ -109,7 +222,7 @@ def test_blocked_hist_matches_scatter():
     try:
         assert hist_pallas.hist_block_plan(32, 3, 16) == (8, 3)
         bins, node, g, h = _rand_case(700, 3, 16, 32, seed=31)
-        G, H = hist_pallas.grad_hist_pallas(bins, node, g, h, 32, 16)
+        G, H = _kernel_hist(bins, node, g, h, 32, 16)
         Gr, Hr = grad_histogram(bins, node, g, h, 32, 16, method="scatter")
         assert G.shape == (32, 3, 16)
         np.testing.assert_allclose(np.asarray(G), np.asarray(Gr),
@@ -118,7 +231,7 @@ def test_blocked_hist_matches_scatter():
                                    rtol=2e-2, atol=2e-2)
         # non-power-of-two node count: last block is short
         bins, node, g, h = _rand_case(500, 3, 16, 20, seed=32)
-        G, _ = hist_pallas.grad_hist_pallas(bins, node, g, h, 20, 16)
+        G, _ = _kernel_hist(bins, node, g, h, 20, 16)
         Gr, _ = grad_histogram(bins, node, g, h, 20, 16, method="scatter")
         assert G.shape == (20, 3, 16)
         np.testing.assert_allclose(np.asarray(G), np.asarray(Gr),
@@ -128,9 +241,9 @@ def test_blocked_hist_matches_scatter():
 
 
 def test_non_power_of_two_nodes_padding():
-    """M = 2*n_pad must stay a multiple of the bf16 tile for any node count."""
+    """The node side's rows stay whole bf16 tiles for any node count."""
     bins, node, g, h = _rand_case(256, 2, 8, 12, seed=4)
-    G, H = hist_pallas.grad_hist_pallas(bins, node, g, h, 12, 8)
+    G, H = _kernel_hist(bins, node, g, h, 12, 8)
     Gr, _ = grad_histogram(bins, node, g, h, 12, 8, method="scatter")
     assert G.shape == (12, 2, 8)
     np.testing.assert_allclose(np.asarray(G), np.asarray(Gr),
@@ -163,52 +276,6 @@ def test_gbdt_fit_pallas_matches_scatter_splits():
                                rtol=5e-2, atol=5e-2)
 
 
-@pytest.mark.parametrize("b,f,nbins,nnodes", [
-    (256, 3, 8, 4),
-    (300, 5, 16, 2),      # padding path (pad rows carry node=-1)
-    (700, 2, 4, 12),      # multi-tile + non-power-of-two nodes
-])
-def test_fused_matches_scatter(b, f, nbins, nnodes):
-    bins, node, g, h = _rand_case(b, f, nbins, nnodes, seed=7)
-    G, H = hist_pallas.grad_hist_pallas_fused(bins, node, g, h, nnodes,
-                                              nbins)
-    Gr, Hr = grad_histogram(bins, node, g, h, nnodes, nbins,
-                            method="scatter")
-    assert G.shape == (nnodes, f, nbins)
-    np.testing.assert_allclose(np.asarray(G), np.asarray(Gr),
-                               rtol=2e-2, atol=2e-2)
-    np.testing.assert_allclose(np.asarray(H), np.asarray(Hr),
-                               rtol=2e-2, atol=2e-2)
-
-
-def test_fused_matches_unfused():
-    bins, node, g, h = _rand_case(512, 4, 16, 8, seed=8)
-    Gf, Hf = hist_pallas.grad_hist_pallas_fused(bins, node, g, h, 8, 16)
-    Gu, Hu = hist_pallas.grad_hist_pallas(bins, node, g, h, 8, 16)
-    np.testing.assert_allclose(np.asarray(Gf), np.asarray(Gu),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(Hf), np.asarray(Hu),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_fused_probe_gates_method(monkeypatch):
-    """A user-selected pallas_fused falls back when the fused kernel's probe
-    fails (ADVICE r1: fused may not lower on real Mosaic where the plain
-    kernel does) — and never crashes at first use."""
-    bins, node, g, h = _rand_case(256, 3, 8, 4, seed=9)
-    monkeypatch.setattr(hist_pallas, "pallas_fused_supported", lambda: False)
-    G, H = grad_histogram(bins, node, g, h, 4, 8, method="pallas_fused")
-    Gr, Hr = grad_histogram(bins, node, g, h, 4, 8, method="scatter")
-    np.testing.assert_allclose(np.asarray(G), np.asarray(Gr),
-                               rtol=2e-2, atol=2e-2)
-    np.testing.assert_allclose(np.asarray(H), np.asarray(Hr),
-                               rtol=2e-2, atol=2e-2)
-
-
-def test_fused_probe_passes_in_interpret_mode():
-    assert hist_pallas.pallas_fused_supported() is True
-
-
 def _mesh_2d(data=4, model=2):
     import jax
     from dmlc_core_tpu.parallel.mesh import make_mesh
@@ -228,7 +295,7 @@ def test_sharded_pallas_matches_scatter():
     orig = hist_pallas.grad_hist_pallas_sharded
 
     def spy(*args, **kwargs):
-        calls.append(kwargs.get("fused"))
+        calls.append(args[7])          # model_axis
         return orig(*args, **kwargs)
 
     hist_pallas.grad_hist_pallas_sharded = spy
@@ -240,24 +307,10 @@ def test_sharded_pallas_matches_scatter():
             G, H = np.asarray(G), np.asarray(H)
     finally:
         hist_pallas.grad_hist_pallas_sharded = orig
-    assert calls == [False], "sharded pallas path was not taken"
+    assert calls == ["model"], "sharded pallas path was not taken"
     Gr, Hr = grad_histogram(bins, node, g, h, 4, 16, method="scatter")
     np.testing.assert_allclose(G, np.asarray(Gr), rtol=2e-2, atol=2e-2)
     np.testing.assert_allclose(H, np.asarray(Hr), rtol=2e-2, atol=2e-2)
-
-
-def test_sharded_pallas_fused_variant():
-    import jax
-
-    bins, node, g, h = _rand_case(512, 4, 8, 6, seed=12)
-    mesh = _mesh_2d()
-    with mesh:
-        G, H = jax.jit(lambda *a: grad_histogram(
-            *a, 6, 8, model_axis="model", method="pallas_fused"))(
-                bins, node, g, h)
-        G = np.asarray(G)
-    Gr, _ = grad_histogram(bins, node, g, h, 6, 8, method="scatter")
-    np.testing.assert_allclose(G, np.asarray(Gr), rtol=2e-2, atol=2e-2)
 
 
 def test_sharded_pallas_uneven_features_falls_back():
@@ -332,45 +385,6 @@ def test_ambient_mesh_probe_on_current_jax():
     assert hist_pallas.ambient_mesh() is None
 
 
-@pytest.mark.parametrize("nbins", [256, 257])
-def test_i8_compare_dtype_gate(nbins):
-    """int8 bins compares apply exactly when bin ids fit 256 (wraparound
-    keeps equality a bijection); wider binnings stay int32."""
-    import jax.numpy as jnp
-
-    dt = hist_pallas._bins_compare_dtype(nbins)
-    if nbins <= 256:
-        assert dt == (jnp.int8 if hist_pallas.pallas_i8_supported()
-                      else jnp.int32)
-    else:
-        assert dt == jnp.int32
-
-
-def test_i8_path_matches_scatter_at_256_bins(monkeypatch):
-    """Full 256-bin case through the int8 compare path (bin 255 wraps to -1
-    in int8 on both sides of the compare)."""
-    monkeypatch.delenv("DMLC_TPU_HIST_I8", raising=False)
-    hist_pallas.pallas_i8_supported.cache_clear()
-    assert hist_pallas.pallas_i8_supported()   # interpret mode lowers it
-    bins, node, g, h = _rand_case(512, 3, 256, 4, seed=21)
-    bins[:16, 0] = 255                          # exercise the wrap edge
-    G, H = hist_pallas.grad_hist_pallas(bins, node, g, h, 4, 256)
-    Gr, Hr = grad_histogram(bins, node, g, h, 4, 256, method="scatter")
-    np.testing.assert_allclose(np.asarray(G), np.asarray(Gr),
-                               rtol=2e-2, atol=2e-2)
-    np.testing.assert_allclose(np.asarray(H), np.asarray(Hr),
-                               rtol=2e-2, atol=2e-2)
-
-
-def test_i8_disable_env(monkeypatch):
-    monkeypatch.setenv("DMLC_TPU_HIST_I8", "0")
-    hist_pallas.pallas_i8_supported.cache_clear()
-    try:
-        assert not hist_pallas.pallas_i8_supported()
-    finally:
-        hist_pallas.pallas_i8_supported.cache_clear()
-
-
 def test_subsample_draw_independent_of_row_padding(interpret_mode):
     """The per-tree subsample draw must be made over the UNPADDED row count:
     fit_binned pads rows to the pallas tile, boost_round does not — with
@@ -381,7 +395,7 @@ def test_subsample_draw_independent_of_row_padding(interpret_mode):
     from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
 
     rng = np.random.RandomState(21)
-    n, F = 1500, 4                       # 1500 % 1024 != 0 -> fit pads
+    n, F = 1500, 4                       # no whole number of tiles -> fit pads
     x = rng.randn(n, F).astype(np.float32)
     y = (x[:, 0] > 0).astype(np.float32)
     m = GBDT(GBDTParam(num_boost_round=3, max_depth=3, num_bins=16,
@@ -455,7 +469,7 @@ def test_gbdt_fit_on_a_dp_mesh_matches_the_one_device_kernel_fit():
     from dmlc_core_tpu.parallel.mesh import data_sharding, make_mesh
 
     rng = np.random.RandomState(33)
-    n, F = 2000, 6                      # 2000 % (8 * 1024) != 0 -> fit pads
+    n, F = 2000, 6                      # no whole tile a shard -> fit pads
     x = rng.randn(n, F).astype(np.float32)
     y = (x[:, 0] * x[:, 1] > 0).astype(np.float32)
     model = GBDT(GBDTParam(num_boost_round=3, max_depth=3, num_bins=16,
@@ -484,14 +498,19 @@ def test_gbdt_fit_on_a_dp_mesh_matches_the_one_device_kernel_fit():
 def feature_block_budget():
     """Shrink the VMEM budget to 8 node slots x 128 features x ``nbins``
     bins, so test-size tables are blocked (module attribute, NOT a
-    from-import: the mutation must hit the live gate)."""
-    orig = hist_pallas._ACC_BYTES_LIMIT
+    from-import: the mutation must hit the live gate).  And unroll the tile
+    body over 16 features, not a whole block: a block then runs the loop
+    over groups that on the chip only a table wider than 128 unblocked
+    features does (100 features: six trips and a static rest of 4), and
+    the interpreter traces an eighth of the body."""
+    budget, unroll = hist_pallas._ACC_BYTES_LIMIT, hist_pallas._UNROLL
+    hist_pallas._UNROLL = 16
 
     def shrink(nbins):
         hist_pallas._ACC_BYTES_LIMIT = 2 * 8 * 128 * nbins * 4
 
     yield shrink
-    hist_pallas._ACC_BYTES_LIMIT = orig
+    hist_pallas._ACC_BYTES_LIMIT, hist_pallas._UNROLL = budget, unroll
 
 
 @pytest.mark.parametrize("b,f,nbins,nnodes,plan", [
@@ -506,7 +525,7 @@ def test_feature_blocked_hist_matches_scatter(feature_block_budget, b, f,
     feature_block_budget(nbins)
     assert hist_pallas.hist_block_plan(nnodes, f, nbins) == plan
     bins, node, g, h = _rand_case(b, f, nbins, nnodes, seed=41)
-    G, H = hist_pallas.grad_hist_pallas(bins, node, g, h, nnodes, nbins)
+    G, H = _kernel_hist(bins, node, g, h, nnodes, nbins)
     Gr, Hr = grad_histogram(bins, node, g, h, nnodes, nbins,
                             method="scatter")
     assert G.shape == (nnodes, f, nbins)
@@ -524,10 +543,10 @@ def test_feature_blocks_side_by_side_are_the_unblocked_result(
     """Blocking changes where a feature's partial sums live, not one bit of
     them: same tiles, same dots, same order over the rows."""
     bins, node, g, h = _rand_case(2100, f, 8, 6, seed=42)
-    whole = hist_pallas.grad_hist_pallas(bins, node, g, h, 6, 8)
+    whole = _kernel_hist(bins, node, g, h, 6, 8)
     feature_block_budget(8)
     assert hist_pallas.hist_block_plan(6, f, 8) == (6, 128)
-    blocked = hist_pallas.grad_hist_pallas(bins, node, g, h, 6, 8)
+    blocked = _kernel_hist(bins, node, g, h, 6, 8)
     for a, b in zip(whole, blocked):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -540,18 +559,63 @@ def test_one_grid_for_every_width():
     import jax
     import jax.numpy as jnp
 
-    w = jnp.zeros((16, 2 * hist_pallas.BLOCK_ROWS), jnp.bfloat16)
-    bins = jnp.zeros((2 * hist_pallas.BLOCK_ROWS, 300), jnp.int32)
+    rows = 2 * hist_pallas.BLOCK_ROWS
+    row = jnp.zeros((rows,), jnp.float32)
+    bins = jnp.zeros((300, rows), jnp.int32)
 
     def calls(block_features):
-        jaxpr = jax.make_jaxpr(lambda w, b: hist_pallas.hist_matmul_pallas(
-            w, b, 8, block_features=block_features))(w, bins)
+        jaxpr = jax.make_jaxpr(lambda n, g, h, b: hist_pallas.hist_matmul_pallas(
+            (n, g, h), b, 8, num_nodes=4,
+            block_features=block_features))(row.astype(jnp.int32), row, row,
+                                            bins)
         return [(m.grid, m.block_mappings[-1].pipeline_mode is not None)
                 for m in (e.params["grid_mapping"] for e in jaxpr.jaxpr.eqns
                           if e.primitive.name == "pallas_call")]
 
     assert calls(None) == calls(300) == calls(512) == [((1, 2), False)]
     assert calls(128) == [((3, 2), True)]
+
+
+@pytest.mark.parametrize("f,nbins,nnodes,asks", [
+    (28, 256, 32, False),       # HIGGS' deepest level: Mosaic's default
+    (128, 256, 32, False),      # a blocked block of epsilon's: 8 MiB, one buffer
+    (2000, 16, 8, True),        # unblocked by the byte rule, 62 MiB lane-padded
+])
+def test_a_table_of_many_narrow_features_asks_for_its_vmem(f, nbins, nnodes,
+                                                           asks):
+    """The byte rule counts a dense ``[2n, F * bins]``; the kernel's block
+    pads ``2nH`` to the lanes, so 2,000 features x 16 bins, one block by the
+    rule, hold 2,000 x 16 x 128 f32 twice: on a described v5e that call runs
+    out of VMEM unasked and compiles with the limit (sandbox, PR 28)."""
+    import jax
+    import jax.numpy as jnp
+
+    rows = hist_pallas.BLOCK_ROWS
+    row = jnp.zeros((rows,), jnp.float32)
+    _, features = hist_pallas.hist_block_plan(nnodes, f, nbins)
+    jaxpr = jax.make_jaxpr(lambda n, g, h, b: hist_pallas.hist_matmul_pallas(
+        (n, g, h), b, nbins, num_nodes=nnodes, block_features=features))(
+            row.astype(jnp.int32), row, row, jnp.zeros((f, rows), jnp.int32))
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    params = call.params["compiler_params"].get("mosaic_tpu")
+    assert (params is not None and params.vmem_limit_bytes > 32 << 20) == asks
+
+
+def test_the_kernel_entry_returns_the_flat_histogram():
+    """``hist_matmul_pallas``: per-row (node, g, h) and feature-major bins
+    in, ``[2 * nodes, F * num_bins]`` out, column ``f * num_bins + b``, g's
+    rows first — the transpose back from the kernel's ``[F, L, 2nH]`` is
+    inside it (the benchmark's dropped-block check wraps it and zeroes
+    columns by that index)."""
+    bins, node, g, h = _rand_case(700, 5, 256, 4, seed=71)
+    out = np.asarray(hist_pallas.hist_matmul_pallas(
+        (node, g, h), np.ascontiguousarray(bins.T), 256, num_nodes=4))
+    assert out.shape == (8, 5 * 256) and out.dtype == np.float32
+    G, H = _kernel_hist(bins, node, g, h, 4, 256)
+    np.testing.assert_array_equal(out[:4].reshape(4, 5, 256), np.asarray(G))
+    np.testing.assert_array_equal(out[4:].reshape(4, 5, 256), np.asarray(H))
+    _assert_hist_of_rounded((G, H), bins, node, g, h, 4, 256)
 
 
 def test_wide_tables_plan_the_kernel_not_onehot():
@@ -568,9 +632,6 @@ def test_wide_tables_plan_the_kernel_not_onehot():
     assert hist_pallas.hist_block_counts(None, 28, 32, 256) == (1, 1)
     assert hist_pallas.hist_kernel_plan("pallas", None, 2000, 32,
                                         256) == ("pallas", None)
-    # a blocked level has no fused variant
-    assert hist_pallas.hist_kernel_plan("pallas_fused", None, 2000, 32,
-                                        256) == ("pallas", None)
     # bins in the tens of thousands: 8 node slots x 128 features overflow
     assert hist_pallas.hist_block_plan(8, 2000, 2 ** 15) is None
     assert hist_pallas.hist_kernel_plan("pallas", None, 2000, 8,
@@ -578,18 +639,20 @@ def test_wide_tables_plan_the_kernel_not_onehot():
     wide = GBDT(GBDTParam(max_depth=6, num_bins=256, hist_method="pallas"),
                 num_feature=2000)
     assert wide._method() == "pallas"
-    assert wide._hist_blocks("pallas") == {"node_blocks": 1,
-                                           "feature_blocks": 16}
+    assert wide._hist_blocks("pallas") == {
+        "node_blocks": 1, "feature_blocks": 16,
+        "bin_split": "16x16,8x32,8x32,4x64,4x64,2x128"}
     assert wide._hist_blocks("scatter") == {"node_blocks": 0,
-                                            "feature_blocks": 0}
+                                            "feature_blocks": 0,
+                                            "bin_split": ""}
     with _mesh_2d():
         sharded = GBDT(GBDTParam(max_depth=6, num_bins=256,
                                  hist_method="pallas"), num_feature=2000,
                        model_axis="model")
         assert sharded._method() == "pallas"
         # each model shard blocks its own 1,000 features
-        assert sharded._hist_blocks("pallas") == {"node_blocks": 1,
-                                                  "feature_blocks": 8}
+        blocks = sharded._hist_blocks("pallas")
+        assert (blocks["node_blocks"], blocks["feature_blocks"]) == (1, 8)
 
 
 def _wide_rehearsal(n=900, f=260, seed=51):
@@ -622,7 +685,8 @@ def test_gbdt_wide_fit_matches_scatter_and_the_plain_reference(
     kernel = model("pallas")
     assert kernel._fit_method(bins) == "pallas"
     assert kernel._hist_blocks("pallas") == {"node_blocks": 1,
-                                             "feature_blocks": 3}
+                                             "feature_blocks": 3,
+                                             "bin_split": "1x16,1x16,1x16"}
     ens_p, margin_p = kernel.fit_binned(bins, y)
     ens_s, margin_s = model("scatter").fit_binned(bins, y)
     np.testing.assert_array_equal(np.asarray(ens_p.split_feat),
@@ -663,8 +727,7 @@ def test_sharded_fits_with_feature_blocks_match_the_one_device_fit(
     sharded = model("pallas", model_axis=model_axis)
     with mesh:
         assert sharded._fit_method(bins) == "pallas"
-        assert sharded._hist_blocks("pallas") == {"node_blocks": 1,
-                                                  "feature_blocks": 3}
+        assert sharded._hist_blocks("pallas")["feature_blocks"] == 3
         ens_sh, margin_sh = sharded.fit_binned(
             jax.device_put(bins, data_sharding(mesh, ndim=2)),
             jax.device_put(y, data_sharding(mesh)))
