@@ -31,9 +31,9 @@ from typing import Any, NamedTuple, Optional, Tuple
 import numpy as np
 
 from dmlc_core_tpu import telemetry
-from dmlc_core_tpu.ops.histogram import (apply_bins, bin_onehot,
+from dmlc_core_tpu.ops.histogram import (HistPlan, apply_bins,
                                          distributed_quantile_boundaries,
-                                         grad_histogram, resolve_hist_method)
+                                         hist_plan)
 from dmlc_core_tpu.param import Parameter, field
 from dmlc_core_tpu.utils.logging import CHECK
 
@@ -107,11 +107,10 @@ class GBDTParam(Parameter):
     num_class = field(int, default=1, lower=1,
                       help="classes for objective=softmax (K trees/round)")
     hist_method = field(str, default="auto",
-                        enum=["auto", "pallas", "pallas_fused", "onehot", "scatter"],
+                        enum=["auto", "pallas", "scatter"],
                         help="histogram algorithm: VMEM-resident pallas "
-                             "kernel (TPU; 'pallas_fused' is an older name "
-                             "for it), one-hot MXU matmul, or segment-sum "
-                             "scatter (CPU)")
+                             "kernel (auto on a TPU) or segment-sum scatter "
+                             "(auto everywhere else)")
 
 
 class TreeEnsemble(NamedTuple):
@@ -148,27 +147,6 @@ def _widen_bins(bins):
 
     bins = jnp.asarray(bins)
     return bins if bins.dtype == jnp.int32 else bins.astype(jnp.int32)
-
-
-def _bin_layouts(bins, pad: int = 0, kernel: bool = False):
-    """The two device layouts a fit keeps of one ``[rows, F]`` binned batch,
-    rows padded by ``pad``: the widened int32 the histogram reads, and
-    ``[F, rows]`` in the wire dtype for ``_feature_pick`` — rows on the
-    minor (lane) axis, so a pass over it streams rows x F narrow bytes
-    instead of a row-major array whose F lanes pad to 128.  The histogram's
-    copy is ``[rows, F]`` for ``scatter`` / ``onehot`` and, with ``kernel``,
-    the same ``[F, rows]`` widened: what the ``pallas`` kernel reads (v5e
-    Mosaic lowers no sub-32-bit compare), and a quarter or less of the
-    lane-padded row-major one.
-    Made once per fit (once per streamed round) under ``gbdt.layout``."""
-    import jax
-    import jax.numpy as jnp
-
-    with jax.named_scope("gbdt.layout"):
-        bins = jnp.asarray(bins)
-        if pad:
-            bins = jnp.pad(bins, ((0, pad), (0, 0)))
-        return _widen_bins(bins.T if kernel else bins), bins.T
 
 
 def _feature_pick(bins_fm, feat):
@@ -281,11 +259,10 @@ def _parse_monotone(spec: str, num_feature: int):
     return None if not arr.any() else arr
 
 
-def _build_tree(bins, bins_fm, g, h, max_depth: int, num_bins: int,
-                reg_lambda: float, min_child_weight: float,
+def _build_tree(hist_bins, bins_fm, g, h, plan: HistPlan, max_depth: int,
+                num_bins: int, reg_lambda: float, min_child_weight: float,
                 learning_rate: float,
-                model_axis: Optional[str] = None, method: str = "scatter",
-                onehot=None, min_split_loss: float = 0.0, feat_mask=None,
+                min_split_loss: float = 0.0, feat_mask=None,
                 missing: bool = False, reg_alpha: float = 0.0,
                 monotone=None, level_mask_fn=None,
                 max_delta_step: float = 0.0):
@@ -293,10 +270,10 @@ def _build_tree(bins, bins_fm, g, h, max_depth: int, num_bins: int,
     leaf_value, default_left, split_gain, split_cover, margin_delta).
     Pure jax, shapes static in (max_depth, num_bins, F).
 
-    ``bins`` is the widened copy the histogram reads (``[rows, F]``, or
-    ``[F, rows]`` for the ``pallas`` kernel), ``bins_fm`` the same bins as
-    ``[F, rows]`` in their wire dtype (``_bin_layouts``): every per-row
-    pick reduces over a leading axis with rows on the lanes.
+    ``hist_bins`` is the copy of the bins that ``plan``'s histogram reads,
+    opaque here, and ``bins_fm`` the same bins as ``[F, rows]`` in their
+    wire dtype (both from ``plan.layouts``): every per-row pick reduces
+    over a leading axis with rows on the lanes.
 
     ``feat_mask`` ([F] bool, optional) disables features for this tree
     (colsample); ``min_split_loss`` is the XGBoost gamma pruning threshold.
@@ -348,10 +325,7 @@ def _build_tree(bins, bins_fm, g, h, max_depth: int, num_bins: int,
         level_off = n_nodes - 1
         with jax.named_scope("gbdt.hist"):
             # G, H: [n, F, nbins]
-            G, H = grad_histogram(bins, node, g, h, n_nodes, num_bins,
-                                  model_axis=model_axis, method=method,
-                                  onehot=onehot,
-                                  feature_major=method == "pallas")
+            G, H = plan.histogram(hist_bins, node, g, h, n_nodes, num_bins)
         with jax.named_scope("gbdt.split"):
             GL = jnp.cumsum(G, axis=-1)
             HL = jnp.cumsum(H, axis=-1)
@@ -500,18 +474,7 @@ def _build_tree(bins, bins_fm, g, h, max_depth: int, num_bins: int,
             node = node * 2 + go_right.astype(jnp.int32)
 
     with jax.named_scope("gbdt.leaf"):
-        n_leaf = 2 ** max_depth
-        if method in ("onehot", "pallas"):
-            # leaf sums as a (tiny) f32 matmul — TPU scatter-adds serialise
-            leafhot = (node[:, None] == jnp.arange(n_leaf, dtype=node.dtype)
-                       ).astype(jnp.float32)                 # [B, n_leaf]
-            gh = jnp.stack([g, h], axis=1)                   # [B, 2]
-            sums = jax.lax.dot_general(leafhot, gh, (((0,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-            Gl, Hl = sums[:, 0], sums[:, 1]
-        else:
-            Gl = jax.ops.segment_sum(g, node, num_segments=n_leaf)
-            Hl = jax.ops.segment_sum(h, node, num_segments=n_leaf)
+        Gl, Hl = plan.leaf_sums(node, g, h, 2 ** max_depth)
         leaf_w = -_l1_threshold(Gl, reg_alpha) / (Hl + reg_lambda)
         if max_delta_step > 0.0:
             leaf_w = jnp.clip(leaf_w, -max_delta_step, max_delta_step)
@@ -755,69 +718,46 @@ class GBDT:
         return apply_bins(x, self.boundaries, missing_bin=miss)
 
     # -- compiled round/predict ----------------------------------------------
-    def _method(self, *arrays, batch: Optional[int] = None) -> str:
-        method = resolve_hist_method(self.param.hist_method, *arrays)
-        if method == "pallas":
-            from dmlc_core_tpu.ops.hist_pallas import hist_kernel_plan
+    def _plan(self, method: str, *arrays,
+              rows: Optional[int] = None) -> HistPlan:
+        """The histogram plan (``ops.histogram.hist_plan``) of this model by
+        ``method`` under the ambient mesh; ``arrays`` decide ``auto``,
+        ``rows`` is the row count the histogram sees where nothing pads."""
+        p = self.param
+        return hist_plan(method, self.model_axis, self.num_feature,
+                         p.max_depth, p.num_bins, rows=rows, arrays=arrays)
 
-            # settled once per fit for the deepest level, so an onehot
-            # outcome (a mesh the kernel cannot be shard_mapped over; never
-            # width or depth) still amortises its matmul RHS across rounds.
-            # ``batch`` is the row count grad_histogram will actually see
-            # (padded for fit, raw for boost_round) so this and the
-            # per-level call inside grad_histogram cannot disagree.
-            method, _ = hist_kernel_plan(
-                method, self.model_axis, self.num_feature,
-                2 ** (self.param.max_depth - 1), self.param.num_bins,
-                batch=batch)
-        return method
+    def _method(self, *arrays) -> str:
+        return self._plan(self.param.hist_method, *arrays).method
+
+    def _fit_plan(self, bins) -> HistPlan:
+        """The plan of a compiled fit over ``bins``: the fit pads rows to
+        the plan's multiple before the histogram sees them."""
+        return self._plan(self.param.hist_method, bins)
 
     def _fit_method(self, bins) -> str:
-        """The hist method a compiled fit over ``bins`` runs (the fit pads
-        rows to the kernel tile before the hist sees them)."""
-        from dmlc_core_tpu.ops.hist_pallas import fit_row_multiple
-
-        mult = fit_row_multiple()
-        return self._method(bins, batch=-(-bins.shape[0] // mult) * mult)
+        """The hist method a compiled fit over ``bins`` runs."""
+        return self._fit_plan(bins).method
 
     def _hist_blocks(self, method: str) -> dict:
-        """The kernel shape a fit runs, as the ``gbdt.fit.dispatch`` span
-        records it: calls at the deepest level (``node_blocks``), grid steps
-        over features inside each (``feature_blocks``), and the split of the
-        bin index each level's call runs, ``HxL`` from the root down
-        (``bin_split``, :func:`~dmlc_core_tpu.ops.hist_pallas.
-        hist_split_plan`); 0, 0 and "" for a method that is no kernel."""
-        counts, split = (0, 0), ""
-        if method == "pallas":
-            from dmlc_core_tpu.ops.hist_pallas import (hist_block_counts,
-                                                       hist_level_splits)
-
-            p = self.param
-            counts = hist_block_counts(self.model_axis, self.num_feature,
-                                       2 ** (p.max_depth - 1), p.num_bins)
-            split = ",".join(f"{hi}x{lo}" for hi, lo in hist_level_splits(
-                self.model_axis, self.num_feature, p.max_depth, p.num_bins))
-        return {"node_blocks": counts[0], "feature_blocks": counts[1],
-                "bin_split": split}
+        """The kernel shape a fit by ``method`` runs, as the
+        ``gbdt.fit.dispatch`` span records it (``HistPlan.blocks``)."""
+        return self._plan(method).blocks()
 
     @functools.lru_cache(maxsize=None)
-    def _round_fn(self, method: str = "scatter"):
+    def _round_fn(self, plan: HistPlan):
         import jax
 
         p = self.param
 
         def one_round(margin, bins, label, weight, rnd):
             B, F = bins.shape
-            bins, bins_fm = _bin_layouts(bins, kernel=method == "pallas")
-            onehot = (bin_onehot(bins, p.num_bins)
-                      if method == "onehot" else None)
+            bins, bins_fm = plan.layouts(bins)
 
             def grow(bins_, g, h, rnd_, fmask):
                 return _build_tree(
-                    bins_, bins_fm, g, h, p.max_depth, p.num_bins,
+                    bins_, bins_fm, g, h, plan, p.max_depth, p.num_bins,
                     p.reg_lambda, p.min_child_weight, p.learning_rate,
-                    self.model_axis,
-                    method=method, onehot=onehot,
                     min_split_loss=p.min_split_loss, feat_mask=fmask,
                     missing=p.handle_missing, reg_alpha=p.reg_alpha,
                     monotone=self._monotone,
@@ -840,25 +780,23 @@ class GBDT:
 
         return jax.jit(one_round)
 
-    @functools.lru_cache(maxsize=None)
     def _fit_fn(self, num_rounds: int, method: str = "scatter"):
-        return self._build_fit(num_rounds, method, with_eval=False)
+        """The compiled fit by a NAMED method, planned here under the
+        ambient mesh: how a caller with no rows in hand (a test, a
+        benchmark's ``.lower``) reaches the program ``fit_binned`` runs."""
+        return self._build_fit(num_rounds, self._plan(method),
+                               with_eval=False)
 
     @functools.lru_cache(maxsize=None)
-    def _fit_eval_fn(self, num_rounds: int, method: str = "scatter",
-                     eval_metric: str = "loss"):
-        """:meth:`_fit_fn` + per-round eval-margin accumulation and
-        train/eval losses — the whole eval-tracked fit is ONE compiled
-        program (the round-by-round host loop costs ~a round-trip per
-        round; early stopping becomes a host post-pass over the losses)."""
-        return self._build_fit(num_rounds, method, with_eval=True,
-                               eval_metric=eval_metric)
-
-    def _build_fit(self, num_rounds: int, method: str, with_eval: bool,
+    def _build_fit(self, num_rounds: int, plan: HistPlan, with_eval: bool,
                    eval_metric: str = "loss"):
         """One jitted scan-fit builder serving both entry points — the
         training body (padding, sampling, grow) must never fork between
-        the plain and eval-tracked fits."""
+        the plain and eval-tracked fits.  ``with_eval`` adds per-round
+        eval-margin accumulation and train/eval losses: the whole
+        eval-tracked fit is ONE compiled program (the round-by-round host
+        loop costs ~a round-trip per round; early stopping becomes a host
+        post-pass over the losses)."""
         import jax
         import jax.lax as lax
 
@@ -870,35 +808,25 @@ class GBDT:
             import jax.numpy as jnp
 
             n_rows, F = bins.shape
-            pad = 0
-            if method == "pallas":
-                from dmlc_core_tpu.ops.hist_pallas import fit_row_multiple
-
-                # pad rows to the kernel's tile multiple ONCE per fit (padded
-                # rows carry weight 0, so they vanish from every histogram);
-                # per-call padding inside the kernel wrapper then no-ops
-                pad = -n_rows % fit_row_multiple()
+            # pad rows to the plan's multiple (the kernel's tile) ONCE per
+            # fit: padded rows carry weight 0, so they vanish from every
+            # histogram, and per-call padding inside the kernel then no-ops
+            pad = -n_rows % plan.row_multiple
             with jax.named_scope("gbdt.layout"):
                 if ev_bins is not None:
                     ev_bins = _widen_bins(ev_bins)
                 if pad:
                     label = jnp.pad(label, (0, pad))
                     weight = jnp.pad(weight, (0, pad))
-            bins, bins_fm = _bin_layouts(bins, pad, kernel=method == "pallas")
+            bins, bins_fm = plan.layouts(bins, pad)
             B = n_rows + pad
             weight = _apply_pos_weight(weight, label, p)
-            # the bin one-hot (the matmul RHS) is invariant across rounds and
-            # levels: materialise once, outside the scan
-            onehot = (bin_onehot(bins, p.num_bins)
-                      if method == "onehot" else None)
             K = p.num_class if p.objective == "softmax" else 1
 
             def grow(bins_, g, h, rnd, fmask):
                 return _build_tree(
-                    bins_, bins_fm, g, h, p.max_depth, p.num_bins,
+                    bins_, bins_fm, g, h, plan, p.max_depth, p.num_bins,
                     p.reg_lambda, p.min_child_weight, p.learning_rate,
-                    self.model_axis,
-                    method=method, onehot=onehot,
                     min_split_loss=p.min_split_loss, feat_mask=fmask,
                     missing=p.handle_missing, reg_alpha=p.reg_alpha,
                     monotone=self._monotone,
@@ -1005,9 +933,10 @@ class GBDT:
             weight = (jnp.ones(bins.shape[0], jnp.float32)
                       if weight is None else jnp.asarray(weight))
             bins = jnp.asarray(bins)
-            method = self._fit_method(bins)
-            sp.set(method=method, **self._hist_blocks(method))
-            return self._fit_fn(self.param.num_boost_round, method)(
+            plan = self._fit_plan(bins)
+            sp.set(method=plan.method, **plan.blocks())
+            return self._build_fit(self.param.num_boost_round, plan,
+                                   with_eval=False)(
                 bins, jnp.asarray(label, jnp.float32), weight)
 
     def boost_round(self, margin, bins, label, weight,
@@ -1032,8 +961,10 @@ class GBDT:
             round_index = 0
         weight = _apply_pos_weight(jnp.asarray(weight),
                                    jnp.asarray(label), self.param)
-        return self._round_fn(self._method(bins, margin,
-                                           batch=bins.shape[0]))(
+        # no padding here: the histogram sees these rows as they are
+        plan = self._plan(self.param.hist_method, bins, margin,
+                          rows=bins.shape[0])
+        return self._round_fn(plan)(
             margin, bins, label, weight,
             jnp.asarray(round_index, jnp.uint32))
 
@@ -1233,8 +1164,9 @@ class GBDT:
         (see :meth:`fit_with_eval`); returns identical (ensemble, history)
         to the round-by-round loop."""
         R = self.param.num_boost_round
-        method = self._fit_method(bins)
-        ens, _, trl, evl = self._fit_eval_fn(R, method, eval_metric)(
+        ens, _, trl, evl = self._build_fit(
+            R, self._fit_plan(bins), with_eval=True,
+            eval_metric=eval_metric)(
             bins, label, weight, eval_bins, eval_label)
         trl = np.asarray(trl)
         evl = np.asarray(evl)

@@ -1,14 +1,15 @@
-"""Pallas TPU kernel: gradient histograms without materialising the one-hot.
+"""Pallas TPU kernel: a level's gradient histogram as an MXU matmul whose
+operands never leave VMEM.
 
-The ``"onehot"`` method in :mod:`.histogram` casts the XGBoost-hist kernel
-(reference workload: src/data + Rabit hist aggregation consumers) as an MXU
-matmul ``W[M, B] @ onehot[B, F*nbins]``.  That is compute-shaped right, but
-HBM-bound: the materialised one-hot is ``F*nbins/8`` times larger than the
-binned features (28 feat x 256 bins -> 14 KB/row in bf16 vs 112 B/row of
-int32 bins), and every tree level of every boosting round re-reads all of it.
+A gradient histogram (the XGBoost-hist kernel; reference workload: src/data
++ Rabit hist aggregation consumers) is the product of two one-hots over the
+rows, ``W[2n, B] @ binhot[B, F*nbins]``.  Written to HBM the bin one-hot is
+``F*nbins/8`` times the binned features (28 feat x 256 bins -> 14 KB/row in
+bf16 vs 112 B/row of int32 bins) and every level of every round would
+re-read it, so it is never written.
 
-This kernel keeps the matmul but builds both of its operands **tile-by-tile
-in VMEM**, rows on the lanes, and splits the bin index between them
+This kernel builds both operands of the matmul **tile-by-tile in VMEM**,
+rows on the lanes, and splits the bin index between them
 (``bin = hi * L + lo``, :func:`hist_split_plan`)::
 
     key_i            = node_i * H + hi_i      (node outside [0, n): no key)
@@ -42,16 +43,16 @@ From 128 nodes ``H = 1``: the plain one-hot matmul, transposed.
   puts it back to ``(G, H)[n, F, num_bins]``.
 
 HBM traffic per level is ``B*(4F + 12)`` bytes — no weight matrix is ever
-written.  Numerics match the ``"onehot"`` method term by term (a bf16-rounded
-g or h, or 0; a bf16 0/1; f32 accumulate); only the order of the f32
-additions may differ.
+written.  Every term is a bf16-rounded g or h (or 0) times a bf16 0/1,
+accumulated in f32: against the exact ``scatter`` histogram only the bf16
+rounding of g and h and the order of the f32 additions differ.
 
-Used automatically on TPU via ``resolve_hist_method("auto")``: the plan
-blocks features, then nodes, until an accumulator block fits VMEM, so width
-and depth never send a fit to the plain one-hot matmul (only a mesh the
-kernel cannot be shard_mapped over does — :func:`hist_kernel_plan`).
-On a TPU backend a kernel Mosaic rejects raises with the compiler's message
-— nothing here probes-and-swallows on behalf of ``auto``.
+``auto`` means this kernel on a TPU (``histogram.resolve_hist_method``) and
+nothing falls back from it.  :func:`hist_kernel_plan` settles a fit's
+kernel once, before tracing: width and depth are blocked (features, then
+nodes) until an accumulator block fits VMEM, and a mesh the kernel cannot
+be shard_mapped over raises a ``ValueError`` that names what to change.  On
+a TPU backend a kernel Mosaic rejects raises with the compiler's message.
 """
 
 from __future__ import annotations
@@ -61,10 +62,9 @@ import math
 
 __all__ = ["hist_matmul_pallas", "grad_hist_pallas",
            "grad_hist_pallas_sharded",
-           "ambient_mesh", "hist_kernel_plan", "fit_row_multiple",
+           "ambient_mesh", "hist_kernel_plan",
            "interpret_mode", "hist_fits_vmem",
-           "hist_block_plan", "hist_block_counts", "hist_split_plan",
-           "hist_level_splits",
+           "hist_block_plan", "hist_split_plan",
            "BLOCK_ROWS", "DATA_AXIS"]
 
 # interpreter mode: runs the kernels on CPU for tests/debugging (flipped by
@@ -94,23 +94,13 @@ def interpret_mode() -> bool:
             "kernels must compile through Mosaic there")
     return True
 
-# row-tile size: callers that want the wrapper's internal padding to no-op
-# (e.g. GBDT's fit-level padding) must pad to a multiple of this.
-# DMLC_TPU_HIST_BLOCK_ROWS overrides for on-chip tuning sweeps.  2048 from
-# this body's sweep on a v5e (a HIGGS fit: 1024 +3.2%, 4096 -1.6%; PERF.md,
-# PR 28): a grid step costs about a third of a microsecond whatever it
-# holds, and at 4096 the widest blocked level leaves Mosaic's default VMEM.
-try:
-    BLOCK_ROWS = int(_os.environ.get("DMLC_TPU_HIST_BLOCK_ROWS", "") or 2048)
-except ValueError:
-    raise ValueError(
-        "DMLC_TPU_HIST_BLOCK_ROWS must be an integer multiple of the 128 "
-        f"lane width, got {_os.environ['DMLC_TPU_HIST_BLOCK_ROWS']!r}"
-    ) from None
-if BLOCK_ROWS < 128 or BLOCK_ROWS % 128:
-    raise ValueError(
-        f"DMLC_TPU_HIST_BLOCK_ROWS must be a positive multiple of the 128 "
-        f"lane width, got {BLOCK_ROWS}")
+# row-tile size, a multiple of the 128 lanes; a fit pads its rows to it
+# once (hist_kernel_plan's row_multiple) so the wrapper's own padding
+# no-ops.  A constant since its sweep on a v5e (a HIGGS fit: 1024 +3.2%,
+# 4096 -1.6%; PERF.md, PR 28): a grid step costs about a third of a
+# microsecond whatever it holds, and at 4096 the widest blocked level leaves
+# Mosaic's default VMEM.
+BLOCK_ROWS = 2048
 
 
 # VMEM budget for the resident accumulator block (bytes): what
@@ -166,34 +156,17 @@ def hist_block_plan(num_nodes: int, num_feature: int, num_bins: int):
     return nodes, _LANES
 
 
-def _shard_features(model_axis, num_feature: int) -> int:
-    """Features one chip's kernel sees: ``F/mp`` under a ``model_axis``."""
-    if model_axis is None:
-        return num_feature
-    return num_feature // ambient_mesh().shape[model_axis]
-
-
-def hist_block_counts(model_axis, num_feature: int, num_nodes: int,
-                      num_bins: int):
-    """``(node blocks, feature blocks)`` one chip's kernel runs a level of
-    ``num_nodes`` nodes in: kernel calls, and grid steps over features
-    inside each.  For a fit :func:`hist_kernel_plan` settled on the kernel;
-    what ``gbdt.fit.dispatch`` records beside the method."""
-    num_feature = _shard_features(model_axis, num_feature)
-    nodes, feats = hist_block_plan(num_nodes, num_feature, num_bins)
-    return -(-num_nodes // nodes), -(-num_feature // feats)
-
-
-def hist_level_splits(model_axis, num_feature: int, max_depth: int,
-                      num_bins: int):
-    """:func:`hist_split_plan` of every level of a ``max_depth`` fit, root
-    first, for the nodes one kernel call of that level holds (a node block
-    where the level is cut into several).  For a fit
-    :func:`hist_kernel_plan` settled on the kernel."""
-    num_feature = _shard_features(model_axis, num_feature)
-    return [hist_split_plan(min(2 ** depth, hist_block_plan(
-        2 ** depth, num_feature, num_bins)[0]), num_bins)
-        for depth in range(max_depth)]
+def _require_block_plan(num_nodes: int, num_feature: int, num_bins: int):
+    """:func:`hist_block_plan`, or a ``ValueError`` where no block fits."""
+    plan = hist_block_plan(num_nodes, num_feature, num_bins)
+    if plan is None:
+        raise ValueError(
+            f"hist_method='pallas': no accumulator block fits VMEM at "
+            f"num_bins={num_bins} (8 node slots x "
+            f"{min(num_feature, _LANES)} features x {num_bins} bins of f32 "
+            f"g and h exceed {_ACC_BYTES_LIMIT} bytes); use fewer bins, or "
+            f"hist_method='scatter'")
+    return plan
 
 
 def hist_split_plan(num_nodes: int, num_bins: int):
@@ -383,8 +356,8 @@ def grad_hist_pallas(bins, node_ids, grad, hess, num_nodes: int,
     """Per-(node, feature, bin) gradient/hessian sums via the VMEM kernel.
 
     ``bins`` is FEATURE-MAJOR, ``[F, B]`` int32 — the layout the kernel
-    reads; otherwise the contract of :func:`.histogram.grad_histogram`
-    (which hands row-major bins over transposed): returns (G, H) each
+    reads (``HistPlan.layouts`` makes it); otherwise the contract of
+    :func:`.histogram.grad_histogram`: returns (G, H) each
     [num_nodes, F, num_bins] float32.  Rows with out-of-range (e.g.
     negative) node ids contribute nothing.
 
@@ -396,9 +369,7 @@ def grad_hist_pallas(bins, node_ids, grad, hess, num_nodes: int,
     import jax.numpy as jnp
 
     bf = bins.shape[0]
-    plan = hist_block_plan(num_nodes, bf, num_bins)
-    assert plan is not None, "caller must gate on hist_block_plan"
-    block, block_features = plan
+    block, block_features = _require_block_plan(num_nodes, bf, num_bins)
     node_ids = node_ids.astype(jnp.int32)
     parts = [
         hist_matmul_pallas((node_ids - b0, grad, hess), bins, num_bins,
@@ -419,8 +390,8 @@ DATA_AXIS = "data"
 def ambient_mesh():
     """The Mesh of an enclosing ``with mesh:`` block, or None outside one.
 
-    grad_histogram reads this at trace time to shard_map the kernel for
-    sharded runs; callers opt in simply by tracing under their mesh (the
+    hist_kernel_plan reads this once per fit to shard_map the kernel for
+    sharded runs; callers opt in simply by fitting under their mesh (the
     convention every sharded path in this package already follows).  One
     accessor, the one the installed jax keeps the ``with mesh:`` stack in;
     if an upgrade moves it this raises (and tests/test_hist_pallas.py pins
@@ -436,37 +407,33 @@ def _data_parallelism(mesh) -> int:
     return 1 if mesh is None else mesh.shape.get(DATA_AXIS, 1)
 
 
-def fit_row_multiple() -> int:
-    """Row count a fit pads to ONCE so no kernel call pads again: the tile
-    size times the ambient data axis (each data shard must itself be a
-    whole number of tiles once the kernel runs under shard_map)."""
-    return BLOCK_ROWS * _data_parallelism(ambient_mesh())
+def hist_kernel_plan(model_axis, num_feature: int, max_depth: int,
+                     num_bins: int, batch=None) -> dict:
+    """Settle the kernel of one fit against the ambient mesh, ONCE, before
+    tracing: none of it depends on the level.  Returns the kernel's share of
+    a :class:`~dmlc_core_tpu.ops.histogram.HistPlan`:
 
+    - ``mesh``: the mesh to shard_map the kernel over, None for one plain
+      call.  A Mosaic kernel has no GSPMD partitioning rule; on a TPU, jit
+      refuses one over sharded operands ("Mosaic kernels cannot be
+      automatically partitioned").  So under a mesh that actually shards —
+      a ``model_axis``, or a data axis wider than one device — the kernel
+      runs inside shard_map: rows over data, features over model;
+    - ``row_multiple``: rows a fit pads to once so no kernel call pads
+      again, the tile size times the data axis (each data shard must itself
+      be a whole number of tiles under shard_map);
+    - what ``gbdt.fit.dispatch`` records: ``node_blocks`` (kernel calls at
+      the deepest level) and ``feature_blocks`` (grid steps over features
+      inside each) of one chip's ``F/mp`` slice, and ``bin_split``, the
+      :func:`hist_split_plan` ``HxL`` of every level's call from the root.
 
-def hist_kernel_plan(method: str, model_axis, num_feature: int,
-                     num_nodes: int, num_bins: int, batch=None):
-    """Settle a ``pallas`` request against the shapes and the ambient mesh.  Returns ``(method, mesh)``: the method that will
-    actually run and the mesh to shard_map the kernel over (None = one
-    plain kernel call).
-
-    Single source of truth for ``GBDT._method`` (decides once per fit, for
-    the deepest level, so an ``onehot`` outcome still amortises its matmul
-    RHS across rounds) and ``grad_histogram`` (per level) — the two cannot
-    drift.
-
-    - A Mosaic kernel has no GSPMD partitioning rule; on a TPU, jit refuses
-      one over sharded operands ("Mosaic kernels cannot be automatically
-      partitioned").  So under a mesh that actually shards — a
-      ``model_axis``, or a data axis wider than one device — the kernel
-      runs inside shard_map: rows over data, features over model.
-    - ``onehot`` (a GSPMD-partitionable matmul) takes over only where the
-      mesh forbids the kernel: features do not divide the model axis, rows
-      do not divide the data axis (``batch=None`` skips that check for
-      callers that pad rows later), or a ``model_axis`` is named with no
-      mesh to find it in.  Width and depth never do: the per-shard ``F/mp``
-      slice is blocked by :func:`hist_block_plan` (whose None — 8 node
-      slots of 128 features over the budget, bins in the tens of
-      thousands — is the one shape left to ``onehot``).
+    A mesh the kernel cannot be shard_mapped over raises a ``ValueError``
+    that names the condition and the remedy — nothing falls back: a
+    ``model_axis`` that is no axis of an enclosing ``with mesh:``, features
+    that do not divide the model axis, ``batch`` rows that do not divide the
+    data axis (``batch=None`` skips that check for callers that pad rows
+    later).  Width and depth never raise, :func:`hist_block_plan` blocks
+    them; only bins in the tens of thousands leave no block that fits.
     """
     mesh = ambient_mesh()
     dp = _data_parallelism(mesh)
@@ -474,19 +441,39 @@ def hist_kernel_plan(method: str, model_axis, num_feature: int,
     if model_axis is not None:
         mp = None if mesh is None else mesh.shape.get(model_axis)
         if mp is None:
-            return "onehot", None
+            raise ValueError(
+                f"hist_method='pallas': model_axis={model_axis!r} is not an "
+                f"axis of an enclosing `with mesh:` block ("
+                f"{'no mesh' if mesh is None else tuple(mesh.shape)}); "
+                f"trace the fit under the mesh that has it, or drop "
+                f"model_axis")
+    if num_feature % mp:
+        raise ValueError(
+            f"hist_method='pallas': num_feature={num_feature} does not "
+            f"divide over the {mp} shards of model axis {model_axis!r}; pad "
+            f"the features to a multiple of {mp}, or choose a model axis "
+            f"that divides them")
+    if batch is not None and batch % dp:
+        raise ValueError(
+            f"hist_method='pallas': {batch} rows do not divide over the "
+            f"{dp} shards of the {DATA_AXIS!r} mesh axis; pad rows as "
+            f"`fit_binned` does (weight-0 rows, to a multiple of {dp})")
+    local = num_feature // mp
+    deepest = 2 ** (max_depth - 1)
+    nodes, feats = _require_block_plan(deepest, local, num_bins)
+    splits = (hist_split_plan(
+        min(2 ** depth, hist_block_plan(2 ** depth, local, num_bins)[0]),
+        num_bins) for depth in range(max_depth))
     sharded = model_axis is not None or dp > 1
-    if sharded and (num_feature % mp != 0
-                    or (batch is not None and batch % dp != 0)):
-        return "onehot", None
-    if hist_block_plan(num_nodes, num_feature // mp, num_bins) is None:
-        return "onehot", None
-    return method, (mesh if sharded else None)
+    return {"mesh": mesh if sharded else None,
+            "row_multiple": BLOCK_ROWS * dp,
+            "node_blocks": -(-deepest // nodes),
+            "feature_blocks": -(-local // feats),
+            "bin_split": ",".join(f"{hi}x{lo}" for hi, lo in splits)}
 
 
 def grad_hist_pallas_sharded(bins, node_ids, grad, hess, num_nodes: int,
-                             num_bins: int, mesh, model_axis=None,
-                             data_axis: str = DATA_AXIS):
+                             num_bins: int, mesh, model_axis=None):
     """shard_map-wrapped VMEM hist: rows dp-sharded, features model-sharded.
 
     The only way the Pallas kernel runs on more than one device: each shard
@@ -499,14 +486,15 @@ def grad_hist_pallas_sharded(bins, node_ids, grad, hess, num_nodes: int,
     GSPMD path advertises, so split-finding code downstream is unchanged;
     without one the output is replicated.
 
-    Requires ``F % mesh.shape[model_axis] == 0``; callers check this (and the
-    per-shard VMEM fit) through :func:`hist_kernel_plan` first.
+    Requires ``F % mesh.shape[model_axis] == 0`` and rows that divide the
+    data axis: what :func:`hist_kernel_plan` checks before it hands out the
+    mesh.
     """
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    row_axis = data_axis if data_axis in mesh.shape else None
+    row_axis = DATA_AXIS if DATA_AXIS in mesh.shape else None
     f_local = (bins.shape[0] if model_axis is None
                else bins.shape[0] // mesh.shape[model_axis])
 

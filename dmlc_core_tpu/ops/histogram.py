@@ -3,58 +3,60 @@
 Design notes (TPU-first):
 - binning is a one-time ``searchsorted`` per feature (vmapped, compiled once);
   bins are uint8/int32 — HBM-friendly, 4x smaller than raw floats at 256 bins;
-- TWO histogram algorithms, chosen per backend:
+- TWO histogram methods, chosen from the platform (``auto``, the default of
+  ``GBDTParam.hist_method``; :func:`resolve_hist_method`):
 
-  * ``"onehot"`` (TPU): the histogram is a **matmul on the MXU**.
-    ``G[n,f,b] = sum_i nodehot[i,n] * g_i * binhot[i,f,b]`` — contract the
-    row axis with ``dot_general``:  ``[2n, B] @ [B, F*nbins]``.  The bin
-    one-hot depends only on the (static) binned features, so a full ``fit``
-    materialises it ONCE in bf16 and every level of every round is a pure
-    matmul read — systolic-array work instead of scatter.  TPU scatter-adds
-    serialise (measured: the flat segment_sum below is >1000x slower than
-    this on v5e); the one-hot matmul is the idiomatic recast.
-  * ``"scatter"`` (CPU): one flat ``segment_sum`` over
+  * ``"pallas"`` (a TPU): the histogram is a **matmul on the MXU** whose two
+    one-hot operands are built tile by tile in VMEM and never written to HBM
+    (:mod:`.hist_pallas`).  TPU scatter-adds serialise (measured: the flat
+    segment_sum below is >1000x slower than the matmul on a v5e);
+  * ``"scatter"`` (anywhere else): one flat ``segment_sum`` over
     ``node*F*nbins + f*nbins + bin`` ids — cache-friendly scalar scatter,
-    the fastest CPU formulation (and the exact-f32 reference in tests).
+    the fastest CPU formulation, and the exact-f32 reference in tests.
 
+  Nothing falls back from the kernel: a mesh it cannot be shard_mapped over
+  raises (``hist_pallas.hist_kernel_plan``), a kernel Mosaic rejects raises.
+- ONE decision per fit: :func:`hist_plan` settles the method, the mesh, the
+  row padding and the kernel's blocking before anything is traced, and the
+  :class:`HistPlan` it returns owns everything that depends on the method —
+  the layout of the bins the histogram reads (:meth:`HistPlan.layouts`,
+  made once per fit: ``[F, rows]`` int32 for the kernel, ``[rows, F]`` int32
+  for ``scatter``), the per-level histogram and the leaf sums.  A caller
+  (``models/gbdt.py``) hands the histogram's copy back as an opaque operand;
 - everything is static-shape: ``num_bins``, ``num_features``, and the level's
   node count are compile-time constants, so XLA tiles the matmul/scatter
   efficiently and the whole boosting round stays inside one jit.
 
-Under a sharded batch (rows split over the "data" mesh axis) GSPMD turns
-either formulation into per-shard partial histograms + an all-reduce over
-ICI — exactly the distributed-hist aggregation XGBoost does over Rabit
-(SURVEY.md §2.9), but compiler-scheduled (the contracted row axis of the
-dot_general is the sharded one, so the psum falls out of SPMD partitioning).
+Under a sharded batch (rows split over the "data" mesh axis) the histogram
+becomes per-shard partials + an all-reduce over ICI — exactly the
+distributed-hist aggregation XGBoost does over Rabit (SURVEY.md §2.9):
+GSPMD partitions the ``scatter`` segment-sum, and the kernel runs under
+``shard_map`` with a ``psum`` over the data axis.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Any, NamedTuple, Optional
 
 import numpy as np
 
 from dmlc_core_tpu.utils.logging import CHECK
 
 __all__ = ["quantile_boundaries", "apply_bins", "grad_histogram",
-           "bin_onehot", "resolve_hist_method", "local_quantile_summary",
+           "HistPlan", "hist_plan", "resolve_hist_method",
+           "local_quantile_summary",
            "merged_quantile_boundaries", "distributed_quantile_boundaries"]
 
 
 def resolve_hist_method(method: str, *arrays) -> str:
-    """Resolve ``"auto"`` to a concrete histogram algorithm.
+    """Resolve ``"auto"`` to a concrete histogram method.
 
     Prefers the committed platform of any input jax.Array, falling back to
-    ``jax.default_backend()``: the VMEM-resident Pallas kernel on TPU,
-    scatter segment-sums on CPU, the plain one-hot matmul anywhere else.
+    ``jax.default_backend()``: the VMEM-resident Pallas kernel on a TPU,
+    scatter segment-sums (which run anywhere) on every other platform.
     Nothing is probed: on a TPU ``auto`` *means* ``pallas``, and a kernel
-    Mosaic rejects raises with the compiler's message instead of quietly
-    training through the HBM-bound ``onehot`` path.
+    Mosaic rejects raises with the compiler's message.
     """
-    if method == "pallas_fused":
-        # the name of a retired variant (W built in the kernel, which the one
-        # kernel now does at every level): still accepted, runs ``pallas``
-        return "pallas"
     if method != "auto":
         return method
     import jax
@@ -70,24 +72,137 @@ def resolve_hist_method(method: str, *arrays) -> str:
                 continue
     if platform is None:
         platform = jax.default_backend()
-    return {"cpu": "scatter", "tpu": "pallas"}.get(platform, "onehot")
+    return "pallas" if platform == "tpu" else "scatter"
 
 
-def bin_onehot(bins, num_bins: int, dtype=None):
-    """One-hot encode binned features: [B, F] int -> [B, F*num_bins].
+class HistPlan(NamedTuple):
+    """What one fit settled about its histograms before tracing
+    (:func:`hist_plan`).  Immutable and hashable: static under ``jit`` and
+    part of the model's compiled-function cache keys.  Everything that
+    depends on the method is a method of the plan, so a caller never asks
+    which one it is."""
 
-    This is the matmul RHS of the one-hot histogram.  It depends only on the
-    binned features, so callers training many rounds materialise it once
-    (bf16: 0/1 exactly representable) and amortise across every level/round.
-    """
-    import jax.numpy as jnp
+    method: str                       # "pallas" | "scatter"
+    model_axis: Optional[str] = None  # the histogram's feature dim splits here
+    mesh: Any = None                  # shard_map the kernel over it, or None
+    row_multiple: int = 1             # rows a fit pads to, once
+    # the kernel's shape as ``gbdt.fit.dispatch`` records it; 0, 0 and ""
+    # for a method that is no kernel (``hist_pallas.hist_kernel_plan``)
+    node_blocks: int = 0
+    feature_blocks: int = 0
+    bin_split: str = ""
 
-    if dtype is None:
-        dtype = jnp.bfloat16
-    bins = jnp.asarray(bins).astype(jnp.int32)  # narrow dtypes must not wrap
-    B, F = bins.shape
-    iota = jnp.arange(num_bins, dtype=jnp.int32)
-    return (bins[:, :, None] == iota).astype(dtype).reshape(B, F * num_bins)
+    def blocks(self) -> dict:
+        """The kernel's shape, as the ``gbdt.fit.dispatch`` span records it
+        beside the method."""
+        return {"node_blocks": self.node_blocks,
+                "feature_blocks": self.feature_blocks,
+                "bin_split": self.bin_split}
+
+    def layouts(self, bins, pad: int = 0):
+        """The two device layouts a fit keeps of one ``[rows, F]`` binned
+        batch, rows padded by ``pad``: the histogram's own copy (opaque to
+        the caller, handed back to :meth:`histogram`) and ``[F, rows]`` in
+        the wire dtype for the per-row feature pick — rows on the minor
+        (lane) axis, so a pass over it streams rows x F narrow bytes
+        instead of a row-major array whose F lanes pad to 128.  The
+        histogram's copy is widened int32 (v5e Mosaic lowers no sub-32-bit
+        compare): ``[F, rows]`` for the kernel, a quarter or less of the
+        lane-padded row-major one, and ``[rows, F]`` for ``scatter``.
+        Made once per fit (once per streamed round) under ``gbdt.layout``."""
+        import jax
+        import jax.numpy as jnp
+
+        with jax.named_scope("gbdt.layout"):
+            bins = jnp.asarray(bins)
+            if pad:
+                bins = jnp.pad(bins, ((0, pad), (0, 0)))
+            return self._operand(bins), bins.T
+
+    def _operand(self, bins):
+        """The histogram's copy of row-major ``bins`` (see :meth:`layouts`);
+        narrow dtypes are widened so no id arithmetic wraps."""
+        import jax.numpy as jnp
+
+        if self.method == "pallas":
+            bins = bins.T
+        return bins if bins.dtype == jnp.int32 else bins.astype(jnp.int32)
+
+    def histogram(self, hist_bins, node_ids, grad, hess, num_nodes: int,
+                  num_bins: int):
+        """One level's ``(G, H)``, each ``[num_nodes, F, num_bins]`` f32,
+        from the histogram's copy of the bins (:meth:`layouts`); otherwise
+        the contract of :func:`grad_histogram`."""
+        import jax
+        import jax.numpy as jnp
+
+        if self.method == "pallas":
+            # looked up at call time: tests put a stand-in on the module
+            from dmlc_core_tpu.ops import hist_pallas
+
+            if self.mesh is not None:
+                G, H = hist_pallas.grad_hist_pallas_sharded(
+                    hist_bins, node_ids, grad, hess, num_nodes, num_bins,
+                    self.mesh, self.model_axis)
+            else:
+                G, H = hist_pallas.grad_hist_pallas(
+                    hist_bins, node_ids, grad, hess, num_nodes, num_bins)
+        else:
+            B, F = hist_bins.shape
+            ids = (node_ids[:, None] * (F * num_bins)
+                   + jnp.arange(F, dtype=jnp.int32)[None, :] * num_bins
+                   + hist_bins)                               # [B, F]
+            flat_ids = ids.reshape(-1)
+            nseg = num_nodes * F * num_bins
+            g_flat = jnp.broadcast_to(grad[:, None], (B, F)).reshape(-1)
+            h_flat = jnp.broadcast_to(hess[:, None], (B, F)).reshape(-1)
+            G = jax.ops.segment_sum(g_flat, flat_ids, num_segments=nseg)
+            H = jax.ops.segment_sum(h_flat, flat_ids, num_segments=nseg)
+            G = G.reshape(num_nodes, F, num_bins)
+            H = H.reshape(num_nodes, F, num_bins)
+        if self.model_axis is not None:
+            from jax.sharding import PartitionSpec as P
+
+            constraint = P(None, self.model_axis, None)
+            G = jax.lax.with_sharding_constraint(G, constraint)
+            H = jax.lax.with_sharding_constraint(H, constraint)
+        return G, H
+
+    def leaf_sums(self, node, g, h, num_leaves: int):
+        """``(sum g, sum h)`` of every leaf, each ``[num_leaves]`` f32, for
+        row leaf ids ``node``: segment-sums, or where those serialise (a
+        TPU's scatter-adds) a small f32 matmul against the leaf one-hot."""
+        import jax
+        import jax.numpy as jnp
+
+        if self.method != "pallas":
+            return (jax.ops.segment_sum(g, node, num_segments=num_leaves),
+                    jax.ops.segment_sum(h, node, num_segments=num_leaves))
+        leafhot = (node[:, None] == jnp.arange(num_leaves, dtype=node.dtype)
+                   ).astype(jnp.float32)                     # [B, n_leaf]
+        gh = jnp.stack([g, h], axis=1)                       # [B, 2]
+        sums = jax.lax.dot_general(leafhot, gh, (((0,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        return sums[:, 0], sums[:, 1]
+
+
+def hist_plan(method: str, model_axis: Optional[str], num_feature: int,
+              max_depth: int, num_bins: int, rows: Optional[int] = None,
+              arrays=()) -> HistPlan:
+    """Settle one fit's histograms from what is known before tracing:
+    ``auto`` from the platform of ``arrays``
+    (:func:`resolve_hist_method`), and for the kernel the ambient mesh, the
+    row padding and the blocking (``hist_pallas.hist_kernel_plan``, which
+    raises where the mesh forbids the kernel).  ``rows`` is the row count
+    the histogram will see (a fit's padded one), None where rows are padded
+    later."""
+    method = resolve_hist_method(method, *arrays)
+    if method != "pallas":
+        return HistPlan(method, model_axis)
+    from dmlc_core_tpu.ops.hist_pallas import hist_kernel_plan
+
+    return HistPlan(method, model_axis, **hist_kernel_plan(
+        model_axis, num_feature, max_depth, num_bins, batch=rows))
 
 
 def _strictly_increasing(bounds: np.ndarray) -> np.ndarray:
@@ -276,12 +391,14 @@ def apply_bins(x, boundaries, missing_bin: Optional[int] = None):
 
 
 def grad_histogram(bins, node_ids, grad, hess, num_nodes: int, num_bins: int,
-                   model_axis: Optional[str] = None, method: str = "scatter",
-                   onehot=None, feature_major: bool = False):
-    """Per-(node, feature, bin) gradient/hessian sums.
+                   model_axis: Optional[str] = None, method: str = "scatter"):
+    """Per-(node, feature, bin) gradient/hessian sums of one level, planned
+    and laid out on the spot (a fit plans once and keeps the layout:
+    :func:`hist_plan`).
 
     Args:
-      bins:     [B, F] int32 binned features.
+      bins:     [B, F] binned features, row-major, int32 or a narrower wire
+        dtype.
       node_ids: [B] int32 current tree-node of each row (in [0, num_nodes)).
       grad/hess: [B] float32 (pre-multiplied by instance weight; padding rows
         must carry 0 weight so they vanish from every bin).
@@ -290,75 +407,18 @@ def grad_histogram(bins, node_ids, grad, hess, num_nodes: int, num_bins: int,
         sharding-constrained to split the feature dim over that axis
         (tensor-parallel hist for very wide feature spaces).
       method: "scatter" (default: segment_sum, exact f32 — the reference
-        formulation and the fast CPU one) | "onehot" (bf16 MXU matmul, the
-        fast TPU one) | "auto" (resolve by platform).  The exact path stays
-        the default so existing callers keep f32 semantics.
-      onehot: optional precomputed :func:`bin_onehot` (amortised across
-        levels/rounds by callers; only used by the onehot method).
-      feature_major: ``bins`` is handed over as ``[F, B]`` int32, the layout
-        the ``pallas`` kernel reads (a fit keeps it once, ``gbdt.layout``);
-        row-major bins are transposed and widened here for the kernel.
+        formulation and the fast CPU one) | "pallas" (the VMEM kernel: bf16
+        g and h on the MXU, the fast TPU one) | "auto" (resolve by
+        platform).  The exact path stays the default so existing callers
+        keep f32 semantics.
 
     Returns (G, H): each [num_nodes, F, num_bins] float32.
     """
-    import jax
     import jax.numpy as jnp
 
     bins = jnp.asarray(bins)
-    F, B = bins.shape if feature_major else bins.shape[::-1]
-    method = resolve_hist_method(method, bins, grad)
-    sharded_mesh = None
-    if method == "pallas":
-        from dmlc_core_tpu.ops.hist_pallas import hist_kernel_plan
-
-        method, sharded_mesh = hist_kernel_plan(method, model_axis, F,
-                                                num_nodes, num_bins, batch=B)
-    if feature_major != (method == "pallas"):
-        bins = bins.T
-    if method == "pallas":
-        bins = bins.astype(jnp.int32)
-
-    if sharded_mesh is not None:
-        from dmlc_core_tpu.ops.hist_pallas import grad_hist_pallas_sharded
-
-        G, H = grad_hist_pallas_sharded(
-            bins, node_ids, grad, hess, num_nodes, num_bins, sharded_mesh,
-            model_axis)
-    elif method == "pallas":
-        from dmlc_core_tpu.ops.hist_pallas import grad_hist_pallas
-
-        G, H = grad_hist_pallas(bins, node_ids, grad, hess, num_nodes,
-                                num_bins)
-    elif method == "onehot":
-        if onehot is None:
-            onehot = bin_onehot(bins, num_bins)
-        dt = onehot.dtype
-        nodehot = (node_ids.astype(jnp.int32)[:, None]
-                   == jnp.arange(num_nodes, dtype=jnp.int32)).astype(dt)
-        # [B, 2n]: per-row node one-hot weighted by g (first n cols) and h
-        W = jnp.concatenate([nodehot * grad[:, None].astype(dt),
-                             nodehot * hess[:, None].astype(dt)], axis=1)
-        GH = jax.lax.dot_general(
-            W, onehot, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [2n, F*nbins] f32 acc
-        GH = GH.reshape(2, num_nodes, F, num_bins)
-        G, H = GH[0], GH[1]
-    else:
-        ids = (node_ids[:, None] * (F * num_bins)
-               + jnp.arange(F, dtype=jnp.int32)[None, :] * num_bins
-               + bins)                                    # [B, F]
-        flat_ids = ids.reshape(-1)
-        nseg = num_nodes * F * num_bins
-        g_flat = jnp.broadcast_to(grad[:, None], (B, F)).reshape(-1)
-        h_flat = jnp.broadcast_to(hess[:, None], (B, F)).reshape(-1)
-        G = jax.ops.segment_sum(g_flat, flat_ids, num_segments=nseg)
-        H = jax.ops.segment_sum(h_flat, flat_ids, num_segments=nseg)
-        G = G.reshape(num_nodes, F, num_bins)
-        H = H.reshape(num_nodes, F, num_bins)
-    if model_axis is not None:
-        from jax.sharding import PartitionSpec as P
-
-        constraint = P(None, model_axis, None)
-        G = jax.lax.with_sharding_constraint(G, constraint)
-        H = jax.lax.with_sharding_constraint(H, constraint)
-    return G, H
+    B, F = bins.shape
+    plan = hist_plan(method, model_axis, F, (num_nodes - 1).bit_length() + 1,
+                     num_bins, rows=B, arrays=(bins, grad))
+    return plan.histogram(plan._operand(bins), node_ids, grad, hess,
+                          num_nodes, num_bins)

@@ -147,11 +147,9 @@ def main():
     ap.add_argument("--num-bins", type=int, default=256)
     ap.add_argument("--learning-rate", type=float, default=0.3)
     ap.add_argument("--hist-method", default="auto",
-                    choices=["auto", "pallas", "pallas_fused", "onehot",
-                             "scatter"],
+                    choices=["auto", "pallas", "scatter"],
                     help="histogram algorithm (auto: pallas VMEM kernel on "
-                         "TPU, scatter on CPU; pallas_fused: an older name "
-                         "for pallas)")
+                         "a TPU, scatter everywhere else)")
     ap.add_argument("--objective", default="logistic",
                     choices=["logistic", "squared", "softmax"])
     ap.add_argument("--num-class", type=int, default=1,

@@ -93,7 +93,7 @@ def test_every_split_of_the_bin_index_on_chip(jx, NN):
     node_ids[::9] = -1
     node_ids[4::13] = NN + 3
     g, h = grad_histogram(bins.astype(np.uint8), node_ids, grad, hess,
-                          num_nodes=NN, num_bins=NB, method="pallas_fused")
+                          num_nodes=NN, num_bins=NB, method="pallas")
     keep = (node_ids >= 0) & (node_ids < NN)
     g_ref, h_ref = _scatter_ref(jx, bins[keep], node_ids[keep], grad[keep],
                                 hess[keep], NN, NB)

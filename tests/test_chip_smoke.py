@@ -71,7 +71,7 @@ def test_train_phase_runs_the_kernel_path_and_checks_hold(libsvm,
     with pytest.raises(AssertionError, match="train accuracy"):
         smoke.check_train(TINY._replace(acc_floor=1.0), trained, "pallas")
     with pytest.raises(AssertionError, match="resolved to"):
-        smoke.check_train(TINY, trained, expect_method="onehot")
+        smoke.check_train(TINY, trained, expect_method="scatter")
 
 
 def test_kernel_vs_scatter_check_catches_a_wrong_histogram(libsvm,

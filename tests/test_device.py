@@ -227,15 +227,13 @@ def test_auto_on_tpu_means_pallas_with_nothing_probed(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert resolve_hist_method("auto") == "pallas"
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
-    assert resolve_hist_method("auto") == "onehot"
+    assert resolve_hist_method("auto") == "scatter"      # runs anywhere
     assert resolve_hist_method("scatter") == "scatter"   # explicit wins
 
 
-@pytest.mark.parametrize("name", ["pallas", "pallas_fused"])
-def test_a_kernel_the_compiler_rejects_raises_its_message(monkeypatch, name):
+def test_a_kernel_the_compiler_rejects_raises_its_message(monkeypatch):
     """No probe stands between a request for the kernel and the compiler:
-    what Mosaic says about the one kernel body reaches the caller, under
-    either of the kernel's names."""
+    what Mosaic says about the one kernel body reaches the caller."""
     import numpy as np
 
     from dmlc_core_tpu.ops import hist_pallas
@@ -251,7 +249,7 @@ def test_a_kernel_the_compiler_rejects_raises_its_message(monkeypatch, name):
     with pytest.raises(RuntimeError, match="Mosaic failed to compile TPU "
                                            "kernel: nope"):
         grad_histogram(np.zeros((128, 2), np.int32), rows.astype(np.int32),
-                       rows, rows, 4, 8, method=name)
+                       rows, rows, 4, 8, method="pallas")
 
 
 def _fake_devices(platform, n=4):

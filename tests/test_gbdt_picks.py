@@ -12,9 +12,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dmlc_core_tpu.models.gbdt import (GBDT, GBDTParam, _bin_layouts,
-                                       _build_tree, _feature_pick,
-                                       _table_pick)
+from dmlc_core_tpu.models.gbdt import (GBDT, GBDTParam, _build_tree,
+                                       _feature_pick, _table_pick)
+from dmlc_core_tpu.ops.histogram import HistPlan
+
+SCATTER = HistPlan("scatter")
 
 ROWS = 333                      # not a multiple of 128
 
@@ -47,7 +49,7 @@ def test_feature_pick_is_take_along_axis(dtype, features):
     bins = rng.integers(0, top, (ROWS, features)).astype(dtype)
     feat = rng.integers(-1, features, ROWS).astype(np.int32)
     feat[:3] = -1
-    widened, bins_fm = _bin_layouts(bins)
+    widened, bins_fm = SCATTER.layouts(bins)
     assert bins_fm.shape == (features, ROWS) and bins_fm.dtype == dtype
     assert widened.shape == bins.shape and widened.dtype == jnp.int32
     got = np.asarray(_feature_pick(bins_fm, jnp.asarray(feat)))
@@ -60,7 +62,7 @@ def test_feature_pick_is_take_along_axis(dtype, features):
 
 def test_bin_layouts_pad_rows_in_both_layouts():
     bins = np.arange(15, dtype=np.uint8).reshape(5, 3)
-    widened, bins_fm = _bin_layouts(bins, pad=3)
+    widened, bins_fm = SCATTER.layouts(bins, pad=3)
     assert widened.shape == (8, 3) and bins_fm.shape == (3, 8)
     np.testing.assert_array_equal(np.asarray(widened)[:5], bins)
     np.testing.assert_array_equal(np.asarray(widened).T, np.asarray(bins_fm))
@@ -192,11 +194,11 @@ def test_build_tree_matches_a_numpy_indexing_oracle(case, wire):
           - (bins[:, 2] > 9) * np.float32(0.25))
     h = (rng.integers(8, 33, rows) / 32).astype(np.float32)
 
-    widened, bins_fm = _bin_layouts(bins.astype(wire))
+    widened, bins_fm = SCATTER.layouts(bins.astype(wire))
     sf, sb, lv, dl, _, _, delta = jax.jit(
         lambda b, bf, g_, h_: _build_tree(
-            b, bf, g_, h_, DEPTH, BINS, float(LAM), float(MCW), LR,
-            method="scatter", missing=missing, monotone=mono))(
+            b, bf, g_, h_, SCATTER, DEPTH, BINS, float(LAM), float(MCW), LR,
+            missing=missing, monotone=mono))(
         widened, bins_fm, g, h)
     want_sf, want_sb, want_dl, want_lv, want_delta = _oracle_tree(
         bins, g, h, missing, mono)

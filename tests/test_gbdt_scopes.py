@@ -55,7 +55,7 @@ def test_compiled_fit_names_every_phase(objective, scope):
 def test_streaming_round_carries_the_same_scopes():
     model = _model("logistic")
     bins, label, weight = _data("logistic")
-    compiled = model._round_fn("scatter").lower(
+    compiled = model._round_fn(model._plan("scatter")).lower(
         np.zeros(ROWS, np.float32), bins, label, weight,
         np.uint32(0)).compile()
     text = compiled.as_text()

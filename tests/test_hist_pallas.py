@@ -1,8 +1,8 @@
 """Pallas histogram kernel vs the exact scatter formulation (interpret mode).
 
 The kernel's numerics are bf16 one-hot x bf16-rounded g/h with f32
-accumulation — the same contract as the plain one-hot matmul — so tolerances
-below reflect bf16 rounding of g/h, not algorithmic drift.
+accumulation, so tolerances below reflect bf16 rounding of g/h, not
+algorithmic drift.
 """
 
 import numpy as np
@@ -75,11 +75,11 @@ def test_split_plan_at_256_bins():
         assert n >= 128 or lo <= 2 * n * hi <= 4 * lo
     # one kernel call's nodes at every level of a fit, root first: a level
     # cut into node blocks runs a block's plan
-    assert hist_pallas.hist_level_splits(None, 28, 6, 256) \
-        == [PLAN_256[2 ** d] for d in range(6)]
+    assert hist_pallas.hist_kernel_plan(None, 28, 6, 256)["bin_split"] \
+        == ",".join("%dx%d" % PLAN_256[2 ** d] for d in range(6))
     assert hist_pallas.hist_block_plan(512, 28, 256) == (128, 28)
-    assert hist_pallas.hist_level_splits(None, 28, 10, 256)[-2:] \
-        == [PLAN_256[128]] * 2
+    assert hist_pallas.hist_kernel_plan(None, 28, 10, 256)[
+        "bin_split"].endswith("1x256,1x256")
 
 
 PLAN_256 = {1: (16, 16), 2: (8, 32), 4: (8, 32), 8: (4, 64), 16: (4, 64),
@@ -111,42 +111,115 @@ def test_feature_counts_of_the_cells_match_scatter(f):
     _assert_hist_of_rounded(got, bins, node, g, h, 8, 256)
 
 
-def test_grad_histogram_takes_either_layout():
-    """Row-major bins (the contract; the benchmark's check calls it so on
-    numpy bins) and the same call with the kernel's layout handed over."""
-    import jax.numpy as jnp
+@pytest.mark.parametrize("method", ["pallas", "scatter"])
+def test_grad_histogram_is_the_plans_histogram_of_its_own_layout(method):
+    """Row-major bins in (the contract; the benchmark's check calls it so
+    on numpy uint8 bins): the histogram a fit gets from the layout its plan
+    keeps, which for the kernel is ``[F, rows]`` int32."""
+    from dmlc_core_tpu.ops.histogram import hist_plan
 
     bins, node, g, h = _rand_case(1500, 5, 256, 4, seed=61)
     narrow = bins.astype(np.uint8)               # 255 must not wrap
     row_major = grad_histogram(narrow, node, g, h, num_nodes=4,
-                               num_bins=256, method="pallas")
-    handed = grad_histogram(jnp.asarray(bins.T), node, g, h, num_nodes=4,
-                            num_bins=256, method="pallas",
-                            feature_major=True)
-    for a, b in zip(row_major, handed):
+                               num_bins=256, method=method)
+    plan = hist_plan(method, None, 5, 3, 256)
+    hist_bins, bins_fm = plan.layouts(narrow)
+    assert hist_bins.dtype == np.int32 and hist_bins.shape == (
+        (5, 1500) if method == "pallas" else (1500, 5))
+    assert bins_fm.dtype == np.uint8 and bins_fm.shape == (5, 1500)
+    kept = plan.histogram(hist_bins, node, g, h, 4, 256)
+    for a, b in zip(row_major, kept):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    _assert_hist_of_rounded(handed, bins, node, g, h, 4, 256)
-    # the layout is the caller's to state for the other methods too
-    exact = grad_histogram(bins.T, node, g, h, 4, 256, method="scatter",
-                           feature_major=True)
-    want = grad_histogram(bins, node, g, h, 4, 256, method="scatter")
-    np.testing.assert_array_equal(np.asarray(exact[0]), np.asarray(want[0]))
+    if method == "pallas":
+        _assert_hist_of_rounded(kept, bins, node, g, h, 4, 256)
 
 
-@pytest.mark.parametrize("name", ["pallas", "pallas_fused"])
-def test_pallas_fused_is_a_name_of_the_one_kernel(name):
-    """``pallas_fused`` built W in the kernel; the one kernel does now, so
-    the name stays accepted and runs it."""
+def test_pallas_is_the_name_of_the_one_kernel():
     from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
     from dmlc_core_tpu.ops.histogram import resolve_hist_method
 
-    assert resolve_hist_method(name) == "pallas"
-    model = GBDT(GBDTParam(max_depth=3, num_bins=16, hist_method=name),
+    assert resolve_hist_method("pallas") == "pallas"
+    model = GBDT(GBDTParam(max_depth=3, num_bins=16, hist_method="pallas"),
                  num_feature=4)
     assert model._method() == "pallas"
     bins, node, g, h = _rand_case(256, 3, 8, 4, seed=9)
-    G, H = grad_histogram(bins, node, g, h, 4, 8, method=name)
+    G, H = grad_histogram(bins, node, g, h, 4, 8, method="pallas")
     _assert_hist_of_rounded((G, H), bins, node, g, h, 4, 8)
+
+
+def _model_axis_with_no_mesh():
+    from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
+
+    GBDT(GBDTParam(max_depth=6, num_bins=256, hist_method="pallas"),
+         num_feature=28, model_axis="model")._method()
+
+
+def _features_that_do_not_divide_the_model_axis():
+    with _mesh_2d():
+        hist_pallas.hist_kernel_plan("model", 7, 3, 16)
+
+
+def _rows_that_do_not_divide_the_data_axis():
+    from dmlc_core_tpu.parallel.mesh import make_mesh
+
+    bins, node, g, h = _rand_case(510, 8, 16, 4, seed=31)
+    with make_mesh({"data": 8}):
+        grad_histogram(bins, node, g, h, 4, 16, method="pallas")
+
+
+def _bins_no_accumulator_block_fits():
+    hist_pallas.hist_kernel_plan(None, 2000, 4, 2 ** 15)
+
+
+def _a_retired_method_name(name):
+    from dmlc_core_tpu.models.gbdt import GBDTParam
+
+    GBDTParam(hist_method=name)
+
+
+@pytest.mark.parametrize("ask,says", [
+    (_model_axis_with_no_mesh,
+     r"model_axis='model' is not an axis of an enclosing `with mesh:`"),
+    (_features_that_do_not_divide_the_model_axis,
+     r"num_feature=7 does not divide over the 2 shards of model axis"),
+    (_rows_that_do_not_divide_the_data_axis,
+     r"510 rows do not divide over the 8 shards .* pad rows as `fit_binned`"),
+    (_bins_no_accumulator_block_fits,
+     r"no accumulator block fits VMEM at num_bins=32768"),
+    (lambda: _a_retired_method_name("onehot"),
+     r"Invalid value 'onehot' for parameter 'hist_method'"),
+    (lambda: _a_retired_method_name("pallas_fused"),
+     r"Invalid value 'pallas_fused' for parameter 'hist_method'"),
+], ids=["model_axis_no_mesh", "features_vs_model_axis", "rows_vs_data_axis",
+        "no_block_fits", "enum_onehot", "enum_pallas_fused"])
+def test_what_the_kernel_cannot_run_raises_and_names_it(ask, says):
+    """Nothing falls back from the kernel: each condition of the plan, and
+    each retired method name, is a ``ValueError`` that says what is wrong."""
+    with pytest.raises(ValueError, match=says):
+        ask()
+
+
+def test_a_fit_plans_its_histograms_once(monkeypatch):
+    """The mesh conditions and the blocking are settled once per fit, before
+    tracing — not again at each of the six levels inside the trace."""
+    from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
+
+    calls = []
+    plan = hist_pallas.hist_kernel_plan
+
+    def counted(*args, **kwargs):
+        calls.append(args)
+        return plan(*args, **kwargs)
+
+    monkeypatch.setattr(hist_pallas, "hist_kernel_plan", counted)
+    rng = np.random.RandomState(3)
+    x = rng.randn(300, 4).astype(np.float32)
+    model = GBDT(GBDTParam(num_boost_round=2, max_depth=6, num_bins=16,
+                           hist_method="pallas"), num_feature=4)
+    model.make_bins(x)
+    model.fit_binned(np.asarray(model.bin_features(x), np.uint8),
+                     (x[:, 0] > 0).astype(np.float32))
+    assert calls == [(None, 4, 6, 16)]
 
 
 @pytest.mark.parametrize("b,f,nbins,nnodes", [
@@ -186,9 +259,9 @@ def test_grad_histogram_dispatches_pallas():
                                rtol=2e-2, atol=2e-2)
 
 
-def test_vmem_overflow_blocks_or_falls_back():
-    """Deep trees keep the kernel via node-blocked sweeps; onehot only when
-    even an 8-node block overflows VMEM."""
+def test_vmem_overflow_blocks():
+    """Deep trees keep the kernel via node-blocked sweeps, wide ones via
+    feature blocks."""
     from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
     from dmlc_core_tpu.ops.hist_pallas import hist_block_plan, hist_fits_vmem
 
@@ -199,17 +272,13 @@ def test_vmem_overflow_blocks_or_falls_back():
     assert hist_block_plan(512, 512, 1024) == (8, 128)  # both axes blocked
     deep = GBDT(GBDTParam(max_depth=10, num_bins=256, hist_method="pallas"),
                 num_feature=28)
-    assert deep._method() == "pallas"             # blocked, not onehot
+    assert deep._method() == "pallas"             # blocked
     wide = GBDT(GBDTParam(max_depth=10, num_bins=1024,
                           hist_method="pallas"), num_feature=512)
     assert wide._method() == "pallas"             # 8 nodes x 128 features
     shallow = GBDT(GBDTParam(max_depth=6, num_bins=256,
                              hist_method="pallas"), num_feature=28)
     assert shallow._method() == "pallas"
-    sharded = GBDT(GBDTParam(max_depth=6, num_bins=256,
-                             hist_method="pallas"), num_feature=28,
-                   model_axis="model")
-    assert sharded._method() == "onehot"
 
 
 def test_blocked_hist_matches_scatter():
@@ -313,18 +382,17 @@ def test_sharded_pallas_matches_scatter():
     np.testing.assert_allclose(H, np.asarray(Hr), rtol=2e-2, atol=2e-2)
 
 
-def test_sharded_pallas_uneven_features_falls_back():
-    """F not divisible by the model axis must fall back, not crash."""
+def test_sharded_pallas_uneven_features_raise_at_trace_time():
+    """F not divisible by the model axis: the jitted caller hears it while
+    tracing, before anything runs."""
     import jax
 
     bins, node, g, h = _rand_case(256, 7, 8, 4, seed=13)   # 7 % 2 != 0
-    mesh = _mesh_2d()
-    with mesh:
-        G, _ = jax.jit(lambda *a: grad_histogram(
-            *a, 4, 8, model_axis="model", method="pallas"))(bins, node, g, h)
-        G = np.asarray(G)
-    Gr, _ = grad_histogram(bins, node, g, h, 4, 8, method="scatter")
-    np.testing.assert_allclose(G, np.asarray(Gr), rtol=2e-2, atol=2e-2)
+    with _mesh_2d():
+        with pytest.raises(ValueError, match="num_feature=7 does not divide"):
+            jax.jit(lambda *a: grad_histogram(
+                *a, 4, 8, model_axis="model", method="pallas"))(
+                    bins, node, g, h)
 
 
 def test_gbdt_model_sharded_keeps_pallas():
@@ -370,7 +438,7 @@ def test_gbdt_model_sharded_keeps_pallas():
 def test_ambient_mesh_probe_on_current_jax():
     """The ambient-mesh accessor reaches into jax internals
     (hist_pallas.ambient_mesh); if a jax upgrade moves it, the model-sharded
-    kernel would silently degrade to onehot.  Pin the probe directly."""
+    kernel could not find its mesh.  Pin the probe directly."""
     mesh = _mesh_2d()
     assert hist_pallas.ambient_mesh() is None
     with mesh:
@@ -379,9 +447,9 @@ def test_ambient_mesh_probe_on_current_jax():
             "ambient_mesh() lost the enclosing mesh on jax "
             + __import__("jax").__version__)
         assert m.shape["model"] == 2
-        # and the single-source-of-truth gate selects the kernel with it
-        assert hist_pallas.hist_kernel_plan("pallas", "model", 8, 4, 16,
-                                            batch=256) == ("pallas", m)
+        # and the plan hands it out for the kernel's shard_map
+        assert hist_pallas.hist_kernel_plan("model", 8, 3, 16,
+                                            batch=256)["mesh"] is m
     assert hist_pallas.ambient_mesh() is None
 
 
@@ -428,9 +496,9 @@ def test_dp_only_mesh_runs_the_kernel_under_shard_map():
     mesh = make_mesh({"data": 8})
     bins, node, g, h = _rand_case(512, 8, 16, 4, seed=31)
     # no mesh: one plain kernel call
-    assert hist_pallas.hist_kernel_plan("pallas", None, 8, 4, 16,
-                                        batch=512) == ("pallas", None)
-    assert hist_pallas.fit_row_multiple() == hist_pallas.BLOCK_ROWS
+    plain = hist_pallas.hist_kernel_plan(None, 8, 3, 16, batch=512)
+    assert plain["mesh"] is None
+    assert plain["row_multiple"] == hist_pallas.BLOCK_ROWS
     calls = []
     orig = hist_pallas.grad_hist_pallas_sharded
 
@@ -441,13 +509,12 @@ def test_dp_only_mesh_runs_the_kernel_under_shard_map():
     hist_pallas.grad_hist_pallas_sharded = spy
     try:
         with mesh:
-            assert hist_pallas.hist_kernel_plan(
-                "pallas", None, 8, 4, 16, batch=512) == ("pallas", mesh)
+            sharded = hist_pallas.hist_kernel_plan(None, 8, 3, 16, batch=512)
+            assert sharded["mesh"] is mesh
+            assert sharded["row_multiple"] == 8 * hist_pallas.BLOCK_ROWS
             # rows that do not divide the data axis cannot be shard_mapped
-            assert hist_pallas.hist_kernel_plan(
-                "pallas", None, 8, 4, 16, batch=510) == ("onehot", None)
-            assert hist_pallas.fit_row_multiple() \
-                == 8 * hist_pallas.BLOCK_ROWS
+            with pytest.raises(ValueError, match="510 rows do not divide"):
+                hist_pallas.hist_kernel_plan(None, 8, 3, 16, batch=510)
             placed = [jax.device_put(a, data_sharding(mesh, ndim=a.ndim))
                       for a in (bins, node, g, h)]
             G, H = jax.jit(lambda *a: grad_histogram(
@@ -618,7 +685,7 @@ def test_the_kernel_entry_returns_the_flat_histogram():
     _assert_hist_of_rounded((G, H), bins, node, g, h, 4, 256)
 
 
-def test_wide_tables_plan_the_kernel_not_onehot():
+def test_wide_tables_plan_feature_blocks():
     """2,000 features x 256 bins (epsilon): 16 blocks of 128 features under
     all 32 nodes of the deepest level, one call a level."""
     from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
@@ -627,15 +694,18 @@ def test_wide_tables_plan_the_kernel_not_onehot():
     assert hist_pallas.hist_block_plan(1, 2000, 256) == (1, 128)
     assert hist_pallas.hist_block_plan(32, 28, 256) == (32, 28)
     assert hist_pallas.hist_block_plan(512, 2000, 256) == (32, 128)
-    assert hist_pallas.hist_block_counts(None, 2000, 32, 256) == (1, 16)
-    assert hist_pallas.hist_block_counts(None, 2000, 512, 256) == (16, 16)
-    assert hist_pallas.hist_block_counts(None, 28, 32, 256) == (1, 1)
-    assert hist_pallas.hist_kernel_plan("pallas", None, 2000, 32,
-                                        256) == ("pallas", None)
+    def blocks(num_feature, max_depth):
+        plan = hist_pallas.hist_kernel_plan(None, num_feature, max_depth, 256)
+        return plan["node_blocks"], plan["feature_blocks"]
+
+    assert blocks(2000, 6) == (1, 16)
+    assert blocks(2000, 10) == (16, 16)
+    assert blocks(28, 6) == (1, 1)
+    assert hist_pallas.hist_kernel_plan(None, 2000, 6, 256)["mesh"] is None
     # bins in the tens of thousands: 8 node slots x 128 features overflow
     assert hist_pallas.hist_block_plan(8, 2000, 2 ** 15) is None
-    assert hist_pallas.hist_kernel_plan("pallas", None, 2000, 8,
-                                        2 ** 15) == ("onehot", None)
+    with pytest.raises(ValueError, match="no accumulator block fits"):
+        hist_pallas.hist_kernel_plan(None, 2000, 4, 2 ** 15)
     wide = GBDT(GBDTParam(max_depth=6, num_bins=256, hist_method="pallas"),
                 num_feature=2000)
     assert wide._method() == "pallas"

@@ -178,43 +178,6 @@ def test_gbdt_model_axis_sharding():
     assert np.isfinite(np.asarray(m)).all()
 
 
-def test_grad_histogram_onehot_matches_scatter():
-    """The MXU one-hot matmul formulation agrees with the exact scatter one
-    (bf16 one-hot with f32 accumulation -> loose-ish tolerance)."""
-    rng = np.random.RandomState(7)
-    B, F, nb, nn = 4096, 5, 16, 4
-    bins = jnp.asarray(rng.randint(0, nb, (B, F)).astype(np.int32))
-    nodes = jnp.asarray(rng.randint(0, nn, B).astype(np.int32))
-    g = jnp.asarray(rng.randn(B).astype(np.float32))
-    h = jnp.asarray(rng.rand(B).astype(np.float32))
-    G0, H0 = grad_histogram(bins, nodes, g, h, nn, nb, method="scatter")
-    G1, H1 = grad_histogram(bins, nodes, g, h, nn, nb, method="onehot")
-    scale = float(jnp.abs(G0).max())
-    np.testing.assert_allclose(np.asarray(G1), np.asarray(G0),
-                               atol=0.02 * scale)
-    np.testing.assert_allclose(np.asarray(H1), np.asarray(H0),
-                               atol=0.02 * float(jnp.abs(H0).max()))
-
-
-def test_gbdt_onehot_method_learns():
-    """Full fit with the TPU (one-hot matmul) hist path, run on CPU."""
-    rng = np.random.RandomState(11)
-    x = rng.randn(3000, 6).astype(np.float32)
-    y = ((x[:, 0] * x[:, 1] > 0)).astype(np.float32)  # xor-ish: needs depth
-    param = GBDTParam(num_boost_round=8, max_depth=4, num_bins=32,
-                      learning_rate=0.5, hist_method="onehot")
-    model = GBDT(param, num_feature=6)
-    model.make_bins(x)
-    bins = model.bin_features(x)
-    ensemble, margin = model.fit_binned(bins, y)
-    acc = float((((np.asarray(margin) > 0) == y)).mean())
-    assert acc > 0.9, acc
-    # prediction path agrees with training margin
-    pred_margin = np.asarray(model.predict_margin(ensemble, bins))
-    np.testing.assert_allclose(pred_margin, np.asarray(margin),
-                               rtol=1e-3, atol=1e-3)
-
-
 def test_gbdt_softmax_data_parallel_agrees_with_single():
     """Multiclass training under a dp mesh agrees with single-device (GSPMD
     turns the per-class hists into per-shard partials + allreduce)."""

@@ -7,6 +7,7 @@ tests/test_serve_chaos.py under the ``chaos`` marker.
 
 import json
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -56,6 +57,19 @@ def get(url, path, timeout=10.0):
         raw = resp.read()
         return resp.status, (json.loads(raw) if "json" in ctype
                              else raw.decode())
+
+
+def get_until(url, path, found, seconds=5.0):
+    """``get`` again until ``found(body)``, for a few seconds: a handler
+    counts its request after the response is on the wire, so a read on a
+    new connection right after a POST returned may come before the count.
+    Returns the last read; the caller's asserts say what was missing."""
+    deadline = time.monotonic() + seconds
+    while True:
+        status, body = get(url, path)
+        if found(body) or time.monotonic() >= deadline:
+            return status, body
+        time.sleep(0.01)
 
 
 # -- bucket ladder ------------------------------------------------------------
@@ -374,16 +388,18 @@ def test_http_healthz_and_stats(linear_server):
     try:
         status, _ = post(linear_server.url, {"instances": [[0, 0, 0, 0]]})
         assert status == 200
-        status, stats = get(linear_server.url, "/stats")
-        assert status == 200
-        assert stats["model"] == "linear"
-        series = stats["metrics"]
         # series names render exactly as the offline report's table keys
         # (every request-path metric carries the model-slot label)
         key = 'dmlc_serve_requests_total{model="linear",status="200"}'
+        timed = 'dmlc_serve_request_seconds{model="linear",status="200"}'
+        # the handler observes the time after it counts the request
+        status, stats = get_until(linear_server.url, "/stats",
+                                  lambda s: timed in s["metrics"])
+        assert status == 200
+        assert stats["model"] == "linear"
+        series = stats["metrics"]
         assert series[key] >= 1
-        hist = series['dmlc_serve_request_seconds'
-                      '{model="linear",status="200"}']
+        hist = series[timed]
         assert hist["count"] >= 1 and hist["p50"] is not None
         assert hist["p50"] <= hist["p99"]
         # the per-slot identity block rides /stats too
@@ -401,7 +417,8 @@ def test_http_metrics_prometheus_form(linear_server):
     try:
         status, _ = post(linear_server.url, {"instances": [[1, 1, 1, 1]]})
         assert status == 200
-        status, text = get(linear_server.url, "/metrics")
+        status, text = get_until(linear_server.url, "/metrics",
+                                 lambda t: "dmlc_serve_requests_total" in t)
         assert status == 200
         assert "dmlc_serve_requests_total" in text
         assert "# TYPE" in text
