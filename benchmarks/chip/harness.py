@@ -224,7 +224,7 @@ def run_cell(ctx: Context, manifest: dict, t_start: float) -> dict:
     dropped = telemetry.get_tracer().dropped
     evidence = {"trace": trace, "window": window, "state": state,
                 "cell": ctx.cell, "config": ctx.config,
-                "device_kind": device["kind"],
+                "device_kind": device["kind"], "say": ctx.say,
                 # a truncated span buffer gives no span-derived metric
                 "spans": None if dropped else telemetry.get_tracer().events(),
                 "counters": telemetry.snapshot()["metrics"]}

@@ -5,7 +5,8 @@ evidence it can read) and ``reduce(evidence)``, which returns the value or
 ``tracereduce.Trace``), ``spans`` (the telemetry span buffer, ``None`` if
 it overflowed), ``counters`` (``telemetry.snapshot()["metrics"]``),
 ``window`` (what the traffic kind's window returned), ``state``, ``cell``,
-``config`` and ``device_kind``."""
+``config``, ``device_kind`` and ``say`` (the run's log: a reader with
+several ways to find nothing says there which one it took)."""
 
 
 def counter_total(counters, name):

@@ -17,8 +17,11 @@ KINDS = ("fit",)
 
 
 def reduce(evidence):
+    say = evidence.get("say") or (lambda msg: None)
     path = scopes.find_xplane(evidence)
     if path is None or not evidence["spans"]:
+        say(f"{NAME}: " + ("no trace file" if path is None
+                           else "no span in the buffer"))
         return None
     names = {e["name"] for e in evidence["spans"]}
     chip = evidence["trace"].worst
@@ -26,7 +29,12 @@ def reduce(evidence):
         path, names | {scopes.LAUNCH, scopes.DONE})
     leads = scopes.host_clock_lead(chip, events)
     if leads is None:
+        say(f"{NAME}: the runtime's launch and done events do not bound "
+            f"the host clock's lead")
         return None
     spans = [a for a in events if a[0] in names]
     shares = [scopes.idle_attributed(chip, spans, lead) for lead in leads]
-    return None if None in shares else 100.0 * min(shares)
+    if None in shares:
+        say(f"{NAME}: the chip never idled between its first and last op")
+        return None
+    return 100.0 * min(shares)

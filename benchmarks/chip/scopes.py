@@ -227,6 +227,17 @@ def idle_intervals(chip: tracereduce.ChipTrace) -> List[Tuple[float, float]]:
     return out
 
 
+def _nearest(times, marks) -> Dict[int, List[float]]:
+    """``{index of a mark: the times that lie nearest to it}``, of those
+    within ``NEAR_S`` of it."""
+    out: Dict[int, List[float]] = {}
+    for t in times:
+        i = min(range(len(marks)), key=lambda j: abs(marks[j] - t))
+        if abs(marks[i] - t) < NEAR_S:
+            out.setdefault(i, []).append(t)
+    return out
+
+
 def host_clock_lead(chip: tracereduce.ChipTrace, host_events
                     ) -> Optional[Tuple[float, float]]:
     """``(least, most)`` seconds by which the host plane's clock runs ahead
@@ -240,16 +251,25 @@ def host_clock_lead(chip: tracereduce.ChipTrace, host_events
     (``DoEnqueueProgram``) nor end after the runtime saw it done
     (``tpu::System::Execute=>Done``): each program of the chip's ``XLA
     Modules`` line bounds the lead from one side.  With several chips the
-    host's events do not say whose they are, so of the events within 10 ms
-    of a program's start the earliest launch is taken, and of those round
-    its end the latest done: looser, never wrong.  ``None`` where either
-    side has no event or the bounds cross."""
+    host's events do not say whose they are, so of the launches within 10
+    ms of a program's start the earliest is taken, and of the dones round
+    its end the latest: looser, never wrong.  An event counts only for the
+    program whose start (end) it lies NEAREST to: the trace's edges cut
+    the first and the last program, whose recorded start (end) is then the
+    trace's and whose own launch (done) the trace does not hold, and the
+    next program's launch, 9 ms after the trace began 5 ms before a fit's
+    end, is not the cut one's (my chip run, PR 30: what kept the metric
+    out of every traced run of ``epsilon400k.fit``).  ``None`` where
+    either side has no event or the bounds cross."""
+    if not chip.modules:
+        return None
+    starts = [start for _, start, _ in chip.modules]
+    ends = [end for _, _, end in chip.modules]
     launches = [s for n, s, _ in host_events if n == LAUNCH]
     dones = [s for n, s, _ in host_events if n == DONE]
-    least = [min(q) - start for _, start, _ in chip.modules
-             if (q := [t for t in launches if abs(t - start) < NEAR_S])]
-    most = [max(d) - end for _, _, end in chip.modules
-            if (d := [t for t in dones if abs(t - end) < NEAR_S])]
+    least = [min(q) - starts[i]
+             for i, q in _nearest(launches, starts).items()]
+    most = [max(d) - ends[i] for i, d in _nearest(dones, ends).items()]
     if not least or not most or max(least) > min(most):
         return None
     return max(least), min(most)

@@ -241,11 +241,15 @@ def test_readers_on_a_recorded_trace_of_the_scoped_program():
     assert len(found) == 2
     events = scopes.host_annotations(
         SCOPED, {"gbdt.fit.dispatch", scopes.LAUNCH, scopes.DONE})
+    # three programs a fit here (two tiny ones 0.06 and 0.9 ms before
+    # jit_fit): the first's launch lies nearest to jit_fit's start and
+    # bounds the lead from there (0.342 ms, where its own start would give
+    # 1.261: looser, never wrong), a tiny program's own done gives 1.559
     assert scopes.host_clock_lead(chip, events) == (
-        pytest.approx(1.2613e-3, rel=1e-3), pytest.approx(1.8021e-3, rel=1e-3))
+        pytest.approx(0.3420e-3, rel=1e-3), pytest.approx(1.5591e-3, rel=1e-3))
     assert scopes.idle_attributed(chip, found) < 0.5
     assert idle_attributed_share.reduce(evidence) == pytest.approx(
-        58.161, rel=1e-3)
+        55.741, rel=1e-3)
 
 
 def test_report_names_the_unscoped_groups():
@@ -392,6 +396,66 @@ def test_host_clock_lead_from_launch_and_done():
     assert lead((scopes.LAUNCH, 2.0), (scopes.DONE, 4.0)) is None  # not near
     # bounds that cross say the events are not this chip's programs'
     assert lead((scopes.LAUNCH, 1.004), (scopes.DONE, 3.001)) is None
+
+
+# modules and host events of an 8 s trace of ``epsilon400k.fit`` (my chip
+# run, PR 30, seed 3000000041): fits of 1.1357 s, and the trace began 5.3 ms
+# before one ended, so the next fit's launch is stamped 9 ms after the cut
+# program's recorded start
+EPSILON_PROGRAMS = [(0.129912, 0.135240), (0.137570, 1.273260),
+                    (1.275199, 2.410891), (2.412899, 3.548565),
+                    (3.550507, 4.686197), (4.688076, 5.823737),
+                    (5.825994, 6.961683), (6.963790, 7.956313)]
+EPSILON_HOST = [(0.137329, 0.137503, 0.138677, 0.138865),
+                (1.275247, 1.275448, 1.276264, 1.276491),
+                (2.412872, 2.413055, 2.413920, 2.414155),
+                (3.550613, 3.550777, 3.551604, 3.551806),
+                (4.688252, 4.688422, 4.689191, 4.689371),
+                (5.825669, 5.825891, 5.826963, 5.827276),
+                (6.963701, 6.963912, 6.964866, 6.965081)]
+
+
+@pytest.mark.parametrize("cut_end_s", [None, 6.969],
+                         ids=["cut_at_the_start", "cut_at_both_edges"])
+def test_programs_cut_by_the_trace_edges_bound_nothing(monkeypatch,
+                                                       cut_end_s):
+    """Before PR 30 every launch within 10 ms of a program's start counted
+    for it: the cut first program read a lead of 8.95 ms, the bounds
+    crossed, and the metric was left out.  A trace that ends 5 ms into a
+    fit mirrors it: the previous fit's done lies within 10 ms of the cut
+    program's recorded end."""
+    programs = EPSILON_PROGRAMS if cut_end_s is None else (
+        EPSILON_PROGRAMS[:-1] + [(EPSILON_PROGRAMS[-1][0], cut_end_s)])
+    ops = [tracereduce.parse_op(ROUTE, s * 1e9, (e - s) * 1e9)
+           for s, e in programs]
+    chip = tracereduce.ChipTrace(
+        0, ops, [], [("jit_fit", s, e) for s, e in programs])
+    host = []
+    for done, opened, closed, launch in EPSILON_HOST:
+        host += [(scopes.DONE, done, done + 1e-4),
+                 ("gbdt.fit.dispatch", opened, closed),
+                 (scopes.LAUNCH, launch, launch + 5e-5)]
+    assert scopes.host_clock_lead(chip, host) == (
+        pytest.approx(1.299e-3, abs=1e-6), pytest.approx(1.932e-3, abs=1e-6))
+    monkeypatch.setattr(scopes, "find_xplane", lambda evidence: "a.pb")
+    monkeypatch.setattr(
+        scopes, "host_annotations",
+        lambda path, names: [a for a in host if a[0] in names])
+    said = []
+    evidence = {"trace": tracereduce.Trace([chip]), "say": said.append,
+                "spans": [{"name": "gbdt.fit.dispatch"}]}
+    # the seven gaps between fits, 14.5 ms; the dispatch spans cover 6.5 of
+    # them under either bound of the lead (the traced run itself, with the
+    # microsecond gaps inside a fit in the idle time too: 44.45%)
+    assert idle_attributed_share.reduce(evidence) == pytest.approx(
+        44.786, abs=0.01)
+    assert not said
+    # a reader with nothing to read says which of its exits it took
+    host[:] = [a for a in host if a[0] != scopes.DONE]
+    assert idle_attributed_share.reduce(evidence) is None
+    assert "do not bound" in said[-1]
+    assert idle_attributed_share.reduce({**evidence, "spans": []}) is None
+    assert "no span" in said[-1]
 
 
 def test_host_clock_lead_of_the_recorded_trace(recorded):

@@ -62,8 +62,9 @@ def test_fit_layer_metrics_on_the_recorded_trace(small_fit_trace):
     rest = fit_nonhist_ms_per_round.reduce(evidence)
     assert rest == pytest.approx((4.12 - 2.67) / 2, rel=0.02)
     share = hist_roofline.reduce(evidence)
-    least_ms = sum(2 * 2 * 2 ** d * 32768 * 28 * 256 / 197e12
-                   for d in range(6)) * 1e3
+    # one child of every pair below the root is built by summation
+    least_ms = sum(2 * 2 * (1 if d == 0 else 2 ** (d - 1))
+                   * 32768 * 28 * 256 / 197e12 for d in range(6)) * 1e3
     assert share == pytest.approx(100 * least_ms / (2.67 / 2), rel=0.01)
     # one chip: no all-reduce in the trace, so nothing to read
     assert allreduce_exposed_ms_per_round.reduce(evidence) is None
@@ -124,6 +125,60 @@ def test_roofline_counts_and_peaks():
     assert (seconds, which) == (1.0, "memory")
     with pytest.raises(KeyError):
         roofline.peaks("TPU v9 imaginary")
+
+
+def test_round_work_builds_one_child_of_every_pair():
+    assert roofline.hist_built_nodes(6) == [1, 1, 2, 4, 8, 16]
+    assert roofline.hist_built_nodes(1) == [1]
+    round_ = roofline.hist_round_work(1000, 28, 256, 6)
+    every = [roofline.hist_level_work(1000, 28, 256, 2 ** d)
+             for d in range(6)]
+    assert sum(f for f, _ in round_) == pytest.approx(
+        sum(f for f, _ in every) * 32 / 63)
+    # the root is one plain product; a level below it also reads its
+    # parents' f32 histograms and writes the siblings taken from them
+    assert round_[0] == roofline.hist_level_work(1000, 28, 256, 1)
+    flops, nbytes = roofline.hist_level_work(1000, 28, 256, 16)
+    assert round_[5] == (flops, nbytes + 2 * (4 * 32 * 28 * 256))
+    assert roofline.hist_round_work(1000, 28, 256, 1) == [round_[0]]
+
+
+def _kernel_trace(chips, call_seconds):
+    """Every chip runs the same kernel calls, 1 ms of other work apart."""
+    ops, t = [], 0.0
+    for n, dur_s in enumerate(call_seconds):
+        ops.append(tracereduce.parse_op(
+            f"%hist_level.{n} = f32[8,128] custom-call(), "
+            f"{tracereduce.MOSAIC_MARK}", t * 1e9, dur_s * 1e9))
+        t += dur_s + 1e-3
+    return tracereduce.Trace([tracereduce.ChipTrace(c, ops, [], [])
+                              for c in range(chips)])
+
+
+@pytest.mark.parametrize("chips,rows,num_feature", [
+    (1, 11_000_000, 28), (4, 4 * 16_777_216, 13)])
+def test_hist_roofline_reads_100_at_the_least_times(chips, rows,
+                                                    num_feature):
+    """Two rounds whose six calls take exactly the per-level least times
+    of the chip's share of the rows (airline's one-node levels by memory,
+    every other by compute): the share is 100, on one chip and on four."""
+    from benchmarks.chip.layer_metrics import hist_roofline
+
+    least = [roofline.least_seconds(f, b, "TPU v5 lite") for f, b in
+             roofline.hist_round_work(rows // chips, num_feature, 256, 6)]
+    assert {w for _, w in least} == (
+        {"compute"} if num_feature == 28 else {"compute", "memory"})
+    calls = [s for s, _ in least] * 2
+    evidence = {"trace": _kernel_trace(chips, calls),
+                "device_kind": "TPU v5 lite",
+                "config": {"max_depth": 6, "num_feature": num_feature,
+                           "num_bins": 256},
+                "state": {"rows": rows}}
+    assert hist_roofline.reduce(evidence) == pytest.approx(100.0)
+    # a kernel that builds every node at the same peaks reads about half
+    slow = _kernel_trace(chips, [s * 63 / 32 for s in calls])
+    assert hist_roofline.reduce({**evidence, "trace": slow}) == \
+        pytest.approx(100 * 32 / 63)
 
 
 def test_reference_histogram_by_hand():
