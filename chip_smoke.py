@@ -383,8 +383,10 @@ def main():
             libsvm_path = data_phase(cfg, workdir)
         trained = train_phase(cfg, libsvm_path, times)
         check_train(cfg, trained, expect_method="pallas")
-        say("hist_level bin splits (HxL, root first): "
-            + trained["model"]._hist_blocks("pallas")["bin_split"])
+        blocks = trained["model"]._hist_blocks("pallas")
+        say("hist_level calls, root first: node slots built "
+            f"{blocks['built_nodes']} (the sibling is parent - built), "
+            f"bin splits HxL {blocks['bin_split']}")
         serve_phase(cfg, trained, workdir, times)
         if info.count > 1:
             mesh_phase(cfg, libsvm_path, trained, info, times)

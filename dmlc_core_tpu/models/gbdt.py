@@ -285,6 +285,16 @@ def _build_tree(hist_bins, bins_fm, g, h, plan: HistPlan, max_depth: int,
     direction is stored per node in ``default_left``.  The histogram
     kernels are untouched: the missing bin is just the last bin.
 
+    Below the root a level's histograms come by sibling subtraction
+    (``HistPlan.level``): the rows of ONE child of every pair are summed,
+    the lighter one by hessian mass at the chosen split as upstream builds
+    the smaller child, and the other child is parent - built.  So the loop
+    carries the level above's ``(G, H)``, and a row carries beside its node
+    id the key the next histogram reads: its parent's id if it sits in the
+    built child, -1 (no node slot) if in the derived one.  A node that does
+    not split sends every row left: its right child is built, exactly zero,
+    and its left is the parent bit for bit.
+
     ``monotone`` ([F] int in {-1, 0, +1}, or None) enforces monotone
     response per feature the XGBoost way: candidate splits whose child
     weights violate the direction are masked, every node carries a
@@ -312,6 +322,8 @@ def _build_tree(hist_bins, bins_fm, g, h, plan: HistPlan, max_depth: int,
     split_gain = jnp.zeros((n_internal,), dtype=jnp.float32)
     split_cover = jnp.zeros((n_internal,), dtype=jnp.float32)
     node = jnp.zeros((B,), dtype=jnp.int32)  # node id within the level
+    key = node                # what the level's histogram reads of a row
+    parent = built_right = None                      # the root has neither
     miss_id = num_bins - 1
     if monotone is not None:
         mono = jnp.asarray(monotone, jnp.int32)          # [F]
@@ -325,7 +337,8 @@ def _build_tree(hist_bins, bins_fm, g, h, plan: HistPlan, max_depth: int,
         level_off = n_nodes - 1
         with jax.named_scope("gbdt.hist"):
             # G, H: [n, F, nbins]
-            G, H = plan.histogram(hist_bins, node, g, h, n_nodes, num_bins)
+            G, H = plan.level(hist_bins, key, g, h, num_bins, parent,
+                              built_right)
         with jax.named_scope("gbdt.split"):
             GL = jnp.cumsum(G, axis=-1)
             HL = jnp.cumsum(H, axis=-1)
@@ -418,25 +431,33 @@ def _build_tree(hist_bins, bins_fm, g, h, plan: HistPlan, max_depth: int,
             default_left = default_left.at[lvl].set(dl)
             split_gain = split_gain.at[lvl].set(
                 jnp.where(do_split, best_gain, 0.0))
+            GTn, HTn = GT[:, 0, 0], HT[:, 0, 0]
             split_cover = split_cover.at[lvl].set(
-                jnp.where(do_split, HT[:, 0, 0], 0.0))
+                jnp.where(do_split, HTn, 0.0))
+
+            def _left_at_best(sums, cums):
+                # the left child's sum at the chosen split, [n]-sized
+                # gathers: the cumsum at ``best``, plus the missing bin's
+                # mass where the node sends missing rows left
+                left = jnp.take_along_axis(
+                    cums.reshape(n_nodes, F * num_bins), best[:, None],
+                    axis=-1)[:, 0]
+                if missing:
+                    left = left + jnp.where(dl, jnp.take_along_axis(
+                        sums[..., miss_id], bf[:, None], axis=-1)[:, 0], 0.0)
+                return left
+
+            # the next level builds the lighter child of every pair and
+            # derives the heavier, whose error so stays at the f32 rounding
+            # of sums of its own size; a node that does not split has an
+            # empty right child
+            HLb = _left_at_best(H, HL)
+            built_right = ~do_split | (HTn - HLb < HLb)
+            parent = (G, H)
             if monotone is not None:
                 # child intervals: the chosen split's child weights set the
                 # midpoint; constrained features split the node interval there
-                def _at_best(a):
-                    return jnp.take_along_axis(
-                        a.reshape(n_nodes, F * num_bins), best[:, None],
-                        axis=-1)[:, 0]
-
-                # gather the chosen split's sums first: wl/wr become
-                # [n]-sized math instead of full [n, F, nbins] passes
-                GLb, HLb = _at_best(GL), _at_best(HL)
-                if missing:
-                    GLb = jnp.where(
-                        dl, _at_best(GL + G[..., miss_id:miss_id + 1]), GLb)
-                    HLb = jnp.where(
-                        dl, _at_best(HL + H[..., miss_id:miss_id + 1]), HLb)
-                GTn, HTn = GT[:, 0, 0], HT[:, 0, 0]
+                GLb = _left_at_best(G, GL)
                 wl = _opt_w(GLb, HLb)
                 wr = _opt_w(GTn - GLb, HTn - HLb)
                 wl = jnp.clip(wl, node_lo, node_hi)
@@ -465,12 +486,17 @@ def _build_tree(hist_bins, bins_fm, g, h, plan: HistPlan, max_depth: int,
             # lanes; take_along_axis was slower still (PERF.md, PR 25).
             nf = _table_pick(sf, node)                       # [B]
             row_bin = _feature_pick(bins_fm, nf)
-            go_right = (row_bin > _table_pick(bb, node)) & (nf >= 0)
+            # one pick for the node's threshold and for which of its
+            # children the next level builds (a pick of its own for the
+            # flag cost 0.29 ms a level at 16.8M rows; PERF.md, PR 31)
+            bin_flag = _table_pick(bb * 2 + built_right, node)
+            go_right = (row_bin > (bin_flag >> 1)) & (nf >= 0)
             if missing:
                 # missing rows sit at bin num_bins-1 > any threshold, so they
                 # already go right; default-left overrides that
                 go_right = go_right & ~((row_bin == miss_id)
                                         & _table_pick(dl, node))
+            key = jnp.where(go_right == ((bin_flag & 1) == 1), node, -1)
             node = node * 2 + go_right.astype(jnp.int32)
 
     with jax.named_scope("gbdt.leaf"):

@@ -17,13 +17,18 @@ rows on the lanes, and splits the bin index between them
     LO[lo, i]         = (lo_i == lo)
     acc_f[lo, (n, hi, c)] += LO . A^T         (contract the tile's rows; f32)
 
-A level of n nodes needs only ``2n * num_bins`` buckets a feature, so ``H``
-and ``L`` are chosen per level to give the product ``2nH * L`` just that
-many, shared out so that neither side of the MXU waits for the other:
-(16, 16) at the root of a 256-bin fit, (2, 128) at 32 nodes, where the dot
+A call that builds n nodes needs only ``2n * num_bins`` buckets a feature,
+so ``H`` and ``L`` are chosen per call to give the product ``2nH * L`` just
+that many, shared out so that neither side of the MXU waits for the other:
+(16, 16) at one node of a 256-bin fit, (2, 128) at 32 nodes, where the dot
 is a full 128 x 128 tile a feature and 128 rows and runs at the MXU's peak;
-the shallower levels cost a quarter to a half of that, not all of it.
-From 128 nodes ``H = 1``: the plain one-hot matmul, transposed.
+fewer nodes cost a quarter to a half of that, not all of it.  From 128
+nodes ``H = 1``: the plain one-hot matmul, transposed.  A fit's level of n
+nodes builds n / 2 of them here, one child of every pair, and takes the
+siblings as parent - built (``histogram.HistPlan.level``): 1, 1, 2, 4, 8, 16
+node slots at depth 6, whose rows are keyed by their parent's id and all
+other rows by -1, which the body drops; the one-shot ``grad_histogram``
+builds every node it is asked for.
 
 - grid = (feature blocks, row tiles), both sequential on TPU, rows inner;
 - the ``[F_blk, L, 2nH]`` f32 accumulator lives in one VMEM output block
@@ -422,10 +427,13 @@ def hist_kernel_plan(model_axis, num_feature: int, max_depth: int,
     - ``row_multiple``: rows a fit pads to once so no kernel call pads
       again, the tile size times the data axis (each data shard must itself
       be a whole number of tiles under shard_map);
-    - what ``gbdt.fit.dispatch`` records: ``node_blocks`` (kernel calls at
-      the deepest level) and ``feature_blocks`` (grid steps over features
-      inside each) of one chip's ``F/mp`` slice, and ``bin_split``, the
-      :func:`hist_split_plan` ``HxL`` of every level's call from the root.
+    - what ``gbdt.fit.dispatch`` records: ``built_nodes``, the node slots
+      each level's call builds from the root (one child of every pair
+      below it, ``histogram.hist_built_nodes``), ``node_blocks`` (kernel
+      calls at the deepest level, for the slots it builds) and
+      ``feature_blocks`` (grid steps over features inside each) of one
+      chip's ``F/mp`` slice, and ``bin_split``, the :func:`hist_split_plan`
+      ``HxL`` of every level's call.
 
     A mesh the kernel cannot be shard_mapped over raises a ``ValueError``
     that names the condition and the remedy — nothing falls back: a
@@ -458,18 +466,21 @@ def hist_kernel_plan(model_axis, num_feature: int, max_depth: int,
             f"hist_method='pallas': {batch} rows do not divide over the "
             f"{dp} shards of the {DATA_AXIS!r} mesh axis; pad rows as "
             f"`fit_binned` does (weight-0 rows, to a multiple of {dp})")
+    from dmlc_core_tpu.ops.histogram import hist_built_nodes
+
     local = num_feature // mp
-    deepest = 2 ** (max_depth - 1)
-    nodes, feats = _require_block_plan(deepest, local, num_bins)
+    built = hist_built_nodes(max_depth)
+    nodes, feats = _require_block_plan(built[-1], local, num_bins)
     splits = (hist_split_plan(
-        min(2 ** depth, hist_block_plan(2 ** depth, local, num_bins)[0]),
-        num_bins) for depth in range(max_depth))
+        min(n, hist_block_plan(n, local, num_bins)[0]), num_bins)
+        for n in built)
     sharded = model_axis is not None or dp > 1
     return {"mesh": mesh if sharded else None,
             "row_multiple": BLOCK_ROWS * dp,
-            "node_blocks": -(-deepest // nodes),
+            "node_blocks": -(-built[-1] // nodes),
             "feature_blocks": -(-local // feats),
-            "bin_split": ",".join(f"{hi}x{lo}" for hi, lo in splits)}
+            "bin_split": ",".join(f"{hi}x{lo}" for hi, lo in splits),
+            "built_nodes": ",".join(map(str, built))}
 
 
 def grad_hist_pallas_sharded(bins, node_ids, grad, hess, num_nodes: int,

@@ -23,6 +23,11 @@ Design notes (TPU-first):
   made once per fit: ``[F, rows]`` int32 for the kernel, ``[rows, F]`` int32
   for ``scatter``), the per-level histogram and the leaf sums.  A caller
   (``models/gbdt.py``) hands the histogram's copy back as an opaque operand;
+- a level of n nodes below the root builds n / 2 of them by summation, one
+  child of every pair, and takes the siblings as parent - built
+  (:meth:`HistPlan.level`, both methods): 32 node-products a depth-6 round
+  (:func:`hist_built_nodes`).  :func:`grad_histogram`, the one-shot entry,
+  builds every node it is asked for;
 - everything is static-shape: ``num_bins``, ``num_features``, and the level's
   node count are compile-time constants, so XLA tiles the matmul/scatter
   efficiently and the whole boosting round stays inside one jit.
@@ -43,7 +48,8 @@ import numpy as np
 from dmlc_core_tpu.utils.logging import CHECK
 
 __all__ = ["quantile_boundaries", "apply_bins", "grad_histogram",
-           "HistPlan", "hist_plan", "resolve_hist_method",
+           "HistPlan", "hist_plan", "hist_built_nodes",
+           "resolve_hist_method",
            "local_quantile_summary",
            "merged_quantile_boundaries", "distributed_quantile_boundaries"]
 
@@ -75,6 +81,13 @@ def resolve_hist_method(method: str, *arrays) -> str:
     return "pallas" if platform == "tpu" else "scatter"
 
 
+def hist_built_nodes(max_depth: int):
+    """Node slots each level of a fit builds by summation, root first: the
+    root, then ONE child of every pair (:meth:`HistPlan.level`) — 1, 1, 2,
+    4, 8, 16 at depth 6."""
+    return [max(1, 2 ** depth // 2) for depth in range(max_depth)]
+
+
 class HistPlan(NamedTuple):
     """What one fit settled about its histograms before tracing
     (:func:`hist_plan`).  Immutable and hashable: static under ``jit`` and
@@ -91,13 +104,16 @@ class HistPlan(NamedTuple):
     node_blocks: int = 0
     feature_blocks: int = 0
     bin_split: str = ""
+    # :func:`hist_built_nodes` of the fit, whatever the method
+    built_nodes: str = ""
 
     def blocks(self) -> dict:
-        """The kernel's shape, as the ``gbdt.fit.dispatch`` span records it
-        beside the method."""
+        """The kernel's shape and the node slots each level builds, as the
+        ``gbdt.fit.dispatch`` span records them beside the method."""
         return {"node_blocks": self.node_blocks,
                 "feature_blocks": self.feature_blocks,
-                "bin_split": self.bin_split}
+                "bin_split": self.bin_split,
+                "built_nodes": self.built_nodes}
 
     def layouts(self, bins, pad: int = 0):
         """The two device layouts a fit keeps of one ``[rows, F]`` binned
@@ -130,9 +146,11 @@ class HistPlan(NamedTuple):
 
     def histogram(self, hist_bins, node_ids, grad, hess, num_nodes: int,
                   num_bins: int):
-        """One level's ``(G, H)``, each ``[num_nodes, F, num_bins]`` f32,
-        from the histogram's copy of the bins (:meth:`layouts`); otherwise
-        the contract of :func:`grad_histogram`."""
+        """``(G, H)`` of ``num_nodes`` node slots built by summation, each
+        ``[num_nodes, F, num_bins]`` f32, from the histogram's copy of the
+        bins (:meth:`layouts`); otherwise the contract of
+        :func:`grad_histogram`.  A row whose id lies outside
+        ``[0, num_nodes)`` adds nothing, under either method."""
         import jax
         import jax.numpy as jnp
 
@@ -152,21 +170,68 @@ class HistPlan(NamedTuple):
             ids = (node_ids[:, None] * (F * num_bins)
                    + jnp.arange(F, dtype=jnp.int32)[None, :] * num_bins
                    + hist_bins)                               # [B, F]
-            flat_ids = ids.reshape(-1)
             nseg = num_nodes * F * num_bins
+            # a row of no node slot goes past the last segment, which drops
+            # it (a negative id would wrap)
+            inside = (node_ids >= 0) & (node_ids < num_nodes)
+            flat_ids = jnp.where(inside[:, None], ids, nseg).reshape(-1)
             g_flat = jnp.broadcast_to(grad[:, None], (B, F)).reshape(-1)
             h_flat = jnp.broadcast_to(hess[:, None], (B, F)).reshape(-1)
             G = jax.ops.segment_sum(g_flat, flat_ids, num_segments=nseg)
             H = jax.ops.segment_sum(h_flat, flat_ids, num_segments=nseg)
             G = G.reshape(num_nodes, F, num_bins)
             H = H.reshape(num_nodes, F, num_bins)
-        if self.model_axis is not None:
-            from jax.sharding import PartitionSpec as P
+        return self._constrain(G), self._constrain(H)
 
-            constraint = P(None, self.model_axis, None)
-            G = jax.lax.with_sharding_constraint(G, constraint)
-            H = jax.lax.with_sharding_constraint(H, constraint)
-        return G, H
+    def _constrain(self, hist):
+        """``hist [n, F, bins]`` with its feature dim split over the model
+        axis, where the plan has one."""
+        if self.model_axis is None:
+            return hist
+        import jax
+        from jax.sharding import PartitionSpec as P
+
+        return jax.lax.with_sharding_constraint(
+            hist, P(None, self.model_axis, None))
+
+    def level(self, hist_bins, keys, grad, hess, num_bins: int,
+              parent=None, built_right=None):
+        """One level's ``(G, H)``, each ``[n, F, num_bins]`` f32 in node
+        order, by sibling subtraction: ONE child of every pair is built by
+        summation (:meth:`histogram`, ``n / 2`` node slots) and the other
+        is ``parent - built`` — what the ``hist`` algorithm is, upstream
+        and here, under both methods.
+
+        ``parent`` is the ``(G, H)`` of the level above, each
+        ``[n / 2, F, num_bins]``, and ``built_right [n / 2]`` says which
+        child of each pair the rows were keyed for: ``keys [B]`` holds the
+        PARENT's id of a row that sits in that child and any id outside
+        ``[0, n / 2)`` (-1) of a row that sits in its sibling.  The root
+        (``parent=None``) has no sibling: ``keys`` are its rows' node ids,
+        all 0.
+
+        The subtraction and the interleave are elementwise on the built
+        half's transposed result (on a v5e they add a quarter to the
+        transposition's time at 2,000 features, PERF.md, PR 31); under
+        ``shard_map`` the ``psum`` has carried the built half only.  A
+        derived child sums the same terms as a built one in another order
+        of f32 additions; a pair whose built child has no rows keeps its
+        parent bit for bit."""
+        import jax.numpy as jnp
+
+        if parent is None:
+            return self.histogram(hist_bins, keys, grad, hess, 1, num_bins)
+        half = parent[0].shape[0]
+        built = self.histogram(hist_bins, keys, grad, hess, half, num_bins)
+        right = built_right[:, None, None]
+
+        def pair(above, summed):
+            other = above - summed
+            both = jnp.stack([jnp.where(right, other, summed),
+                              jnp.where(right, summed, other)], axis=1)
+            return self._constrain(both.reshape(2 * half, *summed.shape[1:]))
+
+        return pair(parent[0], built[0]), pair(parent[1], built[1])
 
     def leaf_sums(self, node, g, h, num_leaves: int):
         """``(sum g, sum h)`` of every leaf, each ``[num_leaves]`` f32, for
@@ -198,7 +263,8 @@ def hist_plan(method: str, model_axis: Optional[str], num_feature: int,
     later."""
     method = resolve_hist_method(method, *arrays)
     if method != "pallas":
-        return HistPlan(method, model_axis)
+        return HistPlan(method, model_axis, built_nodes=",".join(
+            map(str, hist_built_nodes(max_depth))))
     from dmlc_core_tpu.ops.hist_pallas import hist_kernel_plan
 
     return HistPlan(method, model_axis, **hist_kernel_plan(

@@ -103,6 +103,45 @@ def test_every_split_of_the_bin_index_on_chip(jx, NN):
                                rtol=2e-2, atol=6e-2)
 
 
+def test_the_six_built_half_calls_of_a_depth_6_fit_on_chip(jx):
+    """A level loop as ``_build_tree`` runs it at 256 bins: the root, then
+    five levels that build the lighter child of every pair (1, 1, 2, 4, 8,
+    16 node slots; the plan's (H, L) of each) and take the sibling as
+    parent - built.  Every level is the exact histogram of all its nodes."""
+    from dmlc_core_tpu.ops.histogram import hist_plan
+
+    NB, F, rows, depth = 256, 5, 5000, 6
+    rng = np.random.RandomState(31)
+    bins = rng.randint(0, NB, (rows, F)).astype(np.int32)
+    grad = rng.randn(rows).astype(np.float32)
+    hess = np.abs(rng.randn(rows)).astype(np.float32)
+    plan = hist_plan("pallas", None, F, depth, NB, rows=rows)
+    assert plan.built_nodes == "1,1,2,4,8,16"
+    assert plan.bin_split == "16x16,16x16,8x32,8x32,4x64,4x64"
+    hist_bins, _ = plan.layouts(bins.astype(np.uint8))
+    node = np.zeros(rows, np.int32)
+    keys, parent, built_right = node, None, None
+    for d in range(depth):
+        n = 2 ** d
+        g, h = plan.level(hist_bins, jx.numpy.asarray(keys), grad, hess, NB,
+                          parent, built_right)
+        g_ref, h_ref = _scatter_ref(jx, bins, node, grad, hess, n, NB)
+        assert g.shape == (n, F, NB)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
+                                   rtol=2e-2, atol=6e-2)
+        np.testing.assert_allclose(np.asarray(h), np.asarray(h_ref),
+                                   rtol=2e-2, atol=6e-2)
+        # route: an uneven coin a node, one node of each level left whole
+        go_right = rng.rand(rows) < rng.rand(n)[node]
+        go_right &= node != n - 1
+        mass = np.bincount(2 * node + go_right, weights=hess,
+                           minlength=2 * n)
+        flags = mass[1::2] < mass[0::2]
+        keys = np.where(go_right == flags[node], node, -1).astype(np.int32)
+        node = (2 * node + go_right).astype(np.int32)
+        parent, built_right = (g, h), jx.numpy.asarray(flags)
+
+
 def test_tiny_gbdt_fit_on_chip(jx):
     """End-to-end: a small GBDT fit through resolve_hist_method('auto') on
     the chip learns a separable problem (the bench.py path in miniature)."""

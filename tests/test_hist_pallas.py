@@ -73,10 +73,15 @@ def test_split_plan_at_256_bins():
     for n, (hi, lo) in plan.items():
         # the two sides of the dot balanced: 2nH within a factor two of 2L
         assert n >= 128 or lo <= 2 * n * hi <= 4 * lo
-    # one kernel call's nodes at every level of a fit, root first: a level
+    # one kernel call's nodes at every level of a fit, root first: the root,
+    # then one child of every pair (the sibling is parent - built); a level
     # cut into node blocks runs a block's plan
-    assert hist_pallas.hist_kernel_plan(None, 28, 6, 256)["bin_split"] \
-        == ",".join("%dx%d" % PLAN_256[2 ** d] for d in range(6))
+    fit = hist_pallas.hist_kernel_plan(None, 28, 6, 256)
+    assert fit["built_nodes"] == "1,1,2,4,8,16"
+    assert fit["bin_split"] == "16x16,16x16,8x32,8x32,4x64,4x64" \
+        == ",".join("%dx%d" % PLAN_256[n] for n in (1, 1, 2, 4, 8, 16))
+    assert hist_pallas.hist_kernel_plan(None, 28, 1, 256)[
+        "built_nodes"] == "1"
     assert hist_pallas.hist_block_plan(512, 28, 256) == (128, 28)
     assert hist_pallas.hist_kernel_plan(None, 28, 10, 256)[
         "bin_split"].endswith("1x256,1x256")
@@ -699,7 +704,8 @@ def test_wide_tables_plan_feature_blocks():
         return plan["node_blocks"], plan["feature_blocks"]
 
     assert blocks(2000, 6) == (1, 16)
-    assert blocks(2000, 10) == (16, 16)
+    # the deepest level builds 256 of its 512 nodes, 32 a call
+    assert blocks(2000, 10) == (8, 16)
     assert blocks(28, 6) == (1, 1)
     assert hist_pallas.hist_kernel_plan(None, 2000, 6, 256)["mesh"] is None
     # bins in the tens of thousands: 8 node slots x 128 features overflow
@@ -711,10 +717,12 @@ def test_wide_tables_plan_feature_blocks():
     assert wide._method() == "pallas"
     assert wide._hist_blocks("pallas") == {
         "node_blocks": 1, "feature_blocks": 16,
-        "bin_split": "16x16,8x32,8x32,4x64,4x64,2x128"}
+        "bin_split": "16x16,16x16,8x32,8x32,4x64,4x64",
+        "built_nodes": "1,1,2,4,8,16"}
     assert wide._hist_blocks("scatter") == {"node_blocks": 0,
                                             "feature_blocks": 0,
-                                            "bin_split": ""}
+                                            "bin_split": "",
+                                            "built_nodes": "1,1,2,4,8,16"}
     with _mesh_2d():
         sharded = GBDT(GBDTParam(max_depth=6, num_bins=256,
                                  hist_method="pallas"), num_feature=2000,
@@ -756,7 +764,8 @@ def test_gbdt_wide_fit_matches_scatter_and_the_plain_reference(
     assert kernel._fit_method(bins) == "pallas"
     assert kernel._hist_blocks("pallas") == {"node_blocks": 1,
                                              "feature_blocks": 3,
-                                             "bin_split": "1x16,1x16,1x16"}
+                                             "bin_split": "1x16,1x16,1x16",
+                                             "built_nodes": "1,1,2"}
     ens_p, margin_p = kernel.fit_binned(bins, y)
     ens_s, margin_s = model("scatter").fit_binned(bins, y)
     np.testing.assert_array_equal(np.asarray(ens_p.split_feat),
