@@ -5,7 +5,12 @@ A configuration's ``data`` block describes its columns (``cardinality``:
 ``[0, k)``) and its teacher (a seeded linear model on the standardised
 columns plus Gaussian noise, as ``bench.make_higgs_like`` and
 ``chip_smoke.data_phase`` draw it; those two are listed in PERF.md for
-deletion).  Two generators read that block:
+deletion).  With ``missing_share`` (``[F]`` floats) entry ``(row, f)`` is
+absent (NaN) with that column's probability, independently, and an absent
+entry adds ``missing_effect x a[f]`` to the teacher's margin where a
+present one adds ``z x w[f]`` (:func:`absent_teacher`): absence carries
+signal, so the default direction a split learns matters to the loss.
+Two generators read that block:
 
 - :func:`device_binned` makes the rows ON THE DEVICE in one jitted call
   (``jax.random``), bins them with the model's own boundaries and returns
@@ -14,7 +19,8 @@ deletion).  Two generators read that block:
   independent seeded chunks, for the text file the ingest cell parses and
   the request bodies the score cell sends.  The writer assembles the
   file's bytes as numpy arrays (fixed-width ``%.4f`` tokens), never row by
-  row in Python.
+  row in Python.  They hold no absent entries: a configuration with
+  ``missing_share`` is refused there.
 
 This module imports numpy only; JAX is imported inside the device
 functions, so the load generator's child process can use the host half.
@@ -50,11 +56,51 @@ def teacher(config, seed):
     return rng.standard_normal(config["num_feature"]).astype(np.float32)
 
 
+def missing(config):
+    """``(share[F] float32, effect)`` of a configuration whose ``data``
+    block has ``missing_share``, else ``None``."""
+    data = config["data"]
+    if "missing_share" not in data:
+        return None
+    share = np.asarray(data["missing_share"], np.float32)
+    if share.shape != (config["num_feature"],):
+        raise ValueError(f"data.missing_share has {share.shape[0]} columns, "
+                         f"num_feature is {config['num_feature']}")
+    if not ((share >= 0) & (share < 1)).all():
+        raise ValueError("data.missing_share lies in [0, 1)")
+    return share, float(data.get("missing_effect", 0.0))
+
+
+def reserved_bin(config):
+    """The bin id the configuration's model keeps for absent entries
+    (``model.handle_missing``: the last of ``num_bins``), or ``None``."""
+    if (config.get("model") or {}).get("handle_missing"):
+        return config["num_bins"] - 1
+    return None
+
+
+def absent_teacher(config, seed):
+    """``(add[F] float32, intercept)``: what an absent entry of column
+    ``f`` adds to the teacher's margin (``missing_effect x a[f]``, ``a``
+    seeded like ``w``), and the constant that takes the mean of those
+    additions out again, so that the labels stay balanced whatever the
+    seed."""
+    share, effect = missing(config)
+    rng = np.random.default_rng([int(seed), 0xAB5E])
+    a = (effect * rng.standard_normal(config["num_feature"])).astype(
+        np.float32)
+    return a, np.float32(-(share.astype(np.float64) * a).sum())
+
+
 def _chunk_rng(seed, chunk):
     return np.random.default_rng([int(seed), 0xDA7A, int(chunk)])
 
 
 def _host_chunk(config, seed, chunk, n):
+    if missing(config) is not None:
+        raise NotImplementedError(
+            "the host generators write dense rows: a configuration with "
+            "data.missing_share has no ingest or score cell yet")
     card, mean, std = columns(config)
     rng = _chunk_rng(seed, chunk)
     x = rng.standard_normal((n, card.shape[0]), dtype=np.float32)
@@ -168,17 +214,37 @@ def _draw_xt(key, n, card):
     return x
 
 
+def _draw_absent(key, n, share):
+    """``[F, n]`` bool: entry absent, column ``f`` with probability
+    ``share[f]``, every entry on its own."""
+    import jax
+    import jax.numpy as jnp
+
+    u = jax.random.uniform(jax.random.fold_in(key, 3), (share.shape[0], n),
+                           jnp.float32)
+    return u < share[:, None]
+
+
 def device_sample(config, seed, n):
     """A host float32 ``[n, F]`` sample of the device distribution, for
-    ``GBDT.make_bins`` (drawn on the device, one transfer back)."""
+    ``GBDT.make_bins`` (drawn on the device, one transfer back); absent
+    entries are NaN, which ``make_bins`` leaves out of the ranks."""
     import jax
+    import jax.numpy as jnp
 
     card, _, _ = columns(config)
-    return np.asarray(jax.jit(lambda k: _draw_xt(k, n, card).T)(
-        _device_key(seed, 1)))
+    absent = missing(config)
+
+    def draw(k):
+        xt = _draw_xt(k, n, card)
+        if absent is not None:
+            xt = jnp.where(_draw_absent(k, n, absent[0]), jnp.nan, xt)
+        return xt.T
+
+    return np.asarray(jax.jit(draw)(_device_key(seed, 1)))
 
 
-def bin_on_device(xt, boundaries):
+def bin_on_device(xt, boundaries, missing_bin=None):
     """``searchsorted(boundaries[f], x[:, f], side="right")`` for feature-
     major ``xt[F, n]``, as a count of the boundaries at or below each
     value: the ids ``ops.histogram.apply_bins`` gives, without its
@@ -186,7 +252,9 @@ def bin_on_device(xt, boundaries):
     for 11M x 28 on a v5e (my chip run, PR 22; the two not separated).
     The boundaries are walked in blocks of ``_EDGE_BLOCK``: one fused
     elementwise pass over ``xt`` per block, so nothing of shape ``[bins, F,
-    n]`` is ever laid out.  Returns ``[F, n]`` int32."""
+    n]`` is ever laid out.  With ``missing_bin`` a NaN takes that id, as
+    ``apply_bins(..., missing_bin=)`` gives it (no boundary is ``<=`` NaN,
+    so without it a NaN would count 0).  Returns ``[F, n]`` int32."""
     import jax
     import jax.numpy as jnp
 
@@ -201,30 +269,45 @@ def bin_on_device(xt, boundaries):
             count = count + (xt >= block[j][:, None]).astype(jnp.int32)
         return count
 
-    return jax.lax.fori_loop(0, blocks.shape[0], add_block,
-                             jnp.zeros(xt.shape, jnp.int32))
+    ids = jax.lax.fori_loop(0, blocks.shape[0], add_block,
+                            jnp.zeros(xt.shape, jnp.int32))
+    if missing_bin is not None:
+        ids = jnp.where(jnp.isnan(xt), jnp.int32(missing_bin), ids)
+    return ids
 
 
 def device_binned(config, seed, n, boundaries, wire_dtype, sharding=None):
     """``(bins[n, F] wire dtype, label[n] f32, weight[n] f32)`` generated,
     binned with the model's ``boundaries`` and cast on the device in ONE
     jitted call; with ``sharding`` (dim 0 over the mesh's data axis) every
-    chip makes only its own rows.  Whatever depends on the seed (the key,
-    the teacher, the boundaries) is an ARGUMENT of the program, never a
-    constant inside it: every seed then runs the same cached program."""
+    chip makes only its own rows.  Absent entries take the id the
+    configuration's model reserves (:func:`reserved_bin`).  Whatever
+    depends on the seed (the key, the teachers, the boundaries) is an
+    ARGUMENT of the program, never a constant inside it: every seed then
+    runs the same cached program."""
     import jax
     import jax.numpy as jnp
 
     card, mean, std = columns(config)
     noise = float(config["data"]["label_noise"])
+    absent, missing_bin = missing(config), reserved_bin(config)
+    if absent is not None and missing_bin is None:
+        raise ValueError("data.missing_share makes NaN entries: the "
+                         "configuration's model block needs handle_missing")
 
-    def make(key, w, edges):
+    def make(key, w, edges, *absent_args):
         kx, ke = jax.random.split(key)
         xt = _draw_xt(kx, n, card)
-        z = (xt - mean[:, None]) / std[:, None]
-        margin = jnp.sum(z * w[:, None], axis=0) + noise * jax.random.normal(
-            ke, (n,), jnp.float32)
-        bins = bin_on_device(xt, edges).astype(wire_dtype).T
+        terms = (xt - mean[:, None]) / std[:, None] * w[:, None]
+        shift = noise * jax.random.normal(ke, (n,), jnp.float32)
+        if absent is not None:
+            add, intercept = absent_args
+            gone = _draw_absent(kx, n, absent[0])
+            terms = jnp.where(gone, add[:, None], terms)
+            xt = jnp.where(gone, jnp.nan, xt)
+            shift = shift + intercept
+        margin = jnp.sum(terms, axis=0) + shift
+        bins = bin_on_device(xt, edges, missing_bin).astype(wire_dtype).T
         return (bins, (margin > 0).astype(jnp.float32),
                 jnp.ones((n,), jnp.float32))
 
@@ -234,6 +317,7 @@ def device_binned(config, seed, n, boundaries, wire_dtype, sharding=None):
 
         rows2d = NamedSharding(sharding.mesh, P(*sharding.spec, None))
         out_shardings = (rows2d, sharding, sharding)
+    absent_args = () if absent is None else absent_teacher(config, seed)
     return jax.jit(make, out_shardings=out_shardings)(
         _device_key(seed, 2), teacher(config, seed),
-        np.asarray(boundaries, np.float32))
+        np.asarray(boundaries, np.float32), *absent_args)

@@ -177,11 +177,9 @@ def run_cell(ctx: Context, manifest: dict, t_start: float) -> dict:
     ``t_start`` is the process's start on ``time.perf_counter``: ``setup_s``
     runs from it to the first instant of the measured window.
     """
-    from benchmarks.chip import tracereduce
     from dmlc_core_tpu import telemetry
 
-    kind_name = ctx.cell["kind"]
-    kind = load_kind(kind_name)
+    kind = load_kind(ctx.cell["kind"])
     compiles = CompileCounter.get()
     state = kind.setup(ctx)
     if ctx.trace:
@@ -196,27 +194,43 @@ def run_cell(ctx: Context, manifest: dict, t_start: float) -> dict:
     if ctx.trace:
         trace_dir = tracer.join()
         telemetry.disable()
-    checks = list(kind.check(ctx, state, window))
-    checks.append((compiled_in_window == 0,
-                   f"nothing compiled inside the window "
-                   f"({compiled_in_window} lowerings/compiles)"))
-    for ok, what in checks:
-        ctx.say(f"{'ok' if ok else 'FAILED'}: {what}")
+    # what the timed path held, read before the check: its reference and
+    # its eager kernel call hold more than a fit ever does (on epsilon 12
+    # GB against the fit's 5.2), and a process's peak never falls again
     device = {"platform": ctx.devices[0].platform,
               "kind": ctx.devices[0].device_kind,
               "count": len(ctx.devices),
               "memory_peak_bytes": peak_memory_bytes(ctx.devices)}
+    checks = list(kind.check(ctx, state, window))
+    checks.append((compiled_in_window == 0,
+                   f"nothing compiled inside the window "
+                   f"({compiled_in_window} lowerings/compiles)"))
+    compared = [f"{'ok' if ok else 'FAILED'}: {what}" for ok, what in checks]
+    for line in compared:
+        ctx.say(line)
     result = {"correct": all(ok for ok, _ in checks),
               "attempted": int(window["attempted"]),
               "failed": int(window["failed"]), "metrics": {},
               "device": device}
-    cell_name = ctx.cell["name"]
-    if not ctx.trace:
+    if ctx.trace:
+        _read_layers(ctx, manifest, trace_dir, state, window, result)
+    else:
         values = kind.end_to_end(ctx, state, window)
-        for m in cell_metrics(manifest, cell_name, "end_to_end"):
+        for m in cell_metrics(manifest, ctx.cell["name"], "end_to_end"):
             result["metrics"][m["name"]] = {"value": values[m["name"]],
                                             "unit": m["unit"]}
-        return result
+    # every number compared beside its limit, last in the line
+    result["compared"] = compared
+    return result
+
+
+def _read_layers(ctx, manifest, trace_dir, state, window, result):
+    """A traced run's per-layer metrics, busy seconds and breakdown, into
+    ``result``."""
+    from benchmarks.chip import tracereduce
+    from dmlc_core_tpu import telemetry
+
+    device = result["device"]
     trace = tracereduce.load(trace_dir, len(ctx.devices))
     device["busy_s"] = trace.busy_s
     device["window_s"] = trace.window_s
@@ -232,13 +246,12 @@ def run_cell(ctx: Context, manifest: dict, t_start: float) -> dict:
         ctx.say(f"span buffer dropped {dropped} spans: no span-derived "
                 f"metric is reported")
     wanted = {m["name"]: m for m in
-              cell_metrics(manifest, cell_name, "per_layer")}
+              cell_metrics(manifest, ctx.cell["name"], "per_layer")}
     for mod in layer_metric_modules():
-        if mod.NAME not in wanted or kind_name not in mod.KINDS:
+        if mod.NAME not in wanted or ctx.cell["kind"] not in mod.KINDS:
             continue
         value = mod.reduce(evidence)
         if value is None:
             ctx.say(f"per-layer {mod.NAME}: nothing to read, left out")
             continue
         result["metrics"][mod.NAME] = {"value": value, "unit": mod.UNIT}
-    return result

@@ -11,6 +11,15 @@ every node scores every (feature, threshold) by
 ``H >= min_child_weight``, the last bin is never a threshold, the first
 maximum wins, and a node splits only if its best gain is > 0.  Rows go
 right when ``bin > threshold``.  Leaves take ``-G/(H+lam) * eta``.
+
+With ``missing=True`` (sparsity-aware split finding, XGBoost's algorithm
+3) the last bin id is reserved for absent entries.  Every (feature,
+threshold) is scored twice, the reserved bin's ``G, H`` on the right and
+on the left, each under ``min_child_weight``; the node sends its absent
+rows left only where that gain is strictly larger (right on ties); the
+reserved bin is never a threshold and the last real one (``num_bins - 2``,
+present against absent) is allowed; rows in the reserved bin follow the
+node's direction.
 """
 
 from __future__ import annotations
@@ -50,57 +59,76 @@ def logloss(margin, label):
 
 
 def build_tree(bins, g, h, max_depth, num_bins, reg_lambda,
-               min_child_weight, learning_rate):
+               min_child_weight, learning_rate, missing=False):
     """Grow one tree; returns ``(split_feat, split_bin, leaf_value,
-    margin_delta)`` in the level-order layout (``-1`` = no split)."""
+    default_left, margin_delta)`` in the level-order layout (``-1`` = no
+    split; ``default_left`` all False without ``missing``)."""
     n, f = bins.shape
     n_internal = 2 ** max_depth - 1
     split_feat = np.full(n_internal, -1, np.int32)
     split_bin = np.zeros(n_internal, np.int32)
+    default_left = np.zeros(n_internal, bool)
     node = np.zeros(n, np.int64)
     lam = np.float32(reg_lambda)
+    miss = num_bins - 1
     for depth in range(max_depth):
         n_nodes = 2 ** depth
         G, H = histogram(bins, node, g, h, n_nodes, num_bins)
         GL, HL = np.cumsum(G, -1), np.cumsum(H, -1)
         GT, HT = GL[..., -1:], HL[..., -1:]
-        GR, HR = GT - GL, HT - HL
-        gain = (GL * GL / (HL + lam) + GR * GR / (HR + lam)
-                - GT * GT / (HT + lam))
-        valid = (HL >= min_child_weight) & (HR >= min_child_weight)
-        valid[..., num_bins - 1] = False
-        gain = np.where(valid, gain, -np.inf).reshape(n_nodes, -1)
+
+        def score(GL, HL):
+            GR, HR = GT - GL, HT - HL
+            # (at the reserved bin, absent rows counted left a second time,
+            # HR + lam can be 0: never valid, never a threshold)
+            with np.errstate(divide="ignore", invalid="ignore"):
+                gain = (GL * GL / (HL + lam) + GR * GR / (HR + lam)
+                        - GT * GT / (HT + lam))
+            valid = (HL >= min_child_weight) & (HR >= min_child_weight)
+            return np.where(valid, gain, -np.inf)
+
+        gain = score(GL, HL)                  # absent rows on the right
+        go_left = np.zeros(gain.shape, bool)
+        if missing:
+            gain_left = score(GL + G[..., miss:], HL + H[..., miss:])
+            go_left = gain_left > gain
+            gain = np.maximum(gain, gain_left)
+        gain[..., num_bins - 1] = -np.inf
+        gain = gain.reshape(n_nodes, -1)
         best = np.argmax(gain, axis=1)
         best_gain = gain[np.arange(n_nodes), best]
         do_split = best_gain > 0
         sf = np.where(do_split, best // num_bins, -1).astype(np.int32)
         sb = (best % num_bins).astype(np.int32)
+        dl = go_left.reshape(n_nodes, -1)[np.arange(n_nodes), best] & do_split
         lvl = n_nodes - 1 + np.arange(n_nodes)
-        split_feat[lvl], split_bin[lvl] = sf, sb
+        split_feat[lvl], split_bin[lvl], default_left[lvl] = sf, sb, dl
         nf = sf[node]
         row_bin = bins[np.arange(n), np.maximum(nf, 0)].astype(np.int64)
         go_right = (row_bin > sb[node]) & (nf >= 0)
+        if missing:
+            go_right &= ~((row_bin == miss) & dl[node])
         node = node * 2 + go_right
     n_leaf = 2 ** max_depth
     Gl = np.bincount(node, weights=g.astype(np.float64), minlength=n_leaf)
     Hl = np.bincount(node, weights=h.astype(np.float64), minlength=n_leaf)
     leaf = (-Gl / (Hl + reg_lambda) * learning_rate).astype(np.float32)
-    return split_feat, split_bin, leaf, leaf[node]
+    return split_feat, split_bin, leaf, default_left, leaf[node]
 
 
 def boost(bins, label, rounds, *, max_depth, num_bins, learning_rate,
           reg_lambda, min_child_weight, objective="logistic",
-          base_score=0.0):
+          base_score=0.0, missing=False):
     """``rounds`` boosting rounds; returns ``(trees, margin)`` where trees
-    is a list of ``(split_feat, split_bin, leaf_value)``."""
+    is a list of ``(split_feat, split_bin, leaf_value, default_left)``."""
     margin = np.full(bins.shape[0], base_score, np.float32)
     label = label.astype(np.float32)
     trees = []
     for _ in range(rounds):
         g, h = grad_hess(margin, label, objective)
-        sf, sb, leaf, delta = build_tree(bins, g, h, max_depth, num_bins,
-                                         reg_lambda, min_child_weight,
-                                         learning_rate)
-        trees.append((sf, sb, leaf))
+        sf, sb, leaf, dl, delta = build_tree(
+            bins, g, h, max_depth, num_bins, reg_lambda, min_child_weight,
+            learning_rate, missing)
+        trees.append((sf, sb, leaf, dl))
         margin = margin + delta
     return trees, margin

@@ -18,19 +18,28 @@ def bin_rows(x, boundaries):
     return out
 
 
-def margins(bins, split_feat, split_bin, leaf_value, base_score=0.0):
+def margins(bins, split_feat, split_bin, leaf_value, base_score=0.0,
+            default_left=None, miss_id=None):
     """Sum over trees of the leaf each row reaches.  ``split_feat[t, i] ==
-    -1`` means node ``i`` does not split: the row falls to child ``2i``."""
+    -1`` means node ``i`` does not split: the row falls to child ``2i``.
+    With ``default_left`` (``[T, 2**d - 1]`` bool) and ``miss_id`` a row
+    whose bin at the node's feature is ``miss_id`` (absent) goes left
+    where the node says so; without them it goes right like any row above
+    the threshold, ``miss_id`` being the largest bin."""
     n = bins.shape[0]
     depth = int(np.log2(leaf_value.shape[1]))
     rows = np.arange(n)
     out = np.full(n, base_score, np.float64)
-    for sf, sb, leaf in zip(split_feat, split_bin, leaf_value):
+    for t, (sf, sb, leaf) in enumerate(zip(split_feat, split_bin,
+                                           leaf_value)):
         node = np.zeros(n, np.int64)
         for d in range(depth):
             at = 2 ** d - 1 + node
             f = sf[at]
-            go_right = (bins[rows, np.maximum(f, 0)] > sb[at]) & (f >= 0)
+            row_bin = bins[rows, np.maximum(f, 0)]
+            go_right = (row_bin > sb[at]) & (f >= 0)
+            if default_left is not None and miss_id is not None:
+                go_right &= ~((row_bin == miss_id) & default_left[t][at])
             node = node * 2 + go_right
         out += leaf[node].astype(np.float64)
     return out

@@ -12,7 +12,9 @@ that many).  There is no CPU path here.  It then runs the cell through
 ``harness.run_cell`` (set-up, measured window, check) and prints the
 result as one JSON object on the last line of stdout: with ``--trace 0``
 the cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics,
-the device's busy seconds and the breakdown.
+the device's busy seconds and the breakdown; either way ``compared`` comes
+last, every check's line with its numbers beside their limits, and the
+same lines close stderr.
 """
 
 import time
@@ -83,6 +85,9 @@ def main(argv=None):
         result = harness.run_cell(ctx, manifest, _T_START)
     faulthandler.cancel_dump_traceback_later()
     say(f"total {time.perf_counter() - _T_START:.2f} s")
+    # every number compared beside its limit: the last lines of stderr, and
+    # the last key of the result's line
+    print("\n".join(result["compared"]), file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

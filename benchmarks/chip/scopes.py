@@ -259,20 +259,37 @@ def host_clock_lead(chip: tracereduce.ChipTrace, host_events
     trace's and whose own launch (done) the trace does not hold, and the
     next program's launch, 9 ms after the trace began 5 ms before a fit's
     end, is not the cut one's (my chip run, PR 30: what kept the metric
-    out of every traced run of ``epsilon400k.fit``).  ``None`` where
-    either side has no event or the bounds cross."""
+    out of every traced run of ``epsilon400k.fit``).  Nearest is not
+    enough where the cut stub is shorter than the lead: a trace that ended
+    0.03 ms into a fit gave that stub the previous fit's done, 0.56 ms
+    after its recorded end where every other program read 2.46-2.61, and
+    the bounds crossed (my chip run, PR 32: one traced run of
+    ``bosch1m.fit`` in four).  So bounds that cross are taken again
+    without the first program's launch and the last program's done, the
+    two an edge can have cut.  ``None`` where either side has no event or
+    the bounds still cross."""
     if not chip.modules:
         return None
     starts = [start for _, start, _ in chip.modules]
     ends = [end for _, _, end in chip.modules]
     launches = [s for n, s, _ in host_events if n == LAUNCH]
     dones = [s for n, s, _ in host_events if n == DONE]
-    least = [min(q) - starts[i]
-             for i, q in _nearest(launches, starts).items()]
-    most = [max(d) - ends[i] for i, d in _nearest(dones, ends).items()]
-    if not least or not most or max(least) > min(most):
-        return None
-    return max(least), min(most)
+    least = {i: min(q) - starts[i]
+             for i, q in _nearest(launches, starts).items()}
+    most = {i: max(d) - ends[i] for i, d in _nearest(dones, ends).items()}
+
+    def bounds():
+        if not least or not most or \
+                max(least.values()) > min(most.values()):
+            return None
+        return max(least.values()), min(most.values())
+
+    found = bounds()
+    if found is None:
+        least.pop(0, None)
+        most.pop(len(ends) - 1, None)
+        found = bounds()
+    return found
 
 
 def idle_attributed(chip: tracereduce.ChipTrace, annotations,

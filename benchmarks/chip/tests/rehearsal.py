@@ -44,6 +44,18 @@ def config(**changes):
     return out
 
 
+def missing_config(**changes):
+    """The rehearsal table with absent entries, from a column half empty
+    to one 90% empty, and a model that handles them."""
+    shares = [0.5, 0.6, 0.7, 0.8, 0.9]
+    out = config(model={"handle_missing": True},
+                 data={"cardinality": [0] * 5, "label_noise": 0.3,
+                       "missing_share": shares, "missing_effect": 1.0})
+    out["check"]["min_default_left"] = 2
+    out.update(changes)
+    return out
+
+
 def context(cell, cfg, tmp_path, devices, seconds=0.5, trace=False, seed=7):
     """``(ctx, lines)``: a context whose ``say`` appends to ``lines``."""
     lines = []

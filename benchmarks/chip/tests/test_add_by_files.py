@@ -91,3 +91,29 @@ def test_a_cell_a_config_a_kind_and_a_metric_arrive_as_files(
         assert result["correct"] and set(result["metrics"]) == want
     assert result["metrics"]["dummy_ticks"] == {"value": 3.0,
                                                 "unit": "ticks"}
+
+
+def test_memory_peak_is_read_before_the_check(tmp_path, monkeypatch):
+    """``memory_peak_bytes`` is what the timed path held: the check's
+    reference and eager calls come after the reading."""
+    import types
+
+    order = []
+    kind = types.SimpleNamespace(
+        setup=lambda ctx: {},
+        window=lambda ctx, state, t0: {"setup_s": 0.1, "attempted": 1,
+                                       "failed": 0},
+        check=lambda ctx, state, window: order.append("check") or
+        [(True, "a dummy is always right")],
+        end_to_end=lambda ctx, state, window: {"setup_s": 0.1})
+    monkeypatch.setattr(harness, "load_kind", lambda name: kind)
+    monkeypatch.setattr(harness, "peak_memory_bytes",
+                        lambda devices: order.append("peak") or 7)
+    ctx, _ = rehearsal.context({"name": "x", "kind": "dummy"}, {}, tmp_path,
+                               jax.devices()[:1])
+    result = harness.run_cell(ctx, {"end_to_end": [
+        {"name": "setup_s", "unit": "s"}], "per_layer": []}, 0.0)
+    assert order == ["peak", "check"]
+    assert result["device"]["memory_peak_bytes"] == 7
+    assert list(result)[-1] == "compared"
+    assert result["compared"][0] == "ok: a dummy is always right"

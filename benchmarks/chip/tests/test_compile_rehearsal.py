@@ -97,3 +97,18 @@ def test_airline_dp4_fit_compiles_for_the_described_2x2(topo):
     # per chip, with room left for what else the process keeps there
     assert (resident_bytes(config, chips=4) < total_bytes(compiled)
             < HBM_BYTES - 1.5e9)
+
+
+def test_bosch1m_fit_compiles_for_one_described_chip(topo):
+    """The fourth shape, 1,183,747 x 968 with ``handle_missing``: two gains
+    a candidate in split scoring and the default-left pick in routing
+    compile for a v5e, the kernel one call a level over 8 feature blocks,
+    inside a chip and over the bound from shapes."""
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+    compiled, config = compiled_fit("bosch1m.fit", (one, one))
+    assert fit.make_model(config, 1).param.handle_missing
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == config["max_depth"]
+    assert resident_bytes(config) < total_bytes(compiled) < HBM_BYTES

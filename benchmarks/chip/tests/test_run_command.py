@@ -65,3 +65,22 @@ def test_fails_where_only_the_benchmark_is(tmp_path):
         cwd=tmp_path, timeout=300)
     assert done.returncode != 0
     assert no_result_line(done.stdout)
+
+
+def test_prints_what_was_compared_last_on_both_streams(monkeypatch, capfd):
+    """Every number compared beside its limit: the last lines of stderr,
+    and the last key of the result's line."""
+    from dmlc_core_tpu import device
+
+    one_chip = device.DeviceInfo("tpu", "TPU v5 lite", 1, "/nowhere")
+    monkeypatch.setattr(device, "init_device", lambda: one_chip)
+    compared = ["ok: a number 0.01 (limit 0.02)", "FAILED: another 3 (limit 2)"]
+    monkeypatch.setattr(
+        harness, "run_cell", lambda ctx, manifest, t0: {
+            "correct": False, "attempted": 1, "failed": 0, "metrics": {},
+            "device": {}, "compared": compared})
+    assert run.main(["--workload", "higgs11m.fit", *ARGS]) == 0
+    out = capfd.readouterr()
+    assert out.err.splitlines()[-2:] == compared
+    line = json.loads(out.out.splitlines()[-1])
+    assert list(line)[-1] == "compared" and line["compared"] == compared

@@ -458,6 +458,34 @@ def test_programs_cut_by_the_trace_edges_bound_nothing(monkeypatch,
     assert "no span" in said[-1]
 
 
+def test_a_cut_stub_shorter_than_the_lead_takes_no_done():
+    """The traced run of ``bosch1m.fit`` that reported no
+    ``idle_attributed_share`` (my chip run, PR 32, seed 3200000111; ms from
+    the first op): the trace ended 0.03 ms into the ninth fit, and the
+    eighth fit's done, 2.40 ms after its own end, lies 0.56 ms after the
+    stub's.  The bounds cross with it and hold without it."""
+    programs = [(0.0, 4.028), (6.277, 1135.535), (1137.801, 2267.033),
+                (2269.001, 3398.236), (3400.32, 4529.548),
+                (4531.517, 5660.747), (5662.862, 6792.109),
+                (6794.135, 7923.38), (7925.186, 7925.215)]
+    launches = [8.118, 1139.552, 2270.81, 3402.154, 4533.356, 5664.592,
+                6795.955]
+    dones = [6.53, 1138.149, 2269.494, 3400.764, 4532.016, 5663.274,
+             6794.664, 7925.779]
+    chip = tracereduce.ChipTrace(
+        0, [], [], [("jit_fit", s * 1e-3, e * 1e-3) for s, e in programs])
+    host = [(scopes.LAUNCH, t * 1e-3, t * 1e-3 + 5e-5) for t in launches] \
+        + [(scopes.DONE, t * 1e-3, t * 1e-3 + 1e-4) for t in dones]
+    assert scopes.host_clock_lead(chip, host) == (
+        pytest.approx(1.841e-3, abs=1e-6), pytest.approx(2.462e-3, abs=1e-6))
+    # a done that contradicts the launches in the MIDDLE of the trace is no
+    # edge's doing: the events are not this chip's programs'
+    dones[3] = 3398.9
+    host = [(scopes.LAUNCH, t * 1e-3, t * 1e-3 + 5e-5) for t in launches] \
+        + [(scopes.DONE, t * 1e-3, t * 1e-3 + 1e-4) for t in dones]
+    assert scopes.host_clock_lead(chip, host) is None
+
+
 def test_host_clock_lead_of_the_recorded_trace(recorded):
     events = scopes.host_annotations(RECORDED, {scopes.LAUNCH, scopes.DONE})
     least, most = scopes.host_clock_lead(recorded.chips[0], events)

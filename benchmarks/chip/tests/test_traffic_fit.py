@@ -50,3 +50,58 @@ def test_fit_check_catches_a_dropped_level(interpret, tmp_path):
     window = fit.window(ctx, state, 0.0)
     failed = [what for ok, what in fit.check(ctx, state, window) if not ok]
     assert any("train logloss" in what for what in failed), failed
+
+
+# -- a configuration's own model parameters ---------------------------------
+
+@pytest.mark.parametrize("cell", ["higgs11m.fit", "airline115m.fit.dp4",
+                                  "epsilon400k.fit"])
+def test_accepted_configurations_get_the_param_they_always_got(cell):
+    """Without a ``model`` block ``make_model`` gives the ``GBDTParam`` of
+    the eight fields it always passed, field for field."""
+    from benchmarks.chip import harness
+    from benchmarks.chip.traffic import fit
+    from dmlc_core_tpu.models.gbdt import GBDTParam
+
+    cell, config = harness.load_cell(harness.load_manifest(), cell)
+    assert "model" not in config
+    rounds = cell["rounds_per_fit"]
+    model = fit.make_model(config, rounds)
+    before = GBDTParam(
+        num_boost_round=rounds, max_depth=config["max_depth"],
+        num_bins=config["num_bins"], learning_rate=config["learning_rate"],
+        reg_lambda=config["reg_lambda"],
+        min_child_weight=config["min_child_weight"],
+        objective=config["objective"], hist_method=config["hist_method"])
+    assert model.param.to_dict() == before.to_dict()
+    assert model.num_feature == config["num_feature"]
+
+
+def test_model_block_reaches_the_param():
+    from benchmarks.chip.traffic import fit
+
+    plain = fit.make_model(rehearsal.config(), 2).param.to_dict()
+    cfg = rehearsal.config(model={"handle_missing": True, "subsample": 0.5,
+                                  "seed": 3})
+    got = fit.make_model(cfg, 2).param.to_dict()
+    changed = {k for k in got if got[k] != plain[k]}
+    assert changed == {"handle_missing", "subsample", "seed"}
+    assert fit.make_model(cfg, 2).param.handle_missing is True
+    # the Bosch configuration states exactly one further field
+    from benchmarks.chip import harness
+    _, bosch = harness.load_cell(harness.load_manifest(), "bosch1m.fit")
+    assert bosch["model"] == {"handle_missing": True}
+
+
+@pytest.mark.parametrize("name, says", [
+    ("no_such_field", "GBDTParam has no field 'no_such_field'"),
+    ("max_depth", "model.max_depth: 'max_depth' has a key of its own"),
+    ("hist_method", "model.hist_method"),
+    ("num_boost_round", "model.num_boost_round"),
+])
+def test_model_block_refuses_unknown_and_doubled_names(name, says):
+    from benchmarks.chip.traffic import fit
+
+    cfg = rehearsal.config(model={name: 3})
+    with pytest.raises(ValueError, match=says):
+        fit.make_model(cfg, 2)

@@ -201,7 +201,8 @@ def test_reference_tree_by_hand():
     trees, margin = gbdt_hist.boost(
         bins, label, 1, max_depth=1, num_bins=2, learning_rate=0.3,
         reg_lambda=1.0, min_child_weight=0.1)
-    sf, sb, leaf = trees[0]
+    sf, sb, leaf, default_left = trees[0]
+    assert not default_left.any()
     assert (sf.tolist(), sb.tolist()) == ([0], [0])
     assert leaf == pytest.approx([-0.2, 0.2])
     assert margin == pytest.approx([-0.2, -0.2, 0.2, 0.2])

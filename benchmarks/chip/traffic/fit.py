@@ -22,16 +22,42 @@ from benchmarks.chip import datagen, stats
 from benchmarks.chip.reference import gbdt_hist, tree_walk
 
 
+# the GBDTParam fields a configuration states at its top level (the rounds
+# are the cell's); whatever else its model needs comes in its "model" block
+_TOP_LEVEL = ("max_depth", "num_bins", "learning_rate", "reg_lambda",
+              "min_child_weight", "objective", "hist_method")
+
+
+def model_params(config):
+    """The configuration's ``model`` block: further ``GBDTParam`` fields
+    by name (``handle_missing``, ``num_class``, ``subsample``, ...)."""
+    from dmlc_core_tpu.models.gbdt import GBDTParam
+
+    extra = dict(config.get("model") or {})
+    for name in extra:
+        if name in _TOP_LEVEL or name == "num_boost_round":
+            raise ValueError(f"model.{name}: {name!r} has a key of its own "
+                             f"(the configuration's top level, or the "
+                             f"cell's rounds_per_fit)")
+        if name not in GBDTParam.__fields__:
+            raise ValueError(f"model.{name}: GBDTParam has no field "
+                             f"{name!r} (has: {sorted(GBDTParam.__fields__)})")
+    return extra
+
+
 def make_model(config, rounds):
+    """The model a configuration states: its top-level fields, the cell's
+    rounds, and what its ``model`` block names."""
     from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
 
     return GBDT(GBDTParam(
-        num_boost_round=rounds, max_depth=config["max_depth"],
-        num_bins=config["num_bins"], learning_rate=config["learning_rate"],
-        reg_lambda=config["reg_lambda"],
-        min_child_weight=config["min_child_weight"],
-        objective=config["objective"], hist_method=config["hist_method"]),
-        num_feature=config["num_feature"])
+        num_boost_round=rounds, **{k: config[k] for k in _TOP_LEVEL},
+        **model_params(config)), num_feature=config["num_feature"])
+
+
+def reference_params(config):
+    """The same parameters as ``reference.gbdt_hist.boost`` takes them."""
+    return {k: config[k] for k in _TOP_LEVEL if k != "hist_method"}
 
 
 def fit_bins(config, seed, model):
@@ -116,6 +142,27 @@ def end_to_end(ctx, state, window):
                 per_fit / stats.median(window["fit_seconds"])}
 
 
+def hist_case(ctx, state):
+    """What the histogram line compares on: the first ``check.hist_rows``
+    of the fit's own bins under seeded node ids, g and h at the deepest
+    level's node count: ``(bins, node, g, h, num_nodes)``."""
+    n = min(int(ctx.config["check"]["hist_rows"]), state["rows"])
+    nodes = 2 ** (ctx.config["max_depth"] - 1)
+    rng = np.random.default_rng([ctx.seed, 0xC4EC])
+    hb = np.asarray(state["data"][0][:n])
+    node = rng.integers(0, nodes, n).astype(np.int32)
+    g = rng.standard_normal(n).astype(np.float32)
+    h = np.abs(rng.standard_normal(n)).astype(np.float32)
+    return hb, node, g, h, nodes
+
+
+def hist_excess(got, ref, rtol):
+    """The least atol at which ``got`` (G, H) would pass against ``ref`` at
+    ``rtol``: the worst ``|got - ref| - rtol |ref|`` over every bucket."""
+    return max(float((np.abs(np.asarray(a) - b) - rtol * np.abs(b)).max())
+               for a, b in zip(got, ref))
+
+
 def check(ctx, state, window):
     import jax
 
@@ -123,6 +170,7 @@ def check(ctx, state, window):
 
     config, model = ctx.config, state["model"]
     spec = config["check"]
+    miss = datagen.reserved_bin(config)
     bins, label, weight = state["data"]
     ensemble, margin = window["last"]
     want = config["expect_hist_method"]
@@ -132,62 +180,71 @@ def check(ctx, state, window):
 
     # a fit is deterministic in the seed: the warm fit and the window's
     # last fit chose the same splits
-    same = all(np.array_equal(np.asarray(a), np.asarray(b))
-               for a, b in zip(state["warm"][0][:2], ensemble[:2]))
+    same = all(np.array_equal(np.asarray(state["warm"][0][i]),
+                              np.asarray(ensemble[i]))
+               for i in (0, 1, 3))        # features, thresholds, directions
     yield same, "two fits of the same rows chose identical splits"
 
     # the kernel against the exact bincount histogram, deepest level's nodes
-    n = min(int(spec["hist_rows"]), state["rows"])
-    nodes = 2 ** (config["max_depth"] - 1)
-    rng = np.random.default_rng([ctx.seed, 0xC4EC])
-    hb = np.asarray(bins[:n])
-    node = rng.integers(0, nodes, n).astype(np.int32)
-    g = rng.standard_normal(n).astype(np.float32)
-    h = np.abs(rng.standard_normal(n)).astype(np.float32)
+    hb, node, g, h, nodes = hist_case(ctx, state)
+    n = hb.shape[0]
     got = grad_histogram(hb, node, g, h, num_nodes=nodes,
                          num_bins=config["num_bins"],
                          method=state["method"])
     ref = gbdt_hist.histogram(hb, node, g, h, nodes, config["num_bins"])
-    close = all(np.allclose(np.asarray(a), b, rtol=spec["hist_rtol"],
-                            atol=spec["hist_atol"])
-                for a, b in zip(got, ref))
-    yield close, (f"{state['method']} histogram == bincount histogram at "
-                  f"{n} rows x {nodes} nodes (rtol {spec['hist_rtol']}, "
-                  f"atol {spec['hist_atol']})")
+    need = hist_excess(got, ref, spec["hist_rtol"])
+    yield (need <= spec["hist_atol"],
+           f"{state['method']} histogram == bincount histogram at "
+           f"{n} rows x {nodes} nodes (rtol {spec['hist_rtol']}): worst "
+           f"excess over rtol {need:.4f} (atol {spec['hist_atol']})")
 
     # the program's fit of a seeded subsample against the plain reference's
     # fit of the same bins: same parameters, same rounds
     m = min(int(spec["sample_rows"]), state["rows"])
     sb, sl = np.asarray(bins[:m]), np.asarray(label[:m])
-    _, ref_margin = gbdt_hist.boost(
-        sb, sl, state["rounds"], max_depth=config["max_depth"],
-        num_bins=config["num_bins"], learning_rate=config["learning_rate"],
-        reg_lambda=config["reg_lambda"],
-        min_child_weight=config["min_child_weight"],
-        objective=config["objective"])
+    _, ref_margin = gbdt_hist.boost(sb, sl, state["rounds"],
+                                    missing=miss is not None,
+                                    **reference_params(config))
     ref_loss = gbdt_hist.logloss(ref_margin, sl)
     _, sub_margin = model.fit_binned(sb, sl)
     sub_loss = _logloss(sub_margin, jax.numpy.asarray(sl))
     tol = spec["logloss_tolerance"]
     yield (abs(sub_loss - ref_loss) <= tol,
            f"train logloss after {state['rounds']} rounds on {m} sampled "
-           f"rows: program {sub_loss:.5f} vs reference {ref_loss:.5f} "
-           f"(tolerance {tol})")
+           f"rows: program {sub_loss:.5f} vs reference {ref_loss:.5f}, "
+           f"{abs(sub_loss - ref_loss):.2e} apart (tolerance {tol})")
     # the whole fit: its returned margins are what a plain walk of its own
     # trees gives on the sampled rows, and its loss is the sample's but for
     # the sample's overfit
-    walked = tree_walk.margins(sb.astype(np.int64),
-                               *(np.asarray(a) for a in ensemble[:3]))
-    worst = float(np.abs(walked - np.asarray(margin[:m])).max())
+    rows64 = sb.astype(np.int64)
+    trees = [np.asarray(a) for a in ensemble[:3]]
+    directions = np.asarray(ensemble[3])
+    fitted = np.asarray(margin[:m])
+    walked = tree_walk.margins(rows64, *trees, default_left=directions,
+                               miss_id=miss)
+    worst = float(np.abs(walked - fitted).max())
     yield (worst <= spec["margin_atol"],
            f"the {state['rows']}-row fit's margins equal a numpy walk of "
            f"its own trees on {m} rows: worst difference {worst:.2e} "
            f"(atol {spec['margin_atol']})")
+    if miss is not None:
+        # the mechanism worked in the fit that was timed: some splits send
+        # their absent rows left, and the margins say so
+        apart = float(np.abs(tree_walk.margins(rows64, *trees)
+                             - fitted).max())
+        least = int(spec["min_default_left"])
+        yield (apart > spec["margin_atol"] and directions.sum() >= least,
+               f"{int(directions.sum())} of the fit's "
+               f"{int((trees[0] >= 0).sum())} splits send absent rows left "
+               f"(at least {least}), and a walk that sends them all right "
+               f"differs from the fit's margins by {apart:.2e} (more than "
+               f"{spec['margin_atol']})")
     full_loss = _logloss(margin, label)
     band = spec["full_vs_sample_band"]
     yield (abs(full_loss - sub_loss) <= band and np.isfinite(full_loss),
            f"train logloss of the whole {state['rows']}-row fit "
-           f"{full_loss:.5f} within {band} of the sample's")
+           f"{full_loss:.5f}: {abs(full_loss - sub_loss):.5f} from the "
+           f"sample's (band {band})")
 
     if state["mesh"] is not None:
         with state["mesh"]:
