@@ -30,22 +30,26 @@ node slots at depth 6, whose rows are keyed by their parent's id and all
 other rows by -1, which the body drops; the one-shot ``grad_histogram``
 builds every node it is asked for.
 
-- grid = (feature blocks, row tiles), both sequential on TPU, rows inner;
+- grid = (node blocks, feature blocks, row tiles), all sequential on TPU,
+  rows innermost;
 - the ``[F_blk, L, 2nH]`` f32 accumulator lives in one VMEM output block
-  indexed by the feature block alone, so it persists across a block's row
-  tiles (zeroed at the first);
+  indexed by the node and feature block alone, so it persists across a
+  block's row tiles (zeroed at the first);
 - per step: DMA the node / g / h row tiles ``[1, TB]`` and the bins tile
   ``[F_blk, TB]`` (feature-major int32: v5e Mosaic lowers no sub-32-bit
   compare), then for each feature two compare-selects against a sublane
   iota and one MXU dot.  Both operands are built as int32 words that hold
   TWO bf16 rows each (g and h of one key; ones of two neighbouring ``lo``)
   and are bitcast to bf16: half the compares, and no convert;
-- a table whose accumulator fits the VMEM budget is one feature block; a
-  wider one is cut by :func:`hist_block_plan` into blocks of 128 features.
-  Every feature's one-hots are still built once, so a level stays ONE
-  ``hist_level`` call whose cost follows rows x features;
-- the result leaves the kernel as ``[F, L, 2nH]``; one XLA transpose a level
-  puts it back to ``(G, H)[n, F, num_bins]``.
+- a table whose accumulator fits the VMEM budget is one block; a wider
+  one is cut by :func:`hist_block_plan` into blocks of 128 features, and a
+  level of more nodes than those leave room for (64 at 128 features and
+  256 bins) into node blocks.  Both are steps of the grid, so a level is
+  ONE ``hist_level`` call whatever its blocking; a feature block builds
+  its features' one-hots once, a node block builds every feature's again
+  with its own base taken off the node ids;
+- the result leaves the kernel as ``[node blocks, F, L, 2nH]``; one XLA
+  transpose a level puts it back to ``(G, H)[n, F, num_bins]``.
 
 HBM traffic per level is ``B*(4F + 12)`` bytes — no weight matrix is ever
 written.  Every term is a bf16-rounded g or h (or 0) times a bf16 0/1,
@@ -134,19 +138,20 @@ def hist_fits_vmem(num_nodes: int, num_feature: int, num_bins: int) -> bool:
 
 
 def hist_block_plan(num_nodes: int, num_feature: int, num_bins: int):
-    """``(nodes per kernel call, features per accumulator block)`` of one
-    level, or None when even 8 node slots of the narrowest feature block
-    overflow VMEM.  The one place the budget is applied.
+    """``(node slots, features)`` of one accumulator block of a level, or
+    None when even 8 node slots of the narrowest feature block overflow
+    VMEM.  The one place the budget is applied.
 
-    Features are blocked first: a feature block is a grid step of the SAME
-    kernel call (every feature's one-hots are still built once; only the
-    12 B a row of node, g and h are re-read), while a node block is another
-    call that re-reads the bins and re-builds every one-hot.  So: the most
-    nodes for which the narrowest legal feature block (128 features, or all
-    F of a narrower table) fits, and beside them all F features in one
-    block where those fit, else blocks of 128.  Not wider where the budget
-    would allow it: at 512 features a block Mosaic spilled 173 MB for a
-    v5e (PR 27, the body then unrolled over the block's features).
+    Features are blocked first: both kinds of block are grid steps of the
+    SAME kernel call, but under feature blocks every feature's one-hots
+    are still built once (only the 12 B a row of node, g and h are
+    re-read), while a node block re-reads the bins and re-builds every
+    one-hot.  So: the most nodes for which the narrowest legal feature
+    block (128 features, or all F of a narrower table) fits, and beside
+    them all F features in one block where those fit, else blocks of 128.
+    Not wider where the budget would allow it: at 512 features a block
+    Mosaic spilled 173 MB for a v5e (PR 27, the body then unrolled over
+    the block's features).
     """
     narrow = min(num_feature, _LANES)
     nodes = num_nodes
@@ -215,10 +220,13 @@ _UNROLL = _LANES
 
 
 def _kernel(node_ref, g_ref, h_ref, bins_ref, out_ref, *, num_nodes: int,
-            hi: int, lo: int, num_feature: int):
-    """One (feature block, row tile) step: zero the resident accumulator at
-    the block's first tile, then per feature build ``A`` and ``LO`` from the
-    tile's row vectors and accumulate ``LO . A^T``.
+            hi: int, lo: int, num_feature: int, node_blocks: int):
+    """One (node block, feature block, row tile) step: zero the resident
+    accumulator at the block's first tile, then per feature build ``A`` and
+    ``LO`` from the tile's row vectors and accumulate ``LO . A^T``.
+    ``num_nodes`` is a node block's slots; with more than one block the
+    step's block base is taken off the node ids, so the rows of every other
+    block fall outside ``[0, num_nodes)`` and drop out below.
 
     Both operands are built two bf16 rows to an int32 word, the pair that
     ``pltpu.bitcast`` unfolds along the sublanes (low half first): a key's
@@ -230,12 +238,14 @@ def _kernel(node_ref, g_ref, h_ref, bins_ref, out_ref, *, num_nodes: int,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    @pl.when(pl.program_id(1) == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
     shift = lo.bit_length() - 1
     node = node_ref[:]                                       # [1, TB]
+    if node_blocks > 1:
+        node = node - pl.program_id(0) * num_nodes
     # a row whose node is outside [0, n) gets a key no iota value equals
     base = jnp.where((node >= 0) & (node < num_nodes), node * hi, -(1 << 30))
 
@@ -274,7 +284,8 @@ def _kernel(node_ref, g_ref, h_ref, bins_ref, out_ref, *, num_nodes: int,
 
 
 def hist_matmul_pallas(rows, bins, num_bins: int, *, num_nodes: int,
-                       block_rows: int = BLOCK_ROWS, block_features=None):
+                       block_rows: int = BLOCK_ROWS, block_features=None,
+                       block_nodes=None):
     """``out[c*n + k, f*nbins + b] = sum_i [node_i == k] * (g_i, h_i)[c] *
     (bins[f, i] == b)``: the kernel's entry, one ``hist_level`` call.
 
@@ -286,9 +297,13 @@ def hist_matmul_pallas(rows, bins, num_bins: int, *, num_nodes: int,
       block_rows: row-tile size (B is padded up to a multiple internally).
       block_features: features per accumulator block (a multiple of 8);
         None or >= F keeps all F in one block.
+      block_nodes: node slots per accumulator block; None or >= num_nodes
+        keeps all of them in one block.  The bin index is split for a
+        block's slots (:func:`hist_split_plan`); a last block that is
+        short holds slots no row carries.
 
-    Returns [2*num_nodes, F*num_bins] float32, the kernel's ``[F, L, 2nH]``
-    transposed back.
+    Returns [2*num_nodes, F*num_bins] float32, the kernel's
+    ``[node blocks, F, L, 2nH]`` transposed back.
     """
     import jax
     import jax.numpy as jnp
@@ -304,24 +319,30 @@ def hist_matmul_pallas(rows, bins, num_bins: int, *, num_nodes: int,
         b += pad[1][1]
     if block_features is None or block_features >= bf:
         block_features = bf
-    hi, lo = hist_split_plan(num_nodes, num_bins)
-    cols = 2 * _key_rows(num_nodes, hi)
-    # feature blocks on the OUTER axis, row tiles inside: the accumulator
-    # block moves only when a feature block's rows are all in, and the row
-    # vectors' tiles are the same for every block.  F need not divide: the
-    # last block's features beyond F read unspecified bins whose histograms
-    # lie beyond the output and are never written back.  One buffer for an
-    # output block whose index moves — Pallas would keep two, and the
-    # budget is for one.
+    if block_nodes is None or block_nodes >= num_nodes:
+        block_nodes = num_nodes
+    hi, lo = hist_split_plan(block_nodes, num_bins)
+    cols = 2 * _key_rows(block_nodes, hi)
+    # node blocks on the OUTERMOST axis, feature blocks inside them, row
+    # tiles innermost: the accumulator block moves only when a (node,
+    # feature) block's rows are all in, and the row vectors' tiles are the
+    # same for every block.  A node block re-reads the bins and re-builds
+    # every one-hot; a feature block re-reads only the row vectors.  F need
+    # not divide: the last block's features beyond F read unspecified bins
+    # whose histograms lie beyond the output and are never written back.
+    # One buffer for an output block whose index moves — Pallas would keep
+    # two, and the budget is for one.
+    node_blocks = pl.cdiv(num_nodes, block_nodes)
     blocks = pl.cdiv(bf, block_features)
-    out_buffering = {"pipeline_mode": pl.Buffered(1)} if blocks > 1 else {}
+    moves = node_blocks * blocks > 1
+    out_buffering = {"pipeline_mode": pl.Buffered(1)} if moves else {}
     # VMEM the call holds, as Mosaic tiles it: the accumulator (its minor
     # extent padded to the lanes: few bins or few nodes leave a tile mostly
     # empty, which the byte rule of hist_block_plan does not count), the
     # bins tile twice, the two operands.  Within Mosaic's default for every
     # blocked shape; an unblocked table of many narrow features asks for
     # what it needs.
-    vmem = ((1 if blocks > 1 else 2) * block_features * lo
+    vmem = ((1 if moves else 2) * block_features * lo
             * -(-cols // _LANES) * _LANES * 4
             + 2 * block_features * block_rows * 4
             + (cols + lo) * block_rows * 2)
@@ -329,31 +350,37 @@ def hist_matmul_pallas(rows, bins, num_bins: int, *, num_nodes: int,
     if vmem > _VMEM_UNASKED:
         params["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=vmem + _VMEM_UNASKED // 3)
-    row_spec = pl.BlockSpec((1, block_rows), lambda j, i: (0, i),
+    row_spec = pl.BlockSpec((1, block_rows), lambda n, j, i: (0, i),
                             memory_space=pltpu.VMEM)
-    kernel = functools.partial(_kernel, num_nodes=num_nodes, hi=hi, lo=lo,
-                               num_feature=block_features)
+    kernel = functools.partial(_kernel, num_nodes=block_nodes, hi=hi, lo=lo,
+                               num_feature=block_features,
+                               node_blocks=node_blocks)
     out = pl.pallas_call(
         kernel,
-        grid=(blocks, b // block_rows),
+        grid=(node_blocks, blocks, b // block_rows),
         in_specs=[row_spec, row_spec, row_spec,
                   pl.BlockSpec((block_features, block_rows),
-                               lambda j, i: (j, i),
+                               lambda n, j, i: (j, i),
                                memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((block_features, lo, cols),
-                               lambda j, i: (j, 0, 0),
+        out_specs=pl.BlockSpec((None, block_features, lo, cols),
+                               lambda n, j, i: (n, j, 0, 0),
                                memory_space=pltpu.VMEM, **out_buffering),
-        out_shape=jax.ShapeDtypeStruct((bf, lo, cols), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((node_blocks, bf, lo, cols),
+                                       jnp.float32),
         interpret=interpret_mode(),
         name="hist_level",
         **params,
     )(node.astype(jnp.int32), g.astype(jnp.float32), h.astype(jnp.float32),
       bins)
-    # [F, lo, (node, hi, c)] -> [(c, node), (F, hi, lo)], bins past num_bins
-    # (no row has them) dropped
-    out = out[:, :, :2 * num_nodes * hi].reshape(bf, lo, num_nodes, hi, 2)
-    out = out.transpose(4, 2, 0, 3, 1).reshape(2 * num_nodes, bf, hi * lo)
-    return out[:, :, :num_bins].reshape(2 * num_nodes, bf * num_bins)
+    # [block, F, lo, (node, hi, c)] -> [(c, block, node), (F, hi, lo)]; the
+    # short last block's slots past num_nodes and the bins past num_bins
+    # (no row has either) dropped
+    out = out[..., :2 * block_nodes * hi].reshape(
+        node_blocks, bf, lo, block_nodes, hi, 2)
+    out = out.transpose(5, 0, 3, 1, 4, 2).reshape(
+        2, node_blocks * block_nodes, bf, hi * lo)
+    return out[:, :num_nodes, :, :num_bins].reshape(2 * num_nodes,
+                                                    bf * num_bins)
 
 
 def grad_hist_pallas(bins, node_ids, grad, hess, num_nodes: int,
@@ -366,24 +393,19 @@ def grad_hist_pallas(bins, node_ids, grad, hess, num_nodes: int,
     [num_nodes, F, num_bins] float32.  Rows with out-of-range (e.g.
     negative) node ids contribute nothing.
 
-    Levels too wide or deep for one resident accumulator run blocked (see
-    :func:`hist_block_plan`): feature blocks are grid steps of one kernel
-    call; node blocks are calls of their own, and shifting node ids by the
-    block base makes the kernel's own out-of-range drop do the partitioning.
+    ONE ``hist_level`` call for any width and depth: a level too wide or
+    deep for one resident accumulator runs blocked (:func:`hist_block_plan`)
+    and node blocks, like feature blocks, are steps of that call's grid.
     """
     import jax.numpy as jnp
 
     bf = bins.shape[0]
-    block, block_features = _require_block_plan(num_nodes, bf, num_bins)
-    node_ids = node_ids.astype(jnp.int32)
-    parts = [
-        hist_matmul_pallas((node_ids - b0, grad, hess), bins, num_bins,
-                           num_nodes=min(block, num_nodes - b0),
-                           block_features=block_features
-                           ).reshape(2, -1, bf, num_bins)
-        for b0 in range(0, num_nodes, block)
-    ]
-    out = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+    block_nodes, block_features = _require_block_plan(num_nodes, bf,
+                                                      num_bins)
+    out = hist_matmul_pallas(
+        (node_ids.astype(jnp.int32), grad, hess), bins, num_bins,
+        num_nodes=num_nodes, block_features=block_features,
+        block_nodes=block_nodes).reshape(2, num_nodes, bf, num_bins)
     return out[0], out[1]
 
 
@@ -429,11 +451,13 @@ def hist_kernel_plan(model_axis, num_feature: int, max_depth: int,
       be a whole number of tiles under shard_map);
     - what ``gbdt.fit.dispatch`` records: ``built_nodes``, the node slots
       each level's call builds from the root (one child of every pair
-      below it, ``histogram.hist_built_nodes``), ``node_blocks`` (kernel
-      calls at the deepest level, for the slots it builds) and
-      ``feature_blocks`` (grid steps over features inside each) of one
-      chip's ``F/mp`` slice, and ``bin_split``, the :func:`hist_split_plan`
-      ``HxL`` of every level's call.
+      below it, ``histogram.hist_built_nodes``), ``level_node_blocks``
+      (the grid steps over nodes of each level's one call, from which
+      the span's ``node_blocks`` is the deepest level's) and
+      ``feature_blocks`` (grid steps over features inside each node
+      block) of one chip's ``F/mp`` slice, and
+      ``bin_split``, the :func:`hist_split_plan` ``HxL`` of every level's
+      call, that of a node block's slots where the level has several.
 
     A mesh the kernel cannot be shard_mapped over raises a ``ValueError``
     that names the condition and the remedy — nothing falls back: a
@@ -470,14 +494,15 @@ def hist_kernel_plan(model_axis, num_feature: int, max_depth: int,
 
     local = num_feature // mp
     built = hist_built_nodes(max_depth)
-    nodes, feats = _require_block_plan(built[-1], local, num_bins)
-    splits = (hist_split_plan(
-        min(n, hist_block_plan(n, local, num_bins)[0]), num_bins)
-        for n in built)
+    # a level's block: all its slots, or a power of two under them
+    plans = [_require_block_plan(n, local, num_bins) for n in built]
+    feats = plans[-1][1]
+    splits = (hist_split_plan(slots, num_bins) for slots, _ in plans)
+    steps = [-(-n // slots) for n, (slots, _) in zip(built, plans)]
     sharded = model_axis is not None or dp > 1
     return {"mesh": mesh if sharded else None,
             "row_multiple": BLOCK_ROWS * dp,
-            "node_blocks": -(-built[-1] // nodes),
+            "level_node_blocks": ",".join(map(str, steps)),
             "feature_blocks": -(-local // feats),
             "bin_split": ",".join(f"{hi}x{lo}" for hi, lo in splits),
             "built_nodes": ",".join(map(str, built))}

@@ -99,9 +99,9 @@ class HistPlan(NamedTuple):
     model_axis: Optional[str] = None  # the histogram's feature dim splits here
     mesh: Any = None                  # shard_map the kernel over it, or None
     row_multiple: int = 1             # rows a fit pads to, once
-    # the kernel's shape as ``gbdt.fit.dispatch`` records it; 0, 0 and ""
+    # the kernel's shape as ``gbdt.fit.dispatch`` records it; "", 0 and ""
     # for a method that is no kernel (``hist_pallas.hist_kernel_plan``)
-    node_blocks: int = 0
+    level_node_blocks: str = ""
     feature_blocks: int = 0
     bin_split: str = ""
     # :func:`hist_built_nodes` of the fit, whatever the method
@@ -109,8 +109,11 @@ class HistPlan(NamedTuple):
 
     def blocks(self) -> dict:
         """The kernel's shape and the node slots each level builds, as the
-        ``gbdt.fit.dispatch`` span records them beside the method."""
-        return {"node_blocks": self.node_blocks,
+        ``gbdt.fit.dispatch`` span records them beside the method;
+        ``node_blocks`` is the deepest level's of ``level_node_blocks``."""
+        steps = self.level_node_blocks
+        return {"node_blocks": int(steps.split(",")[-1]) if steps else 0,
+                "level_node_blocks": steps,
                 "feature_blocks": self.feature_blocks,
                 "bin_split": self.bin_split,
                 "built_nodes": self.built_nodes}

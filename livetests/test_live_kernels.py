@@ -60,14 +60,20 @@ def test_grad_hist_matches_scatter_on_chip(jx):
                                rtol=2e-2, atol=6e-2)
 
 
-def test_node_blocked_deep_level_on_chip(jx):
-    """Deep levels whose accumulator overflows VMEM run in node blocks."""
+@pytest.mark.parametrize("F,NN,plan", [
+    (28, 512, (128, 28)),     # 4 node blocks, one feature block
+    (200, 64, (32, 128)),     # 2 x 2 blocks, the last feature block short:
+                              # the last level of a depth-8 fit, 2x128 split
+    (200, 80, (32, 128)),     # a short last node block (16 of 32 slots)
+])
+def test_node_blocked_deep_level_on_chip(jx, F, NN, plan):
+    """Deep levels whose accumulator overflows VMEM run in node blocks:
+    steps on the outermost axis of the one ``hist_level`` call's grid."""
     from dmlc_core_tpu.ops import hist_pallas
 
-    NB, F, NN = 256, 28, 512   # 512 nodes x 28 feat x 256 bins > VMEM budget
-    block, features = hist_pallas.hist_block_plan(NN, F, NB)
-    assert block < NN and features == F
-    bins, node_ids, grad, hess = _rand_problem(rows=2048, F=F, NB=NB,
+    NB = 256
+    assert hist_pallas.hist_block_plan(NN, F, NB) == plan
+    bins, node_ids, grad, hess = _rand_problem(rows=5000, F=F, NB=NB,
                                                num_nodes=NN, seed=1)
     g, h = hist_pallas.grad_hist_pallas(bins.T, node_ids, grad, hess,
                                         num_nodes=NN, num_bins=NB)

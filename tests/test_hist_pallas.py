@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from dmlc_core_tpu.ops import hist_pallas
-from dmlc_core_tpu.ops.histogram import grad_histogram
+from dmlc_core_tpu.ops.histogram import grad_histogram, hist_plan
 
 
 @pytest.fixture(autouse=True)
@@ -121,8 +121,6 @@ def test_grad_histogram_is_the_plans_histogram_of_its_own_layout(method):
     """Row-major bins in (the contract; the benchmark's check calls it so
     on numpy uint8 bins): the histogram a fit gets from the layout its plan
     keeps, which for the kernel is ``[F, rows]`` int32."""
-    from dmlc_core_tpu.ops.histogram import hist_plan
-
     bins, node, g, h = _rand_case(1500, 5, 256, 4, seed=61)
     narrow = bins.astype(np.uint8)               # 255 must not wrap
     row_major = grad_histogram(narrow, node, g, h, num_nodes=4,
@@ -623,11 +621,11 @@ def test_feature_blocks_side_by_side_are_the_unblocked_result(
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_one_grid_for_every_width():
-    """One kernel program for every table: a grid of (feature blocks, row
-    tiles) in one call; a table whose features fit one block is the case
-    of one block, whose output block never moves and keeps the default
-    buffering."""
+def test_one_grid_for_every_width_and_depth():
+    """One kernel program for every table and level: a grid of (node
+    blocks, feature blocks, row tiles) in one call; a level whose slots and
+    features fit one block is the case of one block, whose output block
+    never moves and keeps the default buffering."""
     import jax
     import jax.numpy as jnp
 
@@ -635,17 +633,80 @@ def test_one_grid_for_every_width():
     row = jnp.zeros((rows,), jnp.float32)
     bins = jnp.zeros((300, rows), jnp.int32)
 
-    def calls(block_features):
+    def calls(block_features, num_nodes=4, block_nodes=None):
         jaxpr = jax.make_jaxpr(lambda n, g, h, b: hist_pallas.hist_matmul_pallas(
-            (n, g, h), b, 8, num_nodes=4,
+            (n, g, h), b, 8, num_nodes=num_nodes, block_nodes=block_nodes,
             block_features=block_features))(row.astype(jnp.int32), row, row,
                                             bins)
         return [(m.grid, m.block_mappings[-1].pipeline_mode is not None)
                 for m in (e.params["grid_mapping"] for e in jaxpr.jaxpr.eqns
                           if e.primitive.name == "pallas_call")]
 
-    assert calls(None) == calls(300) == calls(512) == [((1, 2), False)]
-    assert calls(128) == [((3, 2), True)]
+    assert calls(None) == calls(300) == calls(512) == [((1, 1, 2), False)]
+    assert calls(None, block_nodes=4) == calls(None, block_nodes=8) \
+        == [((1, 1, 2), False)]
+    assert calls(128) == [((1, 3, 2), True)]
+    # node blocks are steps of the same call, a short last one included
+    assert calls(None, 20, 8) == [((3, 1, 2), True)]
+    assert calls(128, 64, 32) == [((2, 3, 2), True)]
+
+
+@pytest.mark.parametrize("b,f,nbins,nnodes,block_nodes,block_features", [
+    (2100, 5, 16, 32, 8, None),     # four whole node blocks, two row tiles
+    (700, 3, 256, 20, 8, None),     # a short last node block (4 of 8 slots)
+    (900, 40, 8, 20, 8, 16),        # short last node AND feature block
+    (300, 140, 4, 64, 32, 128),     # the shape of a depth-8 level, small
+])
+def test_node_blocks_are_the_one_block_calls_side_by_side(
+        b, f, nbins, nnodes, block_nodes, block_features):
+    """What the loop over kernel calls did, as grid steps of one call: block
+    k is, bit for bit, the one-block call of ``block_nodes`` slots on node
+    ids shifted by ``k * block_nodes`` (same split of the bin index, same
+    tiles, same dots, same order over the rows).  A short last block's
+    spare slots catch the rows whose id is just past ``nnodes`` and are
+    dropped with them: such a row adds nothing, as under one block."""
+    bins, node, g, h = _rand_case(b, f, nbins, nnodes, seed=61)
+    node[::7] = -1
+    node[3::11] = nnodes + 2
+    bins_fm = np.ascontiguousarray(bins.T)
+    blocked = np.asarray(hist_pallas.hist_matmul_pallas(
+        (node, g, h), bins_fm, nbins, num_nodes=nnodes,
+        block_nodes=block_nodes, block_features=block_features))
+    assert blocked.shape == (2 * nnodes, f * nbins)
+    parts = [np.asarray(hist_pallas.hist_matmul_pallas(
+        (node - b0, g, h), bins_fm, nbins, num_nodes=block_nodes,
+        block_features=block_features)).reshape(2, block_nodes, f * nbins)
+        for b0 in range(0, nnodes, block_nodes)]
+    whole = np.concatenate(parts, axis=1)
+    np.testing.assert_array_equal(
+        blocked, whole[:, :nnodes].reshape(2 * nnodes, f * nbins))
+    Gr, Hr = grad_histogram(bins, node, _bf16(g), _bf16(h), nnodes, nbins,
+                            method="scatter")
+    np.testing.assert_allclose(blocked[:nnodes].reshape(nnodes, f, nbins),
+                               np.asarray(Gr), rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(blocked[nnodes:].reshape(nnodes, f, nbins),
+                               np.asarray(Hr), rtol=1e-5, atol=1e-4)
+
+
+def test_any_node_count_is_one_kernel_call(feature_block_budget):
+    """``grad_hist_pallas`` makes exactly one ``hist_level`` call whatever
+    the blocking: 500 nodes x 260 features run 63 x 3 blocks inside it."""
+    import jax
+    import jax.numpy as jnp
+
+    feature_block_budget(4)
+    rows = hist_pallas.BLOCK_ROWS
+    row = jnp.zeros((rows,), jnp.float32)
+    for nodes, f, grid in ((500, 260, (63, 3, 1)), (20, 260, (3, 3, 1)),
+                           (32, 100, (4, 1, 1)), (8, 100, (1, 1, 1))):
+        jaxpr = jax.make_jaxpr(
+            lambda b, n, g, h: hist_pallas.grad_hist_pallas(
+                b, n, g, h, nodes, 4))(jnp.zeros((f, rows), jnp.int32),
+                                       row.astype(jnp.int32), row, row)
+        calls = [e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+        assert [c.params["name"] for c in calls] == ["hist_level"]
+        assert calls[0].params["grid_mapping"].grid == grid
 
 
 @pytest.mark.parametrize("f,nbins,nnodes,asks", [
@@ -700,13 +761,28 @@ def test_wide_tables_plan_feature_blocks():
     assert hist_pallas.hist_block_plan(32, 28, 256) == (32, 28)
     assert hist_pallas.hist_block_plan(512, 2000, 256) == (32, 128)
     def blocks(num_feature, max_depth):
-        plan = hist_pallas.hist_kernel_plan(None, num_feature, max_depth, 256)
+        # the span's scalar is the deepest level's of ``level_node_blocks``
+        plan = hist_plan("pallas", None, num_feature, max_depth, 256).blocks()
+        assert plan["node_blocks"] == int(
+            plan["level_node_blocks"].split(",")[-1])
         return plan["node_blocks"], plan["feature_blocks"]
 
     assert blocks(2000, 6) == (1, 16)
-    # the deepest level builds 256 of its 512 nodes, 32 a call
+    # the deepest level builds 256 of its 512 nodes, 32 a grid step
     assert blocks(2000, 10) == (8, 16)
     assert blocks(28, 6) == (1, 1)
+    # epsilon at depth 8: the last level builds 64 nodes in two blocks of
+    # 32, each the 2x128 split, inside its one call
+    deep = hist_pallas.hist_kernel_plan(None, 2000, 8, 256)
+    assert deep["level_node_blocks"] == "1,1,1,1,1,1,1,2"
+    assert deep["built_nodes"] == "1,1,2,4,8,16,32,64"
+    assert deep["bin_split"] == \
+        "16x16,16x16,8x32,8x32,4x64,4x64,2x128,2x128"
+    assert hist_pallas.hist_kernel_plan(None, 2000, 10, 256)[
+        "level_node_blocks"] == "1,1,1,1,1,1,1,2,4,8"
+    # a narrow table holds 128 slots a block: depth 8 is not blocked
+    assert hist_pallas.hist_kernel_plan(None, 28, 8, 256)[
+        "level_node_blocks"] == "1,1,1,1,1,1,1,1"
     assert hist_pallas.hist_kernel_plan(None, 2000, 6, 256)["mesh"] is None
     # bins in the tens of thousands: 8 node slots x 128 features overflow
     assert hist_pallas.hist_block_plan(8, 2000, 2 ** 15) is None
@@ -716,10 +792,12 @@ def test_wide_tables_plan_feature_blocks():
                 num_feature=2000)
     assert wide._method() == "pallas"
     assert wide._hist_blocks("pallas") == {
-        "node_blocks": 1, "feature_blocks": 16,
+        "node_blocks": 1, "level_node_blocks": "1,1,1,1,1,1",
+        "feature_blocks": 16,
         "bin_split": "16x16,16x16,8x32,8x32,4x64,4x64",
         "built_nodes": "1,1,2,4,8,16"}
     assert wide._hist_blocks("scatter") == {"node_blocks": 0,
+                                            "level_node_blocks": "",
                                             "feature_blocks": 0,
                                             "bin_split": "",
                                             "built_nodes": "1,1,2,4,8,16"}
@@ -763,6 +841,7 @@ def test_gbdt_wide_fit_matches_scatter_and_the_plain_reference(
     kernel = model("pallas")
     assert kernel._fit_method(bins) == "pallas"
     assert kernel._hist_blocks("pallas") == {"node_blocks": 1,
+                                             "level_node_blocks": "1,1,1",
                                              "feature_blocks": 3,
                                              "bin_split": "1x16,1x16,1x16",
                                              "built_nodes": "1,1,2"}

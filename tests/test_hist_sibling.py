@@ -368,11 +368,12 @@ def test_the_plan_follows_the_nodes_a_fit_builds():
     plan = hist_pallas.hist_kernel_plan(None, 28, 6, 256)
     assert plan["bin_split"] == "16x16,16x16,8x32,8x32,4x64,4x64"
     assert plan["built_nodes"] == "1,1,2,4,8,16"
-    assert plan["node_blocks"] == 1
+    assert plan["level_node_blocks"] == "1,1,1,1,1,1"
+    assert "node_blocks" not in plan      # the span derives it: blocks()
     # scatter builds the same node slots; it has no kernel to shape
     assert hist_plan("scatter", None, 28, 6, 256).blocks() == {
-        "node_blocks": 0, "feature_blocks": 0, "bin_split": "",
-        "built_nodes": "1,1,2,4,8,16"}
+        "node_blocks": 0, "level_node_blocks": "", "feature_blocks": 0,
+        "bin_split": "", "built_nodes": "1,1,2,4,8,16"}
     # a depth-1 fit has no level below the root
     assert hist_plan("scatter", None, 28, 1, 256).built_nodes == "1"
 
@@ -398,6 +399,7 @@ def test_the_dispatch_span_carries_split_and_built_nodes():
     assert args["method"] == "pallas"
     assert args["built_nodes"] == "1,1,2,4,8,16"
     assert args["bin_split"] == "16x16,16x16,8x32,8x32,4x64,4x64"
+    assert args["level_node_blocks"] == "1,1,1,1,1,1"
 
 
 def test_one_kernel_call_a_level_of_half_the_nodes():
