@@ -19,16 +19,27 @@ rows on the lanes, and splits the bin index between them
 
 A call that builds n nodes needs only ``2n * num_bins`` buckets a feature,
 so ``H`` and ``L`` are chosen per call to give the product ``2nH * L`` just
-that many, shared out so that neither side of the MXU waits for the other:
-(16, 16) at one node of a 256-bin fit, (2, 128) at 32 nodes, where the dot
-is a full 128 x 128 tile a feature and 128 rows and runs at the MXU's peak;
-fewer nodes cost a quarter to a half of that, not all of it.  From 128
-nodes ``H = 1``: the plain one-hot matmul, transposed.  A fit's level of n
-nodes builds n / 2 of them here, one child of every pair, and takes the
-siblings as parent - built (``histogram.HistPlan.level``): 1, 1, 2, 4, 8, 16
-node slots at depth 6, whose rows are keyed by their parent's id and all
-other rows by -1, which the body drops; the one-shot ``grad_histogram``
-builds every node it is asked for.
+that many (or the next whole tiles above), shared out so that neither side
+of the MXU waits for the other.  On a v5e the dot costs ``max(2nH, 2L)``
+cycles a 1,024-row tile and feature, per 128 of ``2nH`` (measured at the
+powers of two, PERF.md PR 28, and at every whole-tile neighbour, PR 34: 24
+splits at two shapes on one line, 0.2005 ms a cycle at HIGGS's size), and
+``L`` is any multiple of the bf16 tile's 16 sublanes.  At 256 bins::
+
+    built nodes   1      2      4      8      16     32     64     128
+    (H, L)        16x16  8x32   8x32   6x48   4x64   2x128  2x128  1x256
+    cycles        32     64     64     96     128    256    512    1024
+
+(6, 48) covers 288 pairs for 256 bins: ``2nH = 2L = 96`` where (4, 64) and
+(8, 32) pay 128 for one side.  From 16 nodes up the dot is full 128 x 128
+tiles and runs at the MXU's peak for the task's product; a call of fewer
+nodes costs a quarter to three quarters of the 16-node call, not all of
+it.  From 128 nodes ``H = 1``: the plain one-hot matmul, transposed.  A
+fit's level of n nodes builds n / 2 of them here, one child of every pair,
+and takes the siblings as parent - built (``histogram.HistPlan.level``): 1,
+1, 2, 4, 8, 16 node slots at depth 6, whose rows are keyed by their
+parent's id and all other rows by -1, which the body drops; the one-shot
+``grad_histogram`` builds every node it is asked for.
 
 - grid = (node blocks, feature blocks, row tiles), all sequential on TPU,
   rows innermost;
@@ -37,8 +48,10 @@ builds every node it is asked for.
   block's row tiles (zeroed at the first);
 - per step: DMA the node / g / h row tiles ``[1, TB]`` and the bins tile
   ``[F_blk, TB]`` (feature-major int32: v5e Mosaic lowers no sub-32-bit
-  compare), then for each feature two compare-selects against a sublane
-  iota and one MXU dot.  Both operands are built as int32 words that hold
+  compare), then for each feature the bin's quotient and remainder by the
+  static ``L`` (a shift and a mask, or a multiply and a shift where ``L``
+  is no power of two), two compare-selects against a sublane iota and one
+  MXU dot.  Both operands are built as int32 words that hold
   TWO bf16 rows each (g and h of one key; ones of two neighbouring ``lo``)
   and are bitcast to bf16: half the compares, and no convert;
 - a table whose accumulator fits the VMEM budget is one block; a wider
@@ -67,7 +80,6 @@ a TPU backend a kernel Mosaic rejects raises with the compiler's message.
 from __future__ import annotations
 
 import functools
-import math
 
 __all__ = ["hist_matmul_pallas", "grad_hist_pallas",
            "grad_hist_pallas_sharded",
@@ -131,8 +143,9 @@ def _pad_nodes(num_nodes: int) -> int:
 
 def hist_fits_vmem(num_nodes: int, num_feature: int, num_bins: int) -> bool:
     """Whether a level's resident f32 accumulator fits the VMEM budget,
-    counted as ``[2*n_pad, F*nbins]``: an upper bound of the kernel's
-    ``[F, L, 2nH]`` block wherever ``H * L`` is ``num_bins``."""
+    counted as ``[2*n_pad, F*nbins]``: the kernel's ``[F, L, 2nH]`` block
+    wherever ``H * L`` is ``num_bins`` and ``2nH`` fills its lanes; what
+    Mosaic's tiles add to that, ``hist_matmul_pallas`` asks for."""
     return 2 * _pad_nodes(num_nodes) * num_feature * num_bins * 4 \
         <= _ACC_BYTES_LIMIT
 
@@ -185,20 +198,24 @@ def hist_split_plan(num_nodes: int, num_bins: int):
     of the product and ``lo`` in ``[0, L)`` on the other.  A pure function
     of the two static shapes; what ``gbdt.fit.dispatch`` records per level.
 
-    ``L`` is the power of two at or above ``sqrt(num_nodes * num_bins)``,
-    which makes the node side's ``2nH`` rows about twice the ``L`` of the
-    other: on a v5e the dot costs ``max(2nH, 2L)`` cycles a 1,024-row tile
-    and feature (per 128 of ``2nH``; PERF.md, PR 28), and among the splits
-    that tie on it this one builds the fewest one-hot elements.  At 256
-    bins: (16, 16) at the root, then (8, 32), (8, 32), (4, 64), (4, 64),
-    (2, 128) at 32 nodes, (1, 256) from 128.  Never under 16 (the bf16
-    tile's sublanes) nor past the bins: a table of 16 bins or fewer is not
-    split.  ``H * L >= num_bins``; pairs past ``num_bins`` (255, 257 bins)
-    are columns no row matches.
+    Of every whole-tile ``L`` (the multiples of 16, the bf16 tile's
+    sublanes, up to the power of two at or above ``num_bins``) with ``H =
+    ceil(num_bins / L)``, the one whose dot is cheapest by
+    :func:`_dot_cycles`; ties go to the fewest one-hot words built a lane
+    (``K + L / 2``, ``K`` the node side's :func:`_key_rows`), then to the
+    smaller ``L``.  At 256 bins: (16, 16) at one node, (8, 32) at 2 and 4,
+    (6, 48) at 8, (4, 64) at 16, (2, 128) at 32 and 64, (1, 256) from 128.  A
+    table of 16 bins or fewer is not split.  ``H * L >= num_bins``; pairs
+    past ``num_bins`` (bins 256-287 at (6, 48); 255, 257 bins) are columns
+    no row matches.
     """
     most = 1 << max(4, (num_bins - 1).bit_length())
-    want = math.isqrt(num_nodes * num_bins - 1) + 1
-    lo = min(most, max(16, 1 << (want - 1).bit_length()))
+
+    def cost(lo):
+        keys = _key_rows(num_nodes, -(-num_bins // lo))
+        return _dot_cycles(keys, lo), keys + lo // 2, lo
+
+    lo = min(range(16, most + 1, 16), key=cost)
     return -(-num_bins // lo), lo
 
 
@@ -206,6 +223,36 @@ def _key_rows(num_nodes: int, hi: int) -> int:
     """int32 sublanes of the node-side operand: one per (node, hi) key,
     padded to the int32 tile (8) with keys no row carries."""
     return -(-num_nodes * hi // 8) * 8
+
+
+def _dot_cycles(keys: int, lo: int) -> int:
+    """MXU cycles of one feature's dot a 1,024-row tile, ``LO[L, rows] .
+    A[2K, rows]^T``, as measured on a v5e: ``max(2K, 2L)`` for every 128 of
+    the node side's ``2K`` bf16 rows (PERF.md, PRs 28 and 34)."""
+    return -(-2 * keys // _LANES) * max(min(2 * keys, _LANES), 2 * lo)
+
+
+def _bin_split(hi: int, lo: int):
+    """The body's ``bin -> (hi_i, lo_i)`` by a static ``L``, on an int32
+    array: a shift and a mask where ``L`` is a power of two; otherwise, ``L``
+    being ``16 * m``, the quotient of ``bin >> 4`` by ``m`` as a multiply and
+    a shift, ``(t * mul) >> s == t // m`` on every ``t`` of ``[0, H * m)``
+    (``mul = ceil(2^s / m)``, the least ``s`` whose error stays under one
+    step there; inside int32 for any bin count whose accumulator fits
+    VMEM), and the remainder from it."""
+    if lo & (lo - 1) == 0:
+        shift = lo.bit_length() - 1
+        return lambda b: (b >> shift, b & (lo - 1))
+    m, top = lo // 16, hi * lo // 16 - 1
+    shift = next(s for s in range(m.bit_length(), 31)
+                 if (-(1 << s) % m) * top < 1 << s)
+    mul = -(-(1 << shift) // m)
+
+    def split(b):
+        high = ((b >> 4) * mul) >> shift
+        return high, b - high * lo
+
+    return split
 
 
 # bf16 1.0 in the low / high half of an int32 word
@@ -242,7 +289,7 @@ def _kernel(node_ref, g_ref, h_ref, bins_ref, out_ref, *, num_nodes: int,
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    shift = lo.bit_length() - 1
+    split = _bin_split(hi, lo)
     node = node_ref[:]                                       # [1, TB]
     if node_blocks > 1:
         node = node - pl.program_id(0) * num_nodes
@@ -261,8 +308,8 @@ def _kernel(node_ref, g_ref, h_ref, bins_ref, out_ref, *, num_nodes: int,
 
     def one_feature(f):
         b = bins_ref[pl.ds(f, 1), :]                         # [1, TB]
-        a = jnp.where(key_iota == base + (b >> shift), gh, 0)
-        low = b & (lo - 1)
+        high, low = split(b)
+        a = jnp.where(key_iota == base + high, gh, 0)
         one = jnp.where((low & 1) == 1, _ONE_HIGH, _ONE_LOW)
         lo_hot = jnp.where(lo_iota == (low >> 1), one, 0)
         out_ref[f] += jax.lax.dot_general(

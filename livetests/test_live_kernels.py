@@ -84,18 +84,26 @@ def test_node_blocked_deep_level_on_chip(jx, F, NN, plan):
                                rtol=2e-2, atol=6e-2)
 
 
-@pytest.mark.parametrize("NN", [1, 4, 8, 16, 32, 64])
-def test_every_split_of_the_bin_index_on_chip(jx, NN):
+@pytest.mark.parametrize("NN,NB,split", [
+    (1, 256, (16, 16)), (4, 256, (8, 32)), (8, 256, (6, 48)),
+    (16, 256, (4, 64)), (32, 256, (2, 128)), (64, 256, (2, 128)),
+    (8, 255, (6, 48)), (16, 128, (3, 48)),
+])
+def test_every_split_of_the_bin_index_on_chip(jx, NN, NB, split):
     """Each (H, L) a 256-bin fit runs, rows that are no whole tile, and node
     ids outside the level: the two int32-packed operands and their bitcast
-    to bf16 unfold on the chip as the interpreter says they do."""
+    to bf16 unfold on the chip as the interpreter says they do; so does the
+    quotient by an ``L`` of three tiles (48), with rows in the bins either
+    side of its ``hi`` steps."""
     from dmlc_core_tpu.ops import hist_pallas
     from dmlc_core_tpu.ops.histogram import grad_histogram
 
-    NB = 256
+    assert hist_pallas.hist_split_plan(NN, NB) == split
     bins, node_ids, grad, hess = _rand_problem(rows=5000, F=5, NB=NB,
                                                num_nodes=NN, seed=2 + NN)
     bins[:16, 0] = NB - 1
+    for k, edge in enumerate((47, 48, 95, 96, NB - 17, NB - 16)):
+        bins[16 + 8 * k:24 + 8 * k, 1 + k % 4] = edge
     node_ids[::9] = -1
     node_ids[4::13] = NN + 3
     g, h = grad_histogram(bins.astype(np.uint8), node_ids, grad, hess,
@@ -123,7 +131,7 @@ def test_the_six_built_half_calls_of_a_depth_6_fit_on_chip(jx):
     hess = np.abs(rng.randn(rows)).astype(np.float32)
     plan = hist_plan("pallas", None, F, depth, NB, rows=rows)
     assert plan.built_nodes == "1,1,2,4,8,16"
-    assert plan.bin_split == "16x16,16x16,8x32,8x32,4x64,4x64"
+    assert plan.bin_split == "16x16,16x16,8x32,8x32,6x48,4x64"
     hist_bins, _ = plan.layouts(bins.astype(np.uint8))
     node = np.zeros(rows, np.int32)
     keys, parent, built_right = node, None, None

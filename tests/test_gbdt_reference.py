@@ -158,7 +158,7 @@ def test_kernel_fit_is_within_the_tolerance_of_the_reference(name,
         blocks = model._hist_blocks("pallas")
         assert blocks["level_node_blocks"] == "1,1,1,1,1,1,1,2"
         assert (blocks["node_blocks"], blocks["feature_blocks"]) == (2, 2)
-        assert blocks["bin_split"].endswith("4x64,2x128,2x128")
+        assert blocks["bin_split"].endswith("6x48,4x64,2x128,2x128")
     _, margin = model.fit_binned(bins, label)
     _, ref_margin = _reference(name, bins, label)
     margin = np.asarray(margin)

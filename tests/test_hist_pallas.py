@@ -59,7 +59,7 @@ def _assert_hist_of_rounded(got, bins, node, g, h, nnodes, nbins):
 def test_split_plan_covers_the_bins_on_whole_tiles(nnodes, nbins):
     hi, lo = hist_pallas.hist_split_plan(nnodes, nbins)
     assert hi * lo >= nbins and (hi - 1) * lo < nbins
-    assert lo >= 16 and lo & (lo - 1) == 0      # a power of two of bf16 tiles
+    assert lo >= 16 and lo % 16 == 0            # whole bf16 tiles
     # the node side's bf16 rows: whole 16-sublane tiles, every key inside
     rows = 2 * hist_pallas._key_rows(nnodes, hi)
     assert rows % 16 == 0 and rows >= 2 * nnodes * hi
@@ -71,14 +71,16 @@ def test_split_plan_at_256_bins():
     plan = {n: hist_pallas.hist_split_plan(n, 256) for n in PLAN_256}
     assert plan == PLAN_256
     for n, (hi, lo) in plan.items():
-        # the two sides of the dot balanced: 2nH within a factor two of 2L
-        assert n >= 128 or lo <= 2 * n * hi <= 4 * lo
+        # the two sides of the dot balanced up to 128 rows of the node's
+        # side (one pass of the MXU): 2nH between L and 2L, equal at 8
+        assert n >= 64 or lo <= 2 * n * hi <= 2 * lo
+        assert n != 8 or 2 * n * hi == 2 * lo
     # one kernel call's nodes at every level of a fit, root first: the root,
     # then one child of every pair (the sibling is parent - built); a level
     # cut into node blocks runs a block's plan
     fit = hist_pallas.hist_kernel_plan(None, 28, 6, 256)
     assert fit["built_nodes"] == "1,1,2,4,8,16"
-    assert fit["bin_split"] == "16x16,16x16,8x32,8x32,4x64,4x64" \
+    assert fit["bin_split"] == "16x16,16x16,8x32,8x32,6x48,4x64" \
         == ",".join("%dx%d" % PLAN_256[n] for n in (1, 1, 2, 4, 8, 16))
     assert hist_pallas.hist_kernel_plan(None, 28, 1, 256)[
         "built_nodes"] == "1"
@@ -87,8 +89,36 @@ def test_split_plan_at_256_bins():
         "bin_split"].endswith("1x256,1x256")
 
 
-PLAN_256 = {1: (16, 16), 2: (8, 32), 4: (8, 32), 8: (4, 64), 16: (4, 64),
+PLAN_256 = {1: (16, 16), 2: (8, 32), 4: (8, 32), 8: (6, 48), 16: (4, 64),
             32: (2, 128), 64: (2, 128), 128: (1, 256), 512: (1, 256)}
+
+
+def _assert_exact_split(hi, lo):
+    b = np.arange(hi * lo, dtype=np.int32)
+    high, low = hist_pallas._bin_split(hi, lo)(b)
+    assert high.dtype == low.dtype == np.int32
+    np.testing.assert_array_equal(high, b // lo)
+    np.testing.assert_array_equal(low, b % lo)
+
+
+@pytest.mark.parametrize("nnodes", [1, 2, 3, 4, 8, 12, 16, 32, 64, 128])
+def test_the_bodys_quotient_by_the_plans_lo_is_exact(nnodes):
+    """The body's ``bin -> (hi, lo)`` by every ``L`` the plan returns for 8
+    to 1,024 bins, in the int32 arithmetic the chip does (numpy's wraps the
+    same way), on every bin of ``[0, H * L)``: a multiply and a shift where
+    ``L`` is no power of two."""
+    plans = {hist_pallas.hist_split_plan(nnodes, nbins)
+             for nbins in range(8, 1025)}
+    for hi, lo in plans:
+        _assert_exact_split(hi, lo)
+    # from two nodes up the plan does leave the powers of two
+    assert nnodes == 1 or any(lo & (lo - 1) for _, lo in plans)
+
+
+def test_the_quotient_stays_inside_int32_at_any_bins_that_fit_vmem():
+    # one feature x 8 node slots fits VMEM up to 131,072 bins
+    for hi, lo in ((2731, 48), (1366, 96), (745, 176), (8, 16368)):
+        _assert_exact_split(hi, lo)
 
 
 # -- the body against scatter -------------------------------------------------
@@ -107,6 +137,35 @@ def test_every_level_shape_matches_scatter(nnodes, nbins):
     keep = (node >= 0) & (node < nnodes)
     _assert_hist_of_rounded(got, bins[keep], node[keep], g[keep], h[keep],
                             nnodes, nbins)
+
+
+@pytest.mark.parametrize("nnodes,nbins,split", [
+    (8, 255, (6, 48)), (8, 256, (6, 48)), (8, 257, (6, 48)),
+    (16, 128, (3, 48)), (4, 257, (9, 32)), (2, 1024, (22, 48)),
+])
+def test_a_split_by_no_power_of_two_at_its_edges(nnodes, nbins, split):
+    """``L = 48``: rows in the bins either side of every ``hi`` step (47 |
+    48, 239 | 240: ``hi`` 4 | 5, ``lo`` 47 | 0) and in the last bins (255:
+    ``hi`` 5, ``lo`` 15), whose pairs past ``num_bins`` (up to 287) no row
+    has.  With h = 1 the hessian histogram is the rows' count, exact."""
+    assert hist_pallas.hist_split_plan(nnodes, nbins) == split
+    hi, lo = split
+    b, f = 1500, 3
+    bins, node, g, h = _rand_case(b, f, nbins, nnodes, seed=nbins)
+    edges = [e for k in range(1, hi) for e in (k * lo - 1, k * lo)
+             if e < nbins] + [nbins - 1, nbins - 2, 0]
+    for k, e in enumerate(edges):
+        bins[5 * k:5 * k + 5, k % f] = e
+    node[::7] = -1
+    h[:] = 1.0
+    got = _kernel_hist(bins, node, g, h, nnodes, nbins)
+    keep = node >= 0
+    _assert_hist_of_rounded(got, bins[keep], node[keep], g[keep], h[keep],
+                            nnodes, nbins)
+    count = np.zeros((nnodes, f, nbins), np.float32)
+    for j in range(f):
+        np.add.at(count, (node[keep], j, bins[keep, j]), 1.0)
+    np.testing.assert_array_equal(np.asarray(got[1]), count)
 
 
 @pytest.mark.parametrize("f", [1, 13, 28])
@@ -777,7 +836,7 @@ def test_wide_tables_plan_feature_blocks():
     assert deep["level_node_blocks"] == "1,1,1,1,1,1,1,2"
     assert deep["built_nodes"] == "1,1,2,4,8,16,32,64"
     assert deep["bin_split"] == \
-        "16x16,16x16,8x32,8x32,4x64,4x64,2x128,2x128"
+        "16x16,16x16,8x32,8x32,6x48,4x64,2x128,2x128"
     assert hist_pallas.hist_kernel_plan(None, 2000, 10, 256)[
         "level_node_blocks"] == "1,1,1,1,1,1,1,2,4,8"
     # a narrow table holds 128 slots a block: depth 8 is not blocked
@@ -794,7 +853,7 @@ def test_wide_tables_plan_feature_blocks():
     assert wide._hist_blocks("pallas") == {
         "node_blocks": 1, "level_node_blocks": "1,1,1,1,1,1",
         "feature_blocks": 16,
-        "bin_split": "16x16,16x16,8x32,8x32,4x64,4x64",
+        "bin_split": "16x16,16x16,8x32,8x32,6x48,4x64",
         "built_nodes": "1,1,2,4,8,16"}
     assert wide._hist_blocks("scatter") == {"node_blocks": 0,
                                             "level_node_blocks": "",

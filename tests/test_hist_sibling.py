@@ -366,7 +366,7 @@ def test_whole_fits_choose_the_splits_of_the_all_nodes_build(
 
 def test_the_plan_follows_the_nodes_a_fit_builds():
     plan = hist_pallas.hist_kernel_plan(None, 28, 6, 256)
-    assert plan["bin_split"] == "16x16,16x16,8x32,8x32,4x64,4x64"
+    assert plan["bin_split"] == "16x16,16x16,8x32,8x32,6x48,4x64"
     assert plan["built_nodes"] == "1,1,2,4,8,16"
     assert plan["level_node_blocks"] == "1,1,1,1,1,1"
     assert "node_blocks" not in plan      # the span derives it: blocks()
@@ -398,7 +398,7 @@ def test_the_dispatch_span_carries_split_and_built_nodes():
             telemetry.enable()
     assert args["method"] == "pallas"
     assert args["built_nodes"] == "1,1,2,4,8,16"
-    assert args["bin_split"] == "16x16,16x16,8x32,8x32,4x64,4x64"
+    assert args["bin_split"] == "16x16,16x16,8x32,8x32,6x48,4x64"
     assert args["level_node_blocks"] == "1,1,1,1,1,1"
 
 
