@@ -10,15 +10,19 @@ sound run (the lower readings), and beside them what each CONTROL reads
 - ``logloss_level_short``: the program one tree level short, against the
   reference; ``logloss_blind_reference``: the program against a reference
   that ignores default directions (configurations with ``handle_missing``);
-- ``band_learned_nothing``: ln 2 against the sample's loss, a whole fit
-  that learned nothing;
+- ``band_learned_nothing``: the loss of the fit's starting margin (the
+  objective's ``learned_nothing``: ln 2 for logistic) against the sample's
+  loss, a whole fit that learned nothing;
 - ``walk_without_directions``: the fit's margins against a walk of its
   trees that sends every absent row right.
 
     python3 benchmarks/chip/controls.py --workload <cell> --seeds 1,2,3
 
 One process for all seeds (set-up is most of a run); one JSON line a seed.
-The benchmark's own runs never call this.
+The loss is the configuration's objective's (``objectives/<objective>.py``).
+``--root <dir>`` reads the manifest, the cell and the configuration from
+another tree (a scratch copy with a cell that is not admitted).  The
+benchmark's own runs never call this.
 """
 
 import argparse
@@ -45,12 +49,13 @@ def rounded(a, dtype):
 def readings(ctx):
     import jax.numpy as jnp
 
-    from benchmarks.chip import datagen
+    from benchmarks.chip import datagen, objectives
     from benchmarks.chip.reference import gbdt_hist, tree_walk
     from benchmarks.chip.traffic import fit
     from dmlc_core_tpu.ops.histogram import grad_histogram
 
     config, spec = ctx.config, ctx.config["check"]
+    objective = objectives.load(config["objective"])
     state = fit.setup(ctx)
     window = fit.window(ctx, state, time.perf_counter())
     ensemble, margin = window["last"]
@@ -70,28 +75,29 @@ def readings(ctx):
         out[name] = fit.hist_excess(low, exact, spec["hist_rtol"])
     del exact, got, low
 
-    m = min(int(spec["sample_rows"]), state["rows"])
-    sb = np.asarray(state["data"][0][:m])
-    sl = np.asarray(state["data"][1][:m])
-    kw = fit.reference_params(config)
+    sb, sl, sx = fit.sample(ctx, state)
+    m = sb.shape[0]
+    kw = dict(fit.reference_params(config), extras=sx)
 
     def program_loss(model):
-        _, sub = model.fit_binned(sb, sl)
-        return gbdt_hist.logloss(np.asarray(sub), sl)
+        _, sub = model.fit_binned(sb, sl, **objective.fit_args(**sx))
+        return objective.loss(np.asarray(sub), sl, **sx)
 
     _, ref = gbdt_hist.boost(sb, sl, state["rounds"],
                              missing=miss is not None, **kw)
-    ref_loss = gbdt_hist.logloss(ref, sl)
+    ref_loss = objective.loss(ref, sl, **sx)
     sub_loss = program_loss(state["model"])
     out["logloss_program"] = abs(sub_loss - ref_loss)
     shallow = fit.make_model({**config, "max_depth": config["max_depth"] - 1},
                              state["rounds"])
     shallow.set_boundaries(state["model"].boundaries)
     out["logloss_level_short"] = abs(program_loss(shallow) - ref_loss)
-    full_loss = gbdt_hist.logloss(np.asarray(margin),
-                                  np.asarray(state["data"][1]))
+    full_loss = objective.loss(
+        np.asarray(margin), np.asarray(state["data"][1]),
+        **{name: np.asarray(rows) for name, rows in state["extras"].items()})
     out["band_program"] = abs(full_loss - sub_loss)
-    out["band_learned_nothing"] = abs(float(np.log(2.0)) - sub_loss)
+    out["band_learned_nothing"] = abs(
+        objective.learned_nothing(sl, config, **sx) - sub_loss)
     out["loss"] = {"sample": sub_loss, "reference": ref_loss,
                    "whole": full_loss}
 
@@ -104,7 +110,7 @@ def readings(ctx):
     if miss is not None:
         _, blind = gbdt_hist.boost(sb, sl, state["rounds"], **kw)
         out["logloss_blind_reference"] = abs(
-            sub_loss - gbdt_hist.logloss(blind, sl))
+            sub_loss - objective.loss(blind, sl, **sx))
         out["walk_without_directions"] = float(np.abs(
             tree_walk.margins(rows64, *trees) - fitted).max())
         out["default_left_splits"] = int(np.asarray(ensemble[3]).sum())
@@ -115,6 +121,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
+    ap.add_argument("--root", default=ROOT)
     args = ap.parse_args(argv)
 
     import jax
@@ -122,8 +129,8 @@ def main(argv=None):
     from benchmarks.chip import harness
     from dmlc_core_tpu.device import init_device
 
-    manifest = harness.load_manifest(ROOT)
-    cell, config = harness.load_cell(manifest, args.workload, ROOT)
+    manifest = harness.load_manifest(args.root)
+    cell, config = harness.load_cell(manifest, args.workload, args.root)
     info = init_device()
     if info.platform != "tpu" or info.count < cell["chips"]:
         print(f"controls of {args.workload} are read on the chip: JAX "

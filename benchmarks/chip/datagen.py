@@ -5,7 +5,10 @@ A configuration's ``data`` block describes its columns (``cardinality``:
 ``[0, k)``) and its teacher (a seeded linear model on the standardised
 columns plus Gaussian noise, as ``bench.make_higgs_like`` and
 ``chip_smoke.data_phase`` draw it; those two are listed in PERF.md for
-deletion).  With ``missing_share`` (``[F]`` floats) entry ``(row, f)`` is
+deletion).  What a label is, given the teacher's margin, is the
+configuration's objective's to say (``objectives/<objective>.py``:
+``latents`` teachers, ``label``); the host generators below stay binary
+logistic.  With ``missing_share`` (``[F]`` floats) entry ``(row, f)`` is
 absent (NaN) with that column's probability, independently, and an absent
 entry adds ``missing_effect x a[f]`` to the teacher's margin where a
 present one adds ``z x w[f]`` (:func:`absent_teacher`): absence carries
@@ -50,9 +53,17 @@ def columns(config):
     return card, mean.astype(np.float32), std.astype(np.float32)
 
 
-def teacher(config, seed):
-    """The seeded linear teacher's weights, ``[F]`` float32."""
-    rng = np.random.default_rng([int(seed), 0x7EAC])
+def _latent_rng(seed, stream, latent):
+    """Teacher ``latent``'s generator: the first is seeded as the only one
+    always was, so a one-teacher objective's data never changes."""
+    return np.random.default_rng([int(seed), stream, latent] if latent
+                                 else [int(seed), stream])
+
+
+def teacher(config, seed, latent=0):
+    """The seeded linear teacher's weights, ``[F]`` float32 (teacher
+    ``latent`` of an objective that draws several)."""
+    rng = _latent_rng(seed, 0x7EAC, latent)
     return rng.standard_normal(config["num_feature"]).astype(np.float32)
 
 
@@ -79,14 +90,14 @@ def reserved_bin(config):
     return None
 
 
-def absent_teacher(config, seed):
+def absent_teacher(config, seed, latent=0):
     """``(add[F] float32, intercept)``: what an absent entry of column
     ``f`` adds to the teacher's margin (``missing_effect x a[f]``, ``a``
     seeded like ``w``), and the constant that takes the mean of those
     additions out again, so that the labels stay balanced whatever the
     seed."""
     share, effect = missing(config)
-    rng = np.random.default_rng([int(seed), 0xAB5E])
+    rng = _latent_rng(seed, 0xAB5E, latent)
     a = (effect * rng.standard_normal(config["num_feature"])).astype(
         np.float32)
     return a, np.float32(-(share.astype(np.float64) * a).sum())
@@ -277,10 +288,13 @@ def bin_on_device(xt, boundaries, missing_bin=None):
 
 
 def device_binned(config, seed, n, boundaries, wire_dtype, sharding=None):
-    """``(bins[n, F] wire dtype, label[n] f32, weight[n] f32)`` generated,
-    binned with the model's ``boundaries`` and cast on the device in ONE
-    jitted call; with ``sharding`` (dim 0 over the mesh's data axis) every
-    chip makes only its own rows.  Absent entries take the id the
+    """``(bins[n, F] wire dtype, label[n] f32, weight[n] f32, extras)``
+    generated, binned with the model's ``boundaries`` and cast on the
+    device in ONE jitted call; with ``sharding`` (dim 0 over the mesh's data
+    axis) every chip makes only its own rows.  The configuration's
+    objective says how many teachers are drawn and what their margins make
+    of a row: its label, and in ``extras`` whatever further per-row arrays
+    it needs (``{}`` where it needs none).  Absent entries take the id the
     configuration's model reserves (:func:`reserved_bin`).  Whatever
     depends on the seed (the key, the teachers, the boundaries) is an
     ARGUMENT of the program, never a constant inside it: every seed then
@@ -288,6 +302,9 @@ def device_binned(config, seed, n, boundaries, wire_dtype, sharding=None):
     import jax
     import jax.numpy as jnp
 
+    from benchmarks.chip import objectives
+
+    objective = objectives.load(config["objective"])
     card, mean, std = columns(config)
     noise = float(config["data"]["label_noise"])
     absent, missing_bin = missing(config), reserved_bin(config)
@@ -295,29 +312,39 @@ def device_binned(config, seed, n, boundaries, wire_dtype, sharding=None):
         raise ValueError("data.missing_share makes NaN entries: the "
                          "configuration's model block needs handle_missing")
 
-    def make(key, w, edges, *absent_args):
+    def make(key, teachers, edges, absent_teachers):
         kx, ke = jax.random.split(key)
         xt = _draw_xt(kx, n, card)
-        terms = (xt - mean[:, None]) / std[:, None] * w[:, None]
-        shift = noise * jax.random.normal(ke, (n,), jnp.float32)
+        z = (xt - mean[:, None]) / std[:, None]
         if absent is not None:
-            add, intercept = absent_args
             gone = _draw_absent(kx, n, absent[0])
-            terms = jnp.where(gone, add[:, None], terms)
             xt = jnp.where(gone, jnp.nan, xt)
-            shift = shift + intercept
-        margin = jnp.sum(terms, axis=0) + shift
+        latent = []
+        for k, w in enumerate(teachers):        # (the first teacher's noise
+            # comes from ``ke`` itself, as the only teacher's always did)
+            terms = z * w[:, None]
+            shift = noise * jax.random.normal(
+                jax.random.fold_in(ke, k) if k else ke, (n,), jnp.float32)
+            if absent is not None:
+                add, intercept = absent_teachers[k]
+                terms = jnp.where(gone, add[:, None], terms)
+                shift = shift + intercept
+            latent.append(jnp.sum(terms, axis=0) + shift)
+        label, extras = objective.label(jnp.stack(latent),
+                                        jax.random.fold_in(key, 4), config)
         bins = bin_on_device(xt, edges, missing_bin).astype(wire_dtype).T
-        return (bins, (margin > 0).astype(jnp.float32),
-                jnp.ones((n,), jnp.float32))
+        return bins, label, jnp.ones((n,), jnp.float32), extras
 
     out_shardings = None
     if sharding is not None:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         rows2d = NamedSharding(sharding.mesh, P(*sharding.spec, None))
-        out_shardings = (rows2d, sharding, sharding)
-    absent_args = () if absent is None else absent_teacher(config, seed)
+        # (every extra is per row: the one sharding covers the whole dict)
+        out_shardings = (rows2d, sharding, sharding, sharding)
+    latents = range(objective.latents(config))
     return jax.jit(make, out_shardings=out_shardings)(
-        _device_key(seed, 2), teacher(config, seed),
-        np.asarray(boundaries, np.float32), *absent_args)
+        _device_key(seed, 2), [teacher(config, seed, k) for k in latents],
+        np.asarray(boundaries, np.float32),
+        [] if absent is None else [absent_teacher(config, seed, k)
+                                   for k in latents])

@@ -5,11 +5,13 @@ plus ``workloads/<name>.json`` (its traffic parameters, naming a traffic
 ``kind``); a configuration is ``configs/<name>.json``; a traffic kind is
 ``traffic/<kind>.py`` (``setup`` / ``window`` / ``check`` /
 ``end_to_end``); a per-layer metric is ``layer_metrics/<metric>.py``
-(``NAME``, ``UNIT``, ``LAYER``, ``MOVES``, ``KINDS``, ``reduce``).  All are
-found by name, so a later PR adds files and manifest entries and edits
-nothing here.  ``run.py`` is the command (it refuses to measure without
-the TPU and the cell's chips); the tests call :func:`run_cell` with tiny
-rehearsal configurations on the CPU.
+(``NAME``, ``UNIT``, ``LAYER``, ``MOVES``, ``KINDS``, ``reduce``); what a
+fit configuration's ``objective`` means is ``objectives/<objective>.py``
+(labels, further per-row arrays, the reference's gradient, the compared
+loss).  All are found by name, so a later PR adds files and manifest
+entries and edits nothing here.  ``run.py`` is the command (it refuses to
+measure without the TPU and the cell's chips); the tests call
+:func:`run_cell` with tiny rehearsal configurations on the CPU.
 """
 
 from __future__ import annotations

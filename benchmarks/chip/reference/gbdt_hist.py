@@ -3,7 +3,9 @@
 An exact ``bincount`` gradient histogram and greedy level-wise split
 finding with the same parameters the program is given (max_depth, num_bins,
 eta, lambda, min_child_weight, objective).  No kernels, no bf16, no
-batching; it imports nothing from ``dmlc_core_tpu``.
+batching; it imports nothing from ``dmlc_core_tpu``.  The objective is
+found by name (``objectives/<objective>.py``): its gradient is the only
+thing of it a round needs.
 
 Split rule (XGBoost ``hist``, as the program documents it): at each level
 every node scores every (feature, threshold) by
@@ -20,11 +22,18 @@ rows left only where that gain is strictly larger (right on ties); the
 reserved bin is never a threshold and the last real one (``num_bins - 2``,
 present against absent) is allowed; rows in the reserved bin follow the
 node's direction.
+
+A margin of ``[n, K]`` (``num_class = K > 1``) grows K trees a round from
+ONE margin snapshot, tree ``k`` from column ``k`` of g and h (XGBoost
+``multi:softprob``: the gradients are taken before any of the round's K
+updates land); a round's tree arrays then lead with the class axis.
 """
 
 from __future__ import annotations
 
 import numpy as np
+
+from benchmarks.chip import objectives
 
 
 def histogram(bins, node, g, h, num_nodes, num_bins):
@@ -44,18 +53,14 @@ def histogram(bins, node, g, h, num_nodes, num_bins):
     return out
 
 
-def grad_hess(margin, label, objective):
-    if objective == "logistic":
-        p = 1.0 / (1.0 + np.exp(-margin))
-        return (p - label).astype(np.float32), (p * (1 - p)).astype(
-            np.float32)
-    return (margin - label).astype(np.float32), np.ones_like(margin)
+def grad_hess(margin, label, objective, **extras):
+    """The named objective's gradient and hessian, float32."""
+    return objectives.load(objective).grad_hess(margin, label, **extras)
 
 
 def logloss(margin, label):
     """Mean binary cross-entropy of logistic margins, float64."""
-    m = margin.astype(np.float64)
-    return float(np.mean(np.logaddexp(0.0, m) - label * m))
+    return objectives.load("logistic").loss(margin, label)
 
 
 def build_tree(bins, g, h, max_depth, num_bins, reg_lambda,
@@ -118,17 +123,31 @@ def build_tree(bins, g, h, max_depth, num_bins, reg_lambda,
 
 def boost(bins, label, rounds, *, max_depth, num_bins, learning_rate,
           reg_lambda, min_child_weight, objective="logistic",
-          base_score=0.0, missing=False):
+          base_score=0.0, missing=False, num_class=1, extras=None):
     """``rounds`` boosting rounds; returns ``(trees, margin)`` where trees
-    is a list of ``(split_feat, split_bin, leaf_value, default_left)``."""
-    margin = np.full(bins.shape[0], base_score, np.float32)
+    is a list of ``(split_feat, split_bin, leaf_value, default_left)``, one
+    entry a round.  With ``num_class = K > 1`` the margin is ``[n, K]`` and
+    every array of an entry is the round's K trees stacked, ``[K, ...]``.
+    ``extras``: the objective's further per-row arrays, by name."""
+    gradient = objectives.load(objective).grad_hess
+    n = bins.shape[0]
+    margin = np.full((n,) if num_class == 1 else (n, num_class), base_score,
+                     np.float32)
     label = label.astype(np.float32)
+
+    def grow(g, h):
+        return build_tree(bins, g, h, max_depth, num_bins, reg_lambda,
+                          min_child_weight, learning_rate, missing)
+
     trees = []
     for _ in range(rounds):
-        g, h = grad_hess(margin, label, objective)
-        sf, sb, leaf, dl, delta = build_tree(
-            bins, g, h, max_depth, num_bins, reg_lambda, min_child_weight,
-            learning_rate, missing)
-        trees.append((sf, sb, leaf, dl))
+        g, h = gradient(margin, label, **(extras or {}))
+        if margin.ndim == 1:
+            *tree, delta = grow(g, h)
+        else:
+            grown = [grow(g[:, k], h[:, k]) for k in range(num_class)]
+            tree = [np.stack(a) for a in list(zip(*grown))[:4]]
+            delta = np.stack([t[4] for t in grown], axis=1)
+        trees.append(tuple(tree))
         margin = margin + delta
     return trees, margin

@@ -25,7 +25,16 @@ def margins(bins, split_feat, split_bin, leaf_value, base_score=0.0,
     With ``default_left`` (``[T, 2**d - 1]`` bool) and ``miss_id`` a row
     whose bin at the node's feature is ``miss_id`` (absent) goes left
     where the node says so; without them it goes right like any row above
-    the threshold, ``miss_id`` being the largest bin."""
+    the threshold, ``miss_id`` being the largest bin.  A stack with a
+    class axis after the tree axis (``[T, K, ...]``, K trees a round) gives
+    ``[n, K]``: column ``k`` is the walk of every round's tree ``k``."""
+    if np.ndim(split_feat) == 3:
+        return np.stack(
+            [margins(bins, split_feat[:, k], split_bin[:, k],
+                     leaf_value[:, k], base_score,
+                     None if default_left is None else default_left[:, k],
+                     miss_id)
+             for k in range(np.shape(split_feat)[1])], axis=1)
     n = bins.shape[0]
     depth = int(np.log2(leaf_value.shape[1]))
     rows = np.arange(n)

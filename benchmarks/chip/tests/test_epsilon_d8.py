@@ -47,7 +47,7 @@ def test_the_configuration_is_epsilon_at_depth_8():
                                         deep["max_depth"], deep["num_bins"])
     assert plan["level_node_blocks"] == "1,1,1,1,1,1,1,2"
     assert plan["feature_blocks"] == 16
-    assert plan["bin_split"].endswith("4x64,4x64,2x128,2x128")
+    assert plan["bin_split"].endswith("6x48,4x64,2x128,2x128")
     # the check's histogram case: 128 nodes, 4 node blocks of the same call
     assert hist_pallas.hist_block_plan(
         2 ** (deep["max_depth"] - 1), deep["num_feature"],

@@ -67,8 +67,8 @@ def test_device_binned_puts_the_absent_share_into_the_reserved_bin():
     cfg = rehearsal.missing_config()
     model = fit.make_model(cfg, 1)
     fit.fit_bins(cfg, 3, model)
-    bins, label, weight = datagen.device_binned(cfg, 3, 40_000,
-                                                model.boundaries, jnp.uint8)
+    bins, label, weight, _ = datagen.device_binned(
+        cfg, 3, 40_000, model.boundaries, jnp.uint8)
     share = (np.asarray(bins) == cfg["num_bins"] - 1).mean(axis=0)
     assert np.abs(share - cfg["data"]["missing_share"]).max() < 0.01
     # the intercept keeps the labels balanced, and absence carries signal:
@@ -150,8 +150,8 @@ def test_reference_sends_absent_rows_right_on_a_tie():
 def _rows(cfg, seed, n):
     model = fit.make_model(cfg, 3)
     fit.fit_bins(cfg, seed, model)
-    bins, label, _ = datagen.device_binned(cfg, seed, n, model.boundaries,
-                                           jnp.uint8)
+    bins, label, _, _ = datagen.device_binned(cfg, seed, n, model.boundaries,
+                                              jnp.uint8)
     return model, np.asarray(bins), np.asarray(label)
 
 

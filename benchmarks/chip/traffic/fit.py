@@ -9,6 +9,11 @@ fit ends in ``block_until_ready``.
 
 ``check`` (outside the window) holds the program to the plain numpy
 reference in ``reference/gbdt_hist.py``; see each check's message.
+
+What the configuration's ``objective`` means (labels, further per-row
+arrays, the reference's gradient, the loss both sides are compared by) is
+``objectives/<objective>.py``'s, found by name; nothing here knows an
+objective.
 """
 
 from __future__ import annotations
@@ -18,7 +23,7 @@ import time
 
 import numpy as np
 
-from benchmarks.chip import datagen, stats
+from benchmarks.chip import datagen, objectives, stats
 from benchmarks.chip.reference import gbdt_hist, tree_walk
 
 
@@ -57,7 +62,8 @@ def make_model(config, rounds):
 
 def reference_params(config):
     """The same parameters as ``reference.gbdt_hist.boost`` takes them."""
-    return {k: config[k] for k in _TOP_LEVEL if k != "hist_method"}
+    return {**{k: config[k] for k in _TOP_LEVEL if k != "hist_method"},
+            "num_class": (config.get("model") or {}).get("num_class", 1)}
 
 
 def fit_bins(config, seed, model):
@@ -65,12 +71,6 @@ def fit_bins(config, seed, model):
     (``GBDT.make_bins``)."""
     model.make_bins(datagen.device_sample(config, seed,
                                           config["bin_sample_rows"]))
-
-
-def _logloss(margin, label):
-    import jax.numpy as jnp
-
-    return float(jnp.mean(jnp.logaddexp(0.0, margin) - label * margin))
 
 
 def setup(ctx):
@@ -89,12 +89,15 @@ def setup(ctx):
     if len(ctx.devices) > 1:
         mesh = make_mesh(dict(config["mesh"]), devices=ctx.devices)
         sharding = data_sharding(mesh)
-    data = datagen.device_binned(config, ctx.seed, rows, model.boundaries,
-                                 wire_dtype(config["num_bins"]), sharding)
-    jax.block_until_ready(data)
+    *data, extras = datagen.device_binned(
+        config, ctx.seed, rows, model.boundaries,
+        wire_dtype(config["num_bins"]), sharding)
+    jax.block_until_ready((data, extras))
     ctx.say(f"{rows} x {config['num_feature']} rows made and binned on the "
             f"device ({data[0].dtype}, {len(ctx.devices)} chip(s))")
-    state = {"model": model, "data": data, "mesh": mesh, "rows": rows,
+    fit_args = objectives.load(config["objective"]).fit_args(**extras)
+    state = {"model": model, "data": tuple(data), "extras": extras,
+             "fit_args": fit_args, "mesh": mesh, "rows": rows,
              "rounds": int(cell["rounds_per_fit"])}
     with _under(mesh):
         state["method"] = model._fit_method(data[0])
@@ -112,7 +115,8 @@ def _under(mesh):
 def _fit(state):
     import jax
 
-    ensemble, margin = state["model"].fit_binned(*state["data"])
+    ensemble, margin = state["model"].fit_binned(*state["data"],
+                                                 **state["fit_args"])
     jax.block_until_ready(margin)
     return ensemble, margin
 
@@ -156,6 +160,19 @@ def hist_case(ctx, state):
     return hb, node, g, h, nodes
 
 
+def sample(ctx, state):
+    """What the loss line compares on: the first ``check.sample_rows`` of
+    the fit's own rows, cut where the objective allows, on the host:
+    ``(bins, label, extras)``."""
+    objective = objectives.load(ctx.config["objective"])
+    m = objective.sample(min(int(ctx.config["check"]["sample_rows"]),
+                             state["rows"]), **state["extras"])
+    bins, label, _ = state["data"]
+    return (np.asarray(bins[:m]), np.asarray(label[:m]),
+            {name: np.asarray(rows[:m])
+             for name, rows in state["extras"].items()})
+
+
 def hist_excess(got, ref, rtol):
     """The least atol at which ``got`` (G, H) would pass against ``ref`` at
     ``rtol``: the worst ``|got - ref| - rtol |ref|`` over every bucket."""
@@ -170,8 +187,10 @@ def check(ctx, state, window):
 
     config, model = ctx.config, state["model"]
     spec = config["check"]
+    objective = objectives.load(config["objective"])
     miss = datagen.reserved_bin(config)
     bins, label, weight = state["data"]
+    extras = state["extras"]
     ensemble, margin = window["last"]
     want = config["expect_hist_method"]
     yield (state["method"] == want,
@@ -200,18 +219,19 @@ def check(ctx, state, window):
 
     # the program's fit of a seeded subsample against the plain reference's
     # fit of the same bins: same parameters, same rounds
-    m = min(int(spec["sample_rows"]), state["rows"])
-    sb, sl = np.asarray(bins[:m]), np.asarray(label[:m])
+    sb, sl, sx = sample(ctx, state)
+    m = sb.shape[0]
     _, ref_margin = gbdt_hist.boost(sb, sl, state["rounds"],
-                                    missing=miss is not None,
+                                    missing=miss is not None, extras=sx,
                                     **reference_params(config))
-    ref_loss = gbdt_hist.logloss(ref_margin, sl)
-    _, sub_margin = model.fit_binned(sb, sl)
-    sub_loss = _logloss(sub_margin, jax.numpy.asarray(sl))
+    ref_loss = objective.loss(ref_margin, sl, **sx)
+    _, sub_margin = model.fit_binned(sb, sl, **objective.fit_args(**sx))
+    sub_loss = objective.loss(sub_margin, jax.numpy.asarray(sl), **sx)
     tol = spec["logloss_tolerance"]
     yield (abs(sub_loss - ref_loss) <= tol,
-           f"train logloss after {state['rounds']} rounds on {m} sampled "
-           f"rows: program {sub_loss:.5f} vs reference {ref_loss:.5f}, "
+           f"train {objective.LOSS} after {state['rounds']} rounds on {m} "
+           f"sampled rows: program {sub_loss:.5f} vs reference "
+           f"{ref_loss:.5f}, "
            f"{abs(sub_loss - ref_loss):.2e} apart (tolerance {tol})")
     # the whole fit: its returned margins are what a plain walk of its own
     # trees gives on the sampled rows, and its loss is the sample's but for
@@ -239,10 +259,10 @@ def check(ctx, state, window):
                f"(at least {least}), and a walk that sends them all right "
                f"differs from the fit's margins by {apart:.2e} (more than "
                f"{spec['margin_atol']})")
-    full_loss = _logloss(margin, label)
+    full_loss = objective.loss(margin, label, **extras)
     band = spec["full_vs_sample_band"]
     yield (abs(full_loss - sub_loss) <= band and np.isfinite(full_loss),
-           f"train logloss of the whole {state['rows']}-row fit "
+           f"train {objective.LOSS} of the whole {state['rows']}-row fit "
            f"{full_loss:.5f}: {abs(full_loss - sub_loss):.5f} from the "
            f"sample's (band {band})")
 

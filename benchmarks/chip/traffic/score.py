@@ -52,9 +52,10 @@ def _published(ctx):
     start = time.perf_counter()
     model = fit.make_model(config, int(serve["trees"]))
     fit.fit_bins(config, ctx.seed, model)
-    data = datagen.device_binned(config, ctx.seed, int(serve["train_rows"]),
-                                 model.boundaries,
-                                 wire_dtype(config["num_bins"]))
+    *data, _ = datagen.device_binned(config, ctx.seed,
+                                     int(serve["train_rows"]),
+                                     model.boundaries,
+                                     wire_dtype(config["num_bins"]))
     ensemble, margin = model.fit_binned(*data)
     jax.block_until_ready(margin)
     manager.save(1, model.serving_state(ensemble), async_=False)
