@@ -103,7 +103,15 @@ class GBDTParam(Parameter):
                                 "reserved bin and each split learns its "
                                 "default direction (XGBoost semantics)")
     objective = field(str, default="logistic",
-                      enum=["logistic", "squared", "softmax"], help="loss")
+                      enum=["logistic", "squared", "softmax", "lambdarank"],
+                      help="loss (lambdarank: LambdaMART over the query "
+                           "groups fit_binned(group=) names)")
+    lambdarank_truncation_level = field(
+        int, default=30, lower=1, upper=128,
+        help="objective=lambdarank: a pair counts when the better-ranked "
+             "of its two rows is among its query's first this many "
+             "(LightGBM lambdarank_truncation_level; at most a pair "
+             "tile's rows)")
     num_class = field(int, default=1, lower=1,
                       help="classes for objective=softmax (K trees/round)")
     hist_method = field(str, default="auto",
@@ -177,13 +185,30 @@ def _table_pick(table, node):
     return jnp.sum(jnp.where(hit, table[:, None], 0), axis=0)
 
 
-def _grad_hess(margin, label, objective: str):
+def _objective_grad(p: "GBDTParam", margin, label, rank_layout=None):
+    """``(g, h)`` of ``p.objective`` at ``margin``, before the row weight:
+    the ONE place every round body reaches an objective through.
+    ``rank_layout`` is what ``lambdarank`` keeps of its group column
+    (:func:`_rank_layout`, once a fit).  The listwise gradient runs under
+    its own scope, ``gbdt.rank``; the per-row ones under
+    ``gbdt.grad_hess``."""
+    import jax
     import jax.numpy as jnp
 
-    if objective == "logistic":
-        p = 1.0 / (1.0 + jnp.exp(-margin))
-        return p - label, p * (1.0 - p)
-    return margin - label, jnp.ones_like(margin)
+    if p.objective == "lambdarank":
+        CHECK(rank_layout is not None,
+              "objective='lambdarank' takes its gradient over query "
+              "groups: train it through fit_binned(group=)")
+        with jax.named_scope("gbdt.rank"):
+            return _lambdarank_grad_hess(margin, rank_layout,
+                                         p.lambdarank_truncation_level)
+    with jax.named_scope("gbdt.grad_hess"):
+        if p.objective == "softmax":
+            return _softmax_grad_hess(margin, label, p.num_class)
+        if p.objective == "logistic":
+            pr = 1.0 / (1.0 + jnp.exp(-margin))
+            return pr - label, pr * (1.0 - pr)
+        return margin - label, jnp.ones_like(margin)
 
 
 def _apply_pos_weight(weight, label, p):
@@ -213,6 +238,329 @@ def _softmax_grad_hess(margin, label, num_class: int):
     onehot = (label.astype(jnp.int32)[:, None]
               == jnp.arange(num_class, dtype=jnp.int32)).astype(jnp.float32)
     return pr - onehot, jnp.maximum(2.0 * pr * (1.0 - pr), 1e-16)
+
+
+# -- lambdarank: the gradient over query groups ------------------------------
+#
+# A row's gradient depends on every row of its query, and queries are
+# skewed (1 to over a thousand rows), so nothing here is laid out by query.
+# A round sorts the rows by (query, margin descending) and then works on
+# that order cut into TILES of ``_RANK_TILE`` consecutive rows: a query's
+# rows are consecutive, so a pair lies inside one tile, or its better-ranked
+# row (one of its query's first ``k``) lies before the tile: those ``k``
+# rows of the ONE query that reaches into a tile from before it are the
+# tile's *carry*.  Every pair is then met once in a dense
+# ``[tile rows, tile rows + k]`` block, the sums onto both ends of a pair
+# are reductions of that block along one axis or the other, and what
+# crosses tiles (the carry's sums, a query's totals) is a scan over the
+# tiles, a few thousand entries long.  No scatter, no per-row gather.
+
+_RANK_TILE = 128      # rows of a pair block (>= the truncation level)
+_RANK_CHUNK = 512     # tiles a step of the pair loop holds at once
+_RANK_SORT_BLOCK = 4096   # rows a pass of _sort_in_spans sorts together
+
+
+def _rank_tiles(rows: int) -> Tuple[int, int]:
+    """``(tiles, tiles a chunk)`` that hold ``rows`` rows: whole chunks."""
+    tiles = -(-rows // _RANK_TILE)
+    chunk = min(tiles, _RANK_CHUNK)
+    return -(-tiles // chunk) * chunk, chunk
+
+
+def _descending_key(x):
+    """An int32 that sorts ASCENDING as float32 ``x`` sorts descending
+    (0.0 and -0.0 as one): the compiler builds a sort's comparator into
+    every stage of its network, and one over integers compiles several
+    times faster than one over floats."""
+    import jax.lax as lax
+    import jax.numpy as jnp
+
+    bits = lax.bitcast_convert_type(jnp.where(x == 0.0, 0.0, -x), jnp.int32)
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
+def _from_descending_key(key):
+    """The float32 ``_descending_key`` was made from: the key carries it
+    whole, so a sort need not move the float beside it."""
+    import jax.lax as lax
+    import jax.numpy as jnp
+
+    return -lax.bitcast_convert_type(key ^ ((key >> 31) & 0x7FFFFFFF),
+                                     jnp.float32)
+
+
+def _sort_in_spans(keys, payload=()):
+    """``keys + payload`` sorted by ``keys`` (int32, lexicographic, no two
+    rows equal where their order matters), for rows that are OUT OF ORDER
+    ONLY INSIDE SPANS: a query's rows stay in the query's span whatever
+    their margins.  A sort of the whole array is a network of 231 stages
+    at 2.3M rows, every one holding the comparator, and takes the TPU's
+    compiler a minute and a half; blocks of ``_RANK_SORT_BLOCK`` rows take
+    78.  So: sort every block, then the blocks shifted by half a block,
+    and so on in turn until the rows are in order (checked on the device):
+    a span of up to half a block lies whole inside a block of one of the
+    two passes, longer spans take more passes, rows already in order (round
+    0: every margin equal) none."""
+    import jax.lax as lax
+    import jax.numpy as jnp
+
+    n, block = keys[0].shape[0], _RANK_SORT_BLOCK
+    ops = tuple(keys) + tuple(payload)
+    if n <= block:
+        return lax.sort(ops, num_keys=len(keys), is_stable=False)
+    blocks = -(-n // block)
+    fill = blocks * block + block // 2 - n
+    last = jnp.iinfo(jnp.int32).max            # the filling stays behind
+    ops = tuple(jnp.pad(x, (0, fill), constant_values=last if i < len(keys)
+                        else 0) for i, x in enumerate(ops))
+
+    def out_of_order(state):
+        worse = jnp.zeros((ops[0].shape[0] - 1,), bool)
+        for key in reversed(state[1][:len(keys)]):
+            worse = (key[:-1] > key[1:]) | ((key[:-1] == key[1:]) & worse)
+        return jnp.any(worse)
+
+    def one_pass(state):
+        shifted, rows = state
+        at = jnp.where(shifted, block // 2, 0)
+        cut = [lax.dynamic_slice(x, (at,), (blocks * block,))
+               .reshape(blocks, block) for x in rows]
+        cut = lax.sort(cut, dimension=1, num_keys=len(keys), is_stable=False)
+        return ~shifted, tuple(
+            lax.dynamic_update_slice(x, y.reshape(-1), (at,))
+            for x, y in zip(rows, cut))
+
+    _, ops = lax.while_loop(out_of_order, one_pass, (jnp.bool_(False), ops))
+    return tuple(x[:n] for x in ops)
+
+
+def _linked_sums(a, link, reverse: bool = False):
+    """``x[i] = a[i] + link[i] * x[i - 1]`` along axis 0 (``x[i + 1]`` with
+    ``reverse``), ``link`` boolean, ``a``'s shape or its leading part: sums
+    that run on while the link holds."""
+    import jax.lax as lax
+    import jax.numpy as jnp
+
+    m = jnp.broadcast_to(
+        link.reshape(link.shape + (1,) * (a.ndim - link.ndim)),
+        a.shape).astype(a.dtype)
+
+    def combine(first, then):
+        return first[0] * then[0], then[1] + then[0] * first[1]
+
+    return lax.associative_scan(combine, (m, a), reverse=reverse)[1]
+
+
+def _query_sums(x, query):
+    """The sum of ``x`` over each row's whole query, at every row.  ``x``
+    and ``query`` are ``[tiles, _RANK_TILE]`` in row order, a query's rows
+    consecutive.  Inside a tile rows of one query find each other by
+    comparison; a query that runs over a tile's edge takes the rest from
+    the tiles before and after, linked while they hold that query alone."""
+    import jax.numpy as jnp
+
+    same = query[:, :, None] == query[:, None, :]
+    local = jnp.sum(jnp.where(same, x[:, None, :], 0.0), axis=-1)
+    first, last = query[:, 0], query[:, -1]
+    whole = first == last
+    runs_on = first[1:] == last[:-1]       # tile i + 1 opens in i's last query
+    none, zero = jnp.zeros((1,), bool), jnp.zeros((1,), x.dtype)
+    before = _linked_sums(
+        jnp.concatenate([zero, jnp.where(runs_on, local[:-1, -1], 0.0)]),
+        jnp.concatenate([none, runs_on & whole[:-1]]))
+    after = _linked_sums(
+        jnp.concatenate([jnp.where(runs_on, local[1:, 0], 0.0), zero]),
+        jnp.concatenate([runs_on & whole[1:], none]), reverse=True)
+    return (local + jnp.where(query == first[:, None], before[:, None], 0.0)
+            + jnp.where(query == last[:, None], after[:, None], 0.0))
+
+
+def _rank_layout(label, group, k: int):
+    """What ``lambdarank`` needs of the labels and the group column alone,
+    once a fit: every array ``[tiles, _RANK_TILE]`` over the rows in the
+    order ``_lambdarank_grad_hess`` sorts them into (a query keeps its
+    span, so its start, its ranks' discounts and its ``1 / maxDCG`` stay
+    where they are whatever the margins).  ``group`` holds the real rows'
+    ids, a query's rows adjacent; ``label`` may be longer (the fit's row
+    padding), and every row beyond ``group`` is a query of its own, as are
+    the rows that fill the last tile: they form no pair."""
+    import jax.lax as lax
+    import jax.numpy as jnp
+
+    CHECK(k <= _RANK_TILE, f"lambdarank_truncation_level {k} is over a "
+                           f"pair tile's {_RANK_TILE} rows")
+    n, B = group.shape[0], label.shape[0]
+    tiles, _ = _rank_tiles(B)
+    rows = tiles * _RANK_TILE
+    at = jnp.arange(rows, dtype=jnp.int32)
+    ids = jnp.pad(group.astype(jnp.int32), (0, rows - n))
+    opens = ((ids != jnp.roll(ids, 1)) | (at >= n) | (at == 0))
+    # queries numbered in row order: ascending, so a sort by (query,
+    # margin) leaves every query where it is
+    query = jnp.cumsum(opens.astype(jnp.int32)) - 1
+    start = lax.cummax(jnp.where(opens, at, 0))
+    rank = at - start
+    discount = 1.0 / jnp.log2(2.0 + rank.astype(jnp.float32))
+    grade = jnp.pad(label.astype(jnp.float32), (0, rows - B))
+    # maxDCG: the query's grades in descending order under the same
+    # discounts, the first k of them
+    _, _, ideal = _sort_in_spans((query, _descending_key(grade)), (grade,))
+    dcg = jnp.where(rank < k, (jnp.exp2(ideal) - 1.0) * discount, 0.0)
+    shape = (tiles, _RANK_TILE)
+    query = query.reshape(shape)
+    max_dcg = _query_sums(dcg.reshape(shape), query)
+    # the carry of tile i: the first k rows of the query its first row
+    # belongs to, those of them that lie before the tile.  Where they lie
+    # never changes, so what a round does to fetch them is settled here: a
+    # tile hands on its own rows of rank t of its LAST query (``hands``),
+    # and, where it holds that query alone, what it was handed itself
+    # (``passes``); the next tile takes them if it opens in that query
+    rank = rank.reshape(shape)
+    hands = ((rank[:, None, :] == jnp.arange(k, dtype=jnp.int32)[:, None])
+             & (query == query[:, -1:])[:, None, :])          # [tiles, k, T]
+    taken = query[1:, 0] == query[:-1, -1]
+    whole = query[:-1, 0] == query[:-1, -1]
+    handed = jnp.any(hands, axis=-1)[:-1]
+    layout = {"query": query, "grade": grade, "rank": rank,
+              "discount": discount.reshape(shape),
+              "inv_max_dcg": jnp.where(max_dcg > 0.0, 1.0 / max_dcg, 0.0),
+              "hands": hands, "takes": taken,
+              "passes": (taken & whole)[:, None] & ~handed}
+    layout["carry_has"] = _rank_carry(jnp.ones(shape, jnp.float32),
+                                      layout) > 0.0
+    return layout
+
+
+def _rank_carry(x, layout):
+    """``[tiles, k]``: for every tile, ``x`` at the first ``k`` rows of the
+    query that reaches into it from before, 0 where a row of that rank
+    lies in the tile itself or in none (``x`` ``[tiles, _RANK_TILE]`` in
+    sorted order).  A scan over the tiles, no gather: a gather of 17.7k
+    slices ran as a loop of as many steps, 27 ms a round at 2.27M rows."""
+    import jax.numpy as jnp
+
+    own = jnp.sum(jnp.where(layout["hands"], x[:, None, :], 0), axis=-1)
+    given = jnp.where(layout["takes"][:, None], own[:-1], 0)
+    none = jnp.zeros((1,) + own.shape[1:], own.dtype)
+    return _linked_sums(
+        jnp.concatenate([none, given]),
+        jnp.concatenate([none.astype(bool), layout["passes"]]))
+
+
+def _rank_pairs(s_a, y_a, d_a, s_b, y_b, d_b, inv_b, spread_b, pair):
+    """The equations of one pair, over any block of pairs ``(a, b)`` that
+    broadcasts: ``a`` the better-ranked row.  Returns ``(lam as b's
+    gradient takes it, w, lam)``, zero where ``pair`` is False; ``a``'s
+    gradient takes the first negated."""
+    import jax.numpy as jnp
+
+    a_is_hi = y_a > y_b
+    ds = jnp.where(a_is_hi, s_a - s_b, s_b - s_a)
+    dn = (jnp.abs((jnp.exp2(y_a) - 1.0) - (jnp.exp2(y_b) - 1.0))
+          * jnp.abs(d_a - d_b) * inv_b)
+    dn = jnp.where(spread_b, dn / (0.01 + jnp.abs(ds)), dn)
+    rho = 1.0 / (1.0 + jnp.exp(ds))
+    lam = jnp.where(pair, rho * dn, 0.0)
+    return jnp.where(a_is_hi, lam, -lam), lam * (1.0 - rho), lam
+
+
+def _lambdarank_grad_hess(margin, layout, k: int):
+    """LambdaMART's ``(g, h)`` as LightGBM's ``lambdarank`` computes them,
+    float32, before the row weight.  For a query ``q`` with margins ``s``
+    and grades ``y``: ``r`` the 0-based rank by ``s`` descending, ties by
+    ascending row; ``G(y) = 2^y - 1``, ``D(r) = 1 / log2(2 + r)``; for
+    every pair ``r_a < r_b``, ``r_a < k``, ``y_a != y_b``, with ``hi`` the
+    larger grade: ``ds = s_hi - s_lo``, ``dN = (G_hi - G_lo) |D_hi - D_lo|
+    / maxDCG_q``, divided by ``0.01 + |ds|`` where ``q``'s best and worst
+    margins differ; ``rho = 1 / (1 + exp(ds))``; ``g_hi -= rho dN``,
+    ``g_lo += rho dN``, both ``h += rho (1 - rho) dN``, ``S_q += 2 rho
+    dN``; last every ``g`` and ``h`` of ``q`` times ``log2(1 + S_q) /
+    S_q`` where ``S_q > 0``.  Every pair counts, none sampled, whatever
+    the query's size (the layout: the comment above ``_RANK_TILE``)."""
+    import jax
+    import jax.lax as lax
+    import jax.numpy as jnp
+
+    B = margin.shape[0]
+    query = layout["query"]
+    tiles = query.shape[0]
+    rows = tiles * _RANK_TILE
+    _, chunk = _rank_tiles(rows)
+    # rows in (query, margin descending, row ascending) order: no two
+    # keys are equal, and a row moves inside its query's span alone;
+    # ``row`` is what takes the sums back
+    _, s, row, grade = _sort_in_spans(
+        (query.reshape(-1),
+         _descending_key(jnp.pad(margin, (0, rows - B))),
+         jnp.arange(rows, dtype=jnp.int32)), (layout["grade"],))
+    s = _from_descending_key(s)
+    # a query's best and worst margins differ where any two neighbours do
+    steps = (s != jnp.roll(s, 1)) & (layout["rank"].reshape(-1) > 0)
+    s, grade = s.reshape(query.shape), grade.reshape(query.shape)
+    spread = _query_sums(steps.reshape(query.shape).astype(jnp.float32),
+                         query) > 0.0
+
+    first = query[:, :1]
+    carry_d = 1.0 / jnp.log2(2.0 + jnp.arange(k, dtype=jnp.float32))
+
+    def block(t):
+        """The pairs of ``chunk`` tiles: inside each tile ``[a, b]``, and
+        its carry against its rows ``[k, b]``.  Of each of the three sums
+        of :func:`_rank_pairs`: per row what it takes as the pair's ``b``
+        and as its ``a``, per carry row what it takes."""
+        q, r = t["query"], t["rank"]
+        row_b = (t["s"][:, None, :], t["grade"][:, None, :],
+                 t["discount"][:, None, :], t["inv_max_dcg"][:, None, :],
+                 t["spread"][:, None, :])
+        inside = ((q[:, :, None] == q[:, None, :])
+                  & (r[:, :, None] < r[:, None, :]) & (r[:, :, None] < k)
+                  & (t["grade"][:, :, None] != t["grade"][:, None, :]))
+        own = _rank_pairs(t["s"][:, :, None], t["grade"][:, :, None],
+                          t["discount"][:, :, None], *row_b, inside)
+        reaches = (t["carry_has"][:, :, None]
+                   & (q[:, None, :] == t["first"][:, :, None])
+                   & (t["carry_grade"][:, :, None]
+                      != t["grade"][:, None, :]))
+        carried = _rank_pairs(t["carry_s"][:, :, None],
+                              t["carry_grade"][:, :, None],
+                              carry_d[None, :, None], *row_b, reaches)
+        return ([jnp.sum(x, axis=1) + jnp.sum(y, axis=1)
+                 for x, y in zip(own, carried)],
+                [jnp.sum(x, axis=2) for x in own],
+                [jnp.sum(y, axis=2) for y in carried])
+
+    per_tile = {"query": query, "rank": layout["rank"], "s": s,
+                "grade": grade, "discount": layout["discount"],
+                "inv_max_dcg": layout["inv_max_dcg"], "spread": spread,
+                "first": first, "carry_has": layout["carry_has"],
+                "carry_s": _rank_carry(s, layout),
+                "carry_grade": _rank_carry(grade, layout)}
+    as_b, as_a, to_carry = jax.tree_util.tree_map(
+        lambda x: x.reshape((tiles,) + x.shape[2:]),
+        lax.map(block, jax.tree_util.tree_map(
+            lambda x: x.reshape((tiles // chunk, chunk) + x.shape[1:]),
+            per_tile)))
+    # a carry row's sums, over every tile its query reaches into, go back
+    # to the row itself: it lies in the tile before the first of them.
+    # (Three scans, and two in _rank_carry: stacked into one each they ran
+    # 1.0 ms a round SLOWER at 17,920 tiles; PERF.md section 6, PR 36.)
+    runs_on = jnp.concatenate([first[1:, 0] == first[:-1, 0],
+                               jnp.zeros((1,), bool)])
+    opens_next = jnp.concatenate([first[1:, 0] == query[:-1, -1],
+                                  jnp.zeros((1,), bool)])
+
+    def back(x):
+        x = _linked_sums(x, runs_on, reverse=True)
+        x = jnp.where(opens_next[:, None], jnp.roll(x, -1, axis=0), 0.0)
+        return jnp.sum(jnp.where(layout["hands"], x[:, :, None], 0.0), axis=1)
+
+    lam_b, w_b, abs_b = as_b
+    lam_a, w_a, abs_a = (x + back(y) for x, y in zip(as_a, to_carry))
+    total = _query_sums(abs_a + abs_b, query)
+    norm = jnp.where(total > 0.0, jnp.log2(1.0 + total) / total, 1.0)
+    g, h = (lam_b - lam_a) * norm, (w_a + w_b) * norm
+    _, g, h = _sort_in_spans((row,), (g.reshape(-1), h.reshape(-1)))
+    return g[:B], h[:B]
 
 
 def _l1_threshold(G, alpha: float):
@@ -607,8 +955,7 @@ def _softmax_round(p, bins, margin, label, weight, rnd, grow,
     K = p.num_class
     B = margin.shape[0]
     n_rows = B if n_rows is None else n_rows
-    with jax.named_scope("gbdt.grad_hess"):
-        g_all, h_all = _softmax_grad_hess(margin, label, K)
+    g_all, h_all = _objective_grad(p, margin, label)
     trees = []
     for k in range(K):
         with jax.named_scope("gbdt.grad_hess"):
@@ -793,8 +1140,8 @@ class GBDT:
             if p.objective == "softmax":
                 return _softmax_round(p, bins, margin, label, weight, rnd,
                                       grow, F)
+            g, h = _objective_grad(p, margin, label)
             with jax.named_scope("gbdt.grad_hess"):
-                g, h = _grad_hess(margin, label, p.objective)
                 row_w, fmask = _tree_sampling(p, rnd, B, F)
                 if row_w is not None:
                     weight = weight * row_w
@@ -830,7 +1177,8 @@ class GBDT:
         d = p.max_depth
         miss_id = p.num_bins - 1 if p.handle_missing else -1
 
-        def fit(bins, label, weight, ev_bins=None, ev_label=None):
+        def fit(bins, label, weight, ev_bins=None, ev_label=None,
+                group=None):
             import jax.numpy as jnp
 
             n_rows, F = bins.shape
@@ -844,6 +1192,9 @@ class GBDT:
                 if pad:
                     label = jnp.pad(label, (0, pad))
                     weight = jnp.pad(weight, (0, pad))
+                # (the padded rows are queries of their own: no pair)
+                rank_layout = (None if group is None else _rank_layout(
+                    label, group, p.lambdarank_truncation_level))
             bins, bins_fm = plan.layouts(bins, pad)
             B = n_rows + pad
             weight = _apply_pos_weight(weight, label, p)
@@ -861,10 +1212,10 @@ class GBDT:
 
             def round_step(margin, rnd):
                 if K == 1:
+                    g, h = _objective_grad(p, margin, label, rank_layout)
                     with jax.named_scope("gbdt.grad_hess"):
                         row_w, fmask = _row_sampling(p, rnd, n_rows, B, F)
                         w = weight if row_w is None else weight * row_w
-                        g, h = _grad_hess(margin, label, p.objective)
                         g, h = g * w, h * w
                     sf, sb, lv, dl, sg, sc, delta = grow(bins, g, h, rnd,
                                                          fmask)
@@ -946,24 +1297,47 @@ class GBDT:
         return jax.jit(predict)
 
     # -- public API ------------------------------------------------------------
-    def fit_binned(self, bins, label, weight=None) -> Tuple[TreeEnsemble, Any]:
-        """Train on pre-binned features; returns (ensemble, final margin)."""
+    def fit_binned(self, bins, label, weight=None, group=None
+                   ) -> Tuple[TreeEnsemble, Any]:
+        """Train on pre-binned features; returns (ensemble, final margin).
+
+        ``group`` (``objective="lambdarank"`` only, and required there) is
+        a per-row int32 query id, the rows of a query adjacent as a
+        ``qid:`` file has them; the device reads it, the host never does."""
         import jax.numpy as jnp
 
+        p = self.param
         # the host's part of a fit: argument staging and the asynchronous
         # dispatch of the compiled program (it does not wait for the device)
-        with telemetry.span("gbdt.fit.dispatch",
-                            rounds=self.param.num_boost_round) as sp:
-            if self.param.objective == "softmax":
-                _check_softmax_labels(label, self.param.num_class)
+        with telemetry.span("gbdt.fit.dispatch", rounds=p.num_boost_round,
+                            objective=p.objective) as sp:
+            CHECK((group is not None) == (p.objective == "lambdarank"),
+                  f"fit_binned(group=) is objective='lambdarank''s per-row "
+                  f"query id: it needs one, and no other objective takes "
+                  f"one (objective={p.objective!r}, group "
+                  f"{'given' if group is not None else 'missing'})")
+            if p.objective == "softmax":
+                _check_softmax_labels(label, p.num_class)
             weight = (jnp.ones(bins.shape[0], jnp.float32)
                       if weight is None else jnp.asarray(weight))
             bins = jnp.asarray(bins)
             plan = self._fit_plan(bins)
             sp.set(method=plan.method, **plan.blocks())
-            return self._build_fit(self.param.num_boost_round, plan,
+            more = {}
+            if group is not None:
+                CHECK(group.shape == (bins.shape[0],),
+                      f"group has shape {group.shape}, the rows are "
+                      f"{bins.shape[0]}")
+                sp.set(truncation_level=p.lambdarank_truncation_level)
+                more["group"] = jnp.asarray(group, jnp.int32)
+            return self._build_fit(p.num_boost_round, plan,
                                    with_eval=False)(
-                bins, jnp.asarray(label, jnp.float32), weight)
+                bins, jnp.asarray(label, jnp.float32), weight, **more)
+
+    def _refuse_lambdarank(self, entry: str) -> None:
+        CHECK(self.param.objective != "lambdarank",
+              f"{entry} takes no group column: objective='lambdarank' "
+              f"trains through fit_binned(group=) alone")
 
     def boost_round(self, margin, bins, label, weight,
                     round_index: Optional[int] = None):
@@ -973,9 +1347,13 @@ class GBDT:
         scalar: varying it does not recompile).  It is REQUIRED when
         sampling is enabled — otherwise every streamed round would silently
         draw the identical row/feature subset.
+
+        Takes no ``group``: ``objective="lambdarank"`` is refused here by
+        name (its once-a-fit query layout lives in ``fit_binned``).
         """
         import jax.numpy as jnp
 
+        self._refuse_lambdarank("boost_round")
         if round_index is None:
             CHECK(self.param.subsample >= 1.0
                   and self.param.colsample_bytree >= 1.0
@@ -1013,9 +1391,12 @@ class GBDT:
 
         ``ensemble=None`` starts a new ensemble from the base margin (the
         trainer's cold start: same sequence a fresh streaming fit runs).
+
+        Takes no ``group``: ``objective="lambdarank"`` is refused by name.
         """
         import jax.numpy as jnp
 
+        self._refuse_lambdarank("append_rounds")
         CHECK(num_rounds >= 1, "append_rounds needs num_rounds >= 1")
         bins = jnp.asarray(bins)
         label = jnp.asarray(label, jnp.float32)
@@ -1084,7 +1465,7 @@ class GBDT:
         threshold (logistic); int32 [B]."""
         import jax.numpy as jnp
 
-        CHECK(self.param.objective != "squared",
+        CHECK(self.param.objective in ("logistic", "softmax"),
               "predict_class needs a classification objective")
         margin = self.predict_margin(ensemble, bins)
         if self.param.objective == "softmax":
@@ -1124,9 +1505,13 @@ class GBDT:
         on accelerators the flops are cheaper than per-round host syncs).
         ``compiled=False`` keeps the host-driven loop (debugging, or when
         per-round side effects are wanted).
+
+        Takes no ``group`` and has no ranking metric:
+        ``objective="lambdarank"`` is refused by name.
         """
         import jax.numpy as jnp
 
+        self._refuse_lambdarank("fit_with_eval")
         K = (self.param.num_class if self.param.objective == "softmax"
              else 1)
         if K > 1:
@@ -1595,7 +1980,8 @@ class GBDT:
 # serving_state schema: bump when the serve_meta layout changes
 _SERVE_SCHEMA = 1
 _SERVE_META_KEY = "serve_meta"
-_OBJECTIVE_CODES = {"logistic": 0, "squared": 1, "softmax": 2}
+_OBJECTIVE_CODES = {"logistic": 0, "squared": 1, "softmax": 2,
+                    "lambdarank": 3}
 _OBJECTIVE_FROM_CODE = {v: k for k, v in _OBJECTIVE_CODES.items()}
 
 
@@ -1625,6 +2011,9 @@ def _eval_metric_fn(metric: str, objective: str):
     are minimized by early stopping."""
     import jax.numpy as jnp
 
+    CHECK(objective != "lambdarank",
+          "objective='lambdarank' has no in-graph metric (a ranking "
+          "metric needs the group column)")
     if metric == "loss":
         return lambda m, y: _logloss(m, y, objective)
     if metric == "error":

@@ -106,7 +106,8 @@ def test_fit_binned_records_one_dispatch_span_per_call(spans):
         # scatter is no kernel: no blocks of one, but its levels build one
         # child of every pair like the kernel's
         assert found[-1]["args"] == {
-            "rounds": ROUNDS, "method": "scatter", "node_blocks": 0,
+            "rounds": ROUNDS, "objective": "logistic", "method": "scatter",
+            "node_blocks": 0,
             "level_node_blocks": "", "feature_blocks": 0, "bin_split": "",
             "built_nodes": "1,1"}
         assert found[-1]["ph"] == "X" and found[-1]["dur"] > 0
