@@ -1091,14 +1091,16 @@ class GBDT:
         return apply_bins(x, self.boundaries, missing_bin=miss)
 
     # -- compiled round/predict ----------------------------------------------
-    def _plan(self, method: str, *arrays,
-              rows: Optional[int] = None) -> HistPlan:
+    def _plan(self, method: str, *arrays, rows: Optional[int] = None,
+              pads: bool = False) -> HistPlan:
         """The histogram plan (``ops.histogram.hist_plan``) of this model by
         ``method`` under the ambient mesh; ``arrays`` decide ``auto``,
-        ``rows`` is the row count the histogram sees where nothing pads."""
+        ``rows`` is the row count the histogram sees where nothing pads,
+        or the count a caller that ``pads`` starts from."""
         p = self.param
         return hist_plan(method, self.model_axis, self.num_feature,
-                         p.max_depth, p.num_bins, rows=rows, arrays=arrays)
+                         p.max_depth, p.num_bins, rows=rows, arrays=arrays,
+                         pads=pads)
 
     def _method(self, *arrays) -> str:
         return self._plan(self.param.hist_method, *arrays).method
@@ -1106,7 +1108,8 @@ class GBDT:
     def _fit_plan(self, bins) -> HistPlan:
         """The plan of a compiled fit over ``bins``: the fit pads rows to
         the plan's multiple before the histogram sees them."""
-        return self._plan(self.param.hist_method, bins)
+        return self._plan(self.param.hist_method, bins, rows=bins.shape[0],
+                          pads=True)
 
     def _fit_method(self, bins) -> str:
         """The hist method a compiled fit over ``bins`` runs."""

@@ -42,7 +42,11 @@ parent's id and all other rows by -1, which the body drops; the one-shot
 ``grad_histogram`` builds every node it is asked for.
 
 - grid = (node blocks, feature blocks, row tiles), all sequential on TPU,
-  rows innermost;
+  rows innermost.  A step costs 0.13-0.18 us beyond its dot whatever it
+  holds, so the row tile ``TB`` follows the block's features
+  (:func:`hist_row_tile`): a step carries about the row-features of a
+  128-feature block's step of ``BLOCK_ROWS`` rows, 8,192 rows at 28
+  features, 16,384 at 13;
 - the ``[F_blk, L, 2nH]`` f32 accumulator lives in one VMEM output block
   indexed by the node and feature block alone, so it persists across a
   block's row tiles (zeroed at the first);
@@ -85,7 +89,7 @@ __all__ = ["hist_matmul_pallas", "grad_hist_pallas",
            "grad_hist_pallas_sharded",
            "ambient_mesh", "hist_kernel_plan",
            "interpret_mode", "hist_fits_vmem",
-           "hist_block_plan", "hist_split_plan",
+           "hist_block_plan", "hist_split_plan", "hist_row_tile",
            "BLOCK_ROWS", "DATA_AXIS"]
 
 # interpreter mode: runs the kernels on CPU for tests/debugging (flipped by
@@ -115,13 +119,19 @@ def interpret_mode() -> bool:
             "kernels must compile through Mosaic there")
     return True
 
-# row-tile size, a multiple of the 128 lanes; a fit pads its rows to it
-# once (hist_kernel_plan's row_multiple) so the wrapper's own padding
-# no-ops.  A constant since its sweep on a v5e (a HIGGS fit: 1024 +3.2%,
-# 4096 -1.6%; PERF.md, PR 28): a grid step costs about a third of a
-# microsecond whatever it holds, and at 4096 the widest blocked level leaves
-# Mosaic's default VMEM.
+# the base row tile, a multiple of the 128 lanes: the rows of a grid step
+# over a 128-feature block, and of any table too small to fill wider
+# tiles; hist_row_tile widens it for narrower blocks.  At 4096 the widest
+# blocked level leaves Mosaic's default VMEM (PERF.md, PR 28).
 BLOCK_ROWS = 2048
+
+# the widest row tile: at 32,768 rows a 28-feature step's calls of 8 and 16
+# nodes took 3 ms longer than at 16,384 (the sweep, PERF.md, PR 37)
+_ROW_TILE_CAP = 16384
+
+# whole tiles a chip's rows must fill before a tile is widened: a fit pads
+# to the tile (under 1.6% of such rows), and a small table keeps the base
+_ROW_TILES_FILLED = 64
 
 
 # VMEM budget for the resident accumulator block (bytes): what
@@ -177,6 +187,49 @@ def hist_block_plan(num_nodes: int, num_feature: int, num_bins: int):
     if hist_fits_vmem(nodes, num_feature, num_bins):
         return nodes, num_feature
     return nodes, _LANES
+
+
+def hist_row_tile(block_features: int, rows=None) -> int:
+    """Rows of one grid step of a call whose feature block holds
+    ``block_features`` features, over ``rows`` rows a chip.  A pure
+    function of the two static shapes; what ``gbdt.fit.dispatch`` records
+    as ``row_tile``, what a fit pads its rows to, and what
+    :func:`hist_matmul_pallas` cuts them by.
+
+    A grid step costs 0.13-0.18 us beyond its dot, whatever it holds (on a
+    v5e, at 13 and 28 features a step, 1 to 16 built nodes; 0.16 at a
+    128-feature block; PERF.md, PR 37): 0.73-0.98 ms of a 7-27 ms call at
+    HIGGS's 5,372 steps of ``BLOCK_ROWS`` rows.  So a step carries the
+    row-features a 128-feature block's step does, ``128 * BLOCK_ROWS``: the
+    largest ``BLOCK_ROWS * 2^k`` rows that stay under them, and under
+    ``_ROW_TILE_CAP``.  28 features: 8,192; 13: 16,384; 65 and more, and
+    so every blocked table: ``BLOCK_ROWS``.  Measured, ms a call at 1, 2,
+    4, 8, 16 built nodes::
+
+        tile      11,010,048 x 28                 16,777,216 x 13
+        2,048      7.22 13.58 13.73 20.22 26.67    5.83 10.20 10.44 15.08 19.67
+        4,096      6.85 13.21 13.29 19.74 26.19    5.19  9.65  9.77 14.35 18.94
+        8,192      6.67 13.03 13.07 19.50 25.94    4.92  9.37  9.43 13.99 18.56
+        16,384     6.58 12.94 12.96 19.39 25.82    4.78  9.23  9.26 13.81 18.37
+        32,768     6.54 12.90 12.91 22.36 28.89    4.72  9.16  9.18 13.72 18.27
+
+    Never more than the rows fill: a table whose rows, padded to
+    ``BLOCK_ROWS``, are fewer than ``_ROW_TILES_FILLED`` such tiles keeps
+    ``BLOCK_ROWS``, as do rows not yet known (``rows=None``).  Rows padded
+    to the tile this gives are given the same tile again.  A call of so
+    many nodes that its step would leave Mosaic's default VMEM at this tile
+    runs at a half or a quarter of it (:func:`hist_matmul_pallas`: from 64
+    built nodes at 256 bins and 13 or 28 features; no level of a depth-6
+    fit).
+    """
+    if rows is None:
+        return BLOCK_ROWS
+    tile = BLOCK_ROWS
+    while (tile < _ROW_TILE_CAP
+           and block_features * 2 * tile <= _LANES * BLOCK_ROWS):
+        tile *= 2
+    filled = -(-rows // BLOCK_ROWS) * BLOCK_ROWS
+    return tile if filled >= _ROW_TILES_FILLED * tile else BLOCK_ROWS
 
 
 def _require_block_plan(num_nodes: int, num_feature: int, num_bins: int):
@@ -331,7 +384,7 @@ def _kernel(node_ref, g_ref, h_ref, bins_ref, out_ref, *, num_nodes: int,
 
 
 def hist_matmul_pallas(rows, bins, num_bins: int, *, num_nodes: int,
-                       block_rows: int = BLOCK_ROWS, block_features=None,
+                       block_rows=None, block_features=None,
                        block_nodes=None):
     """``out[c*n + k, f*nbins + b] = sum_i [node_i == k] * (g_i, h_i)[c] *
     (bins[f, i] == b)``: the kernel's entry, one ``hist_level`` call.
@@ -341,7 +394,9 @@ def hist_matmul_pallas(rows, bins, num_bins: int, *, num_nodes: int,
         whose id is outside ``[0, num_nodes)`` adds nothing) and f32 g, h.
       bins: [F, B] int32 binned features in [0, num_bins), feature-major.
       num_bins, num_nodes: static.
-      block_rows: row-tile size (B is padded up to a multiple internally).
+      block_rows: row-tile size (B is padded up to a multiple internally);
+        None takes :func:`hist_row_tile` of the feature block and B, halved
+        while a deep level's step would outgrow Mosaic's default VMEM.
       block_features: features per accumulator block (a multiple of 8);
         None or >= F keeps all F in one block.
       block_nodes: node slots per accumulator block; None or >= num_nodes
@@ -359,11 +414,6 @@ def hist_matmul_pallas(rows, bins, num_bins: int, *, num_nodes: int,
 
     node, g, h = (jnp.asarray(r)[None, :] for r in rows)
     bf, b = bins.shape
-    if b % block_rows:
-        pad = ((0, 0), (0, block_rows - b % block_rows))
-        node = jnp.pad(node, pad, constant_values=-1)        # no key
-        g, h, bins = jnp.pad(g, pad), jnp.pad(h, pad), jnp.pad(bins, pad)
-        b += pad[1][1]
     if block_features is None or block_features >= bf:
         block_features = bf
     if block_nodes is None or block_nodes >= num_nodes:
@@ -383,16 +433,33 @@ def hist_matmul_pallas(rows, bins, num_bins: int, *, num_nodes: int,
     blocks = pl.cdiv(bf, block_features)
     moves = node_blocks * blocks > 1
     out_buffering = {"pipeline_mode": pl.Buffered(1)} if moves else {}
-    # VMEM the call holds, as Mosaic tiles it: the accumulator (its minor
-    # extent padded to the lanes: few bins or few nodes leave a tile mostly
-    # empty, which the byte rule of hist_block_plan does not count), the
-    # bins tile twice, the two operands.  Within Mosaic's default for every
-    # blocked shape; an unblocked table of many narrow features asks for
-    # what it needs.
-    vmem = ((1 if moves else 2) * block_features * lo
-            * -(-cols // _LANES) * _LANES * 4
-            + 2 * block_features * block_rows * 4
-            + (cols + lo) * block_rows * 2)
+
+    def held(tile):
+        """VMEM the call holds at a row tile, as Mosaic tiles it: the
+        accumulator (its minor extent padded to the lanes: few bins or few
+        nodes leave a tile mostly empty, which the byte rule of
+        hist_block_plan does not count), the bins tile twice, the two
+        operands.  Within Mosaic's default for every blocked shape; an
+        unblocked table of many narrow features asks for what it needs."""
+        return ((1 if moves else 2) * block_features * lo
+                * -(-cols // _LANES) * _LANES * 4
+                + 2 * block_features * tile * 4 + (cols + lo) * tile * 2)
+
+    if block_rows is None:
+        # the operands grow with a level's key rows: a widened tile is kept
+        # only while the call stays within what Mosaic gives unasked.  Past
+        # it a call took 6-12% longer (64 built nodes at 28 features x
+        # 8,192 rows or 13 x 16,384; 128 at half those; PERF.md, PR 37); a
+        # half or a quarter of the fit's tile still divides its rows
+        block_rows = hist_row_tile(block_features, b)
+        while block_rows > BLOCK_ROWS and held(block_rows) > _VMEM_UNASKED:
+            block_rows //= 2
+    if b % block_rows:
+        pad = ((0, 0), (0, block_rows - b % block_rows))
+        node = jnp.pad(node, pad, constant_values=-1)        # no key
+        g, h, bins = jnp.pad(g, pad), jnp.pad(h, pad), jnp.pad(bins, pad)
+        b += pad[1][1]
+    vmem = held(block_rows)
     params = {}
     if vmem > _VMEM_UNASKED:
         params["compiler_params"] = pltpu.CompilerParams(
@@ -482,7 +549,7 @@ def _data_parallelism(mesh) -> int:
 
 
 def hist_kernel_plan(model_axis, num_feature: int, max_depth: int,
-                     num_bins: int, batch=None) -> dict:
+                     num_bins: int, batch=None, pads: bool = False) -> dict:
     """Settle the kernel of one fit against the ambient mesh, ONCE, before
     tracing: none of it depends on the level.  Returns the kernel's share of
     a :class:`~dmlc_core_tpu.ops.histogram.HistPlan`:
@@ -493,9 +560,14 @@ def hist_kernel_plan(model_axis, num_feature: int, max_depth: int,
       automatically partitioned").  So under a mesh that actually shards —
       a ``model_axis``, or a data axis wider than one device — the kernel
       runs inside shard_map: rows over data, features over model;
+    - ``row_tile``: the rows of a grid step, :func:`hist_row_tile` of one
+      chip's feature block and its share of ``batch`` rows (one tile a fit:
+      a block of 64 features or fewer is the whole table's at any node
+      count; a level of 64 built nodes or more may run at a half or a
+      quarter of it, :func:`hist_matmul_pallas`);
     - ``row_multiple``: rows a fit pads to once so no kernel call pads
-      again, the tile size times the data axis (each data shard must itself
-      be a whole number of tiles under shard_map);
+      again, the tile times the data axis (each data shard must itself be
+      a whole number of tiles under shard_map);
     - what ``gbdt.fit.dispatch`` records: ``built_nodes``, the node slots
       each level's call builds from the root (one child of every pair
       below it, ``histogram.hist_built_nodes``), ``level_node_blocks``
@@ -510,9 +582,11 @@ def hist_kernel_plan(model_axis, num_feature: int, max_depth: int,
     that names the condition and the remedy — nothing falls back: a
     ``model_axis`` that is no axis of an enclosing ``with mesh:``, features
     that do not divide the model axis, ``batch`` rows that do not divide the
-    data axis (``batch=None`` skips that check for callers that pad rows
-    later).  Width and depth never raise, :func:`hist_block_plan` blocks
-    them; only bins in the tens of thousands leave no block that fits.
+    data axis (not checked for a caller that ``pads`` them to
+    ``row_multiple`` itself, a compiled fit, nor where ``batch`` is None:
+    rows not known yet, which get the base tile).  Width and depth never
+    raise, :func:`hist_block_plan` blocks them; only bins in the tens of
+    thousands leave no block that fits.
     """
     mesh = ambient_mesh()
     dp = _data_parallelism(mesh)
@@ -532,7 +606,7 @@ def hist_kernel_plan(model_axis, num_feature: int, max_depth: int,
             f"divide over the {mp} shards of model axis {model_axis!r}; pad "
             f"the features to a multiple of {mp}, or choose a model axis "
             f"that divides them")
-    if batch is not None and batch % dp:
+    if batch is not None and not pads and batch % dp:
         raise ValueError(
             f"hist_method='pallas': {batch} rows do not divide over the "
             f"{dp} shards of the {DATA_AXIS!r} mesh axis; pad rows as "
@@ -547,8 +621,10 @@ def hist_kernel_plan(model_axis, num_feature: int, max_depth: int,
     splits = (hist_split_plan(slots, num_bins) for slots, _ in plans)
     steps = [-(-n // slots) for n, (slots, _) in zip(built, plans)]
     sharded = model_axis is not None or dp > 1
+    tile = hist_row_tile(feats, None if batch is None else -(-batch // dp))
     return {"mesh": mesh if sharded else None,
-            "row_multiple": BLOCK_ROWS * dp,
+            "row_tile": tile,
+            "row_multiple": tile * dp,
             "level_node_blocks": ",".join(map(str, steps)),
             "feature_blocks": -(-local // feats),
             "bin_split": ",".join(f"{hi}x{lo}" for hi, lo in splits),

@@ -99,8 +99,9 @@ class HistPlan(NamedTuple):
     model_axis: Optional[str] = None  # the histogram's feature dim splits here
     mesh: Any = None                  # shard_map the kernel over it, or None
     row_multiple: int = 1             # rows a fit pads to, once
-    # the kernel's shape as ``gbdt.fit.dispatch`` records it; "", 0 and ""
-    # for a method that is no kernel (``hist_pallas.hist_kernel_plan``)
+    # the kernel's shape as ``gbdt.fit.dispatch`` records it; 0, "", 0 and
+    # "" for a method that is no kernel (``hist_pallas.hist_kernel_plan``)
+    row_tile: int = 0
     level_node_blocks: str = ""
     feature_blocks: int = 0
     bin_split: str = ""
@@ -115,6 +116,7 @@ class HistPlan(NamedTuple):
         return {"node_blocks": int(steps.split(",")[-1]) if steps else 0,
                 "level_node_blocks": steps,
                 "feature_blocks": self.feature_blocks,
+                "row_tile": self.row_tile,
                 "bin_split": self.bin_split,
                 "built_nodes": self.built_nodes}
 
@@ -256,14 +258,15 @@ class HistPlan(NamedTuple):
 
 def hist_plan(method: str, model_axis: Optional[str], num_feature: int,
               max_depth: int, num_bins: int, rows: Optional[int] = None,
-              arrays=()) -> HistPlan:
+              arrays=(), pads: bool = False) -> HistPlan:
     """Settle one fit's histograms from what is known before tracing:
     ``auto`` from the platform of ``arrays``
     (:func:`resolve_hist_method`), and for the kernel the ambient mesh, the
-    row padding and the blocking (``hist_pallas.hist_kernel_plan``, which
-    raises where the mesh forbids the kernel).  ``rows`` is the row count
-    the histogram will see (a fit's padded one), None where rows are padded
-    later."""
+    row tile, the row padding and the blocking
+    (``hist_pallas.hist_kernel_plan``, which raises where the mesh forbids
+    the kernel).  ``rows`` is the row count the histogram will see, or with
+    ``pads`` the count a fit has and pads to the plan's ``row_multiple``
+    itself; None where it is not known yet."""
     method = resolve_hist_method(method, *arrays)
     if method != "pallas":
         return HistPlan(method, model_axis, built_nodes=",".join(
@@ -271,7 +274,8 @@ def hist_plan(method: str, model_axis: Optional[str], num_feature: int,
     from dmlc_core_tpu.ops.hist_pallas import hist_kernel_plan
 
     return HistPlan(method, model_axis, **hist_kernel_plan(
-        model_axis, num_feature, max_depth, num_bins, batch=rows))
+        model_axis, num_feature, max_depth, num_bins, batch=rows,
+        pads=pads))
 
 
 def _strictly_increasing(bounds: np.ndarray) -> np.ndarray:

@@ -108,8 +108,8 @@ def test_fit_binned_records_one_dispatch_span_per_call(spans):
         assert found[-1]["args"] == {
             "rounds": ROUNDS, "objective": "logistic", "method": "scatter",
             "node_blocks": 0,
-            "level_node_blocks": "", "feature_blocks": 0, "bin_split": "",
-            "built_nodes": "1,1"}
+            "level_node_blocks": "", "feature_blocks": 0, "row_tile": 0,
+            "bin_split": "", "built_nodes": "1,1"}
         assert found[-1]["ph"] == "X" and found[-1]["dur"] > 0
 
 

@@ -560,7 +560,7 @@ def test_dp_only_mesh_runs_the_kernel_under_shard_map():
     # no mesh: one plain kernel call
     plain = hist_pallas.hist_kernel_plan(None, 8, 3, 16, batch=512)
     assert plain["mesh"] is None
-    assert plain["row_multiple"] == hist_pallas.BLOCK_ROWS
+    assert plain["row_multiple"] == hist_pallas.hist_row_tile(8, 512)
     calls = []
     orig = hist_pallas.grad_hist_pallas_sharded
 
@@ -573,7 +573,8 @@ def test_dp_only_mesh_runs_the_kernel_under_shard_map():
         with mesh:
             sharded = hist_pallas.hist_kernel_plan(None, 8, 3, 16, batch=512)
             assert sharded["mesh"] is mesh
-            assert sharded["row_multiple"] == 8 * hist_pallas.BLOCK_ROWS
+            assert sharded["row_multiple"] == \
+                8 * hist_pallas.hist_row_tile(8, 512 // 8)
             # rows that do not divide the data axis cannot be shard_mapped
             with pytest.raises(ValueError, match="510 rows do not divide"):
                 hist_pallas.hist_kernel_plan(None, 8, 3, 16, batch=510)
@@ -852,12 +853,13 @@ def test_wide_tables_plan_feature_blocks():
     assert wide._method() == "pallas"
     assert wide._hist_blocks("pallas") == {
         "node_blocks": 1, "level_node_blocks": "1,1,1,1,1,1",
-        "feature_blocks": 16,
+        "feature_blocks": 16, "row_tile": hist_pallas.BLOCK_ROWS,
         "bin_split": "16x16,16x16,8x32,8x32,6x48,4x64",
         "built_nodes": "1,1,2,4,8,16"}
     assert wide._hist_blocks("scatter") == {"node_blocks": 0,
                                             "level_node_blocks": "",
                                             "feature_blocks": 0,
+                                            "row_tile": 0,
                                             "bin_split": "",
                                             "built_nodes": "1,1,2,4,8,16"}
     with _mesh_2d():
@@ -902,6 +904,7 @@ def test_gbdt_wide_fit_matches_scatter_and_the_plain_reference(
     assert kernel._hist_blocks("pallas") == {"node_blocks": 1,
                                              "level_node_blocks": "1,1,1",
                                              "feature_blocks": 3,
+                                             "row_tile": 2048,
                                              "bin_split": "1x16,1x16,1x16",
                                              "built_nodes": "1,1,2"}
     ens_p, margin_p = kernel.fit_binned(bins, y)
@@ -955,3 +958,262 @@ def test_sharded_fits_with_feature_blocks_match_the_one_device_fit(
                                   np.asarray(ens_one.split_bin))
     np.testing.assert_allclose(margin_sh, np.asarray(margin_one),
                                rtol=1e-4, atol=1e-4)
+
+
+# -- the row tile: a pure function of (block features, rows a chip) ----------
+
+K = 1024
+# the six configurations of BENCHMARK.json: (features, depth, rows, data
+# axis) -> the tile of a chip's calls
+CELLS = {
+    "higgs11m.fit": ((28, 6, 11_000_000, 1), 8 * K),
+    "airline115m.fit.dp4": ((13, 6, 67_108_864, 4), 16 * K),
+    "epsilon400k.fit": ((2000, 6, 400_000, 1), 2 * K),
+    "bosch1m.fit": ((968, 6, 1_183_747, 1), 2 * K),
+    "epsilon400k.d8.fit": ((2000, 8, 400_000, 1), 2 * K),
+    "mslr30k.rank.fit": ((136, 6, 2_270_296, 1), 2 * K),
+}
+
+
+def _data_mesh(dp):
+    import jax
+    from dmlc_core_tpu.parallel.mesh import make_mesh
+
+    return make_mesh({"data": dp}, devices=jax.devices()[:dp])
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_row_tile_of_every_cell(cell):
+    """A step carries the row-features of a 128-feature block's step: the
+    two narrow cells widen, the four others run today's 2,048 rows."""
+    (features, depth, rows, dp), tile = CELLS[cell]
+    with _data_mesh(dp):
+        plan = hist_pallas.hist_kernel_plan(None, features, depth, 256,
+                                            batch=rows, pads=True)
+    assert plan["row_tile"] == tile
+    assert plan["row_multiple"] == tile * dp
+    block = hist_pallas.hist_block_plan(2 ** depth // 4, features, 256)[1]
+    padded = -(-rows // plan["row_multiple"]) * plan["row_multiple"] // dp
+    assert hist_pallas.hist_row_tile(block, padded) == tile
+
+
+@pytest.mark.parametrize("features, rows, tile", [
+    (28, None, 2 * K),                 # rows not known yet
+    (28, 8192, 2 * K),                 # the benchmark's check, a sample fit
+    (13, 8192, 2 * K),
+    (28, 300, 2 * K),
+    (28, 64 * 8 * K - 2 * K, 2 * K),   # 63.75 tiles of 8,192
+    (28, 64 * 8 * K - 2 * K + 1, 8 * K),   # pads to 64 of them
+    (28, 64 * 8 * K, 8 * K),
+    (13, 64 * 16 * K - 1, 16 * K),
+    (13, 64 * 8 * K, 2 * K),           # the base, not the next tile down
+    (1, 10 ** 7, 16 * K),              # the cap
+    (8, 10 ** 7, 16 * K),
+    (16, 10 ** 7, 16 * K),
+    (17, 10 ** 7, 8 * K),
+    (32, 10 ** 7, 8 * K),
+    (33, 10 ** 7, 4 * K),
+    (64, 10 ** 7, 4 * K),
+    (65, 10 ** 7, 2 * K),
+    (128, 10 ** 7, 2 * K),
+    (136, 10 ** 7, 2 * K),
+    (2000, 10 ** 7, 2 * K),
+])
+def test_the_row_tile_rule(features, rows, tile):
+    assert hist_pallas.hist_row_tile(features, rows) == tile
+    assert tile % hist_pallas.BLOCK_ROWS == 0
+    assert features * tile <= 128 * hist_pallas.BLOCK_ROWS or tile == 2 * K
+
+
+@pytest.mark.parametrize("features", [4, 13, 28, 64, 128])
+def test_rows_padded_to_their_tile_keep_it(features):
+    """What a fit pads to is what its calls cut the padded rows by, at the
+    edge of 64 tiles too."""
+    wide = hist_pallas.hist_row_tile(features, 10 ** 9)
+    for rows in (1, 2047, 2048, 2049, 64 * wide - 2049, 64 * wide - 2048,
+                 64 * wide - 2047, 64 * wide - 1, 64 * wide, 64 * wide + 1,
+                 65 * wide - 1, 10 ** 7 + 3):
+        tile = hist_pallas.hist_row_tile(features, rows)
+        assert tile in (wide, hist_pallas.BLOCK_ROWS)
+        padded = -(-rows // tile) * tile
+        assert hist_pallas.hist_row_tile(features, padded) == tile, rows
+        assert padded - rows < tile and (tile == 2 * K
+                                         or padded - rows <= rows / 63)
+
+
+@pytest.mark.parametrize("dp", [1, 2, 8])
+def test_row_multiple_is_the_tile_times_the_data_axis(dp):
+    """Plain and under a data mesh: every shard a whole number of tiles.  A
+    fit that pads need not bring rows that divide; a caller that does not
+    pad must."""
+    rows = dp * 64 * 8 * K + 1000 * dp + 1      # divides no data axis > 1
+    with _data_mesh(dp):
+        fit = hist_pallas.hist_kernel_plan(None, 28, 6, 256, batch=rows,
+                                           pads=True)
+        assert (fit["row_tile"], fit["row_multiple"]) == (8 * K, 8 * K * dp)
+        assert (fit["mesh"] is None) == (dp == 1)
+        small = hist_pallas.hist_kernel_plan(None, 28, 6, 256,
+                                             batch=dp * 300, pads=True)
+        assert small["row_multiple"] == 2 * K * dp
+        unknown = hist_pallas.hist_kernel_plan(None, 28, 6, 256)
+        assert (unknown["row_tile"], unknown["row_multiple"]) == \
+            (2 * K, 2 * K * dp)
+        if dp > 1:
+            with pytest.raises(ValueError, match="rows do not divide"):
+                hist_pallas.hist_kernel_plan(None, 28, 6, 256, batch=rows)
+        as_they_are = hist_pallas.hist_kernel_plan(None, 28, 6, 256,
+                                                   batch=rows - 1)
+        assert as_they_are["row_tile"] == 8 * K
+
+
+def test_the_model_axis_sees_a_chips_features():
+    """56 features over a model axis of 2: a chip's block is 28 wide."""
+    with _mesh_2d(data=4, model=2):
+        plan = hist_pallas.hist_kernel_plan("model", 56, 6, 256,
+                                            batch=4 * 64 * 8 * K)
+    assert (plan["row_tile"], plan["row_multiple"]) == (8 * K, 4 * 8 * K)
+    assert hist_pallas.hist_kernel_plan(None, 56, 6, 256,
+                                        batch=64 * 8 * K)["row_tile"] == 4 * K
+
+
+@pytest.mark.parametrize("nnodes", [1, 16])
+@pytest.mark.parametrize("tile", [4 * K, 8 * K])
+def test_a_wide_tile_sums_the_same_histogram(tile, nnodes):
+    """A tile above the base, forced: rows that are no whole tile, node ids
+    below 0 and past n (a level's sibling rows, a padded row) drop out, and
+    the result is the exact histogram of the bf16-rounded g and h, as at
+    the base tile."""
+    rows, f = 2 * tile + 777, 3
+    bins, node, g, h = _rand_case(rows, f, 256, nnodes + 2, seed=tile + nnodes)
+    node = node - 1                                  # -1 .. nnodes
+    bins_fm = np.ascontiguousarray(bins.T)
+    wide = np.asarray(hist_pallas.hist_matmul_pallas(
+        (node, g, h), bins_fm, 256, num_nodes=nnodes, block_rows=tile))
+    keep = (node >= 0) & (node < nnodes)
+    _assert_hist_of_rounded(
+        wide.reshape(2, nnodes, f, 256), bins[keep], node[keep], g[keep],
+        h[keep], nnodes, 256)
+    base = np.asarray(hist_pallas.hist_matmul_pallas(
+        (node, g, h), bins_fm, 256, num_nodes=nnodes,
+        block_rows=hist_pallas.BLOCK_ROWS))
+    np.testing.assert_allclose(wide, base, rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("features, rows, nnodes, tile", [
+    (28, 11_001_856, 16, 8 * K), (28, 11_001_856, 32, 8 * K),
+    (28, 11_001_856, 64, 4 * K), (28, 11_001_856, 128, 2 * K),
+    (13, 16_777_216, 16, 16 * K), (13, 16_777_216, 32, 16 * K),
+    (13, 16_777_216, 64, 8 * K), (13, 16_777_216, 128, 4 * K),
+    (136, 2_271_232, 16, 2 * K), (136, 2_271_232, 64, 2 * K),
+])
+def test_a_deep_level_halves_the_tile_that_would_leave_default_vmem(
+        features, rows, nnodes, tile):
+    """The operands grow with a level's key rows: a widened tile is kept
+    while the step stays within Mosaic's default VMEM (every level of a
+    depth-6 fit, and 32 built nodes), then halved: the tiles the sweep read
+    fastest at 64 and 128 built nodes (PERF.md, PR 37).  A traced shape
+    only: nothing runs."""
+    import jax
+    import jax.numpy as jnp
+
+    row = jax.ShapeDtypeStruct((rows,), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda b, n, g, h: hist_pallas.grad_hist_pallas(
+            b, n, g, h, nnodes, 256))(
+        jax.ShapeDtypeStruct((features, rows), jnp.int32),
+        jax.ShapeDtypeStruct((rows,), jnp.int32), row, row)
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    assert rows % tile == 0
+    assert call.params["grid_mapping"].grid[2] == rows // tile
+    asked = call.params["compiler_params"].get("mosaic_tpu")
+    if tile > hist_pallas.BLOCK_ROWS:
+        assert asked is None or asked.vmem_limit_bytes is None
+
+
+@pytest.fixture()
+def tiles_fill_early(monkeypatch):
+    """Widen the tile of a table small enough for the interpreter: two
+    tiles filled, not 64, and a cap of 4,096 rows."""
+    monkeypatch.setattr(hist_pallas, "_ROW_TILES_FILLED", 2)
+    monkeypatch.setattr(hist_pallas, "_ROW_TILE_CAP", 4 * K)
+
+
+@pytest.mark.parametrize("rows, steps", [(2 * 4 * K, 2), (9000, 3),
+                                         (6000, 3)])
+def test_the_call_cuts_its_rows_by_the_rule(tiles_fill_early, rows, steps):
+    """``block_rows`` left out: the grid's row steps are the rule's, from
+    the call's own shapes (6,000 rows pad to 6,144: under two tiles of
+    4,096, so three of 2,048)."""
+    import jax
+    import jax.numpy as jnp
+
+    row = jnp.zeros((rows,), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda b, n, g, h: hist_pallas.grad_hist_pallas(b, n, g, h, 4, 16))(
+            jnp.zeros((5, rows), jnp.int32), row.astype(jnp.int32), row, row)
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    assert call.params["grid_mapping"].grid == (1, 1, steps)
+
+
+@pytest.mark.parametrize("rows, tile", [(9000, 4 * K), (3000, 2 * K)])
+def test_a_fit_pads_to_its_tile_and_says_so(tiles_fill_early, rows, tile):
+    """``gbdt.fit.dispatch`` carries ``row_tile``; the fit pads once to it
+    (no call pads again: the grid's steps are padded rows / tile) and grows
+    the exact scatter fit's trees."""
+    import jax
+    import jax.numpy as jnp
+    from dmlc_core_tpu import telemetry
+    from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
+
+    rng = np.random.RandomState(rows)
+    x = rng.randn(rows, 5).astype(np.float32)
+    y = (x[:, 0] + x[:, 3] * x[:, 1] > 0).astype(np.float32)
+
+    def model(method):
+        m = GBDT(GBDTParam(num_boost_round=2, max_depth=3, num_bins=16,
+                           hist_method=method), num_feature=5)
+        m.make_bins(x)
+        return m
+
+    kernel = model("pallas")
+    bins = np.asarray(kernel.bin_features(x), np.uint8)
+    plan = kernel._fit_plan(bins)
+    assert (plan.row_tile, plan.row_multiple) == (tile, tile)
+    was_enabled = telemetry.enabled()
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        ens_p, _ = kernel.fit_binned(bins, y)
+        args = [e["args"] for e in telemetry.get_tracer().events()
+                if e["name"] == "gbdt.fit.dispatch"][-1]
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+        if was_enabled:
+            telemetry.enable()
+    assert args["row_tile"] == tile and args["feature_blocks"] == 1
+    ens_s, _ = model("scatter").fit_binned(bins, y)
+    np.testing.assert_array_equal(np.asarray(ens_p.split_feat),
+                                  np.asarray(ens_s.split_feat))
+    np.testing.assert_array_equal(np.asarray(ens_p.split_bin),
+                                  np.asarray(ens_s.split_bin))
+    jaxpr = jax.make_jaxpr(kernel._build_fit(2, plan, with_eval=False))(
+        jnp.asarray(bins), jnp.asarray(y), jnp.ones(rows, jnp.float32))
+    padded = -(-rows // tile) * tile
+    grids = _pallas_grids(jaxpr.jaxpr)
+    assert grids and set(grids) == {(1, 1, padded // tile)}
+
+
+def _pallas_grids(jaxpr):
+    """The grid of every ``pallas_call`` of a jaxpr, nested ones too."""
+    import jax
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_grids(sub)
+    return found

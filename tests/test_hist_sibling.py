@@ -373,7 +373,7 @@ def test_the_plan_follows_the_nodes_a_fit_builds():
     # scatter builds the same node slots; it has no kernel to shape
     assert hist_plan("scatter", None, 28, 6, 256).blocks() == {
         "node_blocks": 0, "level_node_blocks": "", "feature_blocks": 0,
-        "bin_split": "", "built_nodes": "1,1,2,4,8,16"}
+        "row_tile": 0, "bin_split": "", "built_nodes": "1,1,2,4,8,16"}
     # a depth-1 fit has no level below the root
     assert hist_plan("scatter", None, 28, 1, 256).built_nodes == "1"
 
