@@ -656,8 +656,11 @@ def _build_tree(hist_bins, bins_fm, g, h, plan: HistPlan, max_depth: int,
 
     Each phase runs under a ``jax.named_scope`` (``gbdt.hist`` /
     ``gbdt.split`` / ``gbdt.route`` / ``gbdt.leaf``; the callers add
-    ``gbdt.layout`` and ``gbdt.grad_hess``): metadata only, read back per
-    phase from a ``jax.profiler`` trace's ``tf_op`` (docs/observability.md).
+    ``gbdt.layout`` and ``gbdt.grad_hess``), and every level's three
+    phases inside a ``gbdt.level<depth>`` one, whose level the kernel's
+    call is named by too (``hist_pallas.hist_kernel_name``): metadata only,
+    read back per phase and per level from a ``jax.profiler`` trace's
+    ``tf_op`` (docs/observability.md).
     """
     import jax
     import jax.numpy as jnp
@@ -681,171 +684,181 @@ def _build_tree(hist_bins, bins_fm, g, h, plan: HistPlan, max_depth: int,
         node_hi = jnp.full((1,), jnp.inf, jnp.float32)
 
     for depth in range(max_depth):
-        n_nodes = 2 ** depth
-        level_off = n_nodes - 1
-        with jax.named_scope("gbdt.hist"):
-            # G, H: [n, F, nbins]
-            G, H = plan.level(hist_bins, key, g, h, num_bins, parent,
-                              built_right)
-        with jax.named_scope("gbdt.split"):
-            GL = jnp.cumsum(G, axis=-1)
-            HL = jnp.cumsum(H, axis=-1)
-            GT = GL[..., -1:]
-            HT = HL[..., -1:]
-            lam = reg_lambda
+        with jax.named_scope(f"gbdt.level{depth}"):
+            n_nodes = 2 ** depth
+            level_off = n_nodes - 1
+            with jax.named_scope("gbdt.hist"):
+                # G, H: [n, F, nbins]
+                G, H = plan.level(hist_bins, key, g, h, num_bins, parent,
+                                  built_right, level=depth)
+            with jax.named_scope("gbdt.split"):
+                GL = jnp.cumsum(G, axis=-1)
+                HL = jnp.cumsum(H, axis=-1)
+                GT = GL[..., -1:]
+                HT = HL[..., -1:]
+                lam = reg_lambda
 
-            mds = max_delta_step
+                mds = max_delta_step
 
-            def _clamp_w(w):
-                return jnp.clip(w, -mds, mds) if mds > 0.0 else w
+                def _clamp_w(w):
+                    return jnp.clip(w, -mds, mds) if mds > 0.0 else w
 
-            def _opt_w(Gv, Hv):
-                # the (possibly mds-clamped) optimum leaf weight — the ONE
-                # definition shared by gain scoring, monotone masking, and the
-                # monotone interval midpoints, so they can never desynchronize
-                return _clamp_w(-_l1_threshold(Gv, reg_alpha) / (Hv + lam))
+                def _opt_w(Gv, Hv):
+                    # the (possibly mds-clamped) optimum leaf weight — the ONE
+                    # definition shared by gain scoring, monotone masking, and
+                    # the monotone interval midpoints, so they can never
+                    # desynchronize
+                    return _clamp_w(-_l1_threshold(Gv, reg_alpha) / (Hv + lam))
 
-            def _weights(GLv, HLv):
-                return _opt_w(GLv, HLv), _opt_w(GT - GLv, HT - HLv)
+                def _weights(GLv, HLv):
+                    return _opt_w(GLv, HLv), _opt_w(GT - GLv, HT - HLv)
 
-            def _score(Gv, Hv):
-                # -2x the leaf objective at the (possibly clamped) optimum
-                # weight; algebraically equal to ThresholdL1(G)^2/(H+lam)
-                # when max_delta_step leaves the weight unclamped, so split
-                # choices under the cap match XGBoost's CalcWeight-clamped
-                # CalcGain rather than ignoring the cap.  Known deviation:
-                # with reg_alpha>0 AND a binding cap, the alpha term here is
-                # -2a|w| (the self-consistent -2x objective) where XGBoost's
-                # CalcGain adds +a|w| — gains, and possibly argmax splits,
-                # differ from XGBoost in that corner
-                if mds == 0.0:
-                    return _l1_threshold(Gv, reg_alpha) ** 2 / (Hv + lam)
-                w = _opt_w(Gv, Hv)
-                return (-(2.0 * Gv * w + (Hv + lam) * w * w)
-                        - 2.0 * reg_alpha * jnp.abs(w))
+                def _score(Gv, Hv):
+                    # -2x the leaf objective at the (possibly clamped) optimum
+                    # weight; algebraically equal to ThresholdL1(G)^2/(H+lam)
+                    # when max_delta_step leaves the weight unclamped, so split
+                    # choices under the cap match XGBoost's CalcWeight-clamped
+                    # CalcGain rather than ignoring the cap.  Known deviation:
+                    # with reg_alpha>0 AND a binding cap, the alpha term here
+                    # is -2a|w| (the self-consistent -2x objective) where
+                    # XGBoost's CalcGain adds +a|w| — gains, and possibly
+                    # argmax splits, differ from XGBoost in that corner
+                    if mds == 0.0:
+                        return _l1_threshold(Gv, reg_alpha) ** 2 / (Hv + lam)
+                    w = _opt_w(Gv, Hv)
+                    return (-(2.0 * Gv * w + (Hv + lam) * w * w)
+                            - 2.0 * reg_alpha * jnp.abs(w))
 
-            def _gain(GLv, HLv):
-                GRv = GT - GLv
-                HRv = HT - HLv
-                gn = (_score(GLv, HLv) + _score(GRv, HRv)
-                      - _score(GT, HT))                      # [n, F, nbins]
-                ok = (HLv >= min_child_weight) & (HRv >= min_child_weight)
-                if monotone is not None:
-                    wl, wr = _weights(GLv, HLv)
-                    c = mono[None, :, None]
-                    ok = ok & ~(c * (wl - wr) > 0)           # violating splits
-                return gn, ok
+                def _gain(GLv, HLv):
+                    GRv = GT - GLv
+                    HRv = HT - HLv
+                    gn = (_score(GLv, HLv) + _score(GRv, HRv)
+                          - _score(GT, HT))                  # [n, F, nbins]
+                    ok = (HLv >= min_child_weight) & (HRv >= min_child_weight)
+                    if monotone is not None:
+                        wl, wr = _weights(GLv, HLv)
+                        c = mono[None, :, None]
+                        ok = ok & ~(c * (wl - wr) > 0)   # violating splits
+                    return gn, ok
 
-            gain, valid = _gain(GL, HL)
-            if missing:
-                # default-right scored above (thresholds below the missing bin
-                # exclude its mass from GL, so it lands right for free); score
-                # default-left by shifting the missing mass into the left sums
-                gain_l, valid_l = _gain(GL + G[..., miss_id:miss_id + 1],
-                                        HL + H[..., miss_id:miss_id + 1])
-                gain = jnp.where(valid, gain, -jnp.inf)
-                gain_l = jnp.where(valid_l, gain_l, -jnp.inf)
-                go_left_default = gain_l > gain
-                gain = jnp.maximum(gain, gain_l)
-                valid = valid | valid_l
-            # splitting on the last bin sends everything left: never valid
-            # (with missing handling the last REAL threshold is num_bins - 2,
-            # which separates non-missing from missing — allowed)
-            valid = valid & (jnp.arange(num_bins)
-                             < num_bins - 1)[None, None, :]
-            if level_mask_fn is not None:
-                # the level/node draw consumes the tree mask (nested sampling)
-                valid = valid & level_mask_fn(depth, n_nodes,
-                                              feat_mask)[:, :, None]
-            elif feat_mask is not None:
-                valid = valid & feat_mask[None, :, None]
-            gain = jnp.where(valid, gain, -jnp.inf)
-            flat = gain.reshape(n_nodes, F * num_bins)
-            best = jnp.argmax(flat, axis=-1)                 # [n]
-            best_gain = jnp.take_along_axis(flat, best[:, None], axis=-1)[:, 0]
-            bf = (best // num_bins).astype(jnp.int32)
-            bb = (best % num_bins).astype(jnp.int32)
-            do_split = best_gain > min_split_loss
-            sf = jnp.where(do_split, bf, -1)
-            if missing:
-                dl = jnp.take_along_axis(
-                    go_left_default.reshape(n_nodes, F * num_bins),
-                    best[:, None], axis=-1)[:, 0] & do_split
-            else:
-                dl = jnp.zeros((n_nodes,), jnp.bool_)
-            lvl = level_off + jnp.arange(n_nodes)
-            split_feat = split_feat.at[lvl].set(sf)
-            split_bin = split_bin.at[lvl].set(bb)
-            default_left = default_left.at[lvl].set(dl)
-            split_gain = split_gain.at[lvl].set(
-                jnp.where(do_split, best_gain, 0.0))
-            GTn, HTn = GT[:, 0, 0], HT[:, 0, 0]
-            split_cover = split_cover.at[lvl].set(
-                jnp.where(do_split, HTn, 0.0))
-
-            def _left_at_best(sums, cums):
-                # the left child's sum at the chosen split, [n]-sized
-                # gathers: the cumsum at ``best``, plus the missing bin's
-                # mass where the node sends missing rows left
-                left = jnp.take_along_axis(
-                    cums.reshape(n_nodes, F * num_bins), best[:, None],
-                    axis=-1)[:, 0]
+                gain, valid = _gain(GL, HL)
                 if missing:
-                    left = left + jnp.where(dl, jnp.take_along_axis(
-                        sums[..., miss_id], bf[:, None], axis=-1)[:, 0], 0.0)
-                return left
+                    # default-right scored above (thresholds below the missing
+                    # bin exclude its mass from GL, so it lands right for free);
+                    # score default-left by shifting the missing mass into the
+                    # left sums
+                    gain_l, valid_l = _gain(GL + G[..., miss_id:miss_id + 1],
+                                            HL + H[..., miss_id:miss_id + 1])
+                    gain = jnp.where(valid, gain, -jnp.inf)
+                    gain_l = jnp.where(valid_l, gain_l, -jnp.inf)
+                    go_left_default = gain_l > gain
+                    gain = jnp.maximum(gain, gain_l)
+                    valid = valid | valid_l
+                # splitting on the last bin sends everything left: never valid
+                # (with missing handling the last REAL threshold is num_bins -
+                # 2, which separates non-missing from missing — allowed)
+                valid = valid & (jnp.arange(num_bins)
+                                 < num_bins - 1)[None, None, :]
+                if level_mask_fn is not None:
+                    # the level/node draw consumes the tree mask (nested
+                    # sampling)
+                    valid = valid & level_mask_fn(depth, n_nodes,
+                                                  feat_mask)[:, :, None]
+                elif feat_mask is not None:
+                    valid = valid & feat_mask[None, :, None]
+                gain = jnp.where(valid, gain, -jnp.inf)
+                flat = gain.reshape(n_nodes, F * num_bins)
+                best = jnp.argmax(flat, axis=-1)                 # [n]
+                best_gain = jnp.take_along_axis(flat, best[:, None],
+                                                axis=-1)[:, 0]
+                bf = (best // num_bins).astype(jnp.int32)
+                bb = (best % num_bins).astype(jnp.int32)
+                do_split = best_gain > min_split_loss
+                sf = jnp.where(do_split, bf, -1)
+                if missing:
+                    dl = jnp.take_along_axis(
+                        go_left_default.reshape(n_nodes, F * num_bins),
+                        best[:, None], axis=-1)[:, 0] & do_split
+                else:
+                    dl = jnp.zeros((n_nodes,), jnp.bool_)
+                lvl = level_off + jnp.arange(n_nodes)
+                split_feat = split_feat.at[lvl].set(sf)
+                split_bin = split_bin.at[lvl].set(bb)
+                default_left = default_left.at[lvl].set(dl)
+                split_gain = split_gain.at[lvl].set(
+                    jnp.where(do_split, best_gain, 0.0))
+                GTn, HTn = GT[:, 0, 0], HT[:, 0, 0]
+                split_cover = split_cover.at[lvl].set(
+                    jnp.where(do_split, HTn, 0.0))
 
-            # the next level builds the lighter child of every pair and
-            # derives the heavier, whose error so stays at the f32 rounding
-            # of sums of its own size; a node that does not split has an
-            # empty right child
-            HLb = _left_at_best(H, HL)
-            built_right = ~do_split | (HTn - HLb < HLb)
-            parent = (G, H)
-            if monotone is not None:
-                # child intervals: the chosen split's child weights set the
-                # midpoint; constrained features split the node interval there
-                GLb = _left_at_best(G, GL)
-                wl = _opt_w(GLb, HLb)
-                wr = _opt_w(GTn - GLb, HTn - HLb)
-                wl = jnp.clip(wl, node_lo, node_hi)
-                wr = jnp.clip(wr, node_lo, node_hi)
-                mid = 0.5 * (wl + wr)
-                c_node = jnp.where(do_split, mono[bf], 0)    # [n]
-                # c=+1: left subtree weights <= mid <= right subtree weights
-                lo_l = node_lo
-                hi_l = jnp.where(c_node > 0, jnp.minimum(node_hi, mid),
-                                 node_hi)
-                lo_r = jnp.where(c_node > 0, jnp.maximum(node_lo, mid),
-                                 node_lo)
-                hi_r = node_hi
-                lo_l = jnp.where(c_node < 0, jnp.maximum(node_lo, mid), lo_l)
-                hi_r = jnp.where(c_node < 0, jnp.minimum(node_hi, mid), hi_r)
-                node_lo = jnp.stack([lo_l, lo_r], axis=1).reshape(-1)
-                node_hi = jnp.stack([hi_l, hi_r], axis=1).reshape(-1)
-        with jax.named_scope("gbdt.route"):
-            # advance every row one level.  Rows' split features come from
-            # a per-node table of 1..2**(d-1) entries and their bin from one
-            # of F columns: both are compare-select-sums over a LEADING
-            # axis, rows on the lanes.  At 11M x 28 on a v5e the row-major
-            # select-sum streamed the lane-padded int32 [rows, F] (512 B a
-            # row) at 81% of the HBM peak, 8.5 ms a level, and the table
-            # gathers took 8.5 + 11 ms a round as one-hot reductions over
-            # lanes; take_along_axis was slower still (PERF.md, PR 25).
-            nf = _table_pick(sf, node)                       # [B]
-            row_bin = _feature_pick(bins_fm, nf)
-            # one pick for the node's threshold and for which of its
-            # children the next level builds (a pick of its own for the
-            # flag cost 0.29 ms a level at 16.8M rows; PERF.md, PR 31)
-            bin_flag = _table_pick(bb * 2 + built_right, node)
-            go_right = (row_bin > (bin_flag >> 1)) & (nf >= 0)
-            if missing:
-                # missing rows sit at bin num_bins-1 > any threshold, so they
-                # already go right; default-left overrides that
-                go_right = go_right & ~((row_bin == miss_id)
-                                        & _table_pick(dl, node))
-            key = jnp.where(go_right == ((bin_flag & 1) == 1), node, -1)
-            node = node * 2 + go_right.astype(jnp.int32)
+                def _left_at_best(sums, cums):
+                    # the left child's sum at the chosen split, [n]-sized
+                    # gathers: the cumsum at ``best``, plus the missing bin's
+                    # mass where the node sends missing rows left
+                    left = jnp.take_along_axis(
+                        cums.reshape(n_nodes, F * num_bins), best[:, None],
+                        axis=-1)[:, 0]
+                    if missing:
+                        left = left + jnp.where(dl, jnp.take_along_axis(
+                            sums[..., miss_id], bf[:, None], axis=-1)[:, 0],
+                            0.0)
+                    return left
+
+                # the next level builds the lighter child of every pair and
+                # derives the heavier, whose error so stays at the f32 rounding
+                # of sums of its own size; a node that does not split has an
+                # empty right child
+                HLb = _left_at_best(H, HL)
+                built_right = ~do_split | (HTn - HLb < HLb)
+                parent = (G, H)
+                if monotone is not None:
+                    # child intervals: the chosen split's child weights set the
+                    # midpoint; constrained features split the node interval
+                    # there
+                    GLb = _left_at_best(G, GL)
+                    wl = _opt_w(GLb, HLb)
+                    wr = _opt_w(GTn - GLb, HTn - HLb)
+                    wl = jnp.clip(wl, node_lo, node_hi)
+                    wr = jnp.clip(wr, node_lo, node_hi)
+                    mid = 0.5 * (wl + wr)
+                    c_node = jnp.where(do_split, mono[bf], 0)    # [n]
+                    # c=+1: left subtree weights <= mid <= right subtree
+                    # weights
+                    lo_l = node_lo
+                    hi_l = jnp.where(c_node > 0, jnp.minimum(node_hi, mid),
+                                     node_hi)
+                    lo_r = jnp.where(c_node > 0, jnp.maximum(node_lo, mid),
+                                     node_lo)
+                    hi_r = node_hi
+                    lo_l = jnp.where(c_node < 0, jnp.maximum(node_lo, mid),
+                                     lo_l)
+                    hi_r = jnp.where(c_node < 0, jnp.minimum(node_hi, mid),
+                                     hi_r)
+                    node_lo = jnp.stack([lo_l, lo_r], axis=1).reshape(-1)
+                    node_hi = jnp.stack([hi_l, hi_r], axis=1).reshape(-1)
+            with jax.named_scope("gbdt.route"):
+                # advance every row one level.  Rows' split features come from
+                # a per-node table of 1..2**(d-1) entries and their bin from
+                # one of F columns: both are compare-select-sums over a LEADING
+                # axis, rows on the lanes.  At 11M x 28 on a v5e the row-major
+                # select-sum streamed the lane-padded int32 [rows, F] (512 B a
+                # row) at 81% of the HBM peak, 8.5 ms a level, and the table
+                # gathers took 8.5 + 11 ms a round as one-hot reductions over
+                # lanes; take_along_axis was slower still (PERF.md, PR 25).
+                nf = _table_pick(sf, node)                       # [B]
+                row_bin = _feature_pick(bins_fm, nf)
+                # one pick for the node's threshold and for which of its
+                # children the next level builds (a pick of its own for the
+                # flag cost 0.29 ms a level at 16.8M rows; PERF.md, PR 31)
+                bin_flag = _table_pick(bb * 2 + built_right, node)
+                go_right = (row_bin > (bin_flag >> 1)) & (nf >= 0)
+                if missing:
+                    # missing rows sit at bin num_bins-1 > any threshold, so
+                    # they already go right; default-left overrides that
+                    go_right = go_right & ~((row_bin == miss_id)
+                                            & _table_pick(dl, node))
+                key = jnp.where(go_right == ((bin_flag & 1) == 1), node, -1)
+                node = node * 2 + go_right.astype(jnp.int32)
 
     with jax.named_scope("gbdt.leaf"):
         Gl, Hl = plan.leaf_sums(node, g, h, 2 ** max_depth)

@@ -65,6 +65,9 @@ parent's id and all other rows by -1, which the body drops; the one-shot
   ONE ``hist_level`` call whatever its blocking; a feature block builds
   its features' one-hots once, a node block builds every feature's again
   with its own base taken off the node ids;
+- the call says which level it is: ``hist_level_L<level>_n<node slots>``
+  (:func:`hist_kernel_name`; ``hist_level_L4_n8``), metadata of the
+  compiled program that a profile's reader finds each level by;
 - the result leaves the kernel as ``[node blocks, F, L, 2nH]``; one XLA
   transpose a level puts it back to ``(G, H)[n, F, num_bins]``.
 
@@ -90,7 +93,7 @@ __all__ = ["hist_matmul_pallas", "grad_hist_pallas",
            "ambient_mesh", "hist_kernel_plan",
            "interpret_mode", "hist_fits_vmem",
            "hist_block_plan", "hist_split_plan", "hist_row_tile",
-           "BLOCK_ROWS", "DATA_AXIS"]
+           "hist_kernel_name", "BLOCK_ROWS", "DATA_AXIS"]
 
 # interpreter mode: runs the kernels on CPU for tests/debugging (flipped by
 # tests, or set DMLC_TPU_PALLAS_INTERPRET=1 to debug without a chip).
@@ -308,6 +311,20 @@ def _bin_split(hi: int, lo: int):
     return split
 
 
+def hist_kernel_name(num_nodes: int, level=None) -> str:
+    """What one kernel call is named in the compiled program and so in a
+    profile (``%hist_level_L4_n8.52 = ... custom-call``): the node slots it
+    builds, and the tree level where the caller says one (a fit's
+    ``_build_tree``; the one-shot ``grad_histogram`` has none:
+    ``hist_level_n32``).  The one rule a trace's reader rests on, which it
+    takes from ``gbdt.fit.dispatch``'s ``level_kernels`` and never
+    rebuilds: the name starts with ``hist_level``, is static, and does not
+    end in ``.<digits>``, the one suffix a reader strips to group an
+    instruction's copies."""
+    at = "" if level is None else f"_L{level}"
+    return f"hist_level{at}_n{num_nodes}"
+
+
 # bf16 1.0 in the low / high half of an int32 word
 _ONE_LOW, _ONE_HIGH = 0x3F80, 0x3F800000
 
@@ -385,7 +402,7 @@ def _kernel(node_ref, g_ref, h_ref, bins_ref, out_ref, *, num_nodes: int,
 
 def hist_matmul_pallas(rows, bins, num_bins: int, *, num_nodes: int,
                        block_rows=None, block_features=None,
-                       block_nodes=None):
+                       block_nodes=None, level=None):
     """``out[c*n + k, f*nbins + b] = sum_i [node_i == k] * (g_i, h_i)[c] *
     (bins[f, i] == b)``: the kernel's entry, one ``hist_level`` call.
 
@@ -403,6 +420,8 @@ def hist_matmul_pallas(rows, bins, num_bins: int, *, num_nodes: int,
         keeps all of them in one block.  The bin index is split for a
         block's slots (:func:`hist_split_plan`); a last block that is
         short holds slots no row carries.
+      level: the tree level this call builds, a label for the call's name
+        alone (:func:`hist_kernel_name`); None where the caller has none.
 
     Returns [2*num_nodes, F*num_bins] float32, the kernel's
     ``[node blocks, F, L, 2nH]`` transposed back.
@@ -482,7 +501,7 @@ def hist_matmul_pallas(rows, bins, num_bins: int, *, num_nodes: int,
         out_shape=jax.ShapeDtypeStruct((node_blocks, bf, lo, cols),
                                        jnp.float32),
         interpret=interpret_mode(),
-        name="hist_level",
+        name=hist_kernel_name(num_nodes, level),
         **params,
     )(node.astype(jnp.int32), g.astype(jnp.float32), h.astype(jnp.float32),
       bins)
@@ -498,7 +517,7 @@ def hist_matmul_pallas(rows, bins, num_bins: int, *, num_nodes: int,
 
 
 def grad_hist_pallas(bins, node_ids, grad, hess, num_nodes: int,
-                     num_bins: int):
+                     num_bins: int, level=None):
     """Per-(node, feature, bin) gradient/hessian sums via the VMEM kernel.
 
     ``bins`` is FEATURE-MAJOR, ``[F, B]`` int32 — the layout the kernel
@@ -509,7 +528,8 @@ def grad_hist_pallas(bins, node_ids, grad, hess, num_nodes: int,
 
     ONE ``hist_level`` call for any width and depth: a level too wide or
     deep for one resident accumulator runs blocked (:func:`hist_block_plan`)
-    and node blocks, like feature blocks, are steps of that call's grid.
+    and node blocks, like feature blocks, are steps of that call's grid;
+    ``level`` is the label of its name (:func:`hist_kernel_name`).
     """
     import jax.numpy as jnp
 
@@ -519,7 +539,8 @@ def grad_hist_pallas(bins, node_ids, grad, hess, num_nodes: int,
     out = hist_matmul_pallas(
         (node_ids.astype(jnp.int32), grad, hess), bins, num_bins,
         num_nodes=num_nodes, block_features=block_features,
-        block_nodes=block_nodes).reshape(2, num_nodes, bf, num_bins)
+        block_nodes=block_nodes, level=level).reshape(2, num_nodes, bf,
+                                                      num_bins)
     return out[0], out[1]
 
 
@@ -571,12 +592,12 @@ def hist_kernel_plan(model_axis, num_feature: int, max_depth: int,
     - what ``gbdt.fit.dispatch`` records: ``built_nodes``, the node slots
       each level's call builds from the root (one child of every pair
       below it, ``histogram.hist_built_nodes``), ``level_node_blocks``
-      (the grid steps over nodes of each level's one call, from which
-      the span's ``node_blocks`` is the deepest level's) and
+      (the grid steps over nodes of each level's one call) and
       ``feature_blocks`` (grid steps over features inside each node
-      block) of one chip's ``F/mp`` slice, and
+      block) of one chip's ``F/mp`` slice,
       ``bin_split``, the :func:`hist_split_plan` ``HxL`` of every level's
-      call, that of a node block's slots where the level has several.
+      call, that of a node block's slots where the level has several, and
+      ``level_kernels``, every level's :func:`hist_kernel_name`.
 
     A mesh the kernel cannot be shard_mapped over raises a ``ValueError``
     that names the condition and the remedy — nothing falls back: a
@@ -628,11 +649,14 @@ def hist_kernel_plan(model_axis, num_feature: int, max_depth: int,
             "level_node_blocks": ",".join(map(str, steps)),
             "feature_blocks": -(-local // feats),
             "bin_split": ",".join(f"{hi}x{lo}" for hi, lo in splits),
-            "built_nodes": ",".join(map(str, built))}
+            "built_nodes": ",".join(map(str, built)),
+            "level_kernels": ",".join(hist_kernel_name(n, level)
+                                      for level, n in enumerate(built))}
 
 
 def grad_hist_pallas_sharded(bins, node_ids, grad, hess, num_nodes: int,
-                             num_bins: int, mesh, model_axis=None):
+                             num_bins: int, mesh, model_axis=None,
+                             level=None):
     """shard_map-wrapped VMEM hist: rows dp-sharded, features model-sharded.
 
     The only way the Pallas kernel runs on more than one device: each shard
@@ -643,7 +667,8 @@ def grad_hist_pallas_sharded(bins, node_ids, grad, hess, num_nodes: int,
     own ``F/mp`` feature rows (bins arrive feature-replicated) and the
     output is ``P(None, model_axis, None)`` — exactly the constraint the
     GSPMD path advertises, so split-finding code downstream is unchanged;
-    without one the output is replicated.
+    without one the output is replicated.  ``level`` is the label of the
+    kernel's name (:func:`hist_kernel_name`).
 
     Requires ``F % mesh.shape[model_axis] == 0`` and rows that divide the
     data axis: what :func:`hist_kernel_plan` checks before it hands out the
@@ -663,7 +688,7 @@ def grad_hist_pallas_sharded(bins, node_ids, grad, hess, num_nodes: int,
             b = jax.lax.dynamic_slice_in_dim(b, idx * f_local, f_local,
                                              axis=0)
         G, H = grad_hist_pallas(b, n.astype(jnp.int32), g, h, num_nodes,
-                                num_bins)
+                                num_bins, level)
         if row_axis is not None:
             G = jax.lax.psum(G, row_axis)
             H = jax.lax.psum(H, row_axis)

@@ -107,18 +107,20 @@ class HistPlan(NamedTuple):
     bin_split: str = ""
     # :func:`hist_built_nodes` of the fit, whatever the method
     built_nodes: str = ""
+    # what each level's kernel call is named in the compiled program, root
+    # first (``hist_pallas.hist_kernel_name``); "" for no kernel
+    level_kernels: str = ""
 
     def blocks(self) -> dict:
-        """The kernel's shape and the node slots each level builds, as the
-        ``gbdt.fit.dispatch`` span records them beside the method;
-        ``node_blocks`` is the deepest level's of ``level_node_blocks``."""
-        steps = self.level_node_blocks
-        return {"node_blocks": int(steps.split(",")[-1]) if steps else 0,
-                "level_node_blocks": steps,
+        """The kernel's shape, the node slots each level builds and the
+        names its calls carry in a profile, as the ``gbdt.fit.dispatch``
+        span records them beside the method."""
+        return {"level_node_blocks": self.level_node_blocks,
                 "feature_blocks": self.feature_blocks,
                 "row_tile": self.row_tile,
                 "bin_split": self.bin_split,
-                "built_nodes": self.built_nodes}
+                "built_nodes": self.built_nodes,
+                "level_kernels": self.level_kernels}
 
     def layouts(self, bins, pad: int = 0):
         """The two device layouts a fit keeps of one ``[rows, F]`` binned
@@ -150,12 +152,14 @@ class HistPlan(NamedTuple):
         return bins if bins.dtype == jnp.int32 else bins.astype(jnp.int32)
 
     def histogram(self, hist_bins, node_ids, grad, hess, num_nodes: int,
-                  num_bins: int):
+                  num_bins: int, *, level=None):
         """``(G, H)`` of ``num_nodes`` node slots built by summation, each
         ``[num_nodes, F, num_bins]`` f32, from the histogram's copy of the
         bins (:meth:`layouts`); otherwise the contract of
         :func:`grad_histogram`.  A row whose id lies outside
-        ``[0, num_nodes)`` adds nothing, under either method."""
+        ``[0, num_nodes)`` adds nothing, under either method.  ``level`` is
+        the tree level a fit builds here, a label the kernel's call is
+        named by (``hist_pallas.hist_kernel_name``) and nothing else."""
         import jax
         import jax.numpy as jnp
 
@@ -166,10 +170,11 @@ class HistPlan(NamedTuple):
             if self.mesh is not None:
                 G, H = hist_pallas.grad_hist_pallas_sharded(
                     hist_bins, node_ids, grad, hess, num_nodes, num_bins,
-                    self.mesh, self.model_axis)
+                    self.mesh, self.model_axis, level=level)
             else:
                 G, H = hist_pallas.grad_hist_pallas(
-                    hist_bins, node_ids, grad, hess, num_nodes, num_bins)
+                    hist_bins, node_ids, grad, hess, num_nodes, num_bins,
+                    level=level)
         else:
             B, F = hist_bins.shape
             ids = (node_ids[:, None] * (F * num_bins)
@@ -200,7 +205,7 @@ class HistPlan(NamedTuple):
             hist, P(None, self.model_axis, None))
 
     def level(self, hist_bins, keys, grad, hess, num_bins: int,
-              parent=None, built_right=None):
+              parent=None, built_right=None, *, level=None):
         """One level's ``(G, H)``, each ``[n, F, num_bins]`` f32 in node
         order, by sibling subtraction: ONE child of every pair is built by
         summation (:meth:`histogram`, ``n / 2`` node slots) and the other
@@ -213,7 +218,7 @@ class HistPlan(NamedTuple):
         PARENT's id of a row that sits in that child and any id outside
         ``[0, n / 2)`` (-1) of a row that sits in its sibling.  The root
         (``parent=None``) has no sibling: ``keys`` are its rows' node ids,
-        all 0.
+        all 0.  ``level`` is the tree level, the label of :meth:`histogram`.
 
         The subtraction and the interleave are elementwise on the built
         half's transposed result (on a v5e they add a quarter to the
@@ -225,9 +230,11 @@ class HistPlan(NamedTuple):
         import jax.numpy as jnp
 
         if parent is None:
-            return self.histogram(hist_bins, keys, grad, hess, 1, num_bins)
+            return self.histogram(hist_bins, keys, grad, hess, 1, num_bins,
+                                  level=level)
         half = parent[0].shape[0]
-        built = self.histogram(hist_bins, keys, grad, hess, half, num_bins)
+        built = self.histogram(hist_bins, keys, grad, hess, half, num_bins,
+                               level=level)
         right = built_right[:, None, None]
 
         def pair(above, summed):

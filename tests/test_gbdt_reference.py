@@ -19,6 +19,7 @@ import pytest
 from benchmarks.chip.reference import gbdt_hist
 from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
 from dmlc_core_tpu.ops import hist_pallas
+from dmlc_core_tpu.ops.histogram import hist_built_nodes
 
 ROUNDS = 3
 CASES = {
@@ -157,7 +158,9 @@ def test_kernel_fit_is_within_the_tolerance_of_the_reference(name,
     if name == "depth8_node_and_feature_blocks":
         blocks = model._hist_blocks("pallas")
         assert blocks["level_node_blocks"] == "1,1,1,1,1,1,1,2"
-        assert (blocks["node_blocks"], blocks["feature_blocks"]) == (2, 2)
+        assert blocks["feature_blocks"] == 2
+        assert blocks["level_kernels"].endswith(
+            "hist_level_L6_n32,hist_level_L7_n64")
         assert blocks["bin_split"].endswith("6x48,4x64,2x128,2x128")
     _, margin = model.fit_binned(bins, label)
     _, ref_margin = _reference(name, bins, label)
@@ -170,12 +173,14 @@ def test_kernel_fit_is_within_the_tolerance_of_the_reference(name,
 
 
 def _hist_level_calls(jaxpr):
-    """``hist_level`` kernel calls in a jaxpr, sub-jaxprs (the scan over
-    rounds, nested jits) included: each is traced once."""
-    found = 0
+    """The names of the ``hist_level`` kernel calls in a jaxpr, in program
+    order, sub-jaxprs (the scan over rounds, nested jits) included: each is
+    traced once."""
+    found = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            found += eqn.params["name"] == "hist_level"
+            assert eqn.params["name"].startswith("hist_level")
+            found.append(eqn.params["name"])
         for value in eqn.params.values():
             for sub in (value if isinstance(value, (list, tuple))
                         else (value,)):
@@ -195,7 +200,9 @@ def test_a_compiled_fit_holds_one_kernel_call_a_level(interpret, depth,
     """Exactly ``max_depth`` ``hist_level`` calls in the body of the scan
     over rounds: a level of two node blocks is still one call, which is
     what ``rounds_traced`` (Mosaic calls / ``max_depth``) and every
-    per-level reader of the benchmark divide by."""
+    per-level reader of the benchmark divide by.  Their names, root first,
+    are the plan's ``level_kernels``: what the whole-round readers find a
+    round by."""
     import jax
     import jax.numpy as jnp
 
@@ -207,4 +214,8 @@ def test_a_compiled_fit_holds_one_kernel_call_a_level(interpret, depth,
     assert plan.method == "pallas" and plan.level_node_blocks == steps
     jaxpr = jax.make_jaxpr(model._build_fit(2, plan, with_eval=False))(
         bins, jnp.zeros(rows), jnp.ones(rows))
-    assert _hist_level_calls(jaxpr.jaxpr) == depth
+    names = _hist_level_calls(jaxpr.jaxpr)
+    assert len(names) == depth
+    assert names == plan.level_kernels.split(",") == [
+        hist_pallas.hist_kernel_name(n, level)
+        for level, n in enumerate(hist_built_nodes(depth))]

@@ -750,7 +750,9 @@ def test_node_blocks_are_the_one_block_calls_side_by_side(
 
 def test_any_node_count_is_one_kernel_call(feature_block_budget):
     """``grad_hist_pallas`` makes exactly one ``hist_level`` call whatever
-    the blocking: 500 nodes x 260 features run 63 x 3 blocks inside it."""
+    the blocking: 500 nodes x 260 features run 63 x 3 blocks inside it,
+    named by all the node slots it builds and by no level (a caller that
+    has none)."""
     import jax
     import jax.numpy as jnp
 
@@ -765,7 +767,7 @@ def test_any_node_count_is_one_kernel_call(feature_block_budget):
                                        row.astype(jnp.int32), row, row)
         calls = [e for e in jaxpr.jaxpr.eqns
                  if e.primitive.name == "pallas_call"]
-        assert [c.params["name"] for c in calls] == ["hist_level"]
+        assert [c.params["name"] for c in calls] == [f"hist_level_n{nodes}"]
         assert calls[0].params["grid_mapping"].grid == grid
 
 
@@ -821,11 +823,12 @@ def test_wide_tables_plan_feature_blocks():
     assert hist_pallas.hist_block_plan(32, 28, 256) == (32, 28)
     assert hist_pallas.hist_block_plan(512, 2000, 256) == (32, 128)
     def blocks(num_feature, max_depth):
-        # the span's scalar is the deepest level's of ``level_node_blocks``
+        # the deepest level's node blocks are the last of
+        # ``level_node_blocks``: the span has no entry of its own for them
         plan = hist_plan("pallas", None, num_feature, max_depth, 256).blocks()
-        assert plan["node_blocks"] == int(
-            plan["level_node_blocks"].split(",")[-1])
-        return plan["node_blocks"], plan["feature_blocks"]
+        assert "node_blocks" not in plan
+        return (int(plan["level_node_blocks"].split(",")[-1]),
+                plan["feature_blocks"])
 
     assert blocks(2000, 6) == (1, 16)
     # the deepest level builds 256 of its 512 nodes, 32 a grid step
@@ -838,6 +841,9 @@ def test_wide_tables_plan_feature_blocks():
     assert deep["built_nodes"] == "1,1,2,4,8,16,32,64"
     assert deep["bin_split"] == \
         "16x16,16x16,8x32,8x32,6x48,4x64,2x128,2x128"
+    # two node blocks are still one call, named by all 64 slots
+    assert deep["level_kernels"].split(",")[-2:] == [
+        "hist_level_L6_n32", "hist_level_L7_n64"]
     assert hist_pallas.hist_kernel_plan(None, 2000, 10, 256)[
         "level_node_blocks"] == "1,1,1,1,1,1,1,2,4,8"
     # a narrow table holds 128 slots a block: depth 8 is not blocked
@@ -852,16 +858,19 @@ def test_wide_tables_plan_feature_blocks():
                 num_feature=2000)
     assert wide._method() == "pallas"
     assert wide._hist_blocks("pallas") == {
-        "node_blocks": 1, "level_node_blocks": "1,1,1,1,1,1",
+        "level_node_blocks": "1,1,1,1,1,1",
         "feature_blocks": 16, "row_tile": hist_pallas.BLOCK_ROWS,
         "bin_split": "16x16,16x16,8x32,8x32,6x48,4x64",
-        "built_nodes": "1,1,2,4,8,16"}
-    assert wide._hist_blocks("scatter") == {"node_blocks": 0,
-                                            "level_node_blocks": "",
+        "built_nodes": "1,1,2,4,8,16",
+        "level_kernels": "hist_level_L0_n1,hist_level_L1_n1,"
+                         "hist_level_L2_n2,hist_level_L3_n4,"
+                         "hist_level_L4_n8,hist_level_L5_n16"}
+    assert wide._hist_blocks("scatter") == {"level_node_blocks": "",
                                             "feature_blocks": 0,
                                             "row_tile": 0,
                                             "bin_split": "",
-                                            "built_nodes": "1,1,2,4,8,16"}
+                                            "built_nodes": "1,1,2,4,8,16",
+                                            "level_kernels": ""}
     with _mesh_2d():
         sharded = GBDT(GBDTParam(max_depth=6, num_bins=256,
                                  hist_method="pallas"), num_feature=2000,
@@ -869,7 +878,8 @@ def test_wide_tables_plan_feature_blocks():
         assert sharded._method() == "pallas"
         # each model shard blocks its own 1,000 features
         blocks = sharded._hist_blocks("pallas")
-        assert (blocks["node_blocks"], blocks["feature_blocks"]) == (1, 8)
+        assert (blocks["level_node_blocks"],
+                blocks["feature_blocks"]) == ("1,1,1,1,1,1", 8)
 
 
 def _wide_rehearsal(n=900, f=260, seed=51):
@@ -901,12 +911,11 @@ def test_gbdt_wide_fit_matches_scatter_and_the_plain_reference(
     model, bins, y = _wide_rehearsal()
     kernel = model("pallas")
     assert kernel._fit_method(bins) == "pallas"
-    assert kernel._hist_blocks("pallas") == {"node_blocks": 1,
-                                             "level_node_blocks": "1,1,1",
-                                             "feature_blocks": 3,
-                                             "row_tile": 2048,
-                                             "bin_split": "1x16,1x16,1x16",
-                                             "built_nodes": "1,1,2"}
+    assert kernel._hist_blocks("pallas") == {
+        "level_node_blocks": "1,1,1", "feature_blocks": 3, "row_tile": 2048,
+        "bin_split": "1x16,1x16,1x16", "built_nodes": "1,1,2",
+        "level_kernels":
+            "hist_level_L0_n1,hist_level_L1_n1,hist_level_L2_n2"}
     ens_p, margin_p = kernel.fit_binned(bins, y)
     ens_s, margin_s = model("scatter").fit_binned(bins, y)
     np.testing.assert_array_equal(np.asarray(ens_p.split_feat),

@@ -44,9 +44,9 @@ class Recorded:
         self.plan, self.levels = plan, []
 
     def level(self, hist_bins, keys, g, h, num_bins, parent=None,
-              built_right=None):
+              built_right=None, *, level=None):
         out = self.plan.level(hist_bins, keys, g, h, num_bins, parent,
-                              built_right)
+                              built_right, level=level)
         self.levels.append({
             "keys": np.asarray(keys),
             "built_right": None if parent is None else np.asarray(built_right),
@@ -255,16 +255,17 @@ def every_node_built(monkeypatch):
     at = {}
 
     def level(self, hist_bins, keys, g, h, num_bins, parent=None,
-              built_right=None):
+              built_right=None, *, level=None):
         import jax.numpy as jnp
 
         if parent is None:
             at["node"] = jnp.zeros_like(keys)
-            return self.histogram(hist_bins, keys, g, h, 1, num_bins)
+            return self.histogram(hist_bins, keys, g, h, 1, num_bins,
+                                  level=level)
         flag = built_right[at["node"]]
         at["node"] = 2 * at["node"] + jnp.where(keys >= 0, flag, ~flag)
         return self.histogram(hist_bins, at["node"], g, h,
-                              2 * parent[0].shape[0], num_bins)
+                              2 * parent[0].shape[0], num_bins, level=level)
 
     def switch(on):
         if on:
@@ -369,11 +370,18 @@ def test_the_plan_follows_the_nodes_a_fit_builds():
     assert plan["bin_split"] == "16x16,16x16,8x32,8x32,6x48,4x64"
     assert plan["built_nodes"] == "1,1,2,4,8,16"
     assert plan["level_node_blocks"] == "1,1,1,1,1,1"
-    assert "node_blocks" not in plan      # the span derives it: blocks()
-    # scatter builds the same node slots; it has no kernel to shape
+    assert plan["level_kernels"] == (
+        "hist_level_L0_n1,hist_level_L1_n1,hist_level_L2_n2,"
+        "hist_level_L3_n4,hist_level_L4_n8,hist_level_L5_n16")
+    # the deepest level's count is the last of level_node_blocks: no entry
+    # of its own, in the plan or on the span
+    assert "node_blocks" not in plan
+    assert "node_blocks" not in hist_plan("pallas", None, 28, 6,
+                                          256).blocks()
+    # scatter builds the same node slots; it has no kernel to shape or name
     assert hist_plan("scatter", None, 28, 6, 256).blocks() == {
-        "node_blocks": 0, "level_node_blocks": "", "feature_blocks": 0,
-        "row_tile": 0, "bin_split": "", "built_nodes": "1,1,2,4,8,16"}
+        "level_node_blocks": "", "feature_blocks": 0, "row_tile": 0,
+        "bin_split": "", "built_nodes": "1,1,2,4,8,16", "level_kernels": ""}
     # a depth-1 fit has no level below the root
     assert hist_plan("scatter", None, 28, 1, 256).built_nodes == "1"
 
@@ -400,11 +408,17 @@ def test_the_dispatch_span_carries_split_and_built_nodes():
     assert args["built_nodes"] == "1,1,2,4,8,16"
     assert args["bin_split"] == "16x16,16x16,8x32,8x32,6x48,4x64"
     assert args["level_node_blocks"] == "1,1,1,1,1,1"
+    assert args["level_kernels"].split(",") == [
+        hist_pallas.hist_kernel_name(n, level)
+        for level, n in enumerate((1, 1, 2, 4, 8, 16))]
+    assert "node_blocks" not in args
 
 
 def test_one_kernel_call_a_level_of_half_the_nodes():
     """The fit's jaxpr holds exactly ``max_depth`` ``hist_level`` calls a
-    tree, the first two of one node: what ``rounds_traced`` counts on."""
+    tree, the first two of one node, each named by its level and its built
+    nodes as the plan's ``level_kernels`` says: what ``rounds_traced``
+    counts on, and what the whole-round readers find a round by."""
     import jax
     import jax.numpy as jnp
 
@@ -419,7 +433,10 @@ def test_one_kernel_call_a_level_of_half_the_nodes():
     jaxpr = jax.make_jaxpr(grow)(jnp.zeros((b, f), jnp.uint8),
                                  jnp.zeros(b), jnp.zeros(b))
     calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
-    assert [c.params["name"] for c in calls] == ["hist_level"] * depth
+    assert [c.params["name"] for c in calls] == \
+        plan.level_kernels.split(",") == [
+            "hist_level_L0_n1", "hist_level_L1_n1", "hist_level_L2_n2",
+            "hist_level_L3_n4", "hist_level_L4_n8"]
     # the accumulator's minor extent is 2 x the key rows of the built nodes
     slots = [c.outvars[0].aval.shape[-1] for c in calls]
     assert slots == [2 * hist_pallas._key_rows(
