@@ -59,12 +59,16 @@ parent's id and all other rows by -1, which the body drops; the one-shot
   TWO bf16 rows each (g and h of one key; ones of two neighbouring ``lo``)
   and are bitcast to bf16: half the compares, and no convert;
 - a table whose accumulator fits the VMEM budget is one block; a wider
-  one is cut by :func:`hist_block_plan` into blocks of 128 features, and a
-  level of more nodes than those leave room for (64 at 128 features and
-  256 bins) into node blocks.  Both are steps of the grid, so a level is
-  ONE ``hist_level`` call whatever its blocking; a feature block builds
-  its features' one-hots once, a node block builds every feature's again
-  with its own base taken off the node ids;
+  one is cut by :func:`hist_block_plan` into feature blocks, and a level
+  of more nodes than a block of 128 features leaves room for (64 at 256
+  bins) into node blocks.  Both are steps of the grid, so a level is ONE
+  ``hist_level`` call whatever its blocking; a feature block builds its
+  features' one-hots once, a node block builds every feature's again with
+  its own base taken off the node ids.  The body is unrolled over all of
+  a block's features, so a call's time follows the SLOTS of its grid and
+  the blocks divide the table (:func:`hist_feature_block`: 968 features
+  are 11 blocks of 88 and 2,000 are 25 of 80, where blocks of 128 paid
+  for 56 and 48 columns no row has);
 - the call says which level it is: ``hist_level_L<level>_n<node slots>``
   (:func:`hist_kernel_name`; ``hist_level_L4_n8``), metadata of the
   compiled program that a profile's reader finds each level by;
@@ -92,7 +96,8 @@ __all__ = ["hist_matmul_pallas", "grad_hist_pallas",
            "grad_hist_pallas_sharded",
            "ambient_mesh", "hist_kernel_plan",
            "interpret_mode", "hist_fits_vmem",
-           "hist_block_plan", "hist_split_plan", "hist_row_tile",
+           "hist_block_plan", "hist_feature_block", "hist_split_plan",
+           "hist_row_tile",
            "hist_kernel_name", "BLOCK_ROWS", "DATA_AXIS"]
 
 # interpreter mode: runs the kernels on CPU for tests/debugging (flipped by
@@ -141,8 +146,15 @@ _ROW_TILES_FILLED = 64
 # hist_block_plan cuts a level's [2n, F*nbins] histogram down to.
 _ACC_BYTES_LIMIT = 8 * 1024 * 1024
 
-# features of a blocked accumulator's block
+# features of a blocked accumulator's widest block (and the MXU's lanes)
 _LANES = 128
+
+# a blocked accumulator's narrowest block: the least width whose step
+# keeps BLOCK_ROWS rows (hist_row_tile doubles the tile at 64 features, and
+# a tile of other rows sums in another order); and what one more feature
+# block costs a round's calls, in feature slots (PERF.md, PR 39)
+_NARROWEST_BLOCK = 72
+_BLOCK_CHARGE = 2
 
 # VMEM a call may count on unasked: Mosaic's default on a v5e is 16 MiB (of
 # 128), less a quarter for what the compiler keeps there itself
@@ -172,12 +184,13 @@ def hist_block_plan(num_nodes: int, num_feature: int, num_bins: int):
     SAME kernel call, but under feature blocks every feature's one-hots
     are still built once (only the 12 B a row of node, g and h are
     re-read), while a node block re-reads the bins and re-builds every
-    one-hot.  So: the most nodes for which the narrowest legal feature
-    block (128 features, or all F of a narrower table) fits, and beside
-    them all F features in one block where those fit, else blocks of 128.
-    Not wider where the budget would allow it: at 512 features a block
-    Mosaic spilled 173 MB for a v5e (PR 27, the body then unrolled over
-    the block's features).
+    one-hot.  So: the most nodes for which a block of ``_LANES`` features
+    (or all F of a narrower table) fits, and beside them all F features in
+    one block where those fit, else blocks of :func:`hist_feature_block`
+    features, the width that divides the table with the fewest slots left
+    over.  Never wider than ``_LANES`` where the budget would allow it: at
+    512 features a block Mosaic spilled 173 MB for a v5e (PR 27, the body
+    then unrolled over the block's features).
     """
     narrow = min(num_feature, _LANES)
     nodes = num_nodes
@@ -189,7 +202,46 @@ def hist_block_plan(num_nodes: int, num_feature: int, num_bins: int):
             return None
     if hist_fits_vmem(nodes, num_feature, num_bins):
         return nodes, num_feature
-    return nodes, _LANES
+    return nodes, hist_feature_block(num_feature)
+
+
+def hist_feature_block(num_feature: int) -> int:
+    """Features of one block of a table too wide for one accumulator: of
+    the multiples of 8 (the int32 sublane tile of the ``[F_blk, rows]``
+    bins block) from ``_NARROWEST_BLOCK`` to ``_LANES``, the width ``w``
+    that minimises ``ceil(F / w) * (w + _BLOCK_CHARGE)``, ties to the wider
+    block.  A pure function of the static ``num_feature``.
+
+    The body is unrolled over ALL of a block's features, so a call's time
+    follows the slots of its grid, not the table's columns: under blocks
+    of 128, 968 features paid for 1,024 (8 blocks, the last 72 wide) and
+    2,000 for 2,048.  So the blocks divide the table: 968 -> 11 blocks of
+    88 and 2,000 -> 25 of 80, no slot empty; 260 -> 3 of 88 (264 slots for
+    384); 136 -> 2 of 72 (144 for 256); 256, 1,024, 2,048 keep 128.
+    Measured on a v5e, the kernel alone, ms a call at 1, 4, 16 built nodes
+    (and 64 in two node blocks) for every width (PERF.md, PR 39)::
+
+        width   1,185,792 x 968 (slots)            401,408 x 2,000 (slots)
+        72      26.38  51.42 101.37 (1,008)   17.87 34.82 68.74 272.54 (2,016)
+        80      27.10  52.92 104.44 (1,040)   17.54 34.35 68.01 270.00 (2,000)
+        88      24.97  48.99  96.93   (968)   17.76 34.76 68.80 273.19 (2,024)
+        96      27.28  53.48 105.77 (1,056)   17.62 34.55 68.44 271.95 (2,016)
+        104     26.75  52.54 104.03 (1,040)   18.12 35.58 70.54 280.44 (2,080)
+        112     25.85  50.85 100.75 (1,008)   17.51 34.43 68.32 271.71 (2,016)
+        120     27.66  54.43 107.89 (1,080)   17.68 34.80 69.08 274.84 (2,040)
+        128     26.14  51.52 102.20 (1,024)   17.70 34.89 69.30 275.81 (2,048)
+
+    ``ms = slots x a(nodes) + steps x 0.17-0.21 us`` to 0.1 ms a call, and
+    a width that divides F reads 0.06-0.10 ms under it.  A block more is a
+    row of grid steps more: 4.2, 2.3, 1.2 feature slots' worth at 1, 4, 16
+    built nodes, 2.1 over the six calls of a depth-6 round, which
+    ``_BLOCK_CHARGE`` is.  Blocks of 128 whose last skipped its empty
+    tail under one ``pl.when`` read 0.09-0.38 ms a call OVER the dividing
+    width (the branch costs every block 2% a slot) and did not ship.
+    """
+    widths = range(_NARROWEST_BLOCK, _LANES + 1, 8)
+    return min(widths, key=lambda w: (-(-num_feature // w)
+                                      * (w + _BLOCK_CHARGE), -w))
 
 
 def hist_row_tile(block_features: int, rows=None) -> int:
@@ -415,7 +467,9 @@ def hist_matmul_pallas(rows, bins, num_bins: int, *, num_nodes: int,
         None takes :func:`hist_row_tile` of the feature block and B, halved
         while a deep level's step would outgrow Mosaic's default VMEM.
       block_features: features per accumulator block (a multiple of 8);
-        None or >= F keeps all F in one block.
+        None or >= F keeps all F in one block.  The body runs all of a
+        block's feature slots, those of a short last block too: the plan's
+        width (:func:`hist_feature_block`) leaves the fewest empty.
       block_nodes: node slots per accumulator block; None or >= num_nodes
         keeps all of them in one block.  The bin index is split for a
         block's slots (:func:`hist_split_plan`); a last block that is
@@ -592,9 +646,12 @@ def hist_kernel_plan(model_axis, num_feature: int, max_depth: int,
     - what ``gbdt.fit.dispatch`` records: ``built_nodes``, the node slots
       each level's call builds from the root (one child of every pair
       below it, ``histogram.hist_built_nodes``), ``level_node_blocks``
-      (the grid steps over nodes of each level's one call) and
+      (the grid steps over nodes of each level's one call),
       ``feature_blocks`` (grid steps over features inside each node
-      block) of one chip's ``F/mp`` slice,
+      block) and ``block_features`` (the features of one such step,
+      :func:`hist_feature_block`: 25 and 80 at 2,000 features, 11 and 88
+      at 968, 1 and all F of a table that is one block) of one chip's
+      ``F/mp`` slice,
       ``bin_split``, the :func:`hist_split_plan` ``HxL`` of every level's
       call, that of a node block's slots where the level has several, and
       ``level_kernels``, every level's :func:`hist_kernel_name`.
@@ -648,6 +705,7 @@ def hist_kernel_plan(model_axis, num_feature: int, max_depth: int,
             "row_multiple": tile * dp,
             "level_node_blocks": ",".join(map(str, steps)),
             "feature_blocks": -(-local // feats),
+            "block_features": feats,
             "bin_split": ",".join(f"{hi}x{lo}" for hi, lo in splits),
             "built_nodes": ",".join(map(str, built)),
             "level_kernels": ",".join(hist_kernel_name(n, level)

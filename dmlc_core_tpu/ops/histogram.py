@@ -99,11 +99,13 @@ class HistPlan(NamedTuple):
     model_axis: Optional[str] = None  # the histogram's feature dim splits here
     mesh: Any = None                  # shard_map the kernel over it, or None
     row_multiple: int = 1             # rows a fit pads to, once
-    # the kernel's shape as ``gbdt.fit.dispatch`` records it; 0, "", 0 and
-    # "" for a method that is no kernel (``hist_pallas.hist_kernel_plan``)
+    # the kernel's shape as ``gbdt.fit.dispatch`` records it; zeros and
+    # empty strings for a method that is no kernel
+    # (``hist_pallas.hist_kernel_plan``)
     row_tile: int = 0
     level_node_blocks: str = ""
     feature_blocks: int = 0
+    block_features: int = 0
     bin_split: str = ""
     # :func:`hist_built_nodes` of the fit, whatever the method
     built_nodes: str = ""
@@ -117,6 +119,7 @@ class HistPlan(NamedTuple):
         span records them beside the method."""
         return {"level_node_blocks": self.level_node_blocks,
                 "feature_blocks": self.feature_blocks,
+                "block_features": self.block_features,
                 "row_tile": self.row_tile,
                 "bin_split": self.bin_split,
                 "built_nodes": self.built_nodes,

@@ -62,9 +62,10 @@ def test_grad_hist_matches_scatter_on_chip(jx):
 
 @pytest.mark.parametrize("F,NN,plan", [
     (28, 512, (128, 28)),     # 4 node blocks, one feature block
-    (200, 64, (32, 128)),     # 2 x 2 blocks, the last feature block short:
-                              # the last level of a depth-8 fit, 2x128 split
-    (200, 80, (32, 128)),     # a short last node block (16 of 32 slots)
+    (200, 64, (32, 104)),     # 2 x 2 blocks, the last feature block short
+                              # (96 of 104): the last level of a depth-8
+                              # fit, 2x128 split
+    (200, 80, (32, 104)),     # a short last node block (16 of 32 slots)
 ])
 def test_node_blocked_deep_level_on_chip(jx, F, NN, plan):
     """Deep levels whose accumulator overflows VMEM run in node blocks:

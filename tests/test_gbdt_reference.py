@@ -158,7 +158,9 @@ def test_kernel_fit_is_within_the_tolerance_of_the_reference(name,
     if name == "depth8_node_and_feature_blocks":
         blocks = model._hist_blocks("pallas")
         assert blocks["level_node_blocks"] == "1,1,1,1,1,1,1,2"
+        # 256 features are two whole blocks of 128: none divides them better
         assert blocks["feature_blocks"] == 2
+        assert blocks["block_features"] == 128
         assert blocks["level_kernels"].endswith(
             "hist_level_L6_n32,hist_level_L7_n64")
         assert blocks["bin_split"].endswith("6x48,4x64,2x128,2x128")

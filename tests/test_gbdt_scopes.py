@@ -219,7 +219,8 @@ def test_fit_binned_records_one_dispatch_span_per_call(spans):
         # child of every pair like the kernel's
         assert found[-1]["args"] == {
             "rounds": ROUNDS, "objective": "logistic", "method": "scatter",
-            "level_node_blocks": "", "feature_blocks": 0, "row_tile": 0,
+            "level_node_blocks": "", "feature_blocks": 0,
+            "block_features": 0, "row_tile": 0,
             "bin_split": "", "built_nodes": "1,1", "level_kernels": ""}
         assert found[-1]["ph"] == "X" and found[-1]["dur"] > 0
 

@@ -645,9 +645,9 @@ def feature_block_budget():
 
 @pytest.mark.parametrize("b,f,nbins,nnodes,plan", [
     (700, 384, 4, 4, (4, 128)),     # three whole feature blocks, two tiles
-    (300, 300, 4, 8, (8, 128)),     # F no multiple of the block; row padding
-    (300, 130, 8, 3, (3, 128)),     # a last block of two features
-    (500, 260, 4, 20, (8, 128)),    # node blocks x feature blocks, short last
+    (300, 300, 4, 8, (8, 104)),     # F no multiple of the block; row padding
+    (300, 130, 8, 3, (3, 72)),      # two blocks, the last 58 of 72 wide
+    (500, 260, 4, 20, (8, 88)),     # node blocks x feature blocks, short last
     (256, 100, 4, 32, (8, 100)),    # under 128 features: node blocks alone
 ])
 def test_feature_blocked_hist_matches_scatter(feature_block_budget, b, f,
@@ -667,15 +667,15 @@ def test_feature_blocked_hist_matches_scatter(feature_block_budget, b, f,
                                rtol=2e-2, atol=6e-2)
 
 
-@pytest.mark.parametrize("f", [256, 300])
+@pytest.mark.parametrize("f,width", [(256, 128), (300, 104)])
 def test_feature_blocks_side_by_side_are_the_unblocked_result(
-        feature_block_budget, f):
+        feature_block_budget, f, width):
     """Blocking changes where a feature's partial sums live, not one bit of
     them: same tiles, same dots, same order over the rows."""
     bins, node, g, h = _rand_case(2100, f, 8, 6, seed=42)
     whole = _kernel_hist(bins, node, g, h, 6, 8)
     feature_block_budget(8)
-    assert hist_pallas.hist_block_plan(6, f, 8) == (6, 128)
+    assert hist_pallas.hist_block_plan(6, f, 8) == (6, width)
     blocked = _kernel_hist(bins, node, g, h, 6, 8)
     for a, b in zip(whole, blocked):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -814,14 +814,14 @@ def test_the_kernel_entry_returns_the_flat_histogram():
 
 
 def test_wide_tables_plan_feature_blocks():
-    """2,000 features x 256 bins (epsilon): 16 blocks of 128 features under
+    """2,000 features x 256 bins (epsilon): 25 blocks of 80 features under
     all 32 nodes of the deepest level, one call a level."""
     from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
 
-    assert hist_pallas.hist_block_plan(32, 2000, 256) == (32, 128)
-    assert hist_pallas.hist_block_plan(1, 2000, 256) == (1, 128)
+    assert hist_pallas.hist_block_plan(32, 2000, 256) == (32, 80)
+    assert hist_pallas.hist_block_plan(1, 2000, 256) == (1, 80)
     assert hist_pallas.hist_block_plan(32, 28, 256) == (32, 28)
-    assert hist_pallas.hist_block_plan(512, 2000, 256) == (32, 128)
+    assert hist_pallas.hist_block_plan(512, 2000, 256) == (32, 80)
     def blocks(num_feature, max_depth):
         # the deepest level's node blocks are the last of
         # ``level_node_blocks``: the span has no entry of its own for them
@@ -830,9 +830,10 @@ def test_wide_tables_plan_feature_blocks():
         return (int(plan["level_node_blocks"].split(",")[-1]),
                 plan["feature_blocks"])
 
-    assert blocks(2000, 6) == (1, 16)
-    # the deepest level builds 256 of its 512 nodes, 32 a grid step
-    assert blocks(2000, 10) == (8, 16)
+    assert blocks(2000, 6) == (1, 25)
+    # the deepest level builds 256 of its 512 nodes, 32 a grid step: the
+    # node blocks are those of a 128-feature block, whatever its width
+    assert blocks(2000, 10) == (8, 25)
     assert blocks(28, 6) == (1, 1)
     # epsilon at depth 8: the last level builds 64 nodes in two blocks of
     # 32, each the 2x128 split, inside its one call
@@ -859,7 +860,8 @@ def test_wide_tables_plan_feature_blocks():
     assert wide._method() == "pallas"
     assert wide._hist_blocks("pallas") == {
         "level_node_blocks": "1,1,1,1,1,1",
-        "feature_blocks": 16, "row_tile": hist_pallas.BLOCK_ROWS,
+        "feature_blocks": 25, "block_features": 80,
+        "row_tile": hist_pallas.BLOCK_ROWS,
         "bin_split": "16x16,16x16,8x32,8x32,6x48,4x64",
         "built_nodes": "1,1,2,4,8,16",
         "level_kernels": "hist_level_L0_n1,hist_level_L1_n1,"
@@ -867,6 +869,7 @@ def test_wide_tables_plan_feature_blocks():
                          "hist_level_L4_n8,hist_level_L5_n16"}
     assert wide._hist_blocks("scatter") == {"level_node_blocks": "",
                                             "feature_blocks": 0,
+                                            "block_features": 0,
                                             "row_tile": 0,
                                             "bin_split": "",
                                             "built_nodes": "1,1,2,4,8,16",
@@ -876,14 +879,16 @@ def test_wide_tables_plan_feature_blocks():
                                  hist_method="pallas"), num_feature=2000,
                        model_axis="model")
         assert sharded._method() == "pallas"
-        # each model shard blocks its own 1,000 features
+        # each model shard blocks its own 1,000 features: 9 x 112, 1,008
+        # slots where 8 x 128 pay 1,024
         blocks = sharded._hist_blocks("pallas")
-        assert (blocks["level_node_blocks"],
-                blocks["feature_blocks"]) == ("1,1,1,1,1,1", 8)
+        assert (blocks["level_node_blocks"], blocks["feature_blocks"],
+                blocks["block_features"]) == ("1,1,1,1,1,1", 9, 112)
 
 
 def _wide_rehearsal(n=900, f=260, seed=51):
-    """Rows whose label hangs on one feature of each 128-feature block."""
+    """Rows whose label hangs on one feature of each of the three feature
+    blocks, 88 wide by the rule or 128."""
     from dmlc_core_tpu.models.gbdt import GBDT, GBDTParam
 
     rng = np.random.RandomState(seed)
@@ -912,7 +917,8 @@ def test_gbdt_wide_fit_matches_scatter_and_the_plain_reference(
     kernel = model("pallas")
     assert kernel._fit_method(bins) == "pallas"
     assert kernel._hist_blocks("pallas") == {
-        "level_node_blocks": "1,1,1", "feature_blocks": 3, "row_tile": 2048,
+        "level_node_blocks": "1,1,1", "feature_blocks": 3,
+        "block_features": 88, "row_tile": 2048,
         "bin_split": "1x16,1x16,1x16", "built_nodes": "1,1,2",
         "level_kernels":
             "hist_level_L0_n1,hist_level_L1_n1,hist_level_L2_n2"}
@@ -931,7 +937,7 @@ def test_gbdt_wide_fit_matches_scatter_and_the_plain_reference(
                                   np.stack([t[1] for t in trees]))
     # splits in all three feature blocks
     used = set(np.asarray(ens_p.split_feat).ravel()) - {-1}
-    assert {f // 128 for f in used} == {0, 1, 2}
+    assert {f // 88 for f in used} == {0, 1, 2}
     np.testing.assert_allclose(np.asarray(margin_p), margin_r,
                                rtol=5e-2, atol=5e-2)
 
@@ -967,6 +973,87 @@ def test_sharded_fits_with_feature_blocks_match_the_one_device_fit(
                                   np.asarray(ens_one.split_bin))
     np.testing.assert_allclose(margin_sh, np.asarray(margin_one),
                                rtol=1e-4, atol=1e-4)
+
+
+# -- the width of a feature block: a pure function of the table's features ---
+
+@pytest.mark.parametrize("f", [129, 131, 136, 257, 260, 968, 1000, 1009, 1024,
+                               2000, 2003, 2048, 4096])
+def test_feature_blocks_divide_the_table(f):
+    """A blocked table's blocks are a multiple of the int32 tile's 8
+    sublanes wide, 128 at most, cover every column, never pay more slots
+    than blocks of 128 do, and none at all where a legal width divides F;
+    the plan hands the width out at every node count that is blocked."""
+    w = hist_pallas.hist_feature_block(f)
+    blocks = -(-f // w)
+    # from 72: at 64 features ``hist_row_tile`` doubles a step's rows
+    assert w % 8 == 0 and 72 <= w <= 128
+    assert hist_pallas.hist_row_tile(w, 10 ** 7) == hist_pallas.BLOCK_ROWS
+    assert f <= blocks * w <= -(-f // 128) * 128
+    if any(f % d == 0 for d in range(72, 129, 8)):
+        assert blocks * w == f
+    assert hist_pallas.hist_block_plan(32, f, 256) == (32, w)
+    assert hist_pallas.hist_block_plan(64, f, 256) == (32, w)
+    # a level that fits one block is not blocked, whatever the rule says
+    if hist_pallas.hist_fits_vmem(8, f, 256):        # up to 512 features
+        assert hist_pallas.hist_block_plan(8, f, 256) == (8, f)
+
+
+def test_the_widths_of_the_tables_the_records_name():
+    """968 -> 11 x 88 and 2,000 -> 25 x 80, no slot wasted (8 and 16
+    blocks of 128 paid 1,024 and 2,048); 260 -> 3 x 88; 136, which only the
+    eager 32-node check blocks, -> 2 x 72; whole blocks of 128 stay."""
+    width = hist_pallas.hist_feature_block
+    assert [width(f) for f in (968, 2000, 260, 136)] == [88, 80, 88, 72]
+    assert [width(f) for f in (256, 512, 1024, 2048, 4096)] == [128] * 5
+    bosch = hist_pallas.hist_kernel_plan(None, 968, 6, 256)
+    assert (bosch["feature_blocks"], bosch["block_features"]) == (11, 88)
+    assert bosch["level_node_blocks"] == "1,1,1,1,1,1"
+    assert bosch["row_tile"] == hist_pallas.BLOCK_ROWS
+    for features in (13, 28, 136):
+        narrow = hist_pallas.hist_kernel_plan(None, features, 6, 256)
+        assert (narrow["feature_blocks"],
+                narrow["block_features"]) == (1, features)
+
+
+@pytest.mark.parametrize("f", [200, 264])
+def test_blocks_of_any_width_sum_the_same_bits(f):
+    """A feature's histogram does not depend on which block holds it: at
+    the rule's width (104 with a short last block; 88, which divides), at
+    128 and unblocked the entry returns the same bits."""
+    bins, node, g, h = _rand_case(2 * 256 + 40, f, 16, 4, seed=39)
+    bins_t = np.ascontiguousarray(bins.T)
+
+    def entry(block_features):
+        return np.asarray(hist_pallas.hist_matmul_pallas(
+            (node, g, h), bins_t, 16, num_nodes=4, block_rows=256,
+            block_features=block_features))
+
+    rule = hist_pallas.hist_feature_block(f)
+    assert rule == {200: 104, 264: 88}[f]
+    whole = entry(None)
+    assert np.abs(whole).sum() > 0
+    np.testing.assert_array_equal(entry(rule), whole)
+    np.testing.assert_array_equal(entry(128), whole)
+
+
+def test_a_fit_grows_the_trees_of_128_wide_blocks(feature_block_budget,
+                                                  monkeypatch):
+    """260 features in 3 blocks of 88 or 3 of 128 (384 slots): the same
+    trees and the same margins, to the bit."""
+    feature_block_budget(8)
+    model, bins, y = _wide_rehearsal()
+    ruled = model("pallas")
+    assert ruled._hist_blocks("pallas")["block_features"] == 88
+    ens, margin = ruled.fit_binned(bins, y)
+    monkeypatch.setattr(hist_pallas, "hist_feature_block", lambda f: 128)
+    forced = model("pallas")
+    assert forced._hist_blocks("pallas")["block_features"] == 128
+    ens_128, margin_128 = forced.fit_binned(bins, y)
+    for name in ("split_feat", "split_bin", "leaf_value"):
+        np.testing.assert_array_equal(np.asarray(getattr(ens, name)),
+                                      np.asarray(getattr(ens_128, name)))
+    np.testing.assert_array_equal(np.asarray(margin), np.asarray(margin_128))
 
 
 # -- the row tile: a pure function of (block features, rows a chip) ----------
@@ -1203,6 +1290,7 @@ def test_a_fit_pads_to_its_tile_and_says_so(tiles_fill_early, rows, tile):
         if was_enabled:
             telemetry.enable()
     assert args["row_tile"] == tile and args["feature_blocks"] == 1
+    assert args["block_features"] == 5
     ens_s, _ = model("scatter").fit_binned(bins, y)
     np.testing.assert_array_equal(np.asarray(ens_p.split_feat),
                                   np.asarray(ens_s.split_feat))

@@ -380,7 +380,8 @@ def test_the_plan_follows_the_nodes_a_fit_builds():
                                           256).blocks()
     # scatter builds the same node slots; it has no kernel to shape or name
     assert hist_plan("scatter", None, 28, 6, 256).blocks() == {
-        "level_node_blocks": "", "feature_blocks": 0, "row_tile": 0,
+        "level_node_blocks": "", "feature_blocks": 0, "block_features": 0,
+        "row_tile": 0,
         "bin_split": "", "built_nodes": "1,1,2,4,8,16", "level_kernels": ""}
     # a depth-1 fit has no level below the root
     assert hist_plan("scatter", None, 28, 1, 256).built_nodes == "1"
