@@ -189,9 +189,15 @@ def _objective_grad(p: "GBDTParam", margin, label, rank_layout=None):
     """``(g, h)`` of ``p.objective`` at ``margin``, before the row weight:
     the ONE place every round body reaches an objective through.
     ``rank_layout`` is what ``lambdarank`` keeps of its group column
-    (:func:`_rank_layout`, once a fit).  The listwise gradient runs under
-    its own scope, ``gbdt.rank``; the per-row ones under
-    ``gbdt.grad_hess``."""
+    (:func:`_rank_layout`, once a fit).  Which scope names what: the
+    listwise gradient runs under ``gbdt.rank``; what a softmax BOOSTING
+    round does once over its class axis under ``gbdt.softmax`` (the
+    class-major ``[K, rows]`` gradient here, from ``margin[K, rows]``, and
+    in :func:`_softmax_round` the margin's update and any transposition
+    to or from ``[rows, K]``); the per-row gradients under
+    ``gbdt.grad_hess``, which is also where every round body does its
+    per-TREE work (the row weight's multiply, the tree's sampling, for a
+    softmax round one class's row of the gradient)."""
     import jax
     import jax.numpy as jnp
 
@@ -202,9 +208,10 @@ def _objective_grad(p: "GBDTParam", margin, label, rank_layout=None):
         with jax.named_scope("gbdt.rank"):
             return _lambdarank_grad_hess(margin, rank_layout,
                                          p.lambdarank_truncation_level)
-    with jax.named_scope("gbdt.grad_hess"):
-        if p.objective == "softmax":
+    if p.objective == "softmax":
+        with jax.named_scope("gbdt.softmax"):
             return _softmax_grad_hess(margin, label, p.num_class)
+    with jax.named_scope("gbdt.grad_hess"):
         if p.objective == "logistic":
             pr = 1.0 / (1.0 + jnp.exp(-margin))
             return pr - label, pr * (1.0 - pr)
@@ -223,8 +230,9 @@ def _apply_pos_weight(weight, label, p):
 
 
 def _softmax_grad_hess(margin, label, num_class: int):
-    """Per-class gradients for softmax cross-entropy: margin [B, K],
-    integer labels [B] -> (g, h) each [B, K].
+    """Per-class gradients for softmax cross-entropy, class-major: margin
+    ``[K, *rows]``, integer labels ``[*rows]`` -> (g, h) each
+    ``[K, *rows]`` (the rows as :func:`_lane_tiles` folds them, or flat).
 
     Matches XGBoost's SoftmaxMultiClassObj exactly: h = max(2*p*(1-p), eps)
     — the factor 2 keeps leaf values on the same scale as the XGBoost
@@ -234,9 +242,10 @@ def _softmax_grad_hess(margin, label, num_class: int):
     import jax
     import jax.numpy as jnp
 
-    pr = jax.nn.softmax(margin, axis=1)
-    onehot = (label.astype(jnp.int32)[:, None]
-              == jnp.arange(num_class, dtype=jnp.int32)).astype(jnp.float32)
+    pr = jax.nn.softmax(margin, axis=0)
+    classes = jnp.arange(num_class, dtype=jnp.int32).reshape(
+        (num_class,) + (1,) * label.ndim)
+    onehot = (label.astype(jnp.int32)[None] == classes).astype(jnp.float32)
     return pr - onehot, jnp.maximum(2.0 * pr * (1.0 - pr), 1e-16)
 
 
@@ -575,15 +584,32 @@ def _l1_threshold(G, alpha: float):
 
 
 def _check_softmax_labels(label, num_class: int, what: str = "labels"):
-    """Host-side class-id range check shared by every softmax entry point:
+    """Class-id range check shared by every softmax entry point:
     out-of-range ids silently clamp under jit (take_along_axis / one-hot),
-    so they must be rejected before tracing."""
-    host = np.asarray(label)
-    if host.size == 0:
+    so they must be rejected before tracing.  Of a label that lives on a
+    device only its least and largest id cross to the host (two scalars
+    of one small program), never the column; a host array is read where
+    it is."""
+    if np.size(label) == 0:
         return
-    CHECK(host.min() >= 0 and host.max() < num_class,
+    import jax
+
+    if isinstance(label, jax.Array):
+        lo, hi = jax.device_get(_label_range()(label))
+    else:
+        host = np.asarray(label)
+        lo, hi = host.min(), host.max()
+    CHECK(lo >= 0 and hi < num_class,
           f"softmax {what} must lie in [0, {num_class}); "
-          f"got range [{host.min()}, {host.max()}]")
+          f"got range [{lo}, {hi}]")
+
+
+@functools.lru_cache(maxsize=None)
+def _label_range():
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(lambda label: (jnp.min(label), jnp.max(label)))
 
 
 def _parse_monotone(spec: str, num_feature: int):
@@ -873,7 +899,7 @@ def _build_tree(hist_bins, bins_fm, g, h, plan: HistPlan, max_depth: int,
             split_cover, margin_delta)
 
 
-def _tree_sampling(p: "GBDTParam", rnd, B: int, F: int, class_index: int = 0):
+def _tree_sampling(p: "GBDTParam", rnd, B: int, F: int, class_index=0):
     """Per-tree (row_weight, feature_mask) for subsample/colsample; both
     None at the default rates so the bench path traces unchanged.  ``rnd``
     is the (traced) round index; sampling is deterministic in
@@ -888,8 +914,11 @@ def _tree_sampling(p: "GBDTParam", rnd, B: int, F: int, class_index: int = 0):
     if p.subsample < 1.0 or p.colsample_bytree < 1.0:
         key = jax.random.fold_in(jax.random.PRNGKey(p.seed),
                                  jnp.asarray(rnd, jnp.uint32))
-        if class_index:
-            key = jax.random.fold_in(key, class_index)
+        if not (isinstance(class_index, int) and class_index == 0):
+            # (a softmax round's index is traced.)  Class 0 keeps the
+            # round's key, as the per-row objectives' one tree does
+            key = jnp.where(class_index == 0, key,
+                            jax.random.fold_in(key, class_index))
         if p.subsample < 1.0:
             row_w = (jax.random.uniform(jax.random.fold_in(key, 0), (B,))
                      < p.subsample).astype(jnp.float32)
@@ -956,32 +985,83 @@ def _row_sampling(p, rnd, n_rows: int, B: int, F: int, class_index=0):
     return row_w, fmask
 
 
-def _softmax_round(p, bins, margin, label, weight, rnd, grow,
-                   num_feature: int, n_rows=None):
-    """One multiclass boosting round: K trees from one margin snapshot
-    (XGBoost multi:softmax — gradients evaluated before any of the round's
-    K updates land), each tree drawing its own row/feature subset.
-    ``grow`` is the caller's _build_tree closure, ``bins`` what it reads."""
-    import jax
+_LANE_TILE = 8 * 128     # elements of one (8 sublanes, 128 lanes) tile
+
+
+def _lane_tiles(x):
+    """``[..., B]`` -> ``[..., R, 128]``: the last axis padded with zeros
+    to whole ``(8, 128)`` tiles and folded onto the lanes.  The chip tiles
+    an array's two minor axes, so in ``[K, B]`` a tile holds 8 classes'
+    rows and reading or writing ONE class's row moves all 8 (0.53 ms a
+    tree for the margin's row at 23 x 4,898,816, where a contiguous row
+    is 0.07: my chip run, PR 40); in ``[K, R, 128]`` a class's rows are
+    tiles of their own."""
     import jax.numpy as jnp
 
-    K = p.num_class
-    B = margin.shape[0]
+    pad = -x.shape[-1] % _LANE_TILE
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+    return x.reshape(x.shape[:-1] + (-1, 128))
+
+
+def _class_major(margin):
+    """The API's ``[rows, K]`` margins as a softmax round works on them:
+    class-major and lane-tiled, ``[K, R, 128]`` (:func:`_lane_tiles`)."""
+    import jax
+
+    with jax.named_scope("gbdt.softmax"):
+        return _lane_tiles(margin.T)
+
+
+def _rows_by_classes(margin, n_rows: int):
+    """:func:`_class_major`'s inverse: the first ``n_rows`` rows of
+    ``[K, R, 128]`` margins as the API's ``[n_rows, K]``."""
+    import jax
+
+    with jax.named_scope("gbdt.softmax"):
+        return margin.reshape(margin.shape[0], -1)[:, :n_rows].T
+
+
+def _softmax_round(p, bins, margin, label, weight, rnd, grow,
+                   num_feature: int, n_rows=None):
+    """One multiclass boosting round over class-major margins
+    ``[K, R, 128]`` (:func:`_class_major`): K trees from one margin
+    snapshot (XGBoost multi:softmax — gradients evaluated before any of
+    the round's K updates land), each tree drawing its own row/feature
+    subset.  ``grow`` is the caller's _build_tree closure, ``bins`` what it
+    reads, ``label`` and ``weight`` the ``[B]`` rows the trees see.
+
+    A class's tree is ONE traced body, scanned over the class axis: the
+    compiled round holds one tree's kernels whatever K is.  The carry is
+    the margin, whose class k tree k's leaves land in; nothing reads it
+    before the round is over (``g_all`` / ``h_all`` are the snapshot's).
+    Returns the margin and the round's trees stacked ``[K, ...]``."""
+    import jax
+    import jax.lax as lax
+    import jax.numpy as jnp
+
+    K, B = margin.shape[0], label.shape[0]
     n_rows = B if n_rows is None else n_rows
-    g_all, h_all = _objective_grad(p, margin, label)
-    trees = []
-    for k in range(K):
+    g_all, h_all = _objective_grad(p, margin, _lane_tiles(label))
+
+    def tiles(x, k):
+        return lax.dynamic_index_in_dim(x, k, 0, keepdims=False)
+
+    def rows(x, k):
+        return tiles(x, k).reshape(-1)[:B]
+
+    def class_tree(margin, k):
         with jax.named_scope("gbdt.grad_hess"):
             row_w, fmask = _row_sampling(p, rnd, n_rows, B, num_feature,
                                          class_index=k)
             w = weight if row_w is None else weight * row_w
-            gk, hk = g_all[:, k] * w, h_all[:, k] * w
-        trees.append(grow(bins, gk, hk, rnd, fmask))
-    with jax.named_scope("gbdt.grad_hess"):
-        delta = jnp.stack([t[6] for t in trees], axis=1)     # [B, K]
-        margin = margin + delta
-    return margin, tuple(
-        jnp.stack([t[i] for t in trees]) for i in range(6))
+            gk, hk = rows(g_all, k) * w, rows(h_all, k) * w
+        *tree, delta = grow(bins, gk, hk, rnd, fmask)
+        with jax.named_scope("gbdt.softmax"):
+            margin = lax.dynamic_update_index_in_dim(
+                margin, tiles(margin, k) + _lane_tiles(delta), k, 0)
+        return margin, tuple(tree)
+
+    return lax.scan(class_tree, margin, jnp.arange(K, dtype=jnp.int32))
 
 
 def _route_tree(split_feat, split_bin, default_left, bins,
@@ -1154,8 +1234,10 @@ class GBDT:
                     max_delta_step=p.max_delta_step)
 
             if p.objective == "softmax":
-                return _softmax_round(p, bins, margin, label, weight, rnd,
-                                      grow, F)
+                margin, trees = _softmax_round(
+                    p, bins, _class_major(margin), label, weight, rnd,
+                    grow, F)
+                return _rows_by_classes(margin, B), trees
             g, h = _objective_grad(p, margin, label)
             with jax.named_scope("gbdt.grad_hess"):
                 row_w, fmask = _tree_sampling(p, rnd, B, F)
@@ -1241,13 +1323,20 @@ class GBDT:
                 return _softmax_round(p, bins, margin, label, weight, rnd,
                                       grow, F, n_rows=n_rows)
 
-            margin0 = jnp.full((B,) if K == 1 else (B, K), p.base_score,
-                               jnp.float32)
+            # a softmax fit's margin is class-major (_class_major) until
+            # it leaves the program as the API's [n_rows, K]
+            margin0 = jnp.full((B,), p.base_score, jnp.float32)
+            if K > 1:
+                margin0 = _lane_tiles(jnp.broadcast_to(margin0, (K, B)))
             rounds = jnp.arange(num_rounds, dtype=jnp.uint32)
+
+            def real_rows(margin):
+                return (margin[:n_rows] if K == 1
+                        else _rows_by_classes(margin, n_rows))
 
             if not with_eval:
                 margin, trees = lax.scan(round_step, margin0, rounds)
-                return TreeEnsemble(*trees), margin[:n_rows]
+                return TreeEnsemble(*trees), real_rows(margin)
 
             def eval_body(carry, rnd):
                 margin, ev_margin = carry
@@ -1264,7 +1353,7 @@ class GBDT:
                 ev_margin = ev_margin + ev_delta
                 # losses on the REAL rows (padded rows carry weight 0 but
                 # _logloss is unweighted)
-                tr_loss = _logloss(margin[:n_rows], label[:n_rows],
+                tr_loss = _logloss(real_rows(margin), label[:n_rows],
                                    p.objective)
                 ev_loss = _eval_metric_fn(eval_metric,
                                           p.objective)(ev_margin, ev_label)
@@ -1275,7 +1364,7 @@ class GBDT:
                            jnp.float32)
             (margin, _), (trees, trl, evl) = lax.scan(
                 eval_body, (margin0, ev0), rounds)
-            return TreeEnsemble(*trees), margin[:n_rows], trl, evl
+            return TreeEnsemble(*trees), real_rows(margin), trl, evl
 
         return jax.jit(fit)
 
@@ -1324,16 +1413,19 @@ class GBDT:
 
         p = self.param
         # the host's part of a fit: argument staging and the asynchronous
-        # dispatch of the compiled program (it does not wait for the device)
+        # dispatch of the compiled program (it does not wait for the device,
+        # but for the two scalars of a softmax fit's label check)
+        K = p.num_class if p.objective == "softmax" else 1
         with telemetry.span("gbdt.fit.dispatch", rounds=p.num_boost_round,
-                            objective=p.objective) as sp:
+                            objective=p.objective, num_class=p.num_class,
+                            trees_per_round=K) as sp:
             CHECK((group is not None) == (p.objective == "lambdarank"),
                   f"fit_binned(group=) is objective='lambdarank''s per-row "
                   f"query id: it needs one, and no other objective takes "
                   f"one (objective={p.objective!r}, group "
                   f"{'given' if group is not None else 'missing'})")
-            if p.objective == "softmax":
-                _check_softmax_labels(label, p.num_class)
+            if K > 1:
+                _check_softmax_labels(label, K)
             weight = (jnp.ones(bins.shape[0], jnp.float32)
                       if weight is None else jnp.asarray(weight))
             bins = jnp.asarray(bins)

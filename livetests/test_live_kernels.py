@@ -118,6 +118,44 @@ def test_every_split_of_the_bin_index_on_chip(jx, NN, NB, split):
                                rtol=2e-2, atol=6e-2)
 
 
+@pytest.mark.parametrize("NN,split", [(1, (16, 16)), (16, (4, 64))])
+def test_a_41_feature_block_takes_the_4096_row_tile_on_chip(jx, NN, split):
+    """The one row tile no other table reaches: 41 features stop
+    ``hist_row_tile``'s doubling at 4,096 rows a grid step (2,048 from 65
+    features up, 8,192 at 28), at the shallowest and the deepest call of a
+    depth-6 fit, over rows that are no whole tile, against the bincount
+    histogram of ``benchmarks/chip/reference``.  The kernel rounds g and h
+    to bfloat16 and sums in float32: held to the bincount of the rounded
+    values tightly, and to the exact one at the rounding's random walk over
+    a bucket's ~1,000 rows (at one node)."""
+    from benchmarks.chip.reference import gbdt_hist
+    from dmlc_core_tpu.ops import hist_pallas
+
+    NB, F, rows = 256, 41, 64 * 4096 + 4321
+    assert hist_pallas.hist_row_tile(F, rows) == 4096
+    assert hist_pallas.hist_split_plan(NN, NB) == split
+    assert hist_pallas.hist_block_plan(NN, F, NB) == (NN, F)
+    bins, node_ids, grad, hess = _rand_problem(rows=rows, F=F, NB=NB,
+                                               num_nodes=NN, seed=40 + NN)
+    g, h = hist_pallas.grad_hist_pallas(bins.T, node_ids, grad, hess,
+                                        num_nodes=NN, num_bins=NB)
+
+    def rounded(x):
+        return np.asarray(jx.numpy.asarray(x).astype(jx.numpy.bfloat16)
+                          .astype(jx.numpy.float32))
+
+    exact = gbdt_hist.histogram(bins, node_ids, grad, hess, NN, NB)
+    as_summed = gbdt_hist.histogram(bins, node_ids, rounded(grad),
+                                    rounded(hess), NN, NB)
+    walk = 2.0 ** -8 * np.sqrt(rows / (NN * NB))     # bf16 steps, a bucket
+    for got, low, ref in zip((g, h), as_summed, exact):
+        assert got.shape == (NN, F, NB)
+        np.testing.assert_allclose(np.asarray(got), low, rtol=1e-4,
+                                   atol=2e-3)
+        np.testing.assert_allclose(np.asarray(got), ref, rtol=2e-2,
+                                   atol=max(6e-2, 4 * walk))
+
+
 def test_the_six_built_half_calls_of_a_depth_6_fit_on_chip(jx):
     """A level loop as ``_build_tree`` runs it at 256 bins: the root, then
     five levels that build the lighter child of every pair (1, 1, 2, 4, 8,

@@ -78,6 +78,70 @@ def test_streaming_round_carries_the_same_scopes():
         assert scope in found, scope
 
 
+@functools.lru_cache(maxsize=None)
+def _streamed_softmax_op_names():
+    model = _model("softmax")
+    bins, label, weight = _data("softmax")
+    return _op_names(model._round_fn(model._plan("scatter")).lower(
+        np.zeros((ROWS, 3), np.float32), bins, label, weight,
+        np.uint32(0)).compile())
+
+
+@pytest.mark.parametrize("program", ["fit", "streamed"])
+def test_a_softmax_program_names_its_class_axis(program):
+    """``gbdt.softmax``: what a boosting round does once over the class
+    axis (the ``[K, rows]`` gradient, the margin's update, the
+    transpositions to and from ``[rows, K]``), through the whole fit and
+    the streamed round.  The name is a phase to the benchmark's readers
+    (``scopes.SCOPE`` matches it) and is nested in no other phase."""
+    from benchmarks.chip import scopes
+
+    paths = (_fit_op_names("softmax") if program == "fit"
+             else _streamed_softmax_op_names())
+    under = [path for path in paths if "gbdt.softmax" in path]
+    assert under
+    for path in under:
+        assert scopes.scope_of("/".join(path)) == "gbdt.softmax", path
+        assert not any(LEVEL.match(part) for part in path), path
+    # the gradient, the margin's update and the transposition, by the
+    # primitive that ends each path
+    said = {path[-1].rstrip(":") for path in under}
+    assert said & {"exp", "div", "reduce_sum", "reduce_max"}, said
+    assert said & {"dynamic_update_slice", "add"}, said
+    assert "transpose" in said, said
+
+
+@pytest.mark.parametrize("program", ["fit", "streamed"])
+def test_no_per_row_program_holds_the_softmax_scope(program):
+    paths = (_fit_op_names("logistic") if program == "fit"
+             else _streamed_op_names())
+    assert "gbdt.softmax" not in _components(paths)
+
+
+def test_a_softmax_round_is_one_traced_tree(monkeypatch):
+    """The K trees of a round are one body scanned over the class axis:
+    the fit's jaxpr holds one tree's kernels whatever K is."""
+    import jax
+    import jax.numpy as jnp
+
+    from dmlc_core_tpu.ops import hist_pallas
+
+    monkeypatch.setattr(hist_pallas, "_INTERPRET", True)
+    rows, depth = hist_pallas.BLOCK_ROWS, 3
+    counts = []
+    for classes in (3, 23):
+        model = GBDT(GBDTParam(num_boost_round=2, max_depth=depth,
+                               num_bins=16, objective="softmax",
+                               num_class=classes, hist_method="pallas"),
+                     num_feature=FEATURES)
+        plan = model._plan("pallas", rows=rows, pads=True)
+        jaxpr = jax.make_jaxpr(model._build_fit(2, plan, with_eval=False))(
+            jnp.zeros((rows, FEATURES), jnp.uint8), jnp.zeros(rows),
+            jnp.ones(rows))
+        counts.append(len(re.findall(r"\bname=hist_level\w*", str(jaxpr))))
+    assert counts == [depth, depth]
+
+
 @pytest.mark.parametrize("depth", range(DEPTH))
 @pytest.mark.parametrize("program", sorted(OBJECTIVES) + ["streamed"])
 def test_compiled_fit_names_every_level(program, depth):
@@ -208,9 +272,14 @@ def spans():
         telemetry.enable()
 
 
-def test_fit_binned_records_one_dispatch_span_per_call(spans):
-    model = _model("logistic")
-    data = _data("logistic")
+@pytest.mark.parametrize("objective", sorted(OBJECTIVES))
+def test_fit_binned_records_one_dispatch_span_per_call(spans, objective):
+    """``num_class`` and ``trees_per_round`` beside ``rounds``: what turns
+    the trees a trace holds into boosting rounds (1 for every objective
+    but softmax, whose round grows a tree a class)."""
+    model = _model(objective)
+    data = _data(objective)
+    classes = OBJECTIVES[objective].get("num_class", 1)
     for calls in (1, 2):
         model.fit_binned(*data)
         found = spans()
@@ -218,7 +287,8 @@ def test_fit_binned_records_one_dispatch_span_per_call(spans):
         # scatter is no kernel: no blocks of one, but its levels build one
         # child of every pair like the kernel's
         assert found[-1]["args"] == {
-            "rounds": ROUNDS, "objective": "logistic", "method": "scatter",
+            "rounds": ROUNDS, "objective": objective, "method": "scatter",
+            "num_class": classes, "trees_per_round": classes,
             "level_node_blocks": "", "feature_blocks": 0,
             "block_features": 0, "row_tile": 0,
             "bin_split": "", "built_nodes": "1,1", "level_kernels": ""}
