@@ -60,6 +60,25 @@ def said(evidence, field: str) -> Tuple[Optional[List[str]], Optional[str]]:
     return entries, None
 
 
+def trees_per_call(evidence) -> Tuple[Optional[int], Optional[str]]:
+    """``(trees, None)``: the trees one level's kernel call builds, the
+    ``trees_per_call`` every ``gbdt.fit.dispatch`` span of the run carries
+    (a softmax round's K where its K trees share each level's call), 1
+    where the spans carry none (a program from before the field);
+    ``(None, why)`` without spans or where they disagree."""
+    if evidence.get("spans") is None:
+        return None, "no span buffer"
+    values = {e.get("args", {}).get("trees_per_call")
+              for e in evidence["spans"] if e["name"] == SPAN}
+    if not values:
+        return None, f"no {SPAN} span"
+    if len(values) > 1:
+        return None, (f"{SPAN} carries no one trees_per_call "
+                      f"(has: {sorted(map(str, values))})")
+    value = next(iter(values))
+    return (1 if value is None else int(value)), None
+
+
 def level_of(tf_op: Optional[str]) -> Optional[int]:
     """The tree level an op ran in: the ``<d>`` of the first ``op_name``
     component ``gbdt.level<d>``, else ``None``."""

@@ -7,7 +7,11 @@ One teacher.  A row's grade ``y`` in 0..4 is its latent margin,
 standardised over the rows, cut at fixed normal quantiles (``GRADE_SHARES``:
 the same shares of every grade for every seed).  Rows come in queries: the
 further per-row array ``group``, ascending ids, a query's rows adjacent,
-``data.queries`` seeded sizes (:func:`query_sizes`).
+``data.queries`` seeded sizes (:func:`query_sizes`).  A configuration
+whose ``mesh`` has a ``data`` axis of ``d`` chips has its queries whole on
+one chip, as a query-aligned ingest gives them and LightGBM's distributed
+``lambdarank`` asks of its input: the rows a chip holds, ``rows / d`` of
+them, are ``queries / d`` whole queries (:func:`label`).
 
 For a query ``q`` of ``n_q`` rows with margins ``s`` and grades ``y``::
 
@@ -55,17 +59,19 @@ def _grade_cuts():
             for c in np.cumsum(GRADE_SHARES)[:-1]]
 
 
-def query_sizes(key, config, rows):
-    """``data.queries`` seeded sizes, ``[queries]`` int32 inside a jitted
-    program: ``round(exp(mu + sigma z))`` clipped to ``[least, largest]``
-    (log-normal: most queries hold about a hundred rows, a few the
-    largest), with ``mu`` found by bisection so that they sum to ``rows``,
-    what float32 leaves over added one row a query from the first on."""
+def query_sizes(key, config, rows, queries=None):
+    """``queries`` (``data.queries`` unless given) seeded sizes,
+    ``[queries]`` int32 inside a jitted program: ``round(exp(mu + sigma
+    z))`` clipped to ``[least, largest]`` (log-normal: most queries hold
+    about a hundred rows, a few the largest), with ``mu`` found by
+    bisection so that they sum to ``rows``, what float32 leaves over added
+    one row a query from the first on."""
     import jax
     import jax.numpy as jnp
 
     spec = config["data"]["query_size"]
-    queries = int(config["data"]["queries"])
+    if queries is None:
+        queries = int(config["data"]["queries"])
     least, largest = int(spec["least"]), int(spec["largest"])
     if not queries * least <= rows <= queries * largest:
         raise ValueError(f"{queries} queries of {least}..{largest} rows "
@@ -90,15 +96,43 @@ def query_sizes(key, config, rows):
     return n + (room & (jnp.cumsum(room) <= left)).astype(jnp.int32)
 
 
+def data_shards(config, rows):
+    """``d``, the configuration's ``mesh`` ``data`` axis (1 without a
+    mesh), once ``rows`` and ``data.queries`` are known to divide by it."""
+    d = int((config.get("mesh") or {}).get("data", 1))
+    queries = int(config["data"]["queries"])
+    if rows % d or queries % d:
+        raise ValueError(
+            f"{rows} rows in {queries} queries cannot lie whole on the "
+            f"{d} chips of the mesh's data axis: a chip holds rows / {d} "
+            f"rows in queries / {d} whole queries, so give rows and "
+            f"data.queries multiples of {d}")
+    return d
+
+
 def label(latent, key, config):
+    """The grades, and the group column: under a ``data`` axis of ``d``
+    chips each chip's ``rows / d`` rows hold ``queries / d`` whole
+    queries, their sizes drawn from the shard's own key; the ids ascend
+    over the whole column.  Without a mesh one draw, from ``key`` itself,
+    covers every row."""
+    import jax
     import jax.numpy as jnp
 
     m = latent[0]
     z = (m - jnp.mean(m)) / jnp.std(m)
     grade = sum((z > cut).astype(jnp.float32) for cut in _grade_cuts())
     rows = m.shape[0]
-    n = query_sizes(key, config, rows)
-    opens = jnp.zeros((rows,), jnp.int32).at[jnp.cumsum(n)[:-1]].add(1)
+    d = data_shards(config, rows)
+    per, queries = rows // d, int(config["data"]["queries"]) // d
+    first = []                          # every query's first row
+    for shard in range(d):
+        n = query_sizes(key if d == 1 else jax.random.fold_in(key, shard),
+                        config, per, queries)
+        first.append(shard * per + jnp.cumsum(n) - n)
+    # the column's first query opens at row 0, where the ids start
+    opens = jnp.zeros((rows,), jnp.int32).at[
+        jnp.concatenate(first)[1:]].add(1)
     return grade, {"group": jnp.cumsum(opens)}
 
 

@@ -22,6 +22,9 @@ CONFIG = {
               "score_atol": 1e-5, "hlo_has": ["all-reduce"]},
 }
 
+# what objectives/ ships: the four values of GBDTParam.objective
+OBJECTIVES = ("lambdarank", "logistic", "softmax", "squared")
+
 MANIFEST = {
     "workloads": [],
     "configs": [],

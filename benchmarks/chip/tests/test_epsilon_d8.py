@@ -46,12 +46,14 @@ def test_the_configuration_is_epsilon_at_depth_8():
     plan = hist_pallas.hist_kernel_plan(None, deep["num_feature"],
                                         deep["max_depth"], deep["num_bins"])
     assert plan["level_node_blocks"] == "1,1,1,1,1,1,1,2"
-    assert plan["feature_blocks"] == 16
+    # blocks of hist_feature_block's width that divide the table
+    width = hist_pallas.hist_feature_block(deep["num_feature"])
+    assert width == 80 and plan["feature_blocks"] == 25
     assert plan["bin_split"].endswith("6x48,4x64,2x128,2x128")
     # the check's histogram case: 128 nodes, 4 node blocks of the same call
     assert hist_pallas.hist_block_plan(
         2 ** (deep["max_depth"] - 1), deep["num_feature"],
-        deep["num_bins"]) == (32, 128)
+        deep["num_bins"]) == (32, width)
     assert deep["check"]["hist_rows"] % hist_pallas.BLOCK_ROWS == 0
 
 

@@ -67,7 +67,8 @@ CELL = {"name": "r.fit", "kind": "fit", "chips": 1, "rounds_per_fit": 2}
 def test_fit_cell_on_a_wide_table_walks_every_check(blocked, tmp_path):
     cfg = rehearsal.config(**WIDE)
     assert hist_pallas.hist_block_plan(
-        2 ** (cfg["max_depth"] - 1), 260, cfg["num_bins"]) == (4, 128)
+        2 ** (cfg["max_depth"] - 1), 260, cfg["num_bins"]) == (
+            4, hist_pallas.hist_feature_block(260)) == (4, 88)
     result, lines = rehearsal.run(CELL, cfg, tmp_path, jax.devices()[:1])
     assert result["correct"], lines
     assert result["failed"] == 0 and result["attempted"] >= 1

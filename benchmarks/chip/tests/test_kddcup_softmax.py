@@ -21,24 +21,39 @@ SOFTMAX = "%multiply_maximum_fusion.9 = (f32[23,8], f32[23,8]) fusion(%p)"
 CLASSES = {"max_depth": 2, "model": {"num_class": 23}}
 
 
-def _evidence(tmp_path, monkeypatch, ops, config):
+def dispatch(**args):
+    return {"name": "gbdt.fit.dispatch", "ph": "X", "ts": 0, "dur": 900,
+            "args": {"rounds": 2, "num_class": 23, **args}}
+
+
+def _evidence(tmp_path, monkeypatch, ops, config, spans=(dispatch(),)):
     monkeypatch.setitem(made.TF_OPS, SOFTMAX,
                         "jit(fit)/while/body/closed_call/gbdt.softmax/max:")
     said = []
     evidence = dict(made._evidence(tmp_path, [made._chip(0, ops)]),
-                    say=said.append)
+                    say=said.append, spans=list(spans))
     evidence["config"] = config
     return evidence, said
 
 
-def test_the_reader_reads_a_boosting_round(tmp_path, monkeypatch):
-    """``phase_ms`` divides by trees (Mosaic calls / max_depth); a boosting
-    round of 23 classes grows 23 of them: 3 ms of ``gbdt.softmax`` in every
-    made-up tree are 69 ms a boosting round."""
+@pytest.mark.parametrize("trees_per_call, per_boosting_round", [
+    (None, 3.0 * 23), (1, 3.0 * 23), (23, 3.0)])
+def test_the_reader_reads_a_boosting_round(tmp_path, monkeypatch,
+                                           trees_per_call,
+                                           per_boosting_round):
+    """``phase_ms`` divides by Mosaic calls / max_depth: the rounds of
+    ``trees_per_call`` trees the calls build.  Where a call builds one
+    tree (a span that says 1, or says nothing), 3 ms of ``gbdt.softmax``
+    a made-up round are 3 ms a tree and 69 ms a boosting round of 23;
+    where a call builds a level of all 23 trees, they are a boosting
+    round's 3 ms."""
+    args = {} if trees_per_call is None else {
+        "trees_per_call": trees_per_call}
     tree = [(SOFTMAX, 3.0)] + made.ROUND
-    evidence, said = _evidence(tmp_path, monkeypatch, tree + tree, CLASSES)
+    evidence, said = _evidence(tmp_path, monkeypatch, tree + tree, CLASSES,
+                               [dispatch(**args)] * 2)
     assert softmax_grad_ms_per_round.reduce(evidence) == pytest.approx(
-        3.0 * 23)
+        per_boosting_round)
     assert not said
     # the scope is no part of the per-tree gradient's reader
     assert leaf_grad_ms_per_round.reduce(evidence) == pytest.approx(2.0)
@@ -60,6 +75,19 @@ def test_the_reader_returns_nothing_without_the_scope_and_says_why(
     assert softmax_grad_ms_per_round.reduce(
         {"trace": trace, "xplane": None, "config": CLASSES,
          "say": said.append}) is None
+
+
+@pytest.mark.parametrize("spans, why", [
+    (None, "no span buffer"), ([], "no gbdt.fit.dispatch span"),
+    ([dispatch(trees_per_call=1), dispatch(trees_per_call=23)],
+     "carries no one trees_per_call")])
+def test_the_reader_does_not_guess_the_trees_a_call_builds(
+        tmp_path, monkeypatch, spans, why):
+    tree = [(SOFTMAX, 3.0)] + made.ROUND
+    evidence, said = _evidence(tmp_path, monkeypatch, tree, CLASSES)
+    evidence["spans"] = spans
+    assert softmax_grad_ms_per_round.reduce(evidence) is None
+    assert len(said) == 1 and why in said[0], said
 
 
 def test_the_reader_is_the_manifests_entry():

@@ -275,15 +275,15 @@ def test_a_program_without_the_names_gives_nothing_and_says_why(tmp_path):
     assert "no .xplane.pb" in said[-1]
 
 
-def test_the_manifest_lists_the_three_readers_for_the_six_fit_cells():
+def test_the_manifest_lists_the_three_readers_for_every_fit_cell():
     manifest = harness.load_manifest()
     listed = {m["name"]: m for m in manifest["per_layer"]}
-    # appended, so that nothing that was there moved
-    assert [m["name"] for m in manifest["per_layer"]][-3:] == [
-        r.NAME for r in READERS]
+    fit_cells = [w["name"] for w in manifest["workloads"]
+                 if w["traffic"] == "fit"]
+    assert set(FIT_CELLS) <= set(fit_cells)
     for reader in READERS:
         entry = listed[reader.NAME]
-        assert entry["workloads"] == FIT_CELLS
+        assert entry["workloads"] == fit_cells
         assert entry["source"] == "device_trace"
         assert entry["better"] == "lower"
         assert (entry["unit"], entry["layer"], entry["moves"]) == (

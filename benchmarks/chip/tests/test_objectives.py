@@ -1,5 +1,5 @@
 """A configuration's objective arrives as ``objectives/<objective>.py``:
-the three that ship run through the unchanged ``fit`` kind on the CPU
+the per-row ones that ship run through the unchanged ``fit`` kind on the CPU
 (Pallas kernel in interpret mode), each check fails what it should, and
 ``logistic`` is to the bit what the harness did before objectives were
 modules."""
@@ -23,6 +23,9 @@ CONFIGS = {"logistic": {},
 # the logistic gradient, softmax's classes as one-hot columns under squared
 # error's
 OTHER = {"squared": "logistic", "softmax": "squared"}
+# the objectives whose gradient of a row is the row's own (lambdarank's is
+# its query's: tests/test_mslr_rank.py holds it)
+PER_ROW = tuple(CONFIGS)
 
 
 @pytest.fixture()
@@ -36,24 +39,28 @@ def _config(name):
 
 # -- the contract -----------------------------------------------------------
 
-def test_the_folder_holds_the_three_objectives_of_the_program():
+def test_the_folder_holds_the_four_objectives_of_the_program():
     from dmlc_core_tpu.models.gbdt import GBDTParam
 
-    assert objectives.names() == ["logistic", "softmax", "squared"]
+    assert set(objectives.names()) == set(rehearsal.OBJECTIVES)
     for name in objectives.names():
         GBDTParam(objective=name, num_class=3)      # the program knows it
         mod = objectives.load(name)
-        assert mod.__doc__ and "loss = " in mod.__doc__
+        assert mod.__doc__
         for given in ("LOSS", "latents", "label", "grad_hess", "loss",
                       "learned_nothing", "sample", "fit_args"):
             assert hasattr(mod, given), (name, given)
-        assert mod.sample(77) == 77 and mod.fit_args() == {}
+        if name in PER_ROW:
+            # a per-row objective: its loss in its docstring, any prefix a
+            # sample, nothing beyond bins, label and weight for fit_binned
+            assert "loss = " in mod.__doc__
+            assert mod.sample(77) == 77 and mod.fit_args() == {}
 
 
 def test_an_unknown_objective_raises_and_names_the_folder():
     says = (r"objective 'hinge' has no module "
             r"benchmarks/chip/objectives/hinge\.py \(the folder has: "
-            r"\['logistic', 'softmax', 'squared'\]\)")
+            r"\['lambdarank', 'logistic', 'softmax', 'squared'\]\)")
     with pytest.raises(ValueError, match=says):
         objectives.load("hinge")
     with pytest.raises(ValueError, match=says):

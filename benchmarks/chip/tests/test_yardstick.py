@@ -61,13 +61,38 @@ def test_fit_layer_metrics_on_the_recorded_trace(small_fit_trace):
     assert per_level == pytest.approx(2.67 / 12, rel=0.01)
     rest = fit_nonhist_ms_per_round.reduce(evidence)
     assert rest == pytest.approx((4.12 - 2.67) / 2, rel=0.02)
-    share = hist_roofline.reduce(evidence)
-    # one child of every pair below the root is built by summation
-    least_ms = sum(2 * 2 * (1 if d == 0 else 2 ** (d - 1))
-                   * 32768 * 28 * 256 / 197e12 for d in range(6)) * 1e3
-    assert share == pytest.approx(100 * least_ms / (2.67 / 2), rel=0.01)
+    # a program from before the kernel named its levels: no whole round
+    # to read the kernel's time from, and the reader says so
+    said = []
+    assert hist_roofline.reduce({**evidence, "say": said.append,
+                                 "spans": []}) is None
+    assert said == ["hist_roofline: no gbdt.fit.dispatch span"]
     # one chip: no all-reduce in the trace, so nothing to read
     assert allreduce_exposed_ms_per_round.reduce(evidence) is None
+
+
+def test_hist_roofline_on_a_recorded_trace_of_the_program_that_names_its_levels():
+    """Two fits of 2 rounds, depth 6, 32,768 x 28 rows, traced on a TPU v5
+    lite: one child of every pair below the root built by summation, over
+    the whole rounds' kernel time (0.26782675 ms a round)."""
+    from benchmarks.chip.layer_metrics import hist_roofline
+
+    path = os.path.join(TESTDATA, "smallfit_levels.xplane.pb.gz")
+    names = [f"hist_level_L{d}_n{n}" for d, n in enumerate((1, 1, 2, 4, 8,
+                                                             16))]
+    evidence = {"trace": tracereduce.from_profile(
+                    tracereduce.read_profile(path)),
+                "device_kind": "TPU v5 lite",
+                "config": {"max_depth": 6, "num_feature": 28,
+                           "num_bins": 256},
+                "state": {"rows": 32768},
+                "spans": [_dispatch(names, 1)] * 2}
+    # at this small size the sibling's bytes bind some levels
+    least_ms = 1e3 * sum(roofline.least_seconds(f, b, "TPU v5 lite")[0]
+                         for f, b in roofline.hist_round_work(32768, 28, 256,
+                                                              6))
+    assert hist_roofline.reduce(evidence) == pytest.approx(
+        100 * least_ms / 0.26782675, rel=1e-6)
 
 
 def test_parse_op_reads_name_and_opcode_from_hlo_text():
@@ -143,42 +168,93 @@ def test_round_work_builds_one_child_of_every_pair():
     assert roofline.hist_round_work(1000, 28, 256, 1) == [round_[0]]
 
 
-def _kernel_trace(chips, call_seconds):
-    """Every chip runs the same kernel calls, 1 ms of other work apart."""
+def _dispatch(names, trees):
+    return {"name": "gbdt.fit.dispatch", "ph": "X", "ts": 0, "dur": 900,
+            "args": {"level_kernels": ",".join(names),
+                     "trees_per_call": trees}}
+
+
+def _kernel_trace(chips, names, call_seconds, head=(), tail=()):
+    """Every chip runs the same named kernel calls, 1 ms of other work
+    apart: ``call_seconds`` a round's levels in order, after the calls
+    ``head`` and before ``tail`` (``(level, seconds)``: the stubs of the
+    programs a trace's edges cut)."""
+    calls = list(head) + [(d % len(names), dur_s) for d, dur_s in
+                          enumerate(call_seconds)] + list(tail)
     ops, t = [], 0.0
-    for n, dur_s in enumerate(call_seconds):
+    for n, (level, dur_s) in enumerate(calls):
         ops.append(tracereduce.parse_op(
-            f"%hist_level.{n} = f32[8,128] custom-call(), "
+            f"%{names[level]}.{n} = f32[8,128] custom-call(), "
             f"{tracereduce.MOSAIC_MARK}", t * 1e9, dur_s * 1e9))
         t += dur_s + 1e-3
     return tracereduce.Trace([tracereduce.ChipTrace(c, ops, [], [])
                               for c in range(chips)])
 
 
+NAMES = [f"hist_level_L{d}_n{n}" for d, n in enumerate((1, 1, 2, 4, 8, 16))]
+
+
+@pytest.mark.parametrize("trees", [1, 23])
 @pytest.mark.parametrize("chips,rows,num_feature", [
     (1, 11_000_000, 28), (4, 4 * 16_777_216, 13)])
 def test_hist_roofline_reads_100_at_the_least_times(chips, rows,
-                                                    num_feature):
-    """Two rounds whose six calls take exactly the per-level least times
-    of the chip's share of the rows (airline's one-node levels by memory,
-    every other by compute): the share is 100, on one chip and on four."""
+                                                    num_feature, trees):
+    """Two rounds whose six calls, each building ``trees`` trees' level,
+    take exactly ``trees`` times the per-level least times of the chip's
+    share of the rows (airline's one-node levels by memory, every other by
+    compute): the share is 100, on one chip and on four."""
     from benchmarks.chip.layer_metrics import hist_roofline
 
     least = [roofline.least_seconds(f, b, "TPU v5 lite") for f, b in
              roofline.hist_round_work(rows // chips, num_feature, 256, 6)]
     assert {w for _, w in least} == (
         {"compute"} if num_feature == 28 else {"compute", "memory"})
-    calls = [s for s, _ in least] * 2
-    evidence = {"trace": _kernel_trace(chips, calls),
+    calls = [trees * s for s, _ in least] * 2
+    evidence = {"trace": _kernel_trace(chips, NAMES, calls),
                 "device_kind": "TPU v5 lite",
                 "config": {"max_depth": 6, "num_feature": num_feature,
                            "num_bins": 256},
-                "state": {"rows": rows}}
+                "state": {"rows": rows},
+                "spans": [_dispatch(NAMES, trees)] * 3}
     assert hist_roofline.reduce(evidence) == pytest.approx(100.0)
     # a kernel that builds every node at the same peaks reads about half
-    slow = _kernel_trace(chips, [s * 63 / 32 for s in calls])
+    slow = _kernel_trace(chips, NAMES, [s * 63 / 32 for s in calls])
     assert hist_roofline.reduce({**evidence, "trace": slow}) == \
         pytest.approx(100 * 32 / 63)
+    # stubs at the trace's edges: the end of a round cut at the start, the
+    # first levels of one cut at the end, each call cut short
+    cut = _kernel_trace(chips, NAMES, calls,
+                        head=[(4, 0.3 * calls[4]), (5, calls[5])],
+                        tail=[(0, calls[0]), (1, 0.1 * calls[1])])
+    assert hist_roofline.reduce({**evidence, "trace": cut}) == \
+        pytest.approx(100.0)
+
+
+def test_hist_roofline_says_why_it_reads_nothing():
+    from benchmarks.chip.layer_metrics import hist_roofline
+
+    calls = [1e-3] * 12
+    evidence = {"trace": _kernel_trace(1, NAMES, calls),
+                "device_kind": "TPU v5 lite",
+                "config": {"max_depth": 6, "num_feature": 28,
+                           "num_bins": 256},
+                "state": {"rows": 1_000_000}}
+    for spans, why in (
+            (None, "no span buffer"),
+            ([_dispatch(NAMES[:5], 1)], "has 5 levels, max_depth is 6"),
+            ([_dispatch(NAMES, 1), _dispatch(NAMES, 23)],
+             "carries no one trees_per_call"),
+            ([_dispatch(["hist_level"] * 6, 1)], "traced no whole round")):
+        said = []
+        assert hist_roofline.reduce({**evidence, "spans": spans,
+                                     "say": said.append}) is None
+        assert len(said) == 1 and why in said[0], said
+        assert said[0].startswith("hist_roofline: ")
+    # a span from before trees_per_call: one tree a call
+    old = {**_dispatch(NAMES, 1), "args": {"level_kernels": ",".join(NAMES)}}
+    assert hist_roofline.reduce({**evidence, "spans": [old]}) == \
+        pytest.approx(hist_roofline.reduce(
+            {**evidence, "spans": [_dispatch(NAMES, 1)]}))
 
 
 def test_reference_histogram_by_hand():

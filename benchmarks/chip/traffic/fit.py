@@ -267,9 +267,11 @@ def check(ctx, state, window):
            f"sample's (band {band})")
 
     if state["mesh"] is not None:
+        # the fit the window times, with what its objective takes beyond
+        # bins, label and weight (a listwise objective's group column)
         with state["mesh"]:
             hlo = model._fit_fn(state["rounds"], state["method"]).lower(
-                bins, label, weight).compile().as_text()
+                bins, label, weight, **state["fit_args"]).compile().as_text()
         full = f"[{bins.shape[0]},{config['num_feature']}]"
         shard_rows = {s.data.shape[0] for s in bins.addressable_shards}
         yield (all(mark in hlo for mark in spec["hlo_has"])
